@@ -75,6 +75,15 @@ def collect(node) -> str:
             out.append(f'{fam}_bucket{{le="{_fmt_le(bound)}"}} {c}')
         out.append(f"{fam}_sum {h.sum}")
         out.append(f"{fam}_count {cum[-1][1]}")
+    dev = getattr(node, "device_info", None)
+    if dev:
+        # the platform the device route path is bound to, as an info
+        # series (value 1, identity in the labels)
+        declare("emqx_pipeline_device_info", "gauge")
+        out.append('emqx_pipeline_device_info{platform="%s",'
+                   'device_kind="%s",count="%d"} 1'
+                   % (_lbl(dev["platform"]), _lbl(dev["device_kind"]),
+                      dev["count"]))
     ru = resource.getrusage(resource.RUSAGE_SELF)
     emit("emqx_vm_used_memory_kb", ru.ru_maxrss, "gauge",
          "resident set size")

@@ -70,7 +70,9 @@ class SysBroker:
         snapshot, piecewise: one JSON payload per stage
         (`pipeline/stages/<stage>`), per occupancy class
         (`pipeline/occupancy/<class>`), plus `pipeline/compiles`,
-        `pipeline/decisions` and — when the relevant layer has traffic —
+        `pipeline/decisions`, `pipeline/device` (the platform /
+        device_kind / count the route path is bound to) and — when the
+        relevant layer has traffic —
         `pipeline/match_cache` / `pipeline/dedup` / `pipeline/readback`
         (dense-vs-compact device→host transfer bytes, ISSUE 3) /
         `pipeline/rebuild` / `pipeline/deliver` (delivery-lane egress
@@ -104,9 +106,9 @@ class SysBroker:
                   json.dumps(snap["compiles"]).encode())
         self._pub("pipeline/decisions",
                   json.dumps(snap["decisions"]).encode())
-        for section in ("match_cache", "dedup", "readback", "rebuild",
-                        "deliver", "supervise", "trace", "ingress",
-                        "memory", "program_costs", "latency",
+        for section in ("device", "match_cache", "dedup", "readback",
+                        "rebuild", "deliver", "supervise", "trace",
+                        "ingress", "memory", "program_costs", "latency",
                         "overload"):
             if section in snap:
                 self._pub(f"pipeline/{section}",

@@ -30,7 +30,7 @@ pipeline stage:
   baseline) consuming per-lane queues. The batcher's consume stage
   submits the plan and returns, so delivery overlaps the next window's
   dispatch/materialize (which run on executor threads and release the
-  GIL in XLA / the relay HTTP client); `admit()` bounds outstanding
+  GIL in XLA); `admit()` bounds outstanding
   plans and propagates backpressure to the batcher's `_inflight` queue,
   and `drain()` serializes host-routed batches behind in-flight lane
   work so device/host interleaving cannot reorder a session's stream.
@@ -482,8 +482,8 @@ class DeliveryLanePool:
     thread workers would need a lock per session; loop tasks keep the
     single-writer discipline for free, and the OVERLAP the stage buys
     is with the device dispatch/materialize stages, which run on
-    executor threads and release the GIL inside XLA / the relay HTTP
-    round trip. The lanes also amortize per-row Python: one view object
+    executor threads and release the GIL inside XLA. The lanes also
+    amortize per-row Python: one view object
     instead of a Message copy, coalesced same-session drains, and
     per-slice (not per-row) metric/hook bookkeeping.
     """

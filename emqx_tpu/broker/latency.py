@@ -6,10 +6,8 @@ experiences from socket read to delivery write, decomposed by path —
 the end-to-end percentile framing the IoT broker benchmarking study
 (arXiv:2603.21600, PAPERS.md) compares brokers on, and the number the
 north star's **p99 < 2ms PUBLISH→route** criterion is judged against.
-The only tail number ever committed before this (BENCH_r02's 194ms sync
-p99) is window-granularity and contaminated by relay HTTP dispatch
-overhead; this module measures per message and starts the clock at
-frame decode, before any relay is involved.
+bench.py's sync p99 is window-granularity; this module measures per
+message and starts the clock at frame decode.
 
 Mechanics:
 

@@ -23,6 +23,23 @@ from emqx_tpu.broker.pubsub import Broker
 from emqx_tpu.broker.router import Router
 
 
+def _bind_device() -> dict:
+    """Name the JAX device the route programs will run on."""
+    import logging
+
+    import jax
+    devs = jax.devices()
+    info = {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind, "count": len(devs)}
+    log = logging.getLogger("emqx.device")
+    if info["platform"] == "cpu":
+        log.warning("device route path bound to the CPU backend "
+                    "(JAX found no accelerator): %s", info)
+    else:
+        log.info("device route path bound to %s", info)
+    return info
+
+
 class Node:
     def __init__(self, config: Optional[dict] = None, *,
                  use_device: Optional[bool] = None,
@@ -38,7 +55,6 @@ class Node:
         perf = self.config.get("broker") or {}
         if use_device is None:
             # default-on: the fused device route step IS the serving path
-            # wherever a jax device exists (real TPU or the CPU backend)
             use_device = bool(perf.get("device_route", True))
         from emqx_tpu.broker.telemetry import PipelineTelemetry
         slow_ms = perf.get("slow_batch_threshold_ms", 250)
@@ -86,6 +102,14 @@ class Node:
                                          False))
         self.device_engine = None
         self.publish_batcher = None
+        # the platform the device route path is bound to. JAX falls back
+        # to its CPU backend when it finds no accelerator, so the node
+        # names what it got: logged here, exported in the telemetry
+        # snapshot and the engine stats, asserted by chip_smoke.py.
+        self.device_info = None
+        if use_device or (perf.get("multichip") or {}).get("enable"):
+            self.device_info = _bind_device()
+            self.pipeline_telemetry.device_info = self.device_info
         # window-causal flight recorder (ISSUE 7): trace ids minted at
         # batcher admit ride the whole pipeline (dispatch/materialize/
         # replay/lanes/settle) into a bounded span ring — always on at

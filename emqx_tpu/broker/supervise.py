@@ -712,8 +712,8 @@ class PipelineSupervisor:
     def deadline(self, stage: str) -> float:
         """Stall deadline for one stage await: clamp(mult * p99, floor,
         cap) off the PR-1 stage histogram — a stage may legitimately be
-        slow (relay round trips), so the deadline adapts to measured
-        behavior instead of hardcoding an SLA. The lane domain's time
+        slow, so the deadline adapts to measured behavior instead of
+        hardcoding an SLA. The lane domain's time
         lands in the per-lane ``deliver_lane{i}`` histograms (there is
         no single ``lane_deliver`` stage), so its deadline tracks the
         SLOWEST lane's p99."""

@@ -9,9 +9,9 @@ for every pipeline stage —
     enqueue      oldest-message wait in the submit queue before its batch
                  forms (broker/batcher._produce)
     batch_form   message.publish hook fold + live-filter per batch
-    dispatch     the jitted route step, executor-thread wall time (on a
-                 dispatch relay this is the HTTP round trip; match +
-                 fan-out + shared picks all run inside it on device)
+    dispatch     the jitted route step, executor-thread wall time
+                 (match + fan-out + shared picks all run inside it on
+                 device)
     dispatch_cached  same span for deduplicated / match-cache-backed
                  dispatches (route_*_cached) — the cached-vs-uncached
                  match latency split falls straight out of comparing the
@@ -78,10 +78,14 @@ _listener_installed = False
 _install_lock = threading.Lock()
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+# fires once per executable XLA had to build (or load from the persistent
+# cache) — unlike the trace event, which also fires for every nested jit
+# of one program and for a bare fast-path miss that re-uses a trace
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _COMPILE_EVENTS = (
     _TRACE_EVENT,
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
+    _BACKEND_EVENT,
 )
 
 
@@ -98,7 +102,8 @@ def _on_jax_event(name: str, dur: float, **_kw) -> None:
     if ctx is None:
         return
     tele, shape = ctx
-    tele._note_compile_event(shape, dur, is_trace=(name == _TRACE_EVENT))
+    tele._note_compile_event(shape, dur, is_trace=(name == _TRACE_EVENT),
+                             is_backend=(name == _BACKEND_EVENT))
 
 
 def thread_compile_seq() -> "int | None":
@@ -174,6 +179,10 @@ class PipelineTelemetry:
         # rates, breach exemplars — from it. None restores the
         # pre-ISSUE-13 schema exactly.
         self.observatory = None
+        # the platform / device_kind / count the node bound its device
+        # route path to (set by the node; None on host-only nodes):
+        # snapshot() carries it as the `device` section
+        self.device_info = None
         # slow-batch watch: a total span beyond this fires the
         # `batch.slow` hook (apps/tracer writes the log line) and counts
         # pipeline.slow_batches. None disables.
@@ -218,11 +227,13 @@ class PipelineTelemetry:
 
     # ---- rebuild stages (ISSUE 4) ---------------------------------------
     # capture/build/warm/swap spans of the snapshot rebuild machinery
-    # plus delta_apply (overlay refresh) — rebuilds used to be invisible
+    # (upload is the device_put slice inside build) plus delta_apply
+    # (overlay refresh) — rebuilds used to be invisible
     # beyond a bare routing.device.rebuilds counter; these histograms
     # ride the registry so all four exporters carry them, and snapshot()
     # derives the `rebuild` section from them.
-    REBUILD_STAGES = ("capture", "build", "warm", "swap", "delta_apply")
+    REBUILD_STAGES = ("capture", "build", "upload", "warm", "swap",
+                      "delta_apply")
 
     def observe_rebuild(self, stage: str, seconds: float) -> None:
         self.metrics.hist(f"pipeline.rebuild.{stage}.seconds",
@@ -286,15 +297,18 @@ class PipelineTelemetry:
             _tls.ctx = prev
 
     def _note_compile_event(self, shape: str, dur: float,
-                            is_trace: bool) -> None:
+                            is_trace: bool, is_backend: bool = False
+                            ) -> None:
         with self._compiles_lock:
             row = self.compiles_by_shape.setdefault(
-                shape, {"count": 0, "total_s": 0.0})
+                shape, {"count": 0, "executables": 0, "total_s": 0.0})
             row["total_s"] += dur
             self.compile_s += dur
             if is_trace:
                 row["count"] += 1
                 self.compiles += 1
+            if is_backend:
+                row["executables"] += 1
         if is_trace:
             self.metrics.inc("pipeline.jit.compiles")
         self.metrics.hist("pipeline.jit.compile.seconds",
@@ -365,6 +379,7 @@ class PipelineTelemetry:
                 }
         with self._compiles_lock:
             by_shape = {k: {"count": v["count"],
+                            "executables": v["executables"],
                             "total_s": round(v["total_s"], 4)}
                         for k, v in self.compiles_by_shape.items()}
             compiles = {"count": self.compiles,
@@ -476,6 +491,7 @@ class PipelineTelemetry:
         for k in ("routing.device.rebuilds",
                   "routing.device.compactions",
                   "routing.device.rebuild_failed",
+                  "routing.device.warm_failed",
                   "routing.device.delta_applies",
                   "routing.device.host_delta",
                   "routing.device.cold_delta_class",
@@ -615,6 +631,8 @@ class PipelineTelemetry:
             "compiles": compiles,
             "decisions": decisions,
         }
+        if self.device_info is not None:
+            out["device"] = dict(self.device_info)
         if supervise or full:
             out["supervise"] = supervise
         if rebuild or full:

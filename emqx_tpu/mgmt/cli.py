@@ -84,7 +84,7 @@ class Cli:
         """emqx_ctl trace analog, plus the device-side jax.profiler trace
         (SURVEY §5.1): `trace device start <dir>` annotates every route
         dispatch as a profiler step so device execution decomposes from
-        host/relay time in the captured trace."""
+        host time in the captured trace."""
         if not args:
             raise _Usage()
         if args[0] == "device":

@@ -46,13 +46,15 @@ def _load() -> Optional[ctypes.CDLL]:
             subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
                            capture_output=True, timeout=120)
         except (subprocess.SubprocessError, OSError) as e:
-            log.info("native build unavailable: %s", e)
+            log.warning("native build failed, serving the pure-Python "
+                        "codec: %s", e)
     if not os.path.exists(_LIB_PATH):
         return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError as e:
-        log.info("native load failed: %s", e)
+        log.warning("native load failed, serving the pure-Python "
+                    "codec: %s", e)
         return None
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u32p = ctypes.POINTER(ctypes.c_uint32)

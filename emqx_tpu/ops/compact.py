@@ -180,9 +180,8 @@ def compact_planes_jit(matches, rows, opts, fan_counts, shared_sids,
     """Standalone jitted compaction over [B, R, ...] mesh planes.
 
     The mesh readback (parallel/serving.py) compacts as a SECOND small
-    dispatch — acceptable on co-located devices where the launch cost is
-    microseconds, unlike the relay path where compaction must ride
-    inside the route program (models/router_engine.route_*_compact).
+    dispatch (the single-chip engine fuses compaction into the route
+    program instead, models/router_engine.route_*_compact).
     Planes are reshaped to one [1, B*R] pseudo-window so the same op and
     the same host-side decode serve both engines; lane index = i*R + r.
     """

@@ -90,10 +90,11 @@ def _fold_kernel(L: int, NB: int, NSc: int, BL: int,
 def shape_fold_pallas(topics: jax.Array, lens: jax.Array,
                       is_dollar: jax.Array, spm: jax.Array,
                       slen: jax.Array, shh: jax.Array, swr: jax.Array,
-                      *, L: int, NB: int, interpret: bool = None):
-    """Fused fold: -> (h1, h2, b1, b2, compat) each [B, NSc] int32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                      *, L: int, NB: int, interpret: bool = False):
+    """Fused fold: -> (h1, h2, b1, b2, compat) each [B, NSc] int32.
+
+    `interpret=True` runs the kernel in the Pallas interpreter (tests on
+    a backend without Mosaic pass it); the kernel never infers it."""
     B = topics.shape[0]
     NSc = spm.shape[0]
     # lanes shrink for small batches (min native tile 8x128) so a 257-row

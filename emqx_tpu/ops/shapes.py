@@ -506,14 +506,16 @@ def fold_backend_effective() -> bool:
     return _FOLD_BACKEND_EFFECTIVE
 
 
-def _fold_pallas(st: ShapeTables, topics, lens, is_dollar):
+def _fold_pallas(st: ShapeTables, topics, lens, is_dollar,
+                 interpret: bool = False):
     """The pallas fold with shape_match's calling convention (shared by
     the env-selected serving path and the benchmarked pallas entry)."""
     from emqx_tpu.ops.pallas_fold import shape_fold_pallas
     return shape_fold_pallas(
         topics, lens.astype(jnp.int32), is_dollar,
         st.shape_plus_mask, st.shape_len, st.shape_has_hash,
-        st.shape_wild_root, L=topics.shape[1], NB=st.buckets.shape[0])
+        st.shape_wild_root, L=topics.shape[1], NB=st.buckets.shape[0],
+        interpret=interpret)
 
 
 @jax.jit
@@ -548,12 +550,14 @@ def _cover_expand_maybe(st: ShapeTables, mr: MatchResult, topics, lens,
     return cover_expand(st.cover, mr, topics, lens, is_dollar)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def shape_match_pallas(st: ShapeTables, topics: jax.Array,
-                       lens: jax.Array,
-                       is_dollar: jax.Array) -> MatchResult:
+                       lens: jax.Array, is_dollar: jax.Array, *,
+                       interpret: bool = False) -> MatchResult:
     """shape_match with the fold stage as a fused Pallas kernel
-    (ops/pallas_fold.py); bit-identical results by construction."""
-    h1, h2, b1, b2, compat = _fold_pallas(st, topics, lens, is_dollar)
+    (ops/pallas_fold.py); bit-identical results by construction.
+    `interpret=True` is for tests on a backend without Mosaic."""
+    h1, h2, b1, b2, compat = _fold_pallas(st, topics, lens, is_dollar,
+                                          interpret)
     mr = _probe_buckets(st, h1, h2, b1, b2, compat)
     return _cover_expand_maybe(st, mr, topics, lens, is_dollar)

@@ -203,8 +203,7 @@ class ShardedRouteServer:
 
         # CSR readback compaction (ISSUE 3), mesh edition: unlike the
         # single-chip engine the compaction is a SECOND small jitted
-        # call in materialize (the mesh is co-located — launch cost is
-        # microseconds, not a relay round trip), run over the stacked
+        # call in materialize, run over the stacked
         # [B, R, ...] planes reshaped to one [1, B*R] pseudo-window.
         # Payload classes are (Bp, P) keyed — independent of the
         # capacity classes, so they survive rebuilds — warmed by the
@@ -262,8 +261,8 @@ class ShardedRouteServer:
         # match, compact each shard's delivery rows to CSR segments
         # keyed by owning delivery shard (sid % route — the PR 5
         # session-affinity discipline) and ring-exchange them
-        # device-to-device (ops.pallas_exchange: remote-DMA kernel on
-        # TPU, ppermute twin elsewhere), so materialize lands ONLY the
+        # device-to-device (parallel.sharded.ring_rotate, a
+        # collective-permute), so materialize lands ONLY the
         # per-dest final delivery plans instead of the gathered result
         # set. broker.device_exchange / EMQX_TPU_EXCHANGE =0 restores
         # host gather/merge exactly. Segment capacity classes (E) ride
@@ -827,7 +826,7 @@ class ShardedRouteServer:
         if self._builts is None:
             return
 
-        def warm():
+        def warm_all():
             # loop until every class is warm for the CURRENT capacity
             # signature: a caps-changing rebuild mid-loop clears earlier
             # classes, and a single ascending pass would never revisit
@@ -862,6 +861,16 @@ class ShardedRouteServer:
                 if not missing and not want_c and not want_e:
                     return
                 self._warm_one((missing + want_c + want_e)[0])
+
+        def warm():
+            try:
+                warm_all()
+            except Exception:  # noqa: BLE001 — classes stay cold, retry
+                import logging
+                logging.getLogger("emqx.device").exception(
+                    "mesh class warm-compile failed; affected classes "
+                    "stay host-routed until the next attempt")
+                self.node.metrics.inc("routing.device.warm_failed")
 
         self._warm_thread = threading.Thread(target=warm, daemon=True)
         self._warm_thread.start()
@@ -1539,6 +1548,10 @@ class ShardedRouteServer:
             # exchange windows skip the occur plane: clean-proof means
             # no shared-slot occurrences, so there is nothing to mirror
             self._writeback_cursors(np_res["occur"], h.built)
+        # one consumed device batch — the same counter the single-chip
+        # engine's finish_sub keeps, so "did the device serve" reads the
+        # same on both engines
+        self.node.metrics.inc("routing.device.batches")
         if plan is not None:
             out = LaneCounts(counts)
             out.plan = plan
@@ -2046,6 +2059,7 @@ class ShardedRouteServer:
 
     def stats(self) -> dict:
         return {
+            **(getattr(self.node, "device_info", None) or {}),
             "built": self._builts is not None,
             "mesh": {"dp": self.n_dp, "route": self.n_route},
             "filters": sum(len(b.fid_filter) for b in self._builts or ()),

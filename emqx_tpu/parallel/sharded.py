@@ -28,7 +28,6 @@ from emqx_tpu.models.router_engine import (ExchangeResult, RouterTables,
                                            RouteResult)
 from emqx_tpu.ops.fanout import fanout_normal, shared_slots
 from emqx_tpu.ops.match import match_batch
-from emqx_tpu.ops.pallas_exchange import exchange_rotate_impl, ring_rotate
 from emqx_tpu.ops.shapes import shape_match
 from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN, pick_members
 
@@ -191,15 +190,8 @@ def make_sharded_route_step(mesh: Mesh, *, backend: str = "trie",
 
 
 def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax>=0.6 exposes it at top level
-    with check_vma; earlier releases keep it in jax.experimental with
-    the check_rep kwarg (same semantics)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---- device-to-device exchange stage (ISSUE 15) -------------------------
@@ -237,8 +229,17 @@ def exchange_compile_stats() -> dict:
     return out
 
 
-def make_exchange_step(mesh: Mesh, *, seg_cap: int,
-                       impl: "str | None" = None):
+def ring_rotate(block, k: int, axis_name: str, size: int):
+    """Rotate `block` k hops around the `axis_name` ring (inside a
+    shard_map): every participant receives the block held by the
+    participant k positions to its LEFT ((my - k) % size), i.e. each
+    device SENDS to (my + k) % size. XLA lowers the permutation to its
+    collective-permute, device-to-device over the interconnect."""
+    return jax.lax.ppermute(
+        block, axis_name, [(j, (j + k) % size) for j in range(size)])
+
+
+def make_exchange_step(mesh: Mesh, *, seg_cap: int):
     """Build the jitted exchange program for `mesh` ('dp', 'route').
 
     Runs as a SECOND shard_map dispatch over the route step's result
@@ -256,9 +257,9 @@ def make_exchange_step(mesh: Mesh, *, seg_cap: int,
          shard (sid % R — the PR 5 session-affinity discipline) into
          fixed-capacity segments [R, E, 3] with counted overflow;
       3. ring-rotates the segments R-1 rounds over 'route'
-         (ops.pallas_exchange: remote-DMA kernel on TPU, ppermute twin
-         elsewhere) so device (dp, d) ends up holding exactly the rows
-         whose sessions it owns, from every source shard;
+         (ring_rotate: a collective-permute) so device (dp, d) ends up
+         holding exactly the rows whose sessions it owns, from every
+         source shard;
       4. merges the received segments source-major into ONE per-dest
          plan [E, 3] — (src asc, msg asc, row asc), the host gather
          path's exact per-session interleaving.
@@ -278,8 +279,6 @@ def make_exchange_step(mesh: Mesh, *, seg_cap: int,
     from emqx_tpu.ops.compact import _rows_searchsorted
     R = mesh.shape["route"]
     E = int(seg_cap)
-    if impl is None:
-        impl = exchange_rotate_impl()
 
     def local(matches, rows, opts, shared_sids, overflow,
               seg_len, fid_slow, fid_off):
@@ -350,8 +349,7 @@ def make_exchange_step(mesh: Mesh, *, seg_cap: int,
         for k in range(1, R):
             send = jax.lax.dynamic_index_in_dim(
                 seg, jax.lax.rem(my_r + k, R), 0, keepdims=False)
-            got = ring_rotate(send, k, "route", R, impl=impl,
-                              lead_axes=("dp",))
+            got = ring_rotate(send, k, "route", R)
             recv = jax.lax.dynamic_update_index_in_dim(
                 recv, got, jax.lax.rem(my_r - k + R, R), 0)
 

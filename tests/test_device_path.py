@@ -328,7 +328,7 @@ class TestAdaptiveDeviceChoice:
     def test_prefers_cheaper_path_and_reprobes(self):
         from emqx_tpu.broker import batcher as BM
         b, node = self._batcher()
-        b._dev_batch_s = 0.200              # relay-like: 200ms per batch
+        b._dev_batch_s = 0.200              # a slow round trip: 200ms per batch
         b._host_msg_s = 0.0001              # 10k msg/s host
         assert not b._device_worth_it(64)   # 64 * 0.1ms << 200ms
         assert node.metrics.val("routing.device.bypassed") == 1

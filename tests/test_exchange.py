@@ -12,9 +12,8 @@ Acceptance criteria, as tests:
   through the host rung with zero QoS>=1 loss and the breaker
   re-closes; a dead ring (the exchange program itself raising)
   degrades THAT window to host gather without losing it.
-- **Twin-selection tier-1 gate**: ops.pallas_exchange imports on every
-  backend and selects the ppermute twin off-TPU (the Mosaic kernel is
-  exercised by the slow-marked hardware smoke below).
+- **Ring rotation**: parallel.sharded.ring_rotate (a collective-permute
+  on every backend) equals np.roll over the stacked blocks.
 - **Knob**: EMQX_TPU_EXCHANGE / broker.device_exchange=0 leaves no
   exchange aux, no exchange program, no pipeline.exchange.* traffic.
 """
@@ -373,31 +372,18 @@ class TestExchangeChaos:
         assert sup.breakers["mesh_exchange"].state == "open"
 
 
-class TestTwinSelectionGate:
-    """Tier-1 gate: the kernel module must import everywhere and the
-    portable twin must serve non-TPU backends."""
-
-    def test_module_imports_and_selects_twin(self):
-        from emqx_tpu.ops import pallas_exchange as PX
-        assert PX.exchange_rotate_impl("cpu") == "ppermute"
-        assert PX.exchange_rotate_impl("gpu") == "ppermute"
-        assert PX.exchange_rotate_impl("tpu") == "pallas"
-        if jax.default_backend() != "tpu":
-            assert PX.exchange_rotate_impl() == "ppermute"
-
+class TestRingRotate:
     def test_ring_rotate_matches_roll_oracle(self):
-        """The ppermute twin over the 'route' ring == np.roll on the
-        stacked blocks, for every hop count."""
-        from emqx_tpu.ops.pallas_exchange import ring_rotate
+        """ring_rotate over the 'route' ring == np.roll on the stacked
+        blocks, for every hop count."""
         from emqx_tpu.parallel.mesh import make_mesh
-        from emqx_tpu.parallel.sharded import _shard_map
+        from emqx_tpu.parallel.sharded import _shard_map, ring_rotate
         from jax.sharding import PartitionSpec as P
         mesh = make_mesh(8, dp=2, route=4)
         x = np.arange(2 * 4 * 6, dtype=np.int32).reshape(2, 4, 6)
         for k in range(1, 4):
             def local(xs, k=k):
-                return ring_rotate(xs[0, 0], k, "route", 4,
-                                   impl="ppermute")[None, None]
+                return ring_rotate(xs[0, 0], k, "route", 4)[None, None]
 
             fn = jax.jit(_shard_map(local, mesh, (P("dp", "route"),),
                                     P("dp", "route")))
@@ -426,33 +412,6 @@ class TestTwinSelectionGate:
         del fn
         gc.collect()
         assert n_steps() <= base
-
-
-
-@pytest.mark.slow
-class TestPallasKernelTPUSmoke:
-    """Hardware smoke for the real remote-DMA kernel (slow-marked; the
-    CPU tier-1 suite covers the ppermute twin + selection gate)."""
-
-    def test_rotate_on_tpu(self):
-        if jax.default_backend() != "tpu":
-            pytest.skip("needs a real TPU backend")
-        if len(jax.devices()) < 2:
-            pytest.skip("needs >= 2 TPU devices")
-        from emqx_tpu.ops.pallas_exchange import ring_rotate
-        from emqx_tpu.parallel.mesh import make_mesh
-        from emqx_tpu.parallel.sharded import _shard_map
-        from jax.sharding import PartitionSpec as P
-        n = len(jax.devices())
-        mesh = make_mesh(n, dp=1)
-        x = np.arange(n * 128, dtype=np.int32).reshape(n, 128)
-
-        def local(xs):
-            return ring_rotate(xs, 1, "route", n, impl="pallas")
-
-        fn = jax.jit(_shard_map(local, mesh, (P("route"),), P("route")))
-        np.testing.assert_array_equal(np.asarray(fn(x)),
-                                      np.roll(x, 1, axis=0))
 
 
 class TestKnobResolution:

@@ -611,7 +611,8 @@ class TestLatencyReport:
 
     def test_report_renders_and_exits_0(self, tmp_path, capsys):
         import latency_report
-        doc = {"phase0": {"metric": "x", "latency": self._section()},
+        doc = {"cpu_latency0": {"metric": "x",
+                                "latency": self._section()},
                "e2e_host": {"latency": self._section()}}
         path = tmp_path / "bench.json"
         path.write_text(json.dumps(doc))
@@ -624,23 +625,22 @@ class TestLatencyReport:
         """The CI gate: a bench row WITHOUT a latency section cannot
         silently commit a p99-less headline."""
         import latency_report
-        doc = {"phase0": {"metric": "x", "value": 123},
+        doc = {"cpu_latency0": {"metric": "x", "value": 123},
                "e2e_device": {"per_sec": 1}}
         path = tmp_path / "bench.json"
         path.write_text(json.dumps(doc))
         assert latency_report.main([str(path)]) == 2
         assert "NO latency section" in capsys.readouterr().err
 
-    def test_checkpoint_shape_and_require(self, tmp_path, capsys):
+    def test_require_pins_rows(self, tmp_path, capsys):
         import latency_report
-        ck = {"sig": {"subs": 1},
-              "phases": {"phase0": {"latency": self._section()}}}
-        path = tmp_path / "ckpt.json"
-        path.write_text(json.dumps(ck))
+        doc = {"cpu_latency0": {"latency": self._section()}}
+        path = tmp_path / "bench.json"
+        path.write_text(json.dumps(doc))
         assert latency_report.main([str(path)]) == 0
         # --require pins a row the artifact lacks -> gate fires
         assert latency_report.main(
-            ["--require", "phase0,e2e_device", str(path)]) == 2
+            ["--require", "cpu_latency0,e2e_device", str(path)]) == 2
 
     def test_exit_1_on_garbage(self, tmp_path):
         import latency_report

@@ -1,40 +1,20 @@
-"""Tests: Pallas kernels (interpret mode on the CPU mesh; the same code
-compiles via Mosaic on a real TPU — verified on hardware, see bench.py's
-xla-vs-pallas section).
+"""Tests: the Pallas shape-fold kernel, run in the Pallas interpreter
+(`interpret=True`, passed explicitly — the kernel never infers it). On a
+TPU the same code is compiled by Mosaic; `chip_smoke.py` checks that
+build against `shape_match` on the smoke's real tables.
 
-Oracles: numpy cumsum for the prefix scan; ops.shapes.shape_match (whose
-own oracle is utils.topic.match, tests/test_shapes.py) for the fold —
-bit-identical uint32 arithmetic means results must be EQUAL, not close.
+Oracle: ops.shapes.shape_match (whose own oracle is utils.topic.match,
+tests/test_shapes.py) — bit-identical uint32 arithmetic means results
+must be EQUAL, not close.
 """
 
 import numpy as np
-import pytest
 
 import jax
 
 from emqx_tpu.ops import shapes as S
 from emqx_tpu.ops.intern import InternTable, PAD
 from emqx_tpu.ops.match import encode_topics
-from emqx_tpu.ops.pallas_scan import prefix_sum_pallas
-
-
-class TestPrefixSumPallas:
-    @pytest.mark.parametrize("n", [1, 7, 128, 1000, 1024, 5000, 16384])
-    def test_matches_numpy(self, n):
-        rng = np.random.default_rng(n)
-        x = rng.integers(0, 3, n).astype(np.int32)
-        out = np.asarray(prefix_sum_pallas(jax.device_put(x)))
-        np.testing.assert_array_equal(out, np.cumsum(x).astype(np.int32))
-
-    def test_block_boundaries(self):
-        # all-ones across several blocks exercises the SMEM carry
-        x = np.ones(3 * 1024 + 17, np.int32)
-        out = np.asarray(prefix_sum_pallas(jax.device_put(x)))
-        np.testing.assert_array_equal(out, np.arange(1, len(x) + 1))
-
-    def test_rejects_overlong(self):
-        with pytest.raises(ValueError):
-            prefix_sum_pallas(jax.numpy.zeros((1 << 24) + 1, jax.numpy.int32))
 
 
 def _build_fixture(rng, n_filters=800, n_topics=257, L=8):
@@ -82,7 +62,7 @@ class TestShapeFoldPallas:
         st, t, tl, dol = _build_fixture(rng)
         stj = jax.device_put(st)
         r_x = S.shape_match(stj, t, tl, dol)
-        r_p = S.shape_match_pallas(stj, t, tl, dol)
+        r_p = S.shape_match_pallas(stj, t, tl, dol, interpret=True)
         np.testing.assert_array_equal(np.asarray(r_x.matches),
                                       np.asarray(r_p.matches))
         np.testing.assert_array_equal(np.asarray(r_x.counts),
@@ -97,7 +77,7 @@ class TestShapeFoldPallas:
         tl[:5] = 0
         stj = jax.device_put(st)
         r_x = S.shape_match(stj, t, tl, dol)
-        r_p = S.shape_match_pallas(stj, t, tl, dol)
+        r_p = S.shape_match_pallas(stj, t, tl, dol, interpret=True)
         assert (np.asarray(r_x.counts)[:5] == 0).all()
         np.testing.assert_array_equal(np.asarray(r_x.matches),
                                       np.asarray(r_p.matches))

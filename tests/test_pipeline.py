@@ -186,7 +186,7 @@ class TestNonBlocking:
         assert host_routed > 200
 
     def test_dispatch_failure_falls_back_to_host(self):
-        """A relay flake mid-dispatch must not lose the batch: the consumer
+        """A dispatch that raises must not lose the batch: the consumer
         falls back to the host route for the whole batch, in order."""
         node = Node()
         engine = node.device_engine
@@ -196,7 +196,7 @@ class TestNonBlocking:
         def flaky(h):
             calls["n"] += 1
             if calls["n"] == 1:
-                raise RuntimeError("synthetic relay failure")
+                raise RuntimeError("synthetic dispatch failure")
             real_dispatch(h)
 
         b = node.broker
@@ -451,7 +451,7 @@ class TestWindowFusion:
                 await asyncio.sleep(0.01)
 
             def boom(h):
-                raise RuntimeError("relay died")
+                raise RuntimeError("device died")
 
             node.device_engine.dispatch = boom
             # pin the choice: the chooser would bypass an unmeasurable

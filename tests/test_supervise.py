@@ -719,48 +719,3 @@ class TestSuperviseTelemetry:
         assert "pipeline/supervise" in published
         doc = json.loads(published["pipeline/supervise"])
         assert doc["faults"] == 1
-
-
-# ---------- bench checkpoint (resumable phase ladder satellite) -------
-
-class TestBenchCheckpoint:
-    def _bench(self):
-        import importlib.util
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench.py")
-        spec = importlib.util.spec_from_file_location("bench_mod", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_roundtrip_and_sig_guard(self, tmp_path, monkeypatch):
-        bench = self._bench()
-        ck = tmp_path / "ckpt.json"
-        monkeypatch.setenv("BENCH_CHECKPOINT", str(ck))
-        monkeypatch.delenv("BENCH_RESUME", raising=False)
-        sig = {"subs": 100, "batch": 8, "window": 2, "shared_pct": 0}
-        phases = {}
-        bench._ckpt_put("phase0", {"value": 42}, sig, phases)
-        bench._ckpt_put("core@100", {"value": 7}, sig, phases)
-        assert ck.exists()
-        got = bench._ckpt_load(sig)
-        assert got == {"phase0": {"value": 42}, "core@100": {"value": 7}}
-        # a different config signature must NOT resume
-        assert bench._ckpt_load(dict(sig, subs=999)) == {}
-        # BENCH_RESUME=0 starts fresh
-        monkeypatch.setenv("BENCH_RESUME", "0")
-        assert bench._ckpt_load(sig) == {}
-        monkeypatch.delenv("BENCH_RESUME")
-        bench._ckpt_clear()
-        assert not ck.exists()
-        assert bench._ckpt_load(sig) == {}
-
-    def test_corrupt_checkpoint_starts_fresh(self, tmp_path,
-                                             monkeypatch):
-        bench = self._bench()
-        ck = tmp_path / "ckpt.json"
-        ck.write_text("{half a json")
-        monkeypatch.setenv("BENCH_CHECKPOINT", str(ck))
-        monkeypatch.delenv("BENCH_RESUME", raising=False)
-        assert bench._ckpt_load({"subs": 1}) == {}

@@ -16,7 +16,10 @@ by least squares, then inverts the fit per HBM budget:
 
     ceiling_subs = (budget * (1 - headroom) - intercept) / per_sub_bytes
 
-The 16 GiB v5e-1 budget is the headline row. Each point also carries
+The budget is the device's own `memory_stats()["bytes_limit"]`; a
+backend that reports none (XLA CPU) has no budget, so a what-if budget
+must be named with `--budget-gb` (bench.py's CPU row asks for 16, a
+v5e-1). Each point also carries
 the reconciliation the ISSUE-8 acceptance demands: ledger-accounted
 bytes vs the summed `.nbytes` of the held pytree (must agree within
 1%), and a release check (weakref finalizers return the bytes when the
@@ -26,11 +29,9 @@ before it lies in production).
 Usage: python tools/hbm_report.py [size ...] [--budget-gb G]...
                                   [--out FILE]
 
-Defaults: sizes 50_000 100_000 200_000 (CPU-friendly; a TPU window can
-pass 1_000_000 10_000_000), budgets 16 GiB. The JSON document goes to
-stdout (and --out FILE); bench.py embeds the same document as the
-`hbm_forecast` phase row, so every round commits a memory headline
-even when the throughput phases die. `report()` is importable — the
+Defaults: sizes 50_000 100_000 200_000 (CPU-friendly; on a chip pass
+1_000_000 10_000_000). The JSON document goes to stdout (and --out
+FILE); bench.py embeds the same document as its `cpu_hbm` row. `report()` is importable — the
 tier-1 test (tests/test_hbm_ledger.py) runs the full fit at small
 sizes and asserts the ceiling forecast.
 
@@ -156,12 +157,31 @@ def ceiling(fit: dict, budget_bytes: int, headroom: float) -> dict:
             "ceiling_subs": max(0, subs)}
 
 
-def report(sizes=(50_000, 100_000, 200_000), budgets_gb=(16,),
+def device_budget_gb() -> float:
+    """The HBM budget of the device this process is bound to, from its
+    own `memory_stats()`. A device that reports none is an error: there
+    is no default chip to assume."""
+    import jax
+
+    from emqx_tpu.broker.hbm_ledger import device_memory_stats
+    jax.devices()       # bind the backend; memory_stats never forces it
+    dev = device_memory_stats()
+    if not dev or "bytes_limit" not in dev:
+        raise SystemExit(
+            f"hbm_report: {jax.devices()[0].device_kind!r} reports no "
+            f"memory_stats()['bytes_limit']; name a what-if budget "
+            f"with --budget-gb")
+    return dev["bytes_limit"] / GIB
+
+
+def report(sizes=(50_000, 100_000, 200_000), budgets_gb=None,
            shared_pct: int = 50, headroom: float = None) -> dict:
-    """The full forecast document (importable: bench.py's hbm phase and
-    the tier-1 test both call this)."""
+    """The full forecast document (importable: the tier-1 test calls
+    this). `budgets_gb=None` takes the bound device's own limit."""
     if headroom is None:
         headroom = float(os.environ.get("BENCH_HBM_HEADROOM", 0.25))
+    if not budgets_gb:
+        budgets_gb = (device_budget_gb(),)
     t0 = time.time()
     points = [measure_point(s, shared_pct) for s in sorted(sizes)]
     fit = fit_points(points)
@@ -216,8 +236,7 @@ def main(argv=None) -> int:
     env_sizes = os.environ.get("BENCH_HBM_SIZES")
     if env_sizes:
         sizes = [int(s) for s in env_sizes.split(",") if s.strip()]
-    doc = report(sizes or (50_000, 100_000, 200_000),
-                 budgets or (16,))
+    doc = report(sizes or (50_000, 100_000, 200_000), budgets)
     text = json.dumps(doc)
     print(text, flush=True)
     if out:

@@ -30,7 +30,7 @@ subscriber covers bench/t0..t{n-1} — 1/16 of traffic by default so
 egress cannot become the measured wall), INGRESS_TIMEOUT_S (240),
 INGRESS_ONE_TIMEOUT_S (300).
 
-Run directly or as `python bench.py` (the `ingress` checkpointed phase).
+Run directly or as `python bench.py` (its `cpu_ingress` row).
 """
 
 import asyncio

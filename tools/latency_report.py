@@ -2,31 +2,29 @@
 """Offline per-path latency percentile / SLO report (ISSUE 13).
 
 Renders the latency observatory's schema (``emqx_tpu.latency/v1``) from
-a bench artifact — the merged bench JSON, a single phase row, or a
-``BENCH_CHECKPOINT`` file — without importing jax or the broker:
+a bench artifact — bench.py's JSON line or a single phase row —
+without importing jax or the broker:
 
-    python tools/latency_report.py BENCH_r06.json
-    python tools/latency_report.py /tmp/bench_ckpt.json
-    python tools/latency_report.py --require e2e_device BENCH_r06.json
+    python tools/latency_report.py bench.json
+    python tools/latency_report.py --require e2e_device bench.json
 
-Exit codes (the CI gate a future relay round cannot sneak past):
+Exit codes (the CI gate):
 
     0  every required row carries a latency section; report printed
     1  usage / unreadable / unparseable input
-    2  a required bench row carries NO latency section — the round is
-       about to commit a p99-less headline (exactly the r02..r05
-       failure mode: tail numbers that are either missing or
-       relay-contaminated). The offending rows are named on stderr.
+    2  a required bench row carries NO latency section — the run is
+       about to commit a p99-less headline. The offending rows are
+       named on stderr.
 
 By default the required rows are every phase row PRESENT in the
-artifact from {phase0, latency0, e2e_host, e2e_device} — a row that
+artifact from {cpu_latency0, e2e_host, e2e_device} — a row that
 ran but lost its latency section fails; a phase that never ran (e.g.
 BENCH_E2E=0) is not invented. ``--require a,b`` pins an explicit list
 instead (a named row that is absent then also fails: the gate is "this
-round MUST carry these measured tails"). The microbench rows
-``sharded`` and ``cover`` are also requirable: they carry matches/s +
-speedup headlines instead of a latency section, so for them the gate
-is row presence and the report prints their scalar summary.
+run MUST carry these measured tails"). The microbench rows
+``cpu_sharded`` and ``cpu_cover`` are also requirable: they carry
+matches/s + speedup headlines instead of a latency section, so for them
+the gate is row presence and the report prints their scalar summary.
 """
 
 from __future__ import annotations
@@ -35,21 +33,17 @@ import json
 import sys
 
 # the phase rows that must carry a latency section when present
-DEFAULT_ROWS = ("phase0", "latency0", "e2e_host", "e2e_device")
+DEFAULT_ROWS = ("cpu_latency0", "e2e_host", "e2e_device")
 # microbench phase rows --require can pin: they carry their own metric
 # (matches/s, speedup, reduction) instead of a latency section, so the
 # gate checks PRESENCE and renders the headline numbers
-MICRO_ROWS = ("sharded", "cover")
+MICRO_ROWS = ("cpu_sharded", "cpu_cover")
 
 
 def _rows_of(doc: dict) -> dict:
     """Candidate phase rows from any supported artifact shape."""
     if not isinstance(doc, dict):
         return {}
-    # checkpoint file: {"sig": ..., "phases": {name: row}}
-    if "phases" in doc and isinstance(doc["phases"], dict):
-        return {k: v for k, v in doc["phases"].items()
-                if isinstance(v, dict)}
     # a single phase row passed directly
     if "latency" in doc and not any(k in doc for k in DEFAULT_ROWS):
         return {"row": doc}
@@ -60,7 +54,7 @@ def _rows_of(doc: dict) -> dict:
 
 def _render_micro(name: str, row: dict) -> str:
     """Headline numbers of a latency-less microbench row (one line per
-    nesting level — enough for the round log, not a full report)."""
+    nesting level — enough for a run log, not a full report)."""
     def scalars(d):
         return {k: v for k, v in d.items()
                 if isinstance(v, (int, float, str, bool))}
@@ -194,7 +188,7 @@ def main(argv=None) -> int:
         printed += 1
     if missing:
         print(f"latency_report: required bench rows missing or carry "
-              f"NO latency section: {missing} — this round would "
+              f"NO latency section: {missing} — this run would "
               f"commit a p99-less headline (run with "
               f"EMQX_TPU_LATENCY=1 / BENCH_LATENCY0=1)",
               file=sys.stderr)

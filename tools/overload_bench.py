@@ -33,8 +33,8 @@ OVERLOAD_ONE_TIMEOUT_S (420), OVERLOAD_POLL_S (0.05: governor\
 tick), OVERLOAD_RATE_MSGS_S (18000: aggregate paced inflow —\
 size it above the box's routing capacity).
 
-Run directly or as `python bench.py` (the `overload` checkpointed
-phase, BENCH_OVERLOAD=0 skips).
+Run directly or as `python bench.py` (its `cpu_overload` row,
+BENCH_OVERLOAD=0 skips).
 """
 
 import asyncio

@@ -39,6 +39,10 @@ async def main() -> None:
     from emqx_tpu.broker.connection import Listener
     from emqx_tpu.broker.node import Node
     from emqx_tpu.cluster import ClusterNode
+    from emqx_tpu.utils.compile_cache import configure_compile_cache
+
+    if not args.no_device:
+        configure_compile_cache()
 
     join_addr = None
     if args.join:
