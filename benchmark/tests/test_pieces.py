@@ -1,0 +1,480 @@
+"""The benchmark's own code, checked on the CPU.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+- the codec agrees with `emqx_tpu.mqtt.frame` at the byte level, both ways;
+- `plain.py` agrees with `emqx_tpu.utils.topic.match` on a seeded set, and
+  both populations' closed forms agree with brute force;
+- the ledger check passes a sound log and fails on a lost, a duplicated,
+  a reordered and a twice-picked delivery, and on an unacknowledged
+  QoS 1 PUBLISH;
+- the trace reduction on a hand-made trace and on the small recorded one;
+- the loader refuses a cell whose per-layer metric lacks its `moves` metric.
+"""
+
+import json
+import os
+import shutil
+import zlib
+
+import numpy as np
+import pytest
+
+from benchmark import check, codec, manifest, plain, traffic_gen
+from benchmark.populations import site_plus
+from benchmark.readers import counter, telemetry, xplane
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+# ------------------------------------------------------------------ codec
+
+def test_codec_matches_the_programs_serializer_byte_for_byte():
+    from emqx_tpu.mqtt import frame, packet as P
+    pay = bytes(range(256))
+    cases = [
+        (codec.connect("bench-sub3"),
+         P.Connect(clientid="bench-sub3", keepalive=0, clean_start=True)),
+        (codec.publish("device/d1/x/n2/t", pay, 0),
+         P.Publish(topic="device/d1/x/n2/t", payload=pay, qos=0)),
+        (codec.publish("site/s1/line/l2/d3/m4", pay, 1, 65535),
+         P.Publish(topic="site/s1/line/l2/d3/m4", payload=pay, qos=1,
+                   packet_id=65535)),
+        (codec.puback(77), P.Puback(packet_id=77)),
+        (codec.subscribe(9, [("$share/bg/device/d1/+/n2/#", 1),
+                             ("device/d2/+/n2/#", 0)]),
+         P.Subscribe(packet_id=9, filters=[
+             ("$share/bg/device/d1/+/n2/#", P.SubOpts(qos=1)),
+             ("device/d2/+/n2/#", P.SubOpts(qos=0))])),
+        (codec.unsubscribe(10, ["a/+/b", "c/#"]),
+         P.Unsubscribe(packet_id=10, filters=["a/+/b", "c/#"])),
+        (codec.PINGREQ_FRAME, P.Pingreq()),
+    ]
+    for mine, pkt in cases:
+        assert mine == frame.serialize(pkt), pkt
+    # a 512-entry SUBSCRIBE needs a 3-byte remaining length
+    big = [(f"device/d{i}/+/n{i}/#", i % 2) for i in range(512)]
+    assert codec.subscribe(1, big) == frame.serialize(P.Subscribe(
+        packet_id=1, filters=[(f, P.SubOpts(qos=q)) for f, q in big]))
+
+
+def test_codec_reads_what_the_program_writes():
+    from emqx_tpu.mqtt import frame, packet as P
+    pay = b"\x05\x00" + bytes(254)
+    wire = b"".join(frame.serialize(p) for p in [
+        P.Connack(), P.Suback(packet_id=3, reason_codes=[0, 1, 0x80]),
+        P.Publish(topic="device/d9/x/n1/t", payload=pay, qos=1,
+                  packet_id=300, dup=True),
+        P.Publish(topic="t", payload=pay, qos=0), P.Puback(packet_id=12)])
+    got = list(codec.scan(wire + b"\x30"))         # plus a broken tail
+    assert [t for t, *_ in got] == [codec.CONNACK, codec.SUBACK,
+                                    codec.PUBLISH, codec.PUBLISH,
+                                    codec.PUBACK]
+    _t, _f, a, b = got[1]
+    assert wire[a:b] == b"\x00\x03\x00\x01\x80"
+    _t, fl, a, b = got[2]
+    topic, qos, dup, retain, pid, p = codec.parse_publish(wire, fl, a, b)
+    assert (topic, qos, dup, retain, pid) == \
+        (b"device/d9/x/n1/t", 1, True, False, 300)
+    assert wire[p:b] == pay
+    # and the program's parser reads the codec's frames in small chunks
+    parser = frame.FrameParser()
+    mine = codec.publish("a/b", pay, 1, 7) + codec.puback(7)
+    pkts = []
+    for k in range(0, len(mine), 5):
+        pkts += parser.feed(mine[k:k + 5])
+    assert (pkts[0].topic, pkts[0].qos, pkts[0].packet_id,
+            pkts[0].payload) == ("a/b", 1, 7, pay)
+    assert pkts[1].packet_id == 7
+
+
+def test_varint_edges():
+    for n in (0, 127, 128, 16383, 16384, 2097151, 2097152, 268435455):
+        frame = b"\x30" + codec.varint(n)
+        assert list(codec.scan(frame + bytes(n)))[0][3] == len(frame) + n
+    with pytest.raises(ValueError):
+        codec.varint(268435456)
+
+
+# ------------------------------------------------- matcher and populations
+
+def test_plain_matcher_agrees_with_the_programs_on_a_seeded_set():
+    from emqx_tpu.utils import topic as T
+    rng = np.random.default_rng(11)
+    words = ["a", "b", "dev", "$SYS", "+", "#", "x1", ""]
+    n = 0
+    for _ in range(4000):
+        t = "/".join(rng.choice(words[:5], rng.integers(1, 5)))
+        levels = list(rng.choice(words, rng.integers(1, 5)))
+        if "#" in levels[:-1]:
+            continue
+        f = "/".join(levels)
+        if "+" in t or not t:
+            continue
+        assert plain.match(t, f) == bool(T.match(t, f)), (t, f)
+        n += 1
+    assert n > 1000
+    assert plain.match("a", "a/#") and not plain.match("$SYS/x", "+/x")
+    assert not plain.match("$SYS/x", "#") and plain.match("$SYS/x", "$SYS/#")
+
+
+SMALL = {"sites": 4, "lines": 3, "devs": 4, "meas": 5, "b_meas": 2}
+
+
+def _pop(conns=6):
+    return site_plus.Population(SMALL, conns)
+
+
+def test_closed_form_equals_brute_force():
+    pop = _pop()
+    keys = np.arange(int(np.prod(pop.dims)))
+    assert check.brute_force(pop, keys, len(keys), seed=5) == 0
+    subs = sum(len(pop.subscriptions(c)) for c in range(pop.conns))
+    assert len(pop.filters()) == subs
+    per_key = (pop.expect(keys) >= 0).sum(axis=1)
+    assert set(per_key) == {2, 3}
+
+
+def test_brute_force_sees_a_wrong_closed_form():
+    pop = _pop()
+    real = pop.expect
+
+    def off_by_one(keys):
+        want = real(keys)
+        return (want + (want >= 0)) % pop.conns - (want < 0)
+    pop.expect = off_by_one
+    assert check.brute_force(pop, np.arange(240), 64, seed=1) > 0
+
+
+def test_full_size_populations_have_the_stated_counts():
+    with open(os.path.join(manifest.HERE, "configs", "plus-100k.json")) as f:
+        cfg = json.load(f)
+    pop = site_plus.Population(cfg["population"]["params"],
+                               cfg["connections"]["subscribers"])
+    assert cfg["filters"] == cfg["subscriptions"] == 100_000
+    assert sum(len(pop.subscriptions(c)) for c in range(pop.conns)) == 100_000
+    assert int(np.prod(pop.dims)) == 3_600_000
+
+
+def test_traffic_is_the_seeds():
+    uniform = {"dist": "uniform"}
+    a = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 9, 1), 1000,
+                              (500, 500), uniform)
+    b = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 9, 1), 1000,
+                              (500, 500), uniform)
+    c = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 10, 1), 1000,
+                              (500, 500), uniform)
+    assert (a == b).all() and (a != c).any()
+    assert a.min() >= 0 and a.max() < 250_000
+    with pytest.raises(ValueError):
+        traffic_gen.draw_keys(traffic_gen.rng_for(1, 1), 4, (5,),
+                              {"dist": "other"})
+
+
+# ------------------------------------------------------------- the ledger
+
+def sound_logs(pop, n=4000, pubs=4, seed=2):
+    """Logs of a broker that keeps every guarantee."""
+    rng = np.random.default_rng(seed)
+    keys = traffic_gen.draw_keys(rng, n, pop.dims, {"dist": "uniform"})
+    pub = {"pub": np.repeat(np.arange(pubs), n // pubs).astype(np.int32),
+           "seq": np.tile(np.arange(n // pubs), pubs).astype(np.int64),
+           "key": keys, "qos": np.zeros(n, np.int8)}
+    pub["qos"][pub["seq"] % 4 == 0] = 1
+    pub["due_ns"] = np.arange(n, dtype=np.int64) * 1000 + 10**9
+    pub["send_ns"] = pub["due_ns"] + 5
+    pub["ack_ns"] = np.where(pub["qos"] == 1, pub["due_ns"] + 900, 0)
+    want = pop.expect(keys)
+    rows = []
+    for m in range(n):
+        to = [int(c) for c in want[m] if c >= 0]
+        crc = zlib.crc32(pop.topic(int(keys[m])).encode())
+        for c in to:
+            rows.append((c, pub["pub"][m], pub["seq"][m], pub["due_ns"][m],
+                         pub["due_ns"][m] + 700, pub["qos"][m], False, crc))
+    cols = list(zip(*rows))
+    sub = {"sub": np.array(cols[0], np.int16),
+           "pub": np.array(cols[1], np.int32),
+           "seq": np.array(cols[2], np.int64),
+           "due_ns": np.array(cols[3], np.int64),
+           "recv_ns": np.array(cols[4], np.int64),
+           "qos": np.array(cols[5], np.int8),
+           "dup": np.array(cols[6], np.bool_),
+           "crc": np.array(cols[7], np.uint32)}
+    return pub, sub
+
+
+def _drop(sub, rows):
+    keep = np.ones(len(sub["sub"]), bool)
+    keep[rows] = False
+    return {k: v[keep] for k, v in sub.items()}
+
+
+def test_sound_logs_pass():
+    for pop in (_pop(), _pop(conns=4)):
+        pub, sub = sound_logs(pop)
+        v = check.check(pop, pub, sub, seed=1)
+        assert v["correct"], v["numbers"]
+        assert v["failed"] == 0 and v["attempted"] == 4000
+        assert all(val == 0 for val, _lim in v["numbers"].values())
+
+
+def tamper_lost(pop, pub, sub):
+    return pub, _drop(sub, [17]), "wrong_delivery_sets"
+
+
+def tamper_duplicated(pop, pub, sub):
+    twice = {k: np.concatenate([v, v[40:41]]) for k, v in sub.items()}
+    return pub, twice, "wrong_delivery_sets"
+
+
+def tamper_reordered(pop, pub, sub):
+    # two deliveries of one (subscriber, publisher, topic, qos) swapped
+    seen: dict = {}
+    for r in range(len(sub["sub"])):
+        k = (sub["sub"][r], sub["pub"][r], sub["crc"][r], sub["qos"][r])
+        if k in seen and sub["seq"][seen[k]] != sub["seq"][r]:
+            a = seen[k]
+            order = np.arange(len(sub["sub"]))
+            order[[a, r]] = order[[r, a]]
+            return pub, {c: v[order] for c, v in sub.items()}, \
+                "order_breaks"
+        seen.setdefault(k, r)
+    raise AssertionError("no repeated topic in the log")
+
+
+def tamper_wrong_subscriber(pop, pub, sub):
+    out = {k: v.copy() for k, v in sub.items()}
+    out["sub"][9] = (out["sub"][9] + 1) % pop.conns
+    return pub, out, "wrong_delivery_sets"
+
+
+def tamper_unacknowledged(pop, pub, sub):
+    out = {k: v.copy() for k, v in pub.items()}
+    out["ack_ns"][np.flatnonzero(pub["qos"] == 1)[3]] = 0
+    return out, sub, "missing_pubacks"
+
+
+def tamper_stray(pop, pub, sub):
+    out = {k: np.concatenate([v, v[:1]]) for k, v in sub.items()}
+    out["seq"][-1] = 10**6
+    return pub, out, "stray_deliveries"
+
+
+def tamper_wrong_topic(pop, pub, sub):
+    out = {k: v.copy() for k, v in sub.items()}
+    out["crc"][5] ^= 1
+    return pub, out, "topic_or_payload_mismatches"
+
+
+@pytest.mark.parametrize("tamper", [
+    tamper_lost, tamper_duplicated, tamper_reordered,
+    tamper_wrong_subscriber, tamper_unacknowledged, tamper_stray,
+    tamper_wrong_topic], ids=lambda f: f.__name__[7:])
+def test_a_weakened_guarantee_fails_the_check(tamper):
+    pop = _pop()
+    pub, sub, number = tamper(pop, *sound_logs(pop))
+    v = check.check(pop, pub, sub, seed=1)
+    assert not v["correct"]
+    assert v["failed"] >= 1
+    value, limit = v["numbers"][number]
+    assert value > limit, v["numbers"]
+
+
+def test_a_retransmission_marked_dup_is_not_a_second_delivery():
+    pop = _pop()
+    pub, sub = sound_logs(pop)
+    q1 = np.flatnonzero(sub["qos"] == 1)[:3]
+    again = {k: np.concatenate([v, v[q1]]) for k, v in sub.items()}
+    again["dup"][-3:] = True
+    v = check.check(pop, pub, again, seed=1)
+    assert v["correct"] and v["info"]["dup_redeliveries"] == 3
+
+
+# ------------------------------------------------------------ the readers
+
+def small_trace():
+    us = 1000.0
+    return {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ["jit_route_window(1)", 100 * us, 300 * us],
+                ["jit_other(2)", 600 * us, 100 * us]]},
+            {"name": "XLA Ops", "events": [
+                ["fusion.1", 100 * us, 100 * us],
+                ["fusion.1", 150 * us, 100 * us],      # overlaps the first
+                ["copy.2", 300 * us, 100 * us],
+                ["fusion.9", 600 * us, 100 * us],
+                ["late", 2000 * us, 50 * us]]},        # outside the window
+            {"name": "Steps", "events": [["0", 0.0, 900 * us]]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "python", "events": [
+                ["bench:trace_window", 0.0, 1000 * us],
+                ["bench:dispatch", 50 * us, 60 * us],
+                ["bench:materialize", 380 * us, 200 * us],
+                ["$other", 0.0, 1000 * us]]}]}]}
+
+
+def test_trace_reduction_on_a_hand_made_trace():
+    r = xplane.reduce(small_trace())
+    assert r["window_s"] == pytest.approx(1e-3)
+    # union: [100,250] + [300,400] + [600,700] us
+    assert r["busy_s"] == pytest.approx(350e-6)
+    ops = dict(r["device_ops"])
+    assert ops["fusion.1"] == pytest.approx(200e-6) and "late" not in ops
+    gaps = dict(r["idle_gaps"])
+    # gaps: [0,100] [250,300] [400,600] [700,1000] us
+    assert gaps["bench:dispatch"] == pytest.approx(50e-6)
+    assert gaps["bench:materialize"] == pytest.approx(180e-6)
+    assert sum(gaps.values()) == pytest.approx(650e-6)
+    seconds, n = xplane.program_seconds(small_trace(), ["route"])
+    assert (seconds, n) == (pytest.approx(300e-6), 1)
+
+
+def test_trace_without_a_device_plane_is_refused():
+    t = small_trace()
+    t["planes"] = t["planes"][1:]
+    with pytest.raises(ValueError):
+        xplane.reduce(t)
+
+
+def test_trace_reduction_on_the_recorded_trace():
+    """A cut of a real trace of `share50-250k.flood` on a TPU v5 lite
+    (PR 23): the planes and lines the reduction counts on are there."""
+    with open(os.path.join(HERE, "data", "trace_small.json")) as f:
+        t = json.load(f)
+    assert xplane.device_planes(t)
+    r = xplane.reduce(t)
+    assert 0 < r["busy_s"] < r["window_s"]
+    assert r["device_ops"] and r["idle_gaps"]
+    seconds, n = xplane.program_seconds(t, ["route"])
+    assert n > 0 and 0 < seconds <= r["window_s"]
+
+
+def test_counter_and_telemetry_readers():
+    ctx = {"m0": {"a": 5, "pipeline.batches.host": 2},
+           "m1": {"a": 25, "pipeline.batches.host": 6,
+                  "pipeline.batches.device": 4},
+           "window": {"publishes": 800, "seconds": 10.0},
+           "tele0": {"stages": {"dispatch": {"sum_ms": 10.0, "count": 2}}},
+           "tele1": {"stages": {"dispatch": {"sum_ms": 40.0, "count": 8}},
+                     "rebuild": {"stages": {"build": {"mean_ms": 2000.0,
+                                                      "count": 2}}}}}
+    assert counter.read(ctx, ["a"], ["window.publishes"], 100.0) == 2.5
+    assert counter.read(ctx, ["window.publishes"],
+                        ["pipeline.batches.*"]) == 100.0
+    assert counter.read(ctx, ["a"], ["nothing"]) == 0.0
+    assert telemetry.read(ctx, "stages/dispatch/sum_ms",
+                          "stages/dispatch/count") == 5.0
+    assert telemetry.read(ctx, "stages/deliver/sum_ms",
+                          "stages/deliver/count") == 0.0
+    assert telemetry.read(ctx, "rebuild/stages/build/mean_ms",
+                          mul="rebuild/stages/build/count", scale=0.001,
+                          how="last") == 4.0
+
+
+# ------------------------------------------------------------- the loader
+
+def test_every_cell_of_the_manifest_loads():
+    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for w in bench["workloads"]:
+        cell = manifest.Cell(w["name"])
+        names = {m["name"] for m in cell.end_to_end}
+        assert "setup_s" in names and len(names) >= 2
+        assert all(m["moves"] in names for m in cell.per_layer)
+        assert cell.chips == 1
+
+
+def _copy_root(tmp_path):
+    root = tmp_path / "root"
+    root.mkdir()
+    shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), root)
+    os.symlink(manifest.HERE, root / "benchmark")
+    with open(root / "BENCHMARK.json") as f:
+        return root, json.load(f)
+
+
+def test_loader_refuses_a_metric_whose_moves_metric_the_cell_lacks(tmp_path):
+    root, bench = _copy_root(tmp_path)
+    cell = bench["workloads"][0]["name"]
+    bench["end_to_end"].append({
+        "name": "delivery_p99_ms", "unit": "ms", "better": "lower",
+        "bound": 0.1, "source": "host_clock", "workloads": []})
+    bench["per_layer"][0].update(moves="delivery_p99_ms", workloads=[cell])
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    with pytest.raises(manifest.ManifestError, match="does not report"):
+        manifest.Cell(cell, root=str(root))
+
+
+def test_loader_refuses_what_the_traffic_mix_cannot_report(tmp_path):
+    root, bench = _copy_root(tmp_path)
+    cell = bench["workloads"][0]["name"]
+    bench["end_to_end"].append({
+        "name": "puback_p99_ms", "unit": "ms", "better": "lower",
+        "bound": 0.1, "source": "host_clock", "workloads": [cell]})
+    with open(root / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    with pytest.raises(manifest.ManifestError, match="traffic mix"):
+        manifest.Cell(cell, root=str(root))
+    with pytest.raises(manifest.ManifestError, match="no workload"):
+        manifest.Cell("nothing.here", root=str(root))
+
+
+# ------------------------------------------------- end-to-end arithmetic
+
+def test_end_to_end_metrics_on_a_known_log():
+    from benchmark import e2e
+    n = 1000
+    due = np.arange(n, dtype=np.int64) * 10**6            # 1 ms apart
+    pub = {"qos": (np.arange(n) % 4 == 0).astype(np.int8), "due_ns": due,
+           "send_ns": due + 200_000, "ack_ns": due + 3_000_000}
+    sub = {"dup": np.zeros(n + 1, bool),
+           "due_ns": np.append(due, due[5]),
+           "recv_ns": np.append(due + 2_000_000 + np.arange(n) * 1000,
+                                due[5] + 9 * 10**9)}
+    sub["dup"][-1] = True                  # a retransmission: not counted
+    t0, t1 = int(due[100]), int(due[600])
+    assert e2e.delivered_per_s(pub, sub, t0, t1) == pytest.approx(
+        ((sub["recv_ns"][:n] >= t0) & (sub["recv_ns"][:n] < t1)).sum() / 0.5)
+    assert e2e.publishes_in(pub, t0, t1) == 500
+
+
+def test_route_bytes_from_shapes():
+    from benchmark.readers import route_bytes
+    assert route_bytes.shapes_of(_pop().filters()) == 3
+    assert route_bytes.shapes_of(["a/+/#", "b/+/#", "a/b", "+/b"]) == 3
+    # 6 levels, 3 shapes, two deliveries
+    assert route_bytes.message_bytes(6, 3, 2.0) == 32 + 576 + 16 + 20
+
+
+def test_route_roofline_counts_messages_not_deliveries():
+    """`messages.routed.device` counts deliveries; with a fan-out above
+    1 the bytes are those of the PUBLISHes behind them."""
+    from benchmark.readers import route_bytes, route_roofline
+    pop = _pop()
+    n = 6000
+    keys = np.arange(n) % int(np.prod(pop.dims))
+    per_msg = (pop.expect(keys) >= 0).sum() / n
+    assert per_msg > 2
+    ctx = {"trace": small_trace(),      # its route program takes 300 us
+           "peaks": {"hbm_bytes_per_s": 1e9}, "pop": pop,
+           "trace_m0": {"messages.routed.device": 1000},
+           "trace_m1": {"messages.routed.device": 1000 + 500 * per_msg},
+           "window": {"t0_ns": 0, "t1_ns": 10},
+           "pub": {"key": keys, "send_ns": np.full(n, 5)}}
+    need = 500 * route_bytes.message_bytes(6, 3, per_msg)
+    assert route_roofline.read(ctx, ["route"]) == pytest.approx(
+        100.0 * (need / 1e9) / 300e-6)
+    ctx["trace_m1"] = ctx["trace_m0"]
+    assert route_roofline.read(ctx, ["route"]) == 0.0
+
+
+def test_the_set_ups_direct_warm_deliveries_are_left_out():
+    pop = _pop()
+    pub, sub = sound_logs(pop)
+    extra = {k: np.concatenate([v, v[:7]]) for k, v in sub.items()}
+    extra["pub"][-7:] = check.WARM_PUB
+    v = check.check(pop, pub, extra, seed=1)
+    assert v["correct"] and v["info"]["deliveries"] == len(sub["sub"])
