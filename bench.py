@@ -1180,7 +1180,7 @@ def run_e2e(n_filters: int, n_sub_conns: int, n_pub_conns: int,
                     measured, key=lambda r: r["lat_p99_ms"])["window_us"]
         # per-stage pipeline telemetry: stage p50/p95/p99, batch
         # occupancy per shape class, compile accounting — one schema
-        # shared with GET /api/v5/pipeline/stats and profile_step.py
+        # shared with GET /api/v5/pipeline/stats and the benchmark
         snap = node.pipeline_telemetry.snapshot()
         out_extra["telemetry"] = snap
         # flight-recorder overlap summary (ISSUE 7), surfaced at the top

@@ -115,6 +115,10 @@ class PublishBatcher:
         # dispatch/materialize/replay/lanes to settle. None (knob off /
         # bare test nodes) restores the pre-ISSUE-7 behavior exactly.
         self.rec = getattr(node, "flight_recorder", None)
+        # the one span call (broker/trace.py): stage histogram + ring +
+        # profiler timeline for every stage boundary below
+        from emqx_tpu.broker.trace import spans_of
+        self.spans = spans_of(node)
         # latency SLO observatory (ISSUE 13): per-message ingress→
         # routed / ingress→delivered recording at settle, keyed by the
         # window's (qos, path) attribution. None (knob off / bare test
@@ -178,6 +182,8 @@ class PublishBatcher:
         # parallels _queue so the submit/enqueue tuple shape is untouched.
         self._q_times: deque = deque()
         self.route_lat: deque = deque(maxlen=8192)
+        # _dev_batch_s / (n * _host_msg_s) of the last cost comparison
+        self.chooser_margin: Optional[float] = None
         self._since_probe = 0         # host batches since last device try
         self._since_host_probe = 0    # device batches since last host probe
         self._last_dev_done: Optional[float] = None
@@ -339,26 +345,26 @@ class PublishBatcher:
                             if sampled is None:
                                 sampled = []
                             sampled.append((len(batch) - 1, tq))
-                    now = time.perf_counter()
-                    if self.tele is not None:
-                        # enqueue stage: oldest-message queue wait before
-                        # its batch formed (upper-bounds the batch)
-                        self.tele.observe_stage("enqueue", now - t_enq)
                     entry = {"batch": batch, "handle": None, "sub": 0,
                              "dispatch_fut": None, "live": None,
                              "live_idx": None, "t_enq": t_enq}
+                    tid = 0
                     if rec is not None:
-                        # the window's trace id, minted at admit; the
-                        # enqueue span doubles as the root every later
-                        # span parents to
+                        # the window's trace id, minted at admit
                         tid = rec.new_trace()
                         entry["trace"] = tid
                         self.last_trace = tid
-                        entry["root_span"] = rec.record(
-                            tid, "enqueue", t_enq, now, track="batcher",
-                            meta={"batch": len(batch)})
                         if sampled:
                             entry["trace_msgs"] = sampled
+                    # enqueue stage: oldest-message queue wait before
+                    # its batch formed (upper-bounds the batch); its
+                    # ring span doubles as the root every later span
+                    # parents to
+                    root = self.spans.record(
+                        "enqueue", tid, t_enq, stage="enqueue",
+                        track="batcher", meta={"batch": len(batch)})
+                    if tid:
+                        entry["root_span"] = root
                     if self.sup is not None:
                         # window journal (ISSUE 6): the window is
                         # journaled the moment it is admitted to the
@@ -595,38 +601,37 @@ class PublishBatcher:
 
     async def _fold_hooks(self, entry: dict) -> None:
         """message.publish hook fold, concurrently across the batch."""
-        t0 = time.perf_counter()
         broker = self.node.broker
         batch = entry["batch"]
-        if not broker.hooks.lookup("message.publish"):
-            # empty hook chain (the common ingest-bound deployment): a
-            # fold would return every message unchanged — skip the
-            # per-message coroutine fan-out, but keep one scheduling
-            # point (the gather was an await; background warms and
-            # readbacks rely on the producer yielding between windows)
-            await asyncio.sleep(0)
-            folded = [m for m, _f in batch]
-        else:
-            folded = await asyncio.gather(*[
-                broker.hooks.run_fold_async("message.publish", (), m)
-                for m, _f in batch])
-        live_idx: list[int] = []
-        live: list[Message] = []
-        for i, m in enumerate(folded):
-            if m is None or m.get_header("allow_publish") is False:
-                continue
-            broker.metrics.inc("messages.publish")
-            live_idx.append(i)
-            live.append(m)
-        entry["live"] = live
-        entry["live_idx"] = live_idx
-        if self.tele is not None:
-            self.tele.observe_stage("batch_form",
-                                    time.perf_counter() - t0)
-        if self.rec is not None and "trace" in entry:
-            self.rec.record(entry["trace"], "batch_form", t0,
-                            time.perf_counter(), track="batcher",
-                            parent=entry.get("root_span", 0))
+        with self.spans.span("batch_form", entry.get("trace", 0),
+                             track="batcher",
+                             parent=entry.get("root_span", 0)) as sp:
+            if not broker.hooks.lookup("message.publish"):
+                # empty hook chain (the common ingest-bound deployment):
+                # a fold would return every message unchanged — skip the
+                # per-message coroutine fan-out, but keep one scheduling
+                # point (the gather was an await; background warms and
+                # readbacks rely on the producer yielding between
+                # windows)
+                with sp.released():
+                    await asyncio.sleep(0)
+                folded = [m for m, _f in batch]
+            else:
+                with sp.released():
+                    folded = await asyncio.gather(*[
+                        broker.hooks.run_fold_async(
+                            "message.publish", (), m)
+                        for m, _f in batch])
+            live_idx: list[int] = []
+            live: list[Message] = []
+            for i, m in enumerate(folded):
+                if m is None or m.get_header("allow_publish") is False:
+                    continue
+                broker.metrics.inc("messages.publish")
+                live_idx.append(i)
+                live.append(m)
+            entry["live"] = live
+            entry["live_idx"] = live_idx
 
     # ---- consumer: complete batches strictly in order --------------------
     async def _complete_host(self, entry: dict, routed=None) -> None:
@@ -640,9 +645,9 @@ class PublishBatcher:
         batch = entry["batch"]
         counts = [0] * len(batch)
         tele = self.tele
-        rec = self.rec
+        spans = self.spans
         obs = self.obs
-        tid = entry.get("trace") if rec is not None else None
+        tid = entry.get("trace", 0)
         path = "host" if routed is None else "device"
         # latency path attribution (ISSUE 13): the fine-grained series
         # key. The coarse `path` above keeps its two historical values
@@ -673,43 +678,36 @@ class PublishBatcher:
                 if pool is not None and pool.busy():
                     t_d = time.perf_counter()
                     await pool.drain()
-                    if tid is not None:
-                        # a real wait on the lanes: the
-                        # lane-backpressure bubble, named
-                        rec.record(tid, "lane_drain", t_d,
-                                   time.perf_counter(), track="batcher",
-                                   parent=entry.get("root_span", 0))
-                t0 = time.perf_counter()
+                    # a real wait on the lanes: the lane-backpressure
+                    # bubble, named
+                    spans.record("lane_drain", tid, t_d, track="batcher",
+                                 parent=entry.get("root_span", 0))
                 routed = []
                 broker = self.node.broker
-                for j, m in enumerate(live):
-                    if tele is not None and j % 32 == 0:
-                        # sampled host match split: the host-side
-                        # decomposition of the device program's match
-                        # stage (1-in-32 keeps the hot loop cheap)
-                        tm = time.perf_counter()
-                        mt = broker.router.match(m.topic)
-                        tele.observe_stage("host_match",
-                                           time.perf_counter() - tm)
-                    else:
-                        mt = broker.router.match(m.topic)
-                    routed.append(broker._route(m, mt))
-                    if j % 64 == 63:
-                        await asyncio.sleep(0)
-                span = time.perf_counter() - t0
-                if tele is not None:
-                    tele.observe_stage("host_route", span)
-                if tid is not None:
-                    # a replayed window's host re-route is a CHILD of
-                    # its replay span — the original trace id is kept
-                    # (ISSUE 7 satellite: causality survives the
-                    # degradation ladder)
-                    rec.record(tid, "host_route", t0,
-                               time.perf_counter(), track="host",
-                               parent=entry.get("replay_span")
-                               or entry.get("root_span", 0))
+                # a replayed window's host re-route is a CHILD of its
+                # replay span — the original trace id is kept (ISSUE 7
+                # satellite: causality survives the degradation ladder)
+                with spans.span("host_route", tid, track="host",
+                                parent=entry.get("replay_span")
+                                or entry.get("root_span", 0)) as sp:
+                    for j, m in enumerate(live):
+                        if tele is not None and j % 32 == 0:
+                            # sampled host match split: the host-side
+                            # decomposition of the device program's
+                            # match stage (1-in-32 keeps the hot loop
+                            # cheap)
+                            tm = time.perf_counter()
+                            mt = broker.router.match(m.topic)
+                            tele.observe_stage("host_match",
+                                               time.perf_counter() - tm)
+                        else:
+                            mt = broker.router.match(m.topic)
+                        routed.append(broker._route(m, mt))
+                        if j % 64 == 63:
+                            with sp.released():
+                                await asyncio.sleep(0)
                 self._host_msg_s, self._host_spike = _ewma(
-                    self._host_msg_s, span / len(live),
+                    self._host_msg_s, sp.dur / len(live),
                     self._host_spike)
                 # a host completion breaks the device completion chain:
                 # the next device sample must be a full round-trip, not
@@ -729,6 +727,11 @@ class PublishBatcher:
                         obs.record_routed(m, lpath, (t_ns - ing) / 1e9,
                                           trace=tr)
             def _settle() -> None:
+                with spans.span("settle", tid, track="batcher",
+                                parent=entry.get("root_span", 0)):
+                    _settle_inner()
+
+            def _settle_inner() -> None:
                 if live:
                     for j, i in enumerate(live_idx):
                         counts[i] = routed[j]
@@ -759,21 +762,21 @@ class PublishBatcher:
                     if tele is not None:
                         tele.record_total(total, batch=len(batch),
                                           path=path)
-                if tid is not None:
+                if tid:
                     now = time.perf_counter()
                     w0 = entry.get("t_enq") or now
                     # the window roll-up span (admit → settle) + the
                     # sampled per-message enqueue→settle spans
-                    rec.record(tid, "window", w0, now, track="window",
-                               meta={"path": path,
-                                     "batch": len(batch)})
+                    spans.record("window", tid, w0, now, track="window",
+                                 meta={"path": path,
+                                       "batch": len(batch)})
                     for i, tq in entry.get("trace_msgs", ()):
                         m = batch[i][0]
-                        rec.record(tid, "message", tq, now,
-                                   track="messages",
-                                   parent=entry.get("root_span", 0),
-                                   meta={"topic": m.topic,
-                                         "qos": m.qos})
+                        spans.record("message", tid, tq, now,
+                                     track="messages",
+                                     parent=entry.get("root_span", 0),
+                                     meta={"topic": m.topic,
+                                           "qos": m.qos})
 
             # deliver-lane hand-off (ISSUE 5): a LaneCounts carries the
             # in-flight DeliveryPlan — publisher futures resolve when
@@ -1057,13 +1060,12 @@ class PublishBatcher:
             # buffering (or dropping) deliveries unboundedly
             t_a = time.perf_counter()
             await pool.admit()
-            if self.rec is not None and "trace" in entry \
-                    and time.perf_counter() - t_a > 5e-4:
+            if time.perf_counter() - t_a > 5e-4:
                 # only a REAL wait is a lane-backpressure bubble worth
                 # a span; the no-wait fast path stays unrecorded
-                self.rec.record(entry["trace"], "lane_admit", t_a,
-                                time.perf_counter(), track="batcher",
-                                parent=entry.get("root_span", 0))
+                self.spans.record("lane_admit", entry.get("trace", 0),
+                                  t_a, track="batcher",
+                                  parent=entry.get("root_span", 0))
         done = time.perf_counter()
         if sub == n_subs - 1:
             if sup is not None:
@@ -1165,7 +1167,9 @@ class PublishBatcher:
         a prospective window (n = its live count) before any fusion;
         _dev_batch_s is the amortized per-sub-batch completion cost, so the
         single-sub-batch comparison is the per-sub-batch comparison."""
+        count = self.node.metrics.inc
         if self._dev_batch_s is None:
+            count("routing.chooser.first")
             return True      # optimistic: measure the device first
         if self._host_msg_s is None \
                 or self._since_host_probe >= self.host_probe_every:
@@ -1176,15 +1180,35 @@ class PublishBatcher:
             # DECISION time — resetting at consume time would turn one
             # scheduled probe into a pipeline_depth-long probe burst.
             self._since_host_probe = 0
+            count("routing.chooser.host_probe")
             return False
         if self._since_probe >= _PROBE_EVERY:
             self._since_probe = 0
+            count("routing.chooser.device_probe")
             return True
-        if self._dev_batch_s <= n * self._host_msg_s:
+        host_s = n * self._host_msg_s
+        if host_s > 0:
+            # < 1: the chip wins this window by the two measured costs
+            self.chooser_margin = self._dev_batch_s / host_s
+        if self._dev_batch_s <= host_s:
+            count("routing.chooser.cost_device")
             return True
-        self.node.metrics.inc("routing.device.bypassed")
+        count("routing.chooser.cost_host")
+        count("routing.device.bypassed")
         self._fuse_cwnd = 1      # re-enter fusion carefully next time
         return False
+
+    def chooser_state(self) -> dict:
+        """The `chooser` section of `PipelineTelemetry.snapshot()`: the
+        two cost EWMAs and the margin of the last cost comparison."""
+        out = {}
+        if self._dev_batch_s is not None:
+            out["dev_batch_ms"] = round(self._dev_batch_s * 1e3, 4)
+        if self._host_msg_s is not None:
+            out["host_msg_us"] = round(self._host_msg_s * 1e6, 4)
+        if self.chooser_margin is not None:
+            out["margin"] = round(self.chooser_margin, 4)
+        return out
 
 
 def _ewma(cur: Optional[float], sample: float, streak: int = 0,

@@ -585,6 +585,38 @@ class Channel:
         if self.conn_state != CONN_CONNECTED:
             raise ProtocolError(C.RC_PROTOCOL_ERROR,
                                 "publish before CONNECT")
+        # the synchronous part of the hand-off is ingress work on the
+        # profiler timeline; the awaits inside it are released
+        from emqx_tpu.broker.trace import spans_of
+        with spans_of(self.node).span(
+                "ingress", meta={"rows": len(burst.topics)}) as sp:
+            futs, seq, v5 = await self._burst_rows(burst, sp)
+        # flush: acks/errors/disconnects strictly in row order (wire
+        # order is the order of _send calls — awaits between them do
+        # not reorder the transport buffer)
+        for item in seq:
+            tag = item[0]
+            if tag == "disc":
+                self._disconnect_now(item[1], item[2])
+            elif tag == "err":
+                self._send([item[1]])
+            else:
+                _tag, qos, pid, ridx = item
+                cnt = await futs[ridx]
+                rc = C.RC_SUCCESS if (cnt or not v5) \
+                    else C.RC_NO_MATCHING_SUBSCRIBERS
+                cls = P.Puback if qos == C.QOS_1 else P.Pubrec
+                self._send([cls(packet_id=pid, reason_code=rc)])
+        # backpressure stragglers (QoS0 rows the batcher bounded): a
+        # full queue stalls this read loop, like a refused enqueue()
+        # falling back to an awaited submit() does on the packet path
+        for fut in futs.values():
+            await fut
+
+    async def _burst_rows(self, burst, sp) -> tuple:
+        """The per-row checks of `handle_publish_burst` and the one
+        `submit_burst`; returns (futures by row, ordered ack plan, v5).
+        `sp` is the caller's open span: every await here releases it."""
         node = self.node
         m = node.metrics
         n = len(burst.topics)
@@ -600,6 +632,7 @@ class Channel:
                         "proto_ver": self.proto_ver}
         valid_memo: dict = {}
         auth_memo: dict = {}
+        authz_hooked = bool(node.hooks.lookup("client.authorize"))
         rows: list = []        # (Message, needs_count) for submit_burst
         seq: list = []         # ordered ack/disconnect plan
         qos_counts = [0, 0, 0]
@@ -613,7 +646,8 @@ class Channel:
             if j and not j % 64:
                 # the handle_in loop's pacing: a read can carry hundreds
                 # of frames; yield so other tasks are not stalled
-                await asyncio.sleep(0)
+                with sp.released():
+                    await asyncio.sleep(0)
             topic = burst.topics[j]
             qos = burst.qos[j]
             props = burst.props[j]
@@ -660,7 +694,14 @@ class Channel:
                 continue
             ok = auth_memo.get(topic)
             if ok is None:
-                ok = await self._authorize("publish", topic)
+                if authz_hooked:
+                    with sp.released():
+                        ok = await self._authorize("publish", topic)
+                else:
+                    # an empty hook chain folds without suspending:
+                    # nothing to release the span for, and a release
+                    # a unique topic would be a span a message
+                    ok = await self._authorize("publish", topic)
                 auth_memo[topic] = ok
             if not ok:
                 m.inc("packets.publish.auth_error")
@@ -710,32 +751,13 @@ class Channel:
                 # in row order (exactly what publish_async would do)
                 loop = asyncio.get_running_loop()
                 for k, (msg, need) in enumerate(rows):
-                    cnt = await node.broker.publish_async(msg)
+                    with sp.released():
+                        cnt = await node.broker.publish_async(msg)
                     if need:
                         f = loop.create_future()
                         f.set_result(cnt)
                         futs[k] = f
-        # flush: acks/errors/disconnects strictly in row order (wire
-        # order is the order of _send calls — awaits between them do
-        # not reorder the transport buffer)
-        for item in seq:
-            tag = item[0]
-            if tag == "disc":
-                self._disconnect_now(item[1], item[2])
-            elif tag == "err":
-                self._send([item[1]])
-            else:
-                _tag, qos, pid, ridx = item
-                cnt = await futs[ridx]
-                rc = C.RC_SUCCESS if (cnt or not v5) \
-                    else C.RC_NO_MATCHING_SUBSCRIBERS
-                cls = P.Puback if qos == C.QOS_1 else P.Pubrec
-                self._send([cls(packet_id=pid, reason_code=rc)])
-        # backpressure stragglers (QoS0 rows the batcher bounded): a
-        # full queue stalls this read loop, like a refused enqueue()
-        # falling back to an awaited submit() does on the packet path
-        for fut in futs.values():
-            await fut
+        return futs, seq, v5
 
     def _burst_puberr(self, seq: list, qos: int, pid, rc: int) -> None:
         """_puberr over a columnar row: same metrics and packets, but

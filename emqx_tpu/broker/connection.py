@@ -84,6 +84,8 @@ class Connection:
         # this connection's read loop is byte-for-byte the per-packet
         # path (parser.feed + handle_in, no ingress counters)
         self._columnar = bool(getattr(node, "columnar_ingress", False))
+        from emqx_tpu.broker.trace import spans_of
+        self._spans = spans_of(node)
         self.channel = Channel(
             node, {"peername": peer, "sockname": sock, "zone": zone,
                    "peercert": peercert},
@@ -192,13 +194,16 @@ class Connection:
                 m.inc("bytes.received", len(data))
                 columnar = self._columnar
                 try:
-                    if columnar:
-                        # columnar ingress (ISSUE 11): PUBLISH runs
-                        # decode as PublishBurst items, everything else
-                        # (and small reads) stays per-packet, in order
-                        items = self.parser.feed_columnar(data)
-                    else:
-                        items = self.parser.feed(data)
+                    with self._spans.span("ingress",
+                                          meta={"bytes": len(data)}):
+                        if columnar:
+                            # columnar ingress (ISSUE 11): PUBLISH runs
+                            # decode as PublishBurst items, everything
+                            # else (and small reads) stays per-packet,
+                            # in order
+                            items = self.parser.feed_columnar(data)
+                        else:
+                            items = self.parser.feed(data)
                 except FrameError as e:
                     reason = f"frame_error:{e.code}"
                     self._frame_error_out(e)
@@ -364,6 +369,7 @@ class Listener:
         rate = (node.config.get_zone(zone, "rate_limit") or {}) \
             .get("max_conn_rate", 0)
         self._accept_bucket = TokenBucket(rate) if rate else None
+        self._gc_watched = False
 
     async def _on_client(self, reader: asyncio.StreamReader,
                          writer: asyncio.StreamWriter) -> None:
@@ -401,6 +407,15 @@ class Listener:
         return getattr(self.node, "ingress_lanes", 1)
 
     async def start(self) -> None:
+        await self._listen()
+        watch = getattr(self.node, "gc_watch", None)
+        if watch is not None and not self._gc_watched:
+            # the node serves from its first listener on: collections
+            # are counted (runtime.gc.*) until the last one stops
+            watch.start()
+            self._gc_watched = True
+
+    async def _listen(self) -> None:
         ssl_ctx = None
         if self.ssl_opts:
             from emqx_tpu.utils.tls import make_server_context
@@ -464,6 +479,9 @@ class Listener:
         # stop accepting first so no connection slips in during the cancel
         # window; then cancel handlers (py3.12 wait_closed blocks until
         # every handler coroutine finishes, so cancel before waiting)
+        if self._gc_watched:
+            self._gc_watched = False
+            self.node.gc_watch.stop()
         servers = self._lane_servers or \
             ([self._server] if self._server else [])
         for srv in servers:

@@ -60,7 +60,6 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
-import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -489,11 +488,18 @@ class DeliveryLanePool:
     """
 
     def __init__(self, broker, metrics, *, hooks=None, telemetry=None,
-                 n_lanes: int = 4, depth: int = 8, supervisor=None):
+                 n_lanes: int = 4, depth: int = 8, supervisor=None,
+                 spans=None):
         self.broker = broker
         self.metrics = metrics
         self.hooks = hooks
         self.telemetry = telemetry
+        # the one span call: deliver_lane{i} histogram, lane{i} ring
+        # span, emqx:lane on the profiler timeline
+        if spans is None:
+            from emqx_tpu.broker.trace import Spans
+            spans = Spans(telemetry, getattr(telemetry, "recorder", None))
+        self.spans = spans
         # fault-domain supervision (ISSUE 6): the lane_deliver breaker
         # gates active() (open → the engines deliver inline, the rung
         # below the lanes), slice faults are contained + retried, dead
@@ -777,15 +783,15 @@ class DeliveryLanePool:
     # ---- lane workers ---------------------------------------------------
     async def _worker(self, lane: int) -> None:
         q = self._queues[lane]
-        tele = self.telemetry
+        spans = self.spans
         while True:
             item = await q.get()
             if item[0] == "park":
                 if self._live_plans == 0 and q.empty():
                     return
                 continue
-            t0 = time.perf_counter()
             worked = True
+            sp = None
             try:
                 if not self._gate.is_set():
                     try:
@@ -798,7 +804,16 @@ class DeliveryLanePool:
                         # watchdog test exposed
                         self._surrender(item)
                         raise
-                t0 = time.perf_counter()   # gate wait is not lane work
+                # one span per lane item, opened after the gate wait
+                # (not lane work). item is ("slice", plan, lo, hi) or
+                # ("barrier", plan): the plan rides at [1] and carries
+                # its window's trace. The profiler annotation covers
+                # the synchronous row walk only: every await below
+                # releases it
+                sp = spans.span(
+                    "lane", getattr(item[1], "trace", 0),
+                    stage=f"deliver_lane{lane}", ring=f"lane{lane}",
+                    track=f"lane{lane}", meta={"lane": lane}).__enter__()
                 if item[0] == "slice":
                     _k, plan, lo, hi = item
                     try:
@@ -808,7 +823,8 @@ class DeliveryLanePool:
                                 # worker failing mid-slice must be
                                 # contained, not a silent task death
                                 self.sup.fire("lane_deliver")
-                            await self._run_slice(plan, lane, lo, hi)
+                            await self._run_slice(plan, lane, lo, hi,
+                                                  sp)
                             if self.sup is not None:
                                 self.sup.note_ok("lane_deliver")
                         except Exception as e:  # noqa: BLE001
@@ -827,7 +843,7 @@ class DeliveryLanePool:
                                 # slice would monopolize the loop, the
                                 # exact stall the chunking prevents
                                 await self._run_slice(plan, lane,
-                                                      lo, hi)
+                                                      lo, hi, sp)
                             except Exception:  # noqa: BLE001
                                 log.exception(
                                     "lane %d slice %d..%d lost after "
@@ -841,7 +857,7 @@ class DeliveryLanePool:
                     plan._barrier_left -= 1
                     if plan._barrier_left == 0:
                         try:
-                            await self._run_slow(plan)
+                            await self._run_slow(plan, sp)
                         finally:
                             plan._barrier_evt.set()
                             plan._finish_part()
@@ -852,24 +868,18 @@ class DeliveryLanePool:
                         # hashing skew in the deliver_lane{i}
                         # histograms
                         worked = False
-                        await plan._barrier_evt.wait()
+                        with sp.released():
+                            await plan._barrier_evt.wait()
             finally:
                 # gauge accounting must survive cancellation anywhere
                 # in the item's processing (mid-slice, barrier wait) or
                 # lane_depth overreports a stuck-deep lane forever
                 self._lane_items[lane] -= 1
-            if tele is not None and worked:
-                now = time.perf_counter()
-                tele.observe_stage(f"deliver_lane{lane}", now - t0)
-                rec = getattr(tele, "recorder", None)
-                if rec is not None:
-                    # item is ("slice", plan, lo, hi) or ("barrier",
-                    # plan): either way the plan rides at [1] and
-                    # carries its window's trace
-                    tr = getattr(item[1], "trace", 0)
-                    if tr:
-                        rec.record(tr, f"lane{lane}", t0, now,
-                                   track=f"lane{lane}")
+                if sp is not None:
+                    if worked:
+                        sp.__exit__(None, None, None)
+                    else:
+                        sp.drop()
 
     def _surrender(self, item) -> None:
         """Account a popped-but-unprocessed queue item when its worker
@@ -897,7 +907,7 @@ class DeliveryLanePool:
                 plan._finish_part()
 
     async def _run_slice(self, plan: DeliveryPlan, lane: int,
-                         lo: int, hi: int) -> None:
+                         lo: int, hi: int, sp) -> None:
         """Deliver one lane's slice, coalescing same-session runs, with
         a cooperative yield between chunks so a huge fan-out cannot
         monopolize the loop (other lanes and the producer keep running;
@@ -934,7 +944,8 @@ class DeliveryLanePool:
                             "pipeline.deliver.deliver_errors")
             pos = nxt
             if pos < hi:
-                await asyncio.sleep(0)
+                with sp.released():
+                    await asyncio.sleep(0)
 
     def _deliver_rows(self, plan: DeliveryPlan, lo: int, hi: int) -> None:
         broker = self.broker
@@ -1034,7 +1045,7 @@ class DeliveryLanePool:
                          lo=1.0 / 256, n_buckets=9,
                          unit="ratio").observe(1.0 - drains / n_rows)
 
-    async def _run_slow(self, plan: DeliveryPlan) -> None:
+    async def _run_slow(self, plan: DeliveryPlan, sp) -> None:
         """The ordering-safe serialized tail: slow-path messages in
         batch order, all lanes held at the barrier."""
         for n, (idx, fn) in enumerate(plan.slow_items):
@@ -1044,4 +1055,5 @@ class DeliveryLanePool:
                 log.exception("slow-path consume failed")  # != lost lane
                 self.metrics.inc("pipeline.deliver.slow_errors")
             if n % 64 == 63:
-                await asyncio.sleep(0)
+                with sp.released():
+                    await asyncio.sleep(0)

@@ -80,6 +80,7 @@ import time
 import zlib
 from typing import Optional
 
+import jax
 import numpy as np
 
 from emqx_tpu.broker.deliver import DEFERRED, OPT_TABLE, LaneCounts
@@ -573,6 +574,8 @@ class DeviceRouteEngine:
         self.node = node
         self.broker = node.broker
         self.router = node.broker.router
+        from emqx_tpu.broker.trace import spans_of
+        self.spans = spans_of(node)
         self.rebuild_threshold = resolve_rebuild_threshold(
             rebuild_threshold)
         self.max_levels = max_levels
@@ -2566,6 +2569,10 @@ class DeviceRouteEngine:
         serve (caller routes host-side; a background rebuild may be
         warming up).
         """
+        with self.spans.span("prepare_window", meta={"W": len(lives)}):
+            return self._prepare_window(lives, gate_cold)
+
+    def _prepare_window(self, lives: list, gate_cold: bool):
         self.poll_rebuild()
         if self._built is None or not lives:
             return None
@@ -2663,22 +2670,17 @@ class DeviceRouteEngine:
         steps (each dispatch is annotated as one profiler step, so the
         trace decomposes device execution from host time). Returns
         False when the backend has no profiler support."""
-        import jax
         try:
             jax.profiler.start_trace(log_dir)
-            self._tracing = True
             return True
         except Exception:  # noqa: BLE001 — a backend may lack it
             return False
 
     def stop_device_trace(self) -> None:
-        import jax
-        if getattr(self, "_tracing", False):
-            self._tracing = False
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                pass
+        try:
+            jax.profiler.stop_trace()
+        except Exception:  # noqa: BLE001 — no session to stop
+            pass
 
     # ---- ISSUE 9: donation + async readback helpers ---------------------
     def _rt(self, fn):
@@ -2773,13 +2775,16 @@ class DeviceRouteEngine:
         an IN-PATH recompile (the kind the warm gates exist to
         prevent)."""
         tele = getattr(self.node, "pipeline_telemetry", None)
-        stage = "dispatch" if h.plan is None else "dispatch_cached"
-        t0 = time.perf_counter()
-        try:
+        Wp, Bp = h.enc[0].shape[0], h.enc[0].shape[1]
+        cached = h.plan is not None
+        with self.spans.span(
+                "dispatch", h.trace,
+                stage="dispatch_cached" if cached else "dispatch",
+                track="dispatch",
+                meta={"W": Wp, "B": Bp, "cached": cached}):
             if tele is not None:
-                Wp, Bp = h.enc[0].shape[0], h.enc[0].shape[1]
-                label = f"dispatch W{Wp}xB{Bp}" if h.plan is None \
-                    else f"dispatch W{Wp}xB{Bp}mB{h.plan.Bm}cached"
+                label = f"dispatch W{Wp}xB{Bp}mB{h.plan.Bm}cached" \
+                    if cached else f"dispatch W{Wp}xB{Bp}"
                 with tele.compile_context(label):
                     self._dispatch_annotated(h)
             else:
@@ -2789,28 +2794,14 @@ class DeviceRouteEngine:
                 # still owns the dispatch slot — the transfer hides
                 # under the NEXT window's dispatch
                 self._start_readback(h)
-        finally:
-            if tele is not None:
-                tele.observe_stage(stage, time.perf_counter() - t0)
-            self._rec_span(h.trace, stage, t0, track="dispatch",
-                           meta={"W": h.enc[0].shape[0],
-                                 "B": h.enc[0].shape[1]})
 
     def _dispatch_annotated(self, h) -> None:
-        if getattr(self, "_tracing", False):
-            import jax
-            # the step_num IS the window's flight-recorder trace id
-            # (ISSUE 7): a jax.profiler capture's device timeline joins
-            # the host-side Perfetto dump on the same key. Windows with
-            # no trace (knob off) keep the old private counter.
-            step = h.trace
-            if not step:
-                self._step_num = getattr(self, "_step_num", 0) + 1
-                step = self._step_num
-            with jax.profiler.StepTraceAnnotation("route_step",
-                                                  step_num=step):
-                self._dispatch_inner(h)
-        else:
+        # the step_num IS the window's flight-recorder trace id
+        # (ISSUE 7): the device timeline of any jax.profiler capture
+        # joins the host-side spans on the same key (0 for a window
+        # that carries no trace)
+        with jax.profiler.StepTraceAnnotation("route_step",
+                                              step_num=h.trace):
             self._dispatch_inner(h)
 
     def _msg_hashes(self, msgs, strat_id) -> list[int]:
@@ -3063,9 +3054,12 @@ class DeviceRouteEngine:
         of the same fused program — the fallback re-dispatches nothing).
         Both paths meter actual transferred bytes into the
         pipeline.readback.* counters all four exporters carry."""
-        tele = getattr(self.node, "pipeline_telemetry", None)
+        with self.spans.span("materialize", h.trace,
+                             track="materialize"):
+            self._materialize(h)
+
+    def _materialize(self, h) -> None:
         metrics = self.node.metrics
-        t0 = time.perf_counter()
         corrupt = None
         if self.sup is not None:
             # ISSUE 6 injection point (executor thread): exceptions
@@ -3128,11 +3122,6 @@ class DeviceRouteEngine:
                                     version=info.version)
                 if corrupt:
                     self._corrupt_readback(h)
-                if tele is not None:
-                    tele.observe_stage("materialize",
-                                       time.perf_counter() - t0)
-                self._rec_span(h.trace, "materialize", t0,
-                               track="materialize")
                 return
         h.np_res = (np.asarray(res.matches), np.asarray(res.rows),
                     np.asarray(res.opts), np.asarray(res.shared_sids),
@@ -3166,18 +3155,6 @@ class DeviceRouteEngine:
         metrics.inc("pipeline.readback.windows.dense")
         if corrupt:
             self._corrupt_readback(h)
-        if tele is not None:
-            tele.observe_stage("materialize", time.perf_counter() - t0)
-        self._rec_span(h.trace, "materialize", t0, track="materialize")
-
-    def _rec_span(self, trace_id: int, name: str, t0: float, *,
-                  track: str, parent: int = 0, meta=None) -> None:
-        """Record one [t0, now] span on the flight recorder (no-op
-        when tracing is off or the window carries no trace)."""
-        rec = getattr(self.node, "flight_recorder", None)
-        if rec is not None and trace_id:
-            rec.record(trace_id, name, t0, time.perf_counter(),
-                       track=track, parent=parent, meta=meta)
 
     def _corrupt_readback(self, h) -> None:
         """Apply the injected corrupt-shape fault: truncate the window
@@ -3239,8 +3216,13 @@ class DeviceRouteEngine:
         itself lands in the per-lane deliver_lane{i} histograms).
         `defer=False` (sync callers: route_batch/finish) keeps the
         inline consume — counts are final on return."""
-        tele = getattr(self.node, "pipeline_telemetry", None)
-        t0 = time.perf_counter()
+        with self.spans.span(
+                "finish_sub",
+                h.sub_traces[k] if h.sub_traces and k < len(h.sub_traces)
+                else h.trace, stage="deliver", track="consume"):
+            return self._finish_sub(h, k, defer)
+
+    def _finish_sub(self, h, k: int, defer: bool):
         plan = None
         deferred = False
         try:
@@ -3354,12 +3336,6 @@ class DeviceRouteEngine:
                 return out
             return counts
         finally:
-            if tele is not None:
-                tele.observe_stage("deliver", time.perf_counter() - t0)
-            self._rec_span(h.sub_traces[k]
-                           if h.sub_traces and k < len(h.sub_traces)
-                           else h.trace,
-                           "deliver", t0, track="consume")
             if not deferred:
                 self._release_one(h)
 

@@ -119,13 +119,21 @@ class Node:
         # (self.flight_recorder stays None everywhere).
         self.flight_recorder = None
         mc = perf.get("multichip") or {}
-        from emqx_tpu.broker.trace import FlightRecorder, resolve_trace
+        from emqx_tpu.broker.trace import (FlightRecorder, GcWatch, Spans,
+                                           resolve_trace)
         if resolve_trace(perf.get("trace")) \
                 and (use_device or mc.get("enable")):
             self.flight_recorder = FlightRecorder(
                 self.metrics, cap=perf.get("trace_ring", 4096),
                 sample=perf.get("trace_sample"))
             self.pipeline_telemetry.recorder = self.flight_recorder
+        # the one span call of every pipeline stage: stage histogram,
+        # ring (when there is one) and the profiler's host timeline
+        self.spans = Spans(self.pipeline_telemetry, self.flight_recorder)
+        # the interpreter's collections as counters (runtime.gc.*) and,
+        # for generation 2, as spans; installed while a listener or the
+        # housekeeping timer runs
+        self.gc_watch = GcWatch(self.metrics, self.spans)
         # fault-domain supervision (ISSUE 6): the per-node supervision
         # tree every pipeline stage plugs into — fault injection points,
         # per-stage circuit breakers driving the degradation ladder
@@ -223,7 +231,7 @@ class Node:
                 self.broker, self.metrics, hooks=self.hooks,
                 telemetry=self.pipeline_telemetry, n_lanes=n_lanes,
                 depth=perf.get("deliver_lane_depth", 8),
-                supervisor=self.supervisor)
+                supervisor=self.supervisor, spans=self.spans)
             self.pipeline_telemetry.deliver_state_fn = \
                 self.deliver_lanes.state
             self.stats.register_stats_fun(self.deliver_lanes.stats_fun)
@@ -287,6 +295,9 @@ class Node:
                 max_batch=perf.get("max_publish_batch", 1024),
                 device_min_batch=perf.get("device_min_batch", 4),
                 dispatch_depth=dispatch_depth)
+        if self.publish_batcher is not None:
+            self.pipeline_telemetry.chooser_state_fn = \
+                self.publish_batcher.chooser_state
         self.cm = ConnectionManager()
         self.cm.broker = self.broker
         self.banned = Banned()
@@ -460,11 +471,13 @@ class Node:
             self._timer_task = guard_task(
                 asyncio.ensure_future(self._housekeeping(interval)),
                 "node-housekeeping", self.metrics)
+            self.gc_watch.start()
 
     def stop_timers(self) -> None:
         if self._timer_task is not None:
             self._timer_task.cancel()
             self._timer_task = None
+            self.gc_watch.stop()
 
     # ---- facade (emqx.erl) ----
     def publish(self, msg: Message) -> int:

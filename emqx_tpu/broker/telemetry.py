@@ -38,8 +38,15 @@ attributed to the (W, Bp) class that triggered it, with trace + lowering
 Everything lands in the node's Metrics registry, so the Prometheus,
 StatsD and $SYS exporters pick the histograms up with zero coupling to
 this module; `snapshot()` is the JSON schema shared by
-`GET /api/v5/pipeline/stats`, bench.py's embedded telemetry and
-`tools/profile_step.py --telemetry-out`.
+`GET /api/v5/pipeline/stats`, bench.py's embedded telemetry and the
+benchmark's `telemetry` reader (`benchmark/readers/telemetry.py`).
+
+The serving path does not call `observe_stage` at its stage boundaries
+itself: it makes ONE span call (`broker/trace.py`, `Spans.span`), which
+observes the stage histogram here, records the flight recorder's ring
+span and puts an `emqx:<name>` annotation on the profiler's host
+timeline. `observe_stage` stays the direct form for per-message
+samples (`host_match`) and for harnesses.
 """
 
 from __future__ import annotations
@@ -132,8 +139,8 @@ def _install_listener() -> bool:
 class PipelineTelemetry:
     """Per-node (or standalone) pipeline telemetry registry.
 
-    Node wires one up as `node.pipeline_telemetry`; tools/profile_step
-    builds a standalone one around its own Metrics. All hot-path entry
+    Node wires one up as `node.pipeline_telemetry`; a harness can
+    build a standalone one around its own Metrics. All hot-path entry
     points are plain histogram observes — no locks, no allocation beyond
     the first observation of a new occupancy class.
     """
@@ -155,6 +162,11 @@ class PipelineTelemetry:
         # ISSUE-6 PipelineSupervisor exists): breaker states, ladder
         # rung, window-journal depth, armed fault clauses
         self.supervise_state_fn = None
+        # the batcher's device/host chooser (set by the node when it
+        # has a PublishBatcher): snapshot() derives the `chooser`
+        # section — the two cost EWMAs, the margin of the last cost
+        # comparison and the verdict counts by reason — from it
+        self.chooser_state_fn = None
         # the window-causal flight recorder (ISSUE 7; set by the node
         # when broker.trace / EMQX_TPU_TRACE is on): snapshot() derives
         # the `trace` section — ring state + overlap/bubble analysis —
@@ -346,13 +358,13 @@ class PipelineTelemetry:
     def snapshot(self, full: bool = False) -> dict:
         """The one pipeline-telemetry JSON schema: served by
         GET /api/v5/pipeline/stats, embedded in bench.py's success and
-        error JSON, dumped by tools/profile_step.py --telemetry-out and
+        error JSON, read by the benchmark's `telemetry` reader and
         published (piecewise) on $SYS/brokers/<node>/pipeline/#.
 
         ``full=True`` emits EVERY section of the schema (rebuild /
         deliver / supervise / readback / match_cache / dedup / trace),
         empty when the layer has no traffic — consumers that diff
-        snapshots across rounds (profile_step, offline tooling) get a
+        snapshots across rounds (offline tooling) get a
         stable shape instead of sections popping in and out."""
         stages = {}
         occupancy = {}
@@ -400,6 +412,21 @@ class PipelineTelemetry:
             v = self.metrics.val(extra)
             if v:
                 decisions[extra] = v
+        # the chooser: why each window went where it went. `margin` is
+        # dev_batch / (n * host_msg) of the last cost comparison (< 1:
+        # the chip wins); `verdicts` counts every decision by reason
+        # (first, host_probe, device_probe, cost_device, cost_host)
+        chooser = {}
+        if self.chooser_state_fn is not None:
+            try:
+                chooser = dict(self.chooser_state_fn())
+            except Exception:  # noqa: BLE001 — telemetry never raises
+                pass
+            verdicts = {k.rsplit(".", 1)[1]: v
+                        for k, v in self.metrics.all().items()
+                        if k.startswith("routing.chooser.")}
+            if verdicts:
+                chooser["verdicts"] = verdicts
         # device-match reuse layers: cross-batch cache + in-window dedup
         # (broker/device_engine.py; counters land in the shared Metrics
         # registry, so all four exporters already carry them — this
@@ -633,6 +660,8 @@ class PipelineTelemetry:
         }
         if self.device_info is not None:
             out["device"] = dict(self.device_info)
+        if chooser or (full and self.chooser_state_fn is not None):
+            out["chooser"] = chooser
         if supervise or full:
             out["supervise"] = supervise
         if rebuild or full:
@@ -672,7 +701,7 @@ class PipelineTelemetry:
             out["jit_cache"] = jc
         # jit-program cost registry (ISSUE 8): per-(program, class)
         # compile wall-time — and flops/bytes once an off-path consumer
-        # (tools/profile_step.py --cost-out) has analyzed them — keyed
+        # (cost_stats(analyze=True)) has analyzed them — keyed
         # by the same labels as compiles.by_shape. Snapshot never
         # triggers the (re-lowering) analysis itself.
         pc = _program_costs()

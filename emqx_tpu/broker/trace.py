@@ -1,10 +1,33 @@
-"""Window-causal flight recorder for the device route pipeline (ISSUE 7).
+"""The pipeline's tracing: one span call, and the window-causal flight
+recorder under it (ISSUE 7, ISSUE 24).
 
-PR 1's stage histograms aggregate away exactly what the device-e2e gap
-diagnosis needs: CAUSALITY (which admit fed which dispatch fed which
-delivery) and OVERLAP (how much dispatch(W+1) actually hides
-materialize(W), and where the bubbles sit). This module is the causal
-layer under the histograms:
+**One span call, three sinks** (`Spans`, wired as `node.spans`). Every
+stage boundary of the serving path (batcher, engines, delivery lanes,
+connection ingress) is one `with node.spans.span(name, trace_id, ...)`,
+and that one call (a) observes the stage histogram of
+`PipelineTelemetry` where the span is a telemetry stage, (b) records
+the span on the flight recorder's ring below, under the window's trace
+id and parent, and (c) wraps the stretch in
+``jax.profiler.TraceAnnotation("emqx:<name>", trace_id=..., ...)``, so
+it is on the host plane of ANY active profiler session, whoever started
+it (a benchmark, or an operator's `emqx_ctl trace device start`), on
+the same clock as the device's operations. Every dispatch also runs
+under ``StepTraceAnnotation("route_step", step_num=<trace id>)``. Spans
+are per window, per read burst and per lane item, never per message;
+on the event-loop thread no `emqx:` span encloses an `await`
+(`span.released()` around each one), or it would bill other
+coroutines' work to itself. Waits (enqueue, lane_admit, lane_drain, the
+window and message roll-ups) are recorded in retrospect
+(`Spans.record`): histogram and ring only. `GcWatch` counts the
+interpreter's collections (`runtime.gc.*`) and makes a generation-2
+collection a span (`emqx:gc`). With ``broker.trace`` off the ring is
+absent and sinks (a) and (c) remain.
+
+**The flight recorder.** PR 1's stage histograms aggregate away exactly
+what the device-e2e gap diagnosis needs: CAUSALITY (which admit fed
+which dispatch fed which delivery) and OVERLAP (how much dispatch(W+1)
+actually hides materialize(W), and where the bubbles sit). The ring is
+the causal layer under the histograms:
 
 - **Window traces**: every publish window gets a trace id minted at
   batcher admit (`FlightRecorder.new_trace`) and propagated through the
@@ -300,6 +323,207 @@ class FlightRecorder:
             if k in a:
                 out[k] = a[k]
         return out
+
+
+# ---- the one span call: histogram + ring + profiler timeline ----------
+
+PROFILER_PREFIX = "emqx:"
+
+
+class _Released:
+    """`with span.released():` around an `await` inside a span on the
+    event-loop thread: the profiler annotation is left for the wait and
+    a fresh one entered after it, so it never bills other coroutines'
+    work to this span. Histogram and ring still see the whole stage."""
+
+    __slots__ = ("_sp",)
+
+    def __init__(self, sp):
+        self._sp = sp
+
+    def __enter__(self):
+        self._sp._ann.__exit__(None, None, None)
+
+    def __exit__(self, *exc):
+        self._sp._ann = self._sp._annotate()
+
+
+class _Span:
+    """One open span (see `Spans.span`). After exit `dur` is its
+    seconds and `sid` its ring span id (0 when the ring took nothing),
+    for child linking."""
+
+    __slots__ = ("_o", "_label", "_kw", "_ann", "trace", "stage", "ring",
+                 "track", "parent", "meta", "t0", "dur", "sid")
+
+    def __init__(self, owner, name, trace, stage, ring, track, parent,
+                 meta):
+        self._o = owner
+        self._label = PROFILER_PREFIX + name
+        kw = dict(meta) if meta else {}
+        if trace:
+            kw["trace_id"] = trace
+        self._kw = kw
+        self.trace = trace
+        self.stage = stage
+        self.ring = ring
+        self.track = track
+        self.parent = parent
+        self.meta = meta
+        self.sid = 0
+
+    def _annotate(self):
+        return self._o.annotate(self._label, **self._kw)
+
+    def released(self) -> _Released:
+        return _Released(self)
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self._ann = self._annotate()
+        return self
+
+    def drop(self) -> None:
+        """Leave the span without feeding histogram or ring (the
+        stretch turned out to be no work of this stage's)."""
+        self._ann.__exit__(None, None, None)
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
+        t1 = time.perf_counter()
+        self.dur = t1 - self.t0
+        self.sid = self._o.record(
+            self.ring, self.trace, self.t0, t1, stage=self.stage,
+            track=self.track, parent=self.parent, meta=self.meta)
+        return False
+
+
+class Spans:
+    """The pipeline's one span call, feeding three sinks:
+
+    (a) the stage histogram of `PipelineTelemetry`, where the span names
+        a telemetry stage (`stage=`, by default the name when it is one
+        of `telemetry.STAGES`);
+    (b) the flight-recorder ring, under the window's trace id and parent
+        (skipped with `broker.trace` off, or for a window that carries
+        no trace: `trace` 0);
+    (c) `jax.profiler.TraceAnnotation("emqx:<name>", trace_id=...,
+        **meta)`, so the stretch is on the host plane of any profiler
+        session that is active, whoever started it. Inactive, it costs
+        about half a microsecond.
+
+    `span()` is for work: per window, per burst, per lane item, never
+    per message; on the event-loop thread every `await` inside one is
+    wrapped in `span.released()`. `record()` is the retrospective form
+    for waits (enqueue, lane_drain, ...): sinks (a) and (b) only."""
+
+    def __init__(self, tele=None, rec=None):
+        from jax.profiler import TraceAnnotation
+        from emqx_tpu.broker.telemetry import STAGES
+        self.tele = tele
+        self.rec = rec
+        self._annotation = TraceAnnotation
+        self._stages = frozenset(STAGES)
+
+    def annotate(self, label: str, **stats):
+        """An entered profiler annotation; the caller leaves it with
+        `__exit__`. A TraceMe starts its clock when it is built, so
+        each stretch gets a new one."""
+        return self._annotation(label, **stats).__enter__()
+
+    def span(self, name: str, trace: int = 0, *,
+             stage: Optional[str] = None, ring: Optional[str] = None,
+             track: str = "pipeline", parent: int = 0,
+             meta: Optional[dict] = None) -> _Span:
+        if stage is None and name in self._stages:
+            stage = name
+        return _Span(self, name, trace, stage, ring or stage or name,
+                     track, parent, meta)
+
+    def record(self, name: str, trace: int, t0: float,
+               t1: Optional[float] = None, *,
+               stage: Optional[str] = None, track: str = "pipeline",
+               parent: int = 0, meta: Optional[dict] = None) -> int:
+        """Observe `[t0, t1 or now]` retrospectively; returns the ring
+        span id (0 when the ring took nothing)."""
+        if t1 is None:
+            t1 = time.perf_counter()
+        if stage is not None and self.tele is not None:
+            self.tele.observe_stage(stage, t1 - t0)
+        if self.rec is not None and trace:
+            return self.rec.record(trace, name, t0, t1, track=track,
+                                   parent=parent, meta=meta)
+        return 0
+
+
+def spans_of(node) -> Spans:
+    """A node's `Spans`; bare test-harness nodes without one get the
+    sinks they do carry."""
+    sp = getattr(node, "spans", None)
+    if sp is None:
+        sp = Spans(getattr(node, "pipeline_telemetry", None),
+                   getattr(node, "flight_recorder", None))
+        try:
+            node.spans = sp
+        except AttributeError:
+            pass
+    return sp
+
+
+class GcWatch:
+    """The interpreter's collections, counted where they happen: one
+    `gc.callbacks` entry while the node serves (`start`/`stop` are
+    counted, one per listener or timer). Every collection lands in
+    `runtime.gc.pauses.gen{0,1,2}` and `runtime.gc.pause_us`; a
+    generation-2 collection (hundreds of milliseconds on a broker's
+    heap, with every thread stopped) is also a span: `emqx:gc` on the
+    profiler timeline, entered in the callback's start phase and left
+    in its stop phase (the same thread by construction), and a `gc`
+    span on the ring's node trace."""
+
+    def __init__(self, metrics, spans: Spans):
+        self.metrics = metrics
+        self.spans = spans
+        self._users = 0
+        self._t0 = 0.0
+        self._ann = None
+
+    def start(self) -> None:
+        self._users += 1
+        if self._users == 1:
+            import gc
+            gc.callbacks.append(self._on_gc)
+
+    def stop(self) -> None:
+        if self._users == 0:
+            return
+        self._users -= 1
+        if self._users == 0:
+            import gc
+            try:
+                gc.callbacks.remove(self._on_gc)
+            except ValueError:
+                pass
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        gen = info.get("generation", 0)
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            if gen == 2:
+                self._ann = self.spans.annotate(PROFILER_PREFIX + "gc",
+                                                generation=2)
+            return
+        t1 = time.perf_counter()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        m = self.metrics
+        m.inc(f"runtime.gc.pauses.gen{gen}")
+        m.inc("runtime.gc.pause_us", round((t1 - self._t0) * 1e6))
+        rec = self.spans.rec
+        if gen == 2 and rec is not None:
+            rec.record(NODE_TRACE, "gc", self._t0, t1, track="runtime",
+                       meta={"generation": 2})
 
 
 # ---- the overlap/bubble analyzer (pure functions, reusable offline) ----

@@ -89,10 +89,14 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
                msg_hash: jax.Array, strategy: jax.Array, *,
                fanout_cap: int, slot_cap: int) -> RouteResult:
     """Fan-out + shared-sub selection on a MatchResult (backend-agnostic)."""
-    fr: FanoutResult = fanout_normal(subs, mr.matches, fanout_cap=fanout_cap)
-    sids, slot_oflow = shared_slots(subs, mr.matches, slot_cap=slot_cap)
-    sp: SharedPickResult = pick_members(subs, cursors, sids, strategy,
-                                        msg_hash)
+    with jax.named_scope("fanout"):
+        fr: FanoutResult = fanout_normal(subs, mr.matches,
+                                         fanout_cap=fanout_cap)
+    with jax.named_scope("shared"):
+        sids, slot_oflow = shared_slots(subs, mr.matches,
+                                        slot_cap=slot_cap)
+        sp: SharedPickResult = pick_members(subs, cursors, sids, strategy,
+                                            msg_hash)
     overflow = mr.overflow | fr.overflow | slot_oflow
     return RouteResult(
         matches=mr.matches, match_counts=mr.counts,
@@ -110,8 +114,9 @@ def route_step(tables: RouterTables, cursors: jax.Array, topics: jax.Array,
                match_cap: int = 64, fanout_cap: int = 128,
                slot_cap: int = 16) -> RouteResult:
     """Trie-NFA route step: match + fan-out + shared picks (general shapes)."""
-    mr = match_batch(tables.trie, topics, lens, is_dollar,
-                     frontier_cap=frontier_cap, match_cap=match_cap)
+    with jax.named_scope("match"):
+        mr = match_batch(tables.trie, topics, lens, is_dollar,
+                         frontier_cap=frontier_cap, match_cap=match_cap)
     return post_match(tables.subs, mr, cursors, msg_hash, strategy,
                       fanout_cap=fanout_cap, slot_cap=slot_cap)
 
@@ -123,7 +128,8 @@ def route_step_shapes(tables: ShapeRouterTables, cursors: jax.Array,
                       strategy: jax.Array, *, fanout_cap: int = 128,
                       slot_cap: int = 16) -> RouteResult:
     """Shape-hash route step: one bucket gather per (topic, shape)."""
-    mr = shape_match(tables.shapes, topics, lens, is_dollar)
+    with jax.named_scope("match"):
+        mr = shape_match(tables.shapes, topics, lens, is_dollar)
     return post_match(tables.subs, mr, cursors, msg_hash, strategy,
                       fanout_cap=fanout_cap, slot_cap=slot_cap)
 
@@ -149,12 +155,13 @@ def route_step_cached(tables: RouterTables, cursors: jax.Array,
     width before the cursor-dependent post stage, so fan-out, shared
     picks and cursor threading are bit-identical to the un-deduplicated
     `route_step` on the same batch (oracle-tested)."""
-    mr = match_batch(tables.trie, miss_topics, miss_lens, miss_dollar,
-                     frontier_cap=frontier_cap, match_cap=match_cap)
-    um = merge_match_results(base_matches, base_counts, base_overflow,
-                             mr, miss_pos)
-    full = MatchResult(matches=um.matches[inv], counts=um.counts[inv],
-                       overflow=um.overflow[inv])
+    with jax.named_scope("match"):
+        mr = match_batch(tables.trie, miss_topics, miss_lens, miss_dollar,
+                         frontier_cap=frontier_cap, match_cap=match_cap)
+        um = merge_match_results(base_matches, base_counts,
+                                 base_overflow, mr, miss_pos)
+        full = MatchResult(matches=um.matches[inv], counts=um.counts[inv],
+                           overflow=um.overflow[inv])
     return post_match(tables.subs, full, cursors, msg_hash, strategy,
                       fanout_cap=fanout_cap, slot_cap=slot_cap)
 
@@ -179,20 +186,24 @@ def route_window_cached(tables: ShapeRouterTables, cursors: jax.Array,
     exactly as W sequential `route_step_shapes` calls, so the stacked
     RouteResult is bit-identical to `route_window_full` on the same
     window (oracle-tested)."""
-    mr = shape_match(tables.shapes, miss_topics, miss_lens, miss_dollar)
-    um = merge_match_results(base_matches, base_counts, base_overflow,
-                             mr, miss_pos)
+    with jax.named_scope("match"):
+        mr = shape_match(tables.shapes, miss_topics, miss_lens,
+                         miss_dollar)
+        um = merge_match_results(base_matches, base_counts,
+                                 base_overflow, mr, miss_pos)
 
     def step(cur, xs):
         inv_k, mh_k = xs
-        full = MatchResult(matches=um.matches[inv_k],
-                           counts=um.counts[inv_k],
-                           overflow=um.overflow[inv_k])
+        with jax.named_scope("match"):
+            full = MatchResult(matches=um.matches[inv_k],
+                               counts=um.counts[inv_k],
+                               overflow=um.overflow[inv_k])
         r = post_match(tables.subs, full, cur, mh_k, strategy,
                        fanout_cap=fanout_cap, slot_cap=slot_cap)
         return r.new_cursors, r
 
-    _, stacked = jax.lax.scan(step, cursors, (inv, msg_hash))
+    with jax.named_scope("scan"):
+        _, stacked = jax.lax.scan(step, cursors, (inv, msg_hash))
     return stacked
 
 
@@ -218,9 +229,11 @@ def _with_compact(r: RouteResult, payload_cap: int,
     compiles away). The engine's window variants are shapes-only and
     the step variants trie-only, so each hardcodes its flag."""
     from emqx_tpu.ops.compact import compact_result
-    cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
-                        r.shared_sids, r.shared_rows, r.shared_opts,
-                        payload_cap=payload_cap, match_holes=match_holes)
+    with jax.named_scope("compact"):
+        cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
+                            r.shared_sids, r.shared_rows, r.shared_opts,
+                            payload_cap=payload_cap,
+                            match_holes=match_holes)
     return CompactRouteResult(res=r, compact=cp)
 
 
@@ -357,11 +370,13 @@ def _window_delta(delta: DeltaTables, topics: jax.Array, lens: jax.Array,
     cursor-independent, so it runs ONCE over the flattened lanes instead
     of per scan step."""
     W, B = topics.shape[:2]
-    mr = delta_match(delta, topics.reshape(W * B, -1),
-                     lens.reshape(W * B), is_dollar.reshape(W * B),
-                     match_cap=dmatch_cap)
-    dp = delta_expand(delta, mr, fanout_cap=dfan_cap)
-    return DeltaPlanes(*[x.reshape((W, B) + x.shape[1:]) for x in dp])
+    with jax.named_scope("delta"):
+        mr = delta_match(delta, topics.reshape(W * B, -1),
+                         lens.reshape(W * B), is_dollar.reshape(W * B),
+                         match_cap=dmatch_cap)
+        dp = delta_expand(delta, mr, fanout_cap=dfan_cap)
+        return DeltaPlanes(*[x.reshape((W, B) + x.shape[1:])
+                             for x in dp])
 
 
 def _cached_delta(delta: DeltaTables, miss_topics, miss_lens, miss_dollar,
@@ -375,11 +390,12 @@ def _cached_delta(delta: DeltaTables, miss_topics, miss_lens, miss_dollar,
     unique rows against the CURRENT overlay CSR — so cached rows carry
     no membership state and a subscriber change can never stale them —
     and `inv` gathers back to full width."""
-    mr = delta_match(delta, miss_topics, miss_lens, miss_dollar,
-                     match_cap=dmatch_cap)
-    um = merge_match_results(base_dm, base_dc, base_do, mr, miss_pos)
-    dp_u = delta_expand(delta, um, fanout_cap=dfan_cap)
-    return DeltaPlanes(*[x[inv] for x in dp_u])
+    with jax.named_scope("delta"):
+        mr = delta_match(delta, miss_topics, miss_lens, miss_dollar,
+                         match_cap=dmatch_cap)
+        um = merge_match_results(base_dm, base_dc, base_do, mr, miss_pos)
+        dp_u = delta_expand(delta, um, fanout_cap=dfan_cap)
+        return DeltaPlanes(*[x[inv] for x in dp_u])
 
 
 def _stack1_dp(dp: DeltaPlanes) -> DeltaPlanes:
@@ -403,9 +419,11 @@ def route_step_delta(tables: RouterTables, delta: DeltaTables,
                    strategy, frontier_cap=frontier_cap,
                    match_cap=match_cap, fanout_cap=fanout_cap,
                    slot_cap=slot_cap)
-    dp = delta_expand(delta, delta_match(delta, topics, lens, is_dollar,
-                                         match_cap=delta_match_cap),
-                      fanout_cap=delta_fanout_cap)
+    with jax.named_scope("delta"):
+        dp = delta_expand(delta,
+                          delta_match(delta, topics, lens, is_dollar,
+                                      match_cap=delta_match_cap),
+                          fanout_cap=delta_fanout_cap)
     return DeltaRouteResult(res=_stack1(r), dp=_stack1_dp(dp))
 
 
@@ -507,16 +525,18 @@ def _with_delta_compact(dres: DeltaRouteResult, payload_cap: int,
     prefix-compacted (match_holes=False compiles the hole stage away)."""
     from emqx_tpu.ops.compact import compact_result
     r, dp = dres.res, dres.dp
-    cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
-                        r.shared_sids, r.shared_rows, r.shared_opts,
-                        payload_cap=payload_cap, match_holes=match_holes)
-    W, B = dp.fids.shape[:2]
-    no_slot = jnp.full((W, B, 1), -1, jnp.int32)
-    zero32 = jnp.zeros((W, B, 1), jnp.int32)
-    zero8 = jnp.zeros((W, B, 1), jnp.int8)
-    dcp = compact_result(dp.fids, dp.rows, dp.opts, dp.fan_counts,
-                         no_slot, zero32, zero8,
-                         payload_cap=d_payload_cap, match_holes=False)
+    with jax.named_scope("compact"):
+        cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
+                            r.shared_sids, r.shared_rows, r.shared_opts,
+                            payload_cap=payload_cap,
+                            match_holes=match_holes)
+        W, B = dp.fids.shape[:2]
+        no_slot = jnp.full((W, B, 1), -1, jnp.int32)
+        zero32 = jnp.zeros((W, B, 1), jnp.int32)
+        zero8 = jnp.zeros((W, B, 1), jnp.int8)
+        dcp = compact_result(dp.fids, dp.rows, dp.opts, dp.fan_counts,
+                             no_slot, zero32, zero8,
+                             payload_cap=d_payload_cap, match_holes=False)
     return CompactDeltaRouteResult(dres=dres, compact=cp, d_compact=dcp)
 
 
@@ -673,8 +693,9 @@ def route_window_shapes(tables: ShapeRouterTables, cursors: jax.Array,
                               fanout_cap=fanout_cap, slot_cap=slot_cap)
         return r.new_cursors, route_digest(r)
 
-    new_cursors, digests = jax.lax.scan(
-        step, cursors, (topics, lens, is_dollar, msg_hash))
+    with jax.named_scope("scan"):
+        new_cursors, digests = jax.lax.scan(
+            step, cursors, (topics, lens, is_dollar, msg_hash))
     return new_cursors, digests
 
 
@@ -695,8 +716,9 @@ def route_window_full(tables: ShapeRouterTables, cursors: jax.Array,
                               fanout_cap=fanout_cap, slot_cap=slot_cap)
         return r.new_cursors, r
 
-    _, stacked = jax.lax.scan(
-        step, cursors, (topics, lens, is_dollar, msg_hash))
+    with jax.named_scope("scan"):
+        _, stacked = jax.lax.scan(
+            step, cursors, (topics, lens, is_dollar, msg_hash))
     return stacked
 
 
@@ -819,9 +841,8 @@ def record_program_cost(program: str, label: str, *,
                         compile_ms: float = 0.0, flops=None,
                         bytes_accessed=None, avals=None) -> None:
     """Register/extend one (program, class) cost row. The wrapped route
-    programs call this on compile detection; external harnesses
-    (tools/profile_step.py) use it to put their own kernels in the same
-    table."""
+    programs call this on compile detection; an external harness can
+    use it to put its own kernels in the same table."""
     with _costs_lock:
         row = _COSTS.setdefault(program, {}).setdefault(
             label, {"compiles": 0, "compile_ms": 0.0})
@@ -856,8 +877,8 @@ def cost_stats(analyze: bool = False) -> dict:
     """The per-program cost table: {program: {class_label: {compiles,
     compile_ms[, flops, bytes_accessed]}}}. `analyze=True` fills any
     missing flop/byte rows by re-lowering from the saved avals —
-    tracing cost only, meant for off-path consumers (profile_step
-    --cost-out, tools) — and drops the avals afterwards. The default
+    tracing cost only, meant for off-path consumers (tools) — and
+    drops the avals afterwards. The default
     is cheap and is what snapshot()["program_costs"] embeds."""
     if analyze:
         with _costs_lock:

@@ -58,8 +58,8 @@ class SharedPickResult(NamedTuple):
 
 # block width of the sort-free rank scan: larger blocks mean fewer
 # sequential scan steps but a quadratically larger [L, L] in-block
-# compare — sweepable on hardware via env (profile_step shows the
-# rank/occur stage cost directly)
+# compare — sweepable on hardware via env (a device trace shows the
+# rank/occur stage cost under the `shared` scope)
 import os as _os
 
 

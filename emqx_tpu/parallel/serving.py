@@ -158,6 +158,8 @@ class ShardedRouteServer:
         self.node = node
         self.broker = node.broker
         self.router = node.broker.router
+        from emqx_tpu.broker.trace import spans_of
+        self.spans = spans_of(node)
         if mesh is None:
             import jax
             n_devices = n_devices or len(jax.devices())
@@ -1034,12 +1036,16 @@ class ShardedRouteServer:
         freshly written cursor row wins — a one-batch fairness blip, not
         a correctness input). The batcher serializes dispatches on one
         thread, so cursor threading across batches is ordered."""
+        with self.spans.span("dispatch", h.trace, track="dispatch",
+                             meta={"B": h.enc[0].shape[0]}):
+            self._dispatch(h)
+
+    def _dispatch(self, h: _Handle) -> None:
         import contextlib
 
         from emqx_tpu.ops.shared import STRATEGIES
         strategy = STRATEGIES.get(self.broker.shared_strategy, 0)
         tele = getattr(self.node, "pipeline_telemetry", None)
-        t0 = time.perf_counter()
         with self._lock:
             # live cursors when no update raced (pipelined batches chain
             # round-robin state); the pinned ones otherwise — they are
@@ -1080,9 +1086,6 @@ class ShardedRouteServer:
             # still owns the dispatch slot — materialize(W) then hides
             # under dispatch(W+1)
             self._start_readback(h)
-        if tele is not None:
-            tele.observe_stage("dispatch", time.perf_counter() - t0)
-        self._rec_span(h.trace, "dispatch", t0, track="dispatch")
 
     def _start_readback(self, h: _Handle) -> None:
         """Async-start the device→host transfer of the planes
@@ -1267,10 +1270,8 @@ class ShardedRouteServer:
         metrics.inc("pipeline.exchange.rounds", R - 1)
         metrics.inc("pipeline.exchange.bytes_exchanged",
                     n_dev * ((R - 1) * E * 12 + R * 4))
-        tele = getattr(self.node, "pipeline_telemetry", None)
-        if tele is not None:
-            tele.observe_stage("exchange", time.perf_counter() - t0)
-        self._rec_span(h.trace, "exchange", t0, track="dispatch")
+        self.spans.record("exchange", h.trace, t0, stage="exchange",
+                          track="dispatch")
         return False
 
     def materialize(self, h: _Handle) -> None:
@@ -1283,16 +1284,14 @@ class ShardedRouteServer:
         way. A window outgrowing its payload class reads the dense
         planes instead (row_overflow) — correctness never depends on the
         class fitting. Bytes transferred land in pipeline.readback.*."""
-        tele = getattr(self.node, "pipeline_telemetry", None)
+        with self.spans.span("materialize", h.trace,
+                             track="materialize"):
+            self._materialize(h)
+
+    def _materialize(self, h: _Handle) -> None:
         metrics = self.node.metrics
-        t0 = time.perf_counter()
         r = h.res
         if h.exch is not None and self._materialize_exchange(h, metrics):
-            if tele is not None:
-                tele.observe_stage("materialize",
-                                   time.perf_counter() - t0)
-            self._rec_span(h.trace, "materialize", t0,
-                           track="materialize")
             return
         Bp = int(r.matches.shape[0])
         P = self._choose_pcap(Bp)
@@ -1333,20 +1332,12 @@ class ShardedRouteServer:
                             off.nbytes + c3.nbytes + pay.nbytes
                             + overflow.nbytes + occur.nbytes)
                 metrics.inc("pipeline.readback.windows.compact")
-                if tele is not None:
-                    tele.observe_stage("materialize",
-                                       time.perf_counter() - t0)
-                self._rec_span(h.trace, "materialize", t0,
-                               track="materialize")
                 return
         h.np_res = self._dense_np_res(r)
         metrics.inc("pipeline.readback.bytes.dense",
                     sum(a.nbytes for a in h.np_res.values())
                     + csr_probe_bytes)
         metrics.inc("pipeline.readback.windows.dense")
-        if tele is not None:
-            tele.observe_stage("materialize", time.perf_counter() - t0)
-        self._rec_span(h.trace, "materialize", t0, track="materialize")
 
     @staticmethod
     def _dense_np_res(r) -> dict:
@@ -1450,15 +1441,6 @@ class ShardedRouteServer:
         self.node.metrics.inc("pipeline.readback.windows.dense")
         return np_res
 
-    def _rec_span(self, trace_id: int, name: str, t0: float, *,
-                  track: str) -> None:
-        """Record one [t0, now] span on the node's flight recorder
-        (no-op when tracing is off or the window carries no trace)."""
-        rec = getattr(self.node, "flight_recorder", None)
-        if rec is not None and trace_id:
-            rec.record(trace_id, name, t0, time.perf_counter(),
-                       track=track)
-
     def finish_sub(self, h: _Handle, k: int,
                    defer: bool = True) -> list[int]:
         """Stage 4 (event loop): consume into deliveries (W=1: k==0).
@@ -1471,8 +1453,11 @@ class ShardedRouteServer:
         host_extra, clustered — rides the plan's ordered barrier
         closures, so the per-session interleaving matches the inline
         loop exactly. `defer=False` (route_batch) stays inline."""
-        tele = getattr(self.node, "pipeline_telemetry", None)
-        t0 = time.perf_counter()
+        with self.spans.span("finish_sub", h.trace, stage="deliver",
+                             track="consume"):
+            return self._finish_sub(h, k, defer)
+
+    def _finish_sub(self, h: _Handle, k: int, defer: bool):
         msgs = h.subs[k]
         np_res = h.np_res
         plan = None
@@ -1558,9 +1543,6 @@ class ShardedRouteServer:
             plan.target = out
             pool.submit(plan)
             counts = out
-        if tele is not None:
-            tele.observe_stage("deliver", time.perf_counter() - t0)
-        self._rec_span(h.trace, "deliver", t0, track="consume")
         if self.ledger is not None:
             # consumed (lane plans keep the arrays alive by reference;
             # the pin tracks swap-blocking in-flight handles only)

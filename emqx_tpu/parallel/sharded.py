@@ -133,29 +133,38 @@ def make_sharded_route_step(mesh: Mesh, *, backend: str = "trie",
         tables = jax.tree.map(lambda x: x[0], tables)  # this shard's slice
         cursors = cursors[0]
 
-        if backend == "shapes":
-            mr = shape_match(tables.shapes, topics, lens, is_dollar)
-        else:
-            mr = match_batch(tables.trie, topics, lens, is_dollar,
-                             frontier_cap=frontier_cap, match_cap=match_cap)
-        fr = fanout_normal(tables.subs, mr.matches, fanout_cap=fanout_cap)
-        sids, slot_oflow = shared_slots(tables.subs, mr.matches,
-                                        slot_cap=slot_cap)
+        # the same scope names as the one-chip programs
+        # (models/router_engine.py): a device trace groups by them
+        with jax.named_scope("match"):
+            if backend == "shapes":
+                mr = shape_match(tables.shapes, topics, lens, is_dollar)
+            else:
+                mr = match_batch(tables.trie, topics, lens, is_dollar,
+                                 frontier_cap=frontier_cap,
+                                 match_cap=match_cap)
+        with jax.named_scope("fanout"):
+            fr = fanout_normal(tables.subs, mr.matches,
+                               fanout_cap=fanout_cap)
+        with jax.named_scope("shared"):
+            sids, slot_oflow = shared_slots(tables.subs, mr.matches,
+                                            slot_cap=slot_cap)
 
-        # cross-dp deterministic round-robin: rebase cursors by the
-        # occurrences seen in lower dp ranks, advance by the global total
-        occur_local = jnp.zeros_like(cursors).at[
-            jnp.clip(sids, 0).reshape(-1)].add(
-            (sids >= 0).reshape(-1).astype(cursors.dtype))
-        occur_all = jax.lax.all_gather(occur_local, "dp")        # [dp, G]
-        my_dp = jax.lax.axis_index("dp")
-        prefix = jnp.sum(jnp.where(
-            jnp.arange(dp_size)[:, None] < my_dp, occur_all, 0), axis=0)
-        is_rr = strategy == STRATEGY_ROUND_ROBIN
-        sp = pick_members(tables.subs, cursors + jnp.where(is_rr, prefix, 0),
-                          sids, strategy, msg_hash)
-        total_occur = occur_all.sum(axis=0)
-        new_cursors = jnp.where(is_rr, cursors + total_occur, cursors)
+            # cross-dp deterministic round-robin: rebase cursors by the
+            # occurrences seen in lower dp ranks, advance by the global
+            # total
+            occur_local = jnp.zeros_like(cursors).at[
+                jnp.clip(sids, 0).reshape(-1)].add(
+                (sids >= 0).reshape(-1).astype(cursors.dtype))
+            occur_all = jax.lax.all_gather(occur_local, "dp")        # [dp, G]
+            my_dp = jax.lax.axis_index("dp")
+            prefix = jnp.sum(jnp.where(
+                jnp.arange(dp_size)[:, None] < my_dp, occur_all, 0), axis=0)
+            is_rr = strategy == STRATEGY_ROUND_ROBIN
+            sp = pick_members(tables.subs,
+                              cursors + jnp.where(is_rr, prefix, 0),
+                              sids, strategy, msg_hash)
+            total_occur = occur_all.sum(axis=0)
+            new_cursors = jnp.where(is_rr, cursors + total_occur, cursors)
 
         overflow = mr.overflow | fr.overflow | slot_oflow
         res = RouteResult(
@@ -235,8 +244,9 @@ def ring_rotate(block, k: int, axis_name: str, size: int):
     participant k positions to its LEFT ((my - k) % size), i.e. each
     device SENDS to (my + k) % size. XLA lowers the permutation to its
     collective-permute, device-to-device over the interconnect."""
-    return jax.lax.ppermute(
-        block, axis_name, [(j, (j + k) % size) for j in range(size)])
+    with jax.named_scope("exchange"):
+        return jax.lax.ppermute(
+            block, axis_name, [(j, (j + k) % size) for j in range(size)])
 
 
 def make_exchange_step(mesh: Mesh, *, seg_cap: int):

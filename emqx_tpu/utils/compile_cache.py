@@ -22,11 +22,17 @@ def configure_compile_cache() -> str:
     is set in code. Unset: `<checkout>/.jax_cache` as an absolute,
     normalised path — never a temp name, pid or timestamp, since a cache
     that moves between runs never hits."""
+    import jax
+    # an executable carries its operations' names (the route programs'
+    # `jax.named_scope`s) as metadata, and a device trace is read by
+    # them: by default the cache key leaves metadata out, and a hit then
+    # hands back an executable with the names it was first built with
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
     path = os.path.join(_CHECKOUT, ".jax_cache")
-    import jax
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 

@@ -709,3 +709,512 @@ class TestOverheadGuard:
         off = min(bench(False), bench(False))
         on = min(bench(True), bench(True))
         assert on <= off * 1.25 + 0.05, (off, on)
+
+
+# ---------- ISSUE 24: one span call, three sinks ----------
+
+def _profile(tmp_path, coro_fn):
+    """Run `coro_fn()` under a jax.profiler session on the CPU backend;
+    returns (result, {thread line name: [(name, start, end, stats)]})
+    of the host plane."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    tdir = str(tmp_path / "prof")
+    jax.profiler.start_trace(tdir, profiler_options=opts)
+    try:
+        out = run(coro_fn())
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(
+        tdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    lines: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for k, line in enumerate(plane.lines):
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                    dict(ev.stats)) for ev in line.events
+                   if ev.name.startswith("emqx:")
+                   or ev.name == "route_step"]
+            if evs:
+                lines[f"{line.name}#{k}"] = evs
+    return out, lines
+
+
+def _events(lines, name):
+    return [e for evs in lines.values() for e in evs if e[0] == name]
+
+
+class TestOneSpanCall:
+    def test_unit_three_sinks_and_release(self, tmp_path):
+        """Spans.span feeds the stage histogram, the ring (with trace
+        id and parent) and an emqx: annotation carrying the trace id;
+        released() splits the annotation, not histogram or ring."""
+        from emqx_tpu.broker.telemetry import PipelineTelemetry
+        tele = PipelineTelemetry(track_compiles=False)
+        rec = T.FlightRecorder(tele.metrics, cap=64, sample=0)
+        spans = T.Spans(tele, rec)
+        tid = rec.new_trace()
+
+        async def go():
+            root = spans.record("enqueue", tid, time.perf_counter(),
+                                stage="enqueue", track="batcher")
+            with spans.span("host_route", tid, track="host",
+                            parent=root, meta={"k": 3}) as sp:
+                with sp.released():
+                    await asyncio.sleep(0.002)
+            with spans.span("finish_sub", tid, stage="deliver"):
+                pass
+            with spans.span("prepare_window"):      # no trace: no ring
+                pass
+            return root, sp
+        (root, sp), lines = _profile(tmp_path, go)
+        ring = {s.name: s for s in rec.spans()}
+        assert set(ring) == {"enqueue", "host_route", "deliver"}
+        assert ring["host_route"].parent_id == root == ring[
+            "enqueue"].span_id
+        assert ring["host_route"].span_id == sp.sid
+        assert ring["host_route"].meta == {"k": 3}
+        assert ring["host_route"].dur == pytest.approx(sp.dur) \
+            and sp.dur >= 0.002
+        stages = tele.snapshot()["stages"]
+        assert {k: v["count"] for k, v in stages.items()} == {
+            "enqueue": 1, "host_route": 1, "deliver": 1}
+        hr = _events(lines, "emqx:host_route")
+        assert len(hr) == 2                     # split at the await
+        assert all(e[3] == {"k": 3, "trace_id": tid} for e in hr)
+        # the two pieces leave the wait out
+        assert sum(e[2] - e[1] for e in hr) < (sp.dur - 0.0015) * 1e9
+        assert [e[3] for e in _events(lines, "emqx:finish_sub")] == [
+            {"trace_id": tid}]
+        assert [e[3] for e in _events(lines, "emqx:prepare_window")] \
+            == [{}]
+
+    def test_pipeline_spans_reach_any_profiler_session(self, tmp_path):
+        """Nobody calls start_device_trace: a profiler session started
+        from outside sees the stages as emqx:* events with their
+        window's trace id, and every dispatch as a route_step step
+        whose step_num is that id."""
+        node = _mk_node(trace_sample=0)
+        _subscribe(node)
+        assert not hasattr(node.device_engine, "_tracing")
+
+        async def go():
+            await _warm(node)
+            node.publish_batcher._device_worth_it = lambda n: True
+            n0 = len(node.flight_recorder.spans())
+            out = await _drive(node, windows=4, warm=False)
+            return n0, out
+        (n0, counts), lines = _profile(tmp_path, go)
+        assert sum(counts) > 0
+        ring = node.flight_recorder.spans()[n0:]
+        by_name: dict = {}
+        for s in ring:
+            by_name.setdefault(s.name, set()).add(s.trace_id)
+        # (the warm pass's direct route_batch carries no trace: its
+        # spans are there too, with no trace_id and step_num 0)
+        assert all({"W", "B", "cached"} <= set(e[3])
+                   for e in _events(lines, "emqx:dispatch"))
+        disp = [e for e in _events(lines, "emqx:dispatch")
+                if "trace_id" in e[3]]
+        assert {e[3]["trace_id"] for e in disp} == by_name["dispatch"]
+        steps = [e for e in _events(lines, "route_step")
+                 if e[3]["step_num"]]
+        assert {e[3]["step_num"] for e in steps} == by_name["dispatch"]
+        for st in steps:        # the step nests inside emqx:dispatch
+            assert any(d[1] <= st[1] and st[2] <= d[2] for d in disp)
+        for name, ring_name in (("emqx:materialize", "materialize"),
+                                ("emqx:finish_sub", "deliver"),
+                                ("emqx:batch_form", "batch_form"),
+                                ("emqx:settle", "settle")):
+            got = {e[3]["trace_id"] for e in _events(lines, name)
+                   if "trace_id" in e[3]}
+            assert got and got <= by_name[ring_name], name
+        lanes = _events(lines, "emqx:lane")
+        assert lanes and all(e[3]["lane"] in (0, 1) for e in lanes)
+        assert _events(lines, "emqx:prepare_window")
+        # the histograms moved with them (the one call's first sink)
+        st = node.pipeline_telemetry.snapshot()["stages"]
+        assert st["dispatch"]["count"] >= len(by_name["dispatch"])
+        assert st["materialize"]["count"] >= 1 \
+            and st["deliver"]["count"] >= 1
+
+    def test_loop_thread_spans_never_enclose_an_await(self, tmp_path):
+        """Two publishers interleave on the loop (host windows of 200
+        messages yield every 64; device windows ride the lanes): on
+        every thread, any two emqx: spans are disjoint or nested —
+        a span left open across an await would partially overlap the
+        other coroutine's."""
+        node = _mk_node(trace_sample=0, max_publish_batch=256)
+        _subscribe(node)
+        flip = [0]
+
+        def alternate(n):
+            flip[0] += 1
+            return flip[0] % 2 == 0
+
+        async def pub(tag):
+            out = []
+            for w in range(3):
+                out += await asyncio.gather(*[
+                    node.publish_async(make(
+                        tag, 1, f"t/{i % 8}/x", b"%d" % w))
+                    for i in range(200)])
+                await asyncio.sleep(0)
+            return out
+
+        async def go():
+            await _warm(node)
+            node.publish_batcher._device_worth_it = alternate
+            a, b = await asyncio.gather(pub("p1"), pub("p2"))
+            pool = node.deliver_lanes
+            if pool is not None and pool.busy():
+                await pool.drain()
+            return a + b
+        counts, lines = _profile(tmp_path, go)
+        assert len(counts) == 1200 and all(c >= 1 for c in counts)
+        pieces = _events(lines, "emqx:host_route")
+        hosts = [s for s in node.flight_recorder.spans()
+                 if s.name == "host_route"]
+        assert hosts and len(pieces) > len(hosts)   # released at yields
+        checked = 0
+        for evs in lines.values():
+            evs = sorted((e for e in evs if e[0].startswith("emqx:")),
+                         key=lambda e: (e[1], -e[2]))
+            stack = []
+            for name, s, e, _st in evs:
+                while stack and stack[-1][2] <= s:
+                    stack.pop()
+                if stack:
+                    assert e <= stack[-1][2], (
+                        f"{name} [{s}, {e}] partially overlaps "
+                        f"{stack[-1][0]} [{stack[-1][1]}, "
+                        f"{stack[-1][2]}]")
+                stack.append((name, s, e))
+                checked += 1
+        assert checked > 20
+
+
+class TestGcAndChooserCounters:
+    def test_gc_callback_lives_from_start_to_stop(self):
+        import gc
+
+        from emqx_tpu.broker.connection import Listener
+        node = _mk_node()
+        cb = node.gc_watch._on_gc
+        assert cb not in gc.callbacks
+
+        async def go():
+            lst = Listener(node, bind="127.0.0.1", port=0)
+            await lst.start()
+            node.start_timers(30.0)
+            assert gc.callbacks.count(cb) == 1
+            m0 = dict(node.metrics.all())
+            n0 = len([s for s in node.flight_recorder.spans()
+                      if s.name == "gc"])
+            gc.collect(0)
+            gc.collect(2)
+            m1 = dict(node.metrics.all())
+            node.stop_timers()
+            assert gc.callbacks.count(cb) == 1      # a listener is up
+            await lst.stop()
+            return m0, m1, n0
+        m0, m1, n0 = run(go())
+        assert cb not in gc.callbacks
+
+        def d(k):
+            return m1.get(k, 0) - m0.get(k, 0)
+        assert d("runtime.gc.pauses.gen0") >= 1
+        assert d("runtime.gc.pauses.gen2") >= 1
+        assert d("runtime.gc.pause_us") > 0
+        gcs = [s for s in node.flight_recorder.spans() if s.name == "gc"]
+        assert len(gcs) - n0 >= 1 and gcs[-1].trace_id == T.NODE_TRACE
+        assert gcs[-1].meta == {"generation": 2} and gcs[-1].dur > 0
+        before = dict(node.metrics.all())
+        gc.collect(2)                               # gone after stop
+        assert node.metrics.all().get("runtime.gc.pauses.gen2") \
+            == before.get("runtime.gc.pauses.gen2")
+
+    def test_gen2_collection_is_a_span_on_the_profiler(self, tmp_path):
+        import gc
+        node = _mk_node()
+
+        async def go():
+            node.gc_watch.start()
+            try:
+                gc.collect(1)
+                gc.collect(2)
+            finally:
+                node.gc_watch.stop()
+        _out, lines = _profile(tmp_path, go)
+        assert [e[3] for e in _events(lines, "emqx:gc")] \
+            == [{"generation": 2}]
+
+    def test_each_chooser_verdict_is_counted(self):
+        node = _mk_node()
+        pb = node.publish_batcher
+        m = node.metrics
+
+        def verdicts():
+            return {k.rsplit(".", 1)[1]: v for k, v in m.all().items()
+                    if k.startswith("routing.chooser.")}
+        assert pb._device_worth_it(8) is True           # nothing measured
+        assert verdicts() == {"first": 1}
+        pb._dev_batch_s = 0.004
+        assert pb._device_worth_it(8) is False          # no host cost yet
+        pb._host_msg_s = 0.001
+        pb._since_host_probe = pb.host_probe_every
+        assert pb._device_worth_it(8) is False
+        assert verdicts()["host_probe"] == 2
+        pb._since_probe = 64
+        assert pb._device_worth_it(8) is True
+        assert verdicts()["device_probe"] == 1
+        assert pb._device_worth_it(8) is True           # 4 ms <= 8 x 1 ms
+        assert pb.chooser_margin == pytest.approx(0.5)
+        assert pb._device_worth_it(2) is False          # 4 ms > 2 x 1 ms
+        assert pb.chooser_margin == pytest.approx(2.0)
+        assert verdicts() == {"first": 1, "host_probe": 2,
+                              "device_probe": 1, "cost_device": 1,
+                              "cost_host": 1}
+        assert m.val("routing.device.bypassed") == 1
+        ch = node.pipeline_telemetry.snapshot()["chooser"]
+        assert ch == {"dev_batch_ms": 4.0, "host_msg_us": 1000.0,
+                      "margin": 2.0, "verdicts": verdicts()}
+
+
+# ---------- ISSUE 24: named scopes change metadata only ----------
+
+def _scope_fixture():
+    import numpy as np
+
+    from emqx_tpu.models import router_engine as RE
+    from emqx_tpu.ops import intern as I
+    from emqx_tpu.ops.delta import build_delta_tables
+    from emqx_tpu.ops.fanout import build_subtable
+    from emqx_tpu.ops.match import encode_topics
+    from emqx_tpu.ops.shapes import build_shape_tables
+    from emqx_tpu.ops.trie import build_tables
+    from emqx_tpu.utils import topic as TP
+    filters = ["dev/+/t", "dev/#", "q/job", "+/x/+"]
+    intern = I.InternTable()
+    rows = np.zeros((len(filters), 8), np.int32)
+    lens = np.zeros(len(filters), np.int64)
+    for fid, f in enumerate(filters):
+        w = intern.encode_filter(TP.words(f))
+        rows[fid, :len(w)] = w
+        lens[fid] = len(w)
+    subs = build_subtable(len(filters),
+                          {0: [(1, 1)], 1: [(2, 2)], 3: [(3, 1)]},
+                          {2: [0]}, {0: [(50, 1), (51, 1), (52, 1)]})
+    dw = intern.encode_filter(TP.words("n/+/m"))
+    delta = build_delta_tables([(dw, 900, [(7, 1), (8, 0)])],
+                               row_cap=8, level_cap=8)
+    rng = np.random.RandomState(11)
+    W, B = 4, 8
+    names = ["dev/a/t", "q/job", "n/x/m", "dev/b/c", "none"]
+    enc, ln, dol = [], [], []
+    for _k in range(W):
+        e, l, d, too_long = encode_topics(
+            intern, [TP.words(names[rng.randint(len(names))])
+                     for _ in range(B)], 8)
+        assert not too_long.any()
+        enc.append(e), ln.append(l), dol.append(d)
+    return {
+        "RE": RE, "W": W, "B": B,
+        "trie": RE.RouterTables(trie=build_tables(rows, lens), subs=subs),
+        "shapes": RE.ShapeRouterTables(
+            shapes=build_shape_tables(rows, lens), subs=subs),
+        "delta": delta,
+        "enc": np.stack(enc), "lens": np.stack(ln), "dol": np.stack(dol),
+        "hash": rng.randint(0, 1 << 30, size=(W, B)).astype(np.int32),
+        "strat": np.int32(0), "cur": np.zeros(1, np.int32),
+    }
+
+
+def _plain_steps(fx, match):
+    """The reference: the same ops, each called on its own outside any
+    route program (no scope anywhere), W sequential steps threading the
+    cursors; every RouteResult field stacked [W, ...]."""
+    import numpy as np
+
+    from emqx_tpu.ops.fanout import fanout_normal, shared_slots
+    from emqx_tpu.ops.shared import pick_members
+    RE, subs = fx["RE"], fx["trie"].subs
+    cur, out = fx["cur"], []
+    for k in range(fx["W"]):
+        mr = match(fx["enc"][k], fx["lens"][k], fx["dol"][k])
+        fr = fanout_normal(subs, mr.matches, fanout_cap=8)
+        sids, so = shared_slots(subs, mr.matches, slot_cap=4)
+        sp = pick_members(subs, cur, sids, fx["strat"], fx["hash"][k])
+        out.append(RE.RouteResult(
+            matches=mr.matches, match_counts=mr.counts, rows=fr.rows,
+            opts=fr.opts, fan_counts=fr.counts, shared_sids=sids,
+            shared_rows=sp.rows, shared_opts=sp.opts,
+            overflow=mr.overflow | fr.overflow | so,
+            new_cursors=sp.new_cursors, occur=sp.occur))
+        cur = sp.new_cursors
+    return RE.RouteResult(*[np.stack([np.asarray(r[i]) for r in out])
+                            for i in range(len(out[0]))])
+
+
+def _same(got, want):
+    import jax
+    import numpy as np
+    g, w = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(g) == len(w)
+    for a, b in zip(g, w):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert (a == b).all()
+
+
+@pytest.mark.parametrize("family", [
+    "step", "step_shapes", "window_full", "window_cached",
+    "window_full_compact", "window_delta_compact"])
+def test_route_outputs_bit_equal_with_scopes(family):
+    """jax.named_scope changes HLO metadata only: each route program
+    family, scopes and all, returns bit for bit what the same ops
+    return when called one by one with no scope around them; and the
+    scopes are in the lowered program's metadata."""
+    import numpy as np
+
+    from emqx_tpu.ops.compact import compact_result
+    from emqx_tpu.ops.delta import delta_overlay
+    from emqx_tpu.ops.match import match_batch
+    from emqx_tpu.ops.shapes import shape_match
+    fx = _scope_fixture()
+    RE, W, B = fx["RE"], fx["W"], fx["B"]
+    caps = dict(fanout_cap=8, slot_cap=4)
+    win = (fx["enc"], fx["lens"], fx["dol"], fx["hash"], fx["strat"])
+
+    def by_trie(e, l, d):
+        return match_batch(fx["trie"].trie, e, l, d, frontier_cap=16,
+                           match_cap=64)
+
+    def by_shapes(e, l, d):
+        return shape_match(fx["shapes"].shapes, e, l, d)
+
+    scopes = {"match", "fanout", "shared"}
+    if family == "step":
+        fn, args, kw = RE.route_step, (fx["trie"], fx["cur"]) + tuple(
+            a[0] if getattr(a, "ndim", 0) else a for a in win), dict(
+            caps, frontier_cap=16, match_cap=64)
+        want = RE.RouteResult(*[x[0] for x in _plain_steps(
+            dict(fx, W=1), by_trie)])
+    elif family == "step_shapes":
+        fn, args, kw = RE.route_step_shapes, (
+            fx["shapes"], fx["cur"]) + tuple(
+            a[0] if getattr(a, "ndim", 0) else a for a in win), caps
+        want = RE.RouteResult(*[x[0] for x in _plain_steps(
+            dict(fx, W=1), by_shapes)])
+    elif family == "window_full":
+        fn, args, kw = RE.route_window_full, (
+            fx["shapes"], fx["cur"]) + win, caps
+        want = _plain_steps(fx, by_shapes)
+        scopes |= {"scan"}
+    elif family == "window_cached":
+        # every lane a miss of its own: the plan's degenerate case
+        U = W * B
+        base = (np.full((U, 64), -1, np.int32), np.zeros(U, np.int32),
+                np.zeros(U, bool))
+        probe = by_shapes(fx["enc"].reshape(U, -1),
+                          fx["lens"].reshape(U), fx["dol"].reshape(U))
+        base = (np.full((U,) + probe.matches.shape[1:], -1, np.int32),
+                base[1], base[2])
+        fn, kw = RE.route_window_cached, caps
+        args = (fx["shapes"], fx["cur"], fx["enc"].reshape(U, -1),
+                fx["lens"].reshape(U), fx["dol"].reshape(U)) + base + (
+            np.arange(U, dtype=np.int32),
+            np.arange(U, dtype=np.int32).reshape(W, B),
+            fx["hash"], fx["strat"])
+        want = _plain_steps(fx, by_shapes)
+        scopes |= {"scan"}
+    elif family == "window_full_compact":
+        fn, args, kw = RE.route_window_full_compact, (
+            fx["shapes"], fx["cur"]) + win, dict(caps, payload_cap=256)
+        r = _plain_steps(fx, by_shapes)
+        want = RE.CompactRouteResult(res=r, compact=compact_result(
+            r.matches, r.rows, r.opts, r.fan_counts, r.shared_sids,
+            r.shared_rows, r.shared_opts, payload_cap=256,
+            match_holes=True))
+        scopes |= {"scan", "compact"}
+    else:
+        fn = RE.route_window_delta_compact
+        args = (fx["shapes"], fx["delta"], fx["cur"]) + win
+        kw = dict(caps, delta_match_cap=4, delta_fanout_cap=8,
+                  payload_cap=256, d_payload_cap=64)
+        r = _plain_steps(fx, by_shapes)
+        dp = delta_overlay(fx["delta"], fx["enc"].reshape(W * B, -1),
+                           fx["lens"].reshape(W * B),
+                           fx["dol"].reshape(W * B), match_cap=4,
+                           fanout_cap=8)
+        dp = type(dp)(*[np.asarray(x).reshape((W, B) + x.shape[1:])
+                        for x in dp])
+        cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
+                            r.shared_sids, r.shared_rows, r.shared_opts,
+                            payload_cap=256, match_holes=True)
+        dcp = compact_result(
+            dp.fids, dp.rows, dp.opts, dp.fan_counts,
+            np.full((W, B, 1), -1, np.int32),
+            np.zeros((W, B, 1), np.int32), np.zeros((W, B, 1), np.int8),
+            payload_cap=64, match_holes=False)
+        want = RE.CompactDeltaRouteResult(
+            dres=RE.DeltaRouteResult(res=r, dp=dp), compact=cp,
+            d_compact=dcp)
+        scopes |= {"scan", "compact", "delta"}
+    _same(fn(*args, **kw), want)
+    ops = [ln for ln in fn.lower(*args, **kw).compile().as_text()
+           .splitlines() if "op_name=" in ln]
+    for name in scopes:
+        assert any(f"/{name}/" in ln for ln in ops), name
+
+
+def test_no_span_is_opened_per_message(tmp_path):
+    """1,500 PUBLISHes on 1,500 distinct topics in one write arrive as
+    a few read bursts: the spans count with the bursts, the windows
+    and the lane items, never with the messages (an authz check that
+    released its span for every new topic once made 17,000 spans a
+    second of `emqx:ingress`)."""
+    from emqx_tpu.broker.connection import Listener
+    from emqx_tpu.client import Client
+    from emqx_tpu.mqtt import packet as P
+    from emqx_tpu.mqtt.frame import serialize
+    node = _mk_node(trace_sample=0)
+    n = 1500
+
+    async def go():
+        lst = Listener(node, bind="127.0.0.1", port=0)
+        await lst.start()
+        sub = Client(port=lst.port, clientid="sub")
+        await sub.connect()
+        await sub.subscribe("t/#", qos=0)
+        pub = Client(port=lst.port, clientid="pub")
+        await pub.connect()
+        blob = bytearray()
+        for i in range(n):
+            blob += serialize(P.Publish(topic=f"t/{i}", payload=b"x",
+                                        qos=0), 4)
+        pub._writer.write(bytes(blob))
+        await pub._writer.drain()
+        for _ in range(n):
+            await asyncio.wait_for(sub.messages.get(), 30)
+        bursts = node.metrics.val("pipeline.ingress.bursts")
+        await pub.close()
+        await sub.close()
+        await lst.stop()
+        await node.publish_batcher.stop()
+        return bursts
+    bursts, lines = _profile(tmp_path, go)
+    assert node.metrics.val("pipeline.ingress.rows") >= n - 64
+    ingress = _events(lines, "emqx:ingress")
+    # a decode span a read, and a burst's hand-off split at its
+    # 64-row yields
+    assert bursts <= len(ingress) <= 4 * bursts + n // 64 + 64
+    spans = [e for evs in lines.values() for e in evs
+             if e[0].startswith("emqx:")]
+    assert len(spans) < n / 4

@@ -4,10 +4,9 @@ one process at a time). Exits non-zero without a TPU or when any
 section fails.
 
 Covers, in order of importance:
-  1. per-stage profile of the fused step at bench scale (profile_step)
-  2. fold backends: xla vs lane-major pallas (match-only window)
-  3. rank-scan block-width sweep (the sort-free kernel's knob)
-  4. fuse-width sweep (per-dispatch overhead amortization curve)
+  1. fold backends: xla vs lane-major pallas (match-only window)
+  2. rank-scan block-width sweep (the sort-free kernel's knob)
+  3. fuse-width sweep (per-dispatch overhead amortization curve)
 
 Prints a JSON summary line at the end; everything logs to stderr as it
 goes.
@@ -71,7 +70,7 @@ def main():
                                       .astype(np.int32))))
     log("staged")
 
-    # ---- 2. fold backends --------------------------------------------
+    # ---- 1. fold backends --------------------------------------------
     def match_window(fn, n=16):
         acc = jax.device_put(np.int32(0))
         t0 = time.time()
@@ -94,7 +93,7 @@ def main():
         f"pallas {out['match_pallas_per_s']/1e6:.1f}M/s "
         f"identical={out['pallas_bit_identical']}")
 
-    # ---- 4. fuse-width sweep (also yields the headline number) -------
+    # ---- 3. fuse-width sweep (also yields the headline number) -------
     out["fuse_sweep"] = {}
     for fuse in (1, 2, 4, 8, 16):
         stacked = tuple(jnp.stack([staged[k % 8][i] for k in range(fuse)])
@@ -109,7 +108,7 @@ def main():
             f"({dt/ (n_calls*fuse) * 1000:.2f}ms/batch)")
     out["value"] = max(out["fuse_sweep"].values())
 
-    # ---- 3. rank-block sweep (in-process: block width is a static
+    # ---- 2. rank-block sweep (in-process: block width is a static
     # jit arg, so one process covers the whole curve) ------------------
     import functools
 
