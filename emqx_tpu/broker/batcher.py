@@ -25,9 +25,11 @@ Round-2 rework:
   `host_probe_every` device batches (round 2's estimator starved: under
   steady device load the host was never sampled and `device_bypassed`
   could not fire); the device cost is re-probed every `_PROBE_EVERY`
-  bypassed batches so a transiently slow device is not written off forever.
+  bypassed batches so a transiently slow device is not written off forever
+  (that re-try's sample replaces the estimate outright).
   Pipelined device cost is sampled as completion-to-completion time (the
-  amortized rate the pipeline actually delivers), not the full round-trip.
+  amortized rate the pipeline actually delivers), not the full round-trip
+  — except across an idle gap, where the round-trip is the sample.
 
 Round-10 rework (ISSUE 9 tentpole) — the **double-buffered window
 pipeline**: at ``dispatch_depth >= 2`` the consumer becomes a bounded
@@ -185,6 +187,7 @@ class PublishBatcher:
         # _dev_batch_s / (n * _host_msg_s) of the last cost comparison
         self.chooser_margin: Optional[float] = None
         self._since_probe = 0         # host batches since last device try
+        self._dev_reprobe = False     # next device sample is that re-try's
         self._since_host_probe = 0    # device batches since last host probe
         self._last_dev_done: Optional[float] = None
         self._consuming = False       # consumer mid-entry (fast-path gate)
@@ -1073,21 +1076,10 @@ class PublishBatcher:
                 # consecutive-fault counters
                 sup.note_ok("dispatch")
                 sup.note_ok("materialize")
-            # ONE cost sample per WINDOW, divided by its width — sampling
-            # per entry would count the near-instant later subs of a
-            # window as full batches and drag the EWMA to ~zero (the
-            # chooser then never bypasses a slow device).  Pipelined cost
-            # = completion-to-completion when the pipeline was busy; full
-            # latency otherwise.
-            if self._last_dev_done is not None \
-                    and (not self._inflight.empty()
-                         or entry.get("_pipeline_busy")):
-                sample = (done - self._last_dev_done) / n_subs
-            else:
-                sample = (done - (handle.t0 or done)) / n_subs
-            self._last_dev_done = done
-            self._dev_batch_s, self._dev_spike = _ewma(
-                self._dev_batch_s, sample, self._dev_spike)
+            self._observe_device_cost(
+                handle.t0, done, n_subs,
+                not self._inflight.empty()
+                or bool(entry.get("_pipeline_busy")))
             # slow-start growth: this window completed, widen the next
             self._fuse_cwnd = min(8, max(2, 2 * n_subs))
         return counts
@@ -1158,6 +1150,34 @@ class PublishBatcher:
             "samples": len(s),
         }
 
+    def _observe_device_cost(self, t0: Optional[float], done: float,
+                             n_subs: int, busy: bool) -> None:
+        """ONE cost sample per completed WINDOW, divided by its width —
+        sampling per entry would count the near-instant later subs of a
+        window as full batches and drag the EWMA to ~zero (the chooser
+        then never bypasses a slow device). Pipelined cost =
+        completion-to-completion when the pipeline was `busy`; full
+        latency (from `t0`, the window's stage start) otherwise — and
+        never across an idle gap: a window that started after the last
+        completion did not wait behind it (the first window of a burst
+        would otherwise sample the whole pause before it)."""
+        start = t0 or done
+        if busy and self._last_dev_done is not None:
+            start = max(start, self._last_dev_done)
+        sample = (done - start) / n_subs
+        self._last_dev_done = done
+        if self._dev_reprobe:
+            # the scheduled re-try of a written-off device measures an
+            # estimate no sample has touched for _PROBE_EVERY host
+            # batches: adopt it, do not blend it in at alpha (a
+            # pessimized estimate would otherwise take several probe
+            # periods of host routing to decay)
+            self._dev_reprobe = False
+            self._dev_batch_s, self._dev_spike = sample, 0
+        else:
+            self._dev_batch_s, self._dev_spike = _ewma(
+                self._dev_batch_s, sample, self._dev_spike)
+
     def _device_worth_it(self, n: int) -> bool:
         """Measured-cost routing choice with active probes BOTH ways: the
         device is re-tried every _PROBE_EVERY host batches, and the host is
@@ -1184,6 +1204,7 @@ class PublishBatcher:
             return False
         if self._since_probe >= _PROBE_EVERY:
             self._since_probe = 0
+            self._dev_reprobe = True
             count("routing.chooser.device_probe")
             return True
         host_s = n * self._host_msg_s

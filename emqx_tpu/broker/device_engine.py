@@ -148,9 +148,12 @@ def resolve_delta_overlay(configured=None) -> bool:
 def resolve_subscription_covering(configured=None) -> bool:
     """The one subscription-covering resolution: config
     (``broker.subscription_covering``) beats ``EMQX_TPU_COVERING``
-    beats default-on. ``=0`` restores the full-set match exactly — the
-    ISSUE-18 A/B baseline (twin-tested bit-identical on delivery
-    counts and per-session order)."""
+    beats default-on. On means the engine MAY use covering: a snapshot
+    build engages it only where the full set does not fit the
+    shape-hash backend (``ops/cover.covering_decision``). ``=0`` means
+    never: the full-set match exactly — the ISSUE-18 A/B baseline
+    (twin-tested bit-identical on delivery counts and per-session
+    order)."""
     if configured is not None:
         return bool(configured)
     return os.environ.get("EMQX_TPU_COVERING", "1") \
@@ -489,7 +492,8 @@ class _Built:
 
     __slots__ = ("fid_of", "fid_filter", "seg_len", "slot_of", "slot_key",
                  "n_slots", "backend", "remote_members", "seg_np",
-                 "fid_shared", "fid_rich", "sid", "match_width", "cover")
+                 "fid_shared", "fid_rich", "sid", "match_width", "cover",
+                 "cover_decision")
 
     def __init__(self):
         self.fid_of: dict[str, int] = {}
@@ -515,9 +519,13 @@ class _Built:
         self.fid_rich = np.zeros(0, bool)         # fid has rich subopts
         # subscription covering (ISSUE 18): _CoverState when this
         # snapshot matched the covering set only, else None. With
-        # covering on, seg_np/fid_shared/fid_rich are padded to
+        # covering engaged, seg_np/fid_shared/fid_rich are padded to
         # filter_cap so APPENDED fids (cover-set churn) index safely.
         self.cover: Optional[_CoverState] = None
+        # why the build engaged covering or did not: "off" (the knob),
+        # "fits_shapes" / "too_deep" (ops/cover.covering_decision),
+        # "none_covered", "engaged"
+        self.cover_decision = "off"
 
 
 class _Handle:
@@ -669,7 +677,9 @@ class DeviceRouteEngine:
         # subscription covering (ISSUE 18 tentpole): the snapshot match
         # tables hold only the COVERING set; a fused expansion CSR
         # (ops/cover) re-expands matched covers after the match stage.
-        # Config beats env beats default-on; =0 builds the full set.
+        # Config beats env beats default-on. On = the build MAY engage
+        # it, and does only where the full set overflows the shape-hash
+        # table (cover.covering_decision); =0 always builds the full set.
         if subscription_covering is None:
             subscription_covering = _ENV_COVERING
         self.subscription_covering = bool(subscription_covering)
@@ -1260,51 +1270,55 @@ class DeviceRouteEngine:
         # relations over the interned columnar table and shrink the
         # match set to the ROOTS (uncovered filters); the expansion CSR
         # re-expands matched covers after the match stage (ops/cover).
-        # Disabled when nothing is covered (zero overhead, the tables
-        # stay cover-free) or when a filter is too deep for the int32
-        # order key — always correct, covering is a pure optimization.
+        # Engaged only where the FULL set does not fit the shape-hash
+        # backend (cover.covering_decision, evaluated BEFORE detection:
+        # a set the shape table holds whole builds cover-free and skips
+        # detection, owners, the expansion CSR and the padding below),
+        # and only when something is covered — always correct, covering
+        # is a pure optimization.
         from emqx_tpu.ops import cover as cover_mod
         cover_np = None
         cover_state = None
         sub_ids = None                 # fids the match tables hold
         cover_shapes = False
-        if self.subscription_covering and n >= 2 \
-                and L <= cover_mod.MAX_KEY_LEVELS:
-            dollar = np.fromiter((f.startswith("$") for f in filters),
-                                 bool, n)
-            covs, inc = cover_mod.detect_covers(rows, lens, dollar)
-            owner = cover_mod.assign_owners(covs, inc)
-            covered = np.flatnonzero(owner >= 0)
-            if len(covered):
-                # backend choice is free: the expansion stage re-sorts
-                # every candidate by the per-filter order key, and two
-                # DISTINCT filters matching the same topic always carry
-                # distinct keys (equal key + same topic forces equal
-                # literals), so the expanded row reproduces the off
-                # twin's order whatever backend matched the roots. Pick
-                # the ORDER KEY family and row width from what the off
-                # twin would run (shapes iff the FULL set fits the
-                # shape cap — its row is the full set's shape width),
-                # but match the roots under shapes whenever the ROOT
+        engage = False
+        if self.subscription_covering:
+            engage, b.cover_decision = cover_mod.covering_decision(
+                cover_mod.full_shape_count(rows, lens), self.shape_cap, L)
+        if engage:
+            covered = ()
+            if n >= 2:
+                dollar = np.fromiter((f.startswith("$") for f in filters),
+                                     bool, n)
+                covs, inc = cover_mod.detect_covers(rows, lens, dollar)
+                owner = cover_mod.assign_owners(covs, inc)
+                covered = np.flatnonzero(owner >= 0)
+            if not len(covered):
+                b.cover_decision = "none_covered"
+            else:
+                # the off twin runs the trie NFA here, so the order keys
+                # and the expanded row width are the trie's. The backend
+                # that matches the ROOTS is free: the expansion stage
+                # re-sorts every candidate by the per-filter order key,
+                # and two DISTINCT filters matching the same topic
+                # always carry distinct keys (equal key + same topic
+                # forces equal literals), so the expanded row reproduces
+                # the off twin's order whatever backend matched the
+                # roots. Match them under shapes whenever the ROOT
                 # subset fits: that is the covering win on populations
                 # whose full diversity overflows the shape cap into
                 # the trie
-                roots_pre = np.flatnonzero(owner < 0)
-                ns_full = cover_mod.full_shape_count(rows, lens)
-                ns_root = cover_mod.full_shape_count(
-                    rows[roots_pre], lens[roots_pre])
-                cover_shapes = L <= 20 and ns_root <= self.shape_cap
-                if cover_shapes and ns_full <= self.shape_cap:
-                    keys = cover_mod.shape_order_keys(rows, lens)
-                    out_w = 1 << max(0, (ns_full - 1).bit_length())
-                else:
-                    keys = cover_mod.trie_order_keys(rows, lens)
-                    out_w = self.match_cap
-                cand_cap = min(4096, _next_pow2(max(256, 4 * out_w)))
-                cover_np = cover_mod.build_cover_tables(
-                    rows, lens, owner, keys, fid_cap=filter_cap,
-                    out_width=out_w, cand_cap=cand_cap)
                 sub_ids = np.flatnonzero(owner < 0)
+                cover_shapes = L <= cover_mod.SHAPE_MAX_LEVELS \
+                    and cover_mod.full_shape_count(
+                        rows[sub_ids], lens[sub_ids]) <= self.shape_cap
+                cand_cap = min(4096,
+                               _next_pow2(max(256, 4 * self.match_cap)))
+                cover_np = cover_mod.build_cover_tables(
+                    rows, lens, owner,
+                    cover_mod.trie_order_keys(rows, lens),
+                    fid_cap=filter_cap, out_width=self.match_cap,
+                    cand_cap=cand_cap)
                 cover_state = _CoverState(
                     sub_ids, cover_np, L, len(covered), int(inc.sum()))
                 # pad the consume companions to filter_cap: cover-set
@@ -1342,9 +1356,9 @@ class DeviceRouteEngine:
                                         shape_cap=self.shape_cap)
                 tables = ShapeRouterTables(shapes=st, subs=subs_tbl)
                 b.backend = "shapes"
-                # the EXPANDED row is padded to the FULL set's shape
-                # width, so the cache/compact/consume row width matches
-                # the covering-off twin's exactly
+                # the EXPANDED row is as wide as the covering-off
+                # twin's (the trie NFA's match_cap), so the
+                # cache/compact/consume row width matches it exactly
                 b.match_width = int(cover_np.out_pad.shape[0])
             else:
                 node_cap = _next_pow2(
@@ -1424,6 +1438,11 @@ class DeviceRouteEngine:
             self._cur_sig = ()
         else:
             b, tables, cursors, _rich = result
+            if b.cover_decision == "fits_shapes":
+                # covering allowed, not engaged: the shape-hash table
+                # holds the full set (counted here, on the loop — the
+                # build itself may run on an executor thread)
+                self.node.metrics.inc("routing.cover.skipped_builds")
             self._built = b
             self._tables = tables
             self._cursors = cursors
@@ -3882,6 +3901,7 @@ class DeviceRouteEngine:
             if ov is not None else None,
             "journal_depth": self.journal_depth(),
             "subscription_covering": self.subscription_covering,
+            "cover_decision": b.cover_decision if b else None,
             "cover": {"roots": b.cover.n_roots,
                       "covered": b.cover.n_covered,
                       "appends": b.cover.app_used,

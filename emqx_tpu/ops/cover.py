@@ -39,20 +39,26 @@ Exactness & order: a matched cover does NOT imply its covered filters
 match (`sports/#` matches `sports/golf` but `sports/+/score` does not),
 so expansion verifies every candidate against the topic with the
 linear level-wise matcher before emitting it. The expanded row is then
-sorted by a per-filter ORDER KEY that reproduces the full-set
-backend's emission order exactly:
+sorted by a per-filter ORDER KEY that reproduces the emission order of
+the full set's backend — the trie NFA, wherever covering engages —
+exactly: (emit step, hash-emission-before-exact, frontier lane). The
+lane order of ops/match's valid-first compaction is the plus-choice
+bits read LSB-first (exact children sort before plus children every
+step), so the key is `((step*2 + is_exact) << level_bits) | plus_bits`.
 
-  - trie NFA: (emit step, hash-emission-before-exact, frontier lane) —
-    the lane order of ops/match's valid-first compaction is the plus-
-    choice bits read LSB-first (exact children sort before plus
-    children every step), so the key is
-    `((step*2 + is_exact) << level_bits) | plus_bits`;
-  - shape tables: shape ids are assigned in ascending `sig_small`
-    order (ops/shapes flatnonzero factorization), which is independent
-    of the built subset — the key is `sig_small` itself.
-
-With `broker.subscription_covering=0` the full set builds as today;
-the on/off twins are bit-identical on delivery counts and per-session
+Where covering engages (`covering_decision`): only on a snapshot
+whose FULL set does not fit the shape-hash backend, so the off twin
+would run the trie NFA — the roots then match under shapes (where they
+fit) or under a smaller trie. A full set the shape-hash table holds
+whole builds cover-free even with covering allowed: the probe costs
+two row gathers a SHAPE whatever the filter count, and the expansion's
+floor (a 256-lane segment expand, a verify gather and a 320-key sort a
+topic) is more than the most shapes the cap admits can save — on the
+chip the expansion was 58 % of the route programs' time on such a
+snapshot (PERF.md, PR 25). `broker.subscription_covering` /
+`EMQX_TPU_COVERING` say whether the engine MAY use covering; `=0` is
+"never" (the full set always builds as without this module). The
+on/off twins are bit-identical on delivery counts and per-session
 order by construction (oracle + A/B tested).
 """
 
@@ -85,11 +91,10 @@ class CoverTables(NamedTuple):
     vwords/vlens: covered filters' interned level ids for the
       per-candidate linear verification (delta_match semantics).
     order_key: per-fid emission order key, DENSIFIED to ranks at build
-      (backend-specific raw keys, see module docstring; ranking is
+      (`trie_order_keys`, see module docstring; ranking is
       order-preserving and keeps the expansion sort in int32).
     out_pad: [M_out] zeros — static carrier of the expanded match-row
-      width (match_cap for the trie backend, the FULL set's padded
-      shape count for the shapes backend, so the expanded plane is
+      width (the trie NFA's match_cap, so the expanded plane is
       exactly as wide as the covering-off twin's).
     cand_pad: [C] zeros — static carrier of the candidate capacity;
       a topic whose matched covers own more than C candidates flags
@@ -176,15 +181,16 @@ def trie_order_keys(words: np.ndarray, lens: np.ndarray) -> np.ndarray:
     return key.astype(np.int32)
 
 
-def shape_order_keys(words: np.ndarray, lens: np.ndarray) -> np.ndarray:
-    """Per-filter `sig_small` — the shapes backend's shape-id order
-    (ops/shapes assigns shape ids in ascending sig_small, independent
-    of the built subset)."""
+def full_shape_count(words: np.ndarray, lens: np.ndarray) -> int:
+    """Distinct shapes of a filter set — what decides whether the
+    shape-hash backend can hold it (`covering_decision`). A shape is
+    ops/shapes' `sig_small`: the '+' mask, the '#'-less length and the
+    trailing-'#' flag."""
     words = np.asarray(words, np.int32)
     lens = np.asarray(lens, np.int64)
     F = len(lens)
     if F == 0:
-        return np.zeros(0, np.int32)
+        return 0
     ar = np.arange(F)
     has_hash = (words[ar, np.maximum(lens - 1, 0)] == HASH).astype(np.int64)
     slen = lens - has_hash
@@ -192,17 +198,36 @@ def shape_order_keys(words: np.ndarray, lens: np.ndarray) -> np.ndarray:
     for l in range(min(words.shape[1], int(slen.max(initial=0)))):
         plus_mask |= ((words[:, l] == PLUS)
                       & (l < slen)).astype(np.int64) << l
-    sig = plus_mask | (slen << 20) | (has_hash << 25)
-    return sig.astype(np.int32)
+    return len(np.unique(plus_mask | (slen << 20) | (has_hash << 25)))
 
 
-def full_shape_count(words: np.ndarray, lens: np.ndarray) -> int:
-    """Distinct shapes of the FULL filter set — the covering-off twin's
-    match-row width driver (the expanded plane must be at least this
-    wide so expansion can never overflow where the off twin cannot)."""
-    if len(lens) == 0:
-        return 0
-    return len(np.unique(shape_order_keys(words, lens)))
+# the shape-hash backend's level bound (ops/shapes.build_shape_tables
+# refuses deeper filters: plus_mask rides 20 bits of the signature)
+SHAPE_MAX_LEVELS = 20
+
+
+def covering_decision(ns_full: int, shape_cap: int,
+                      L: int) -> tuple[bool, str]:
+    """Should a snapshot build engage covering? -> (engage, why).
+
+    ns_full: distinct shapes of the FULL set (`full_shape_count`);
+    shape_cap: the engine's shape-hash capacity; L: the table's level
+    width. Covering pays only across the shapes/trie boundary, so the
+    rule is "the full set does not fit the shape-hash backend":
+
+      - "fits_shapes": the off twin would run the shape-hash match,
+        which does not get cheaper with fewer filters — build
+        cover-free, skip detection altogether;
+      - "too_deep": a filter too deep for the int32 order key;
+      - "engaged": the off twin would run the trie NFA — detect covers
+        and match the roots (the build reports "none_covered" instead
+        when detection then finds no cover relation).
+    """
+    if L <= SHAPE_MAX_LEVELS and ns_full <= shape_cap:
+        return False, "fits_shapes"
+    if L > MAX_KEY_LEVELS:
+        return False, "too_deep"
+    return True, "engaged"
 
 
 # ---- detection -----------------------------------------------------------

@@ -8,12 +8,16 @@ Covering must be INVISIBLE except for speed. The proof obligations:
   level, '$'-prefix exclusion, self-cover;
 - vectorized `detect_covers` against exhaustive `covers_pair` pairwise
   sweeps over mixed populations;
-- per-filter order keys reproduce both backends' emission order;
+- per-filter order keys reproduce the trie NFA's emission order;
 - engine A/B twins (covering on vs off) bit-identical on delivery
   counts AND per-session delivery order across clean / shared-group /
-  '$'-topic / dirty-overlay / churn traffic and all backend pairings
-  (shapes-shapes, trie-off vs shapes-root-on, trie-trie), plus the
+  '$'-topic / dirty-overlay / churn traffic and both pairings covering
+  engages on (trie-off vs shapes-root-on, trie-trie), plus the
   2/4/8-shard mesh;
+- the engage rule (`covering_decision`, PR 25): a full set the
+  shape-hash table holds whole builds cover-free and never runs
+  detection; the engine cases therefore give their engines a
+  `shape_cap` the full set overflows and the roots fit;
 - the append path: a covered new subscription lands in the expansion
   CSR (no rebuild) and the match cache drops cached topics against the
   EXPANDED set — insert and delete;
@@ -194,11 +198,14 @@ class TestDetection:
 # ---------------- engine A/B twins ----------------
 
 POPULATIONS = {
-    # both twins on the shapes backend (few shapes)
+    # few shapes (5 in the full set, 3 among the roots): with the
+    # engine's shape_cap at SHAPE_CAPS["shapes"] the off twin runs the
+    # trie and the on twin the roots under shapes
     "shapes": ["s/#", "s/+/t", "s/u/t", "s/u/v", "s/a/t",
                "q/1", "q/2", "w/+", "w/x"],
-    # off twin trie (diverse shapes force past shape_cap via deep '+'
-    # spread), on twin shapes-over-roots — the mixed-backend pairing
+    # 10 shapes in the full set (deep '+' spread), 6 among the roots:
+    # at shape_cap 8 the off twin runs the trie, the on twin
+    # shapes-over-roots — the mixed-backend pairing
     "mixed": (["top/#"]
               + [f"top/{'+/' * (i % 4)}x{i}" for i in range(12)]
               + [f"d{i}/{'+/' * (i % 5)}m{i}/t{i}" for i in range(12)]
@@ -206,13 +213,22 @@ POPULATIONS = {
 }
 
 
-def _mk_twin_nodes(filters, conf=None):
-    """(covering-on, covering-off) nodes with one sink+sid per filter."""
+# the shape_cap at which a population's full set overflows the
+# shape-hash table and its roots fit
+SHAPE_CAPS = {"shapes": 4, "mixed": 8}
+
+
+def _mk_twin_nodes(filters, conf=None, shape_cap=None):
+    """(covering-on, covering-off) nodes with one sink+sid per filter.
+    `shape_cap` (set before the first build) is how a small population
+    overflows the shape-hash table, which is where covering engages."""
     nodes = []
     for covering in (True, False):
         cfg = {"broker": dict(conf or {},
                               subscription_covering=covering)}
         node = Node(cfg)
+        if shape_cap is not None:
+            node.device_engine.shape_cap = shape_cap
         sinks, sids = {}, {}
         for i, f in enumerate(filters):
             s = Sink()
@@ -245,13 +261,15 @@ class TestEngineTwins:
     @pytest.mark.parametrize("pop", sorted(POPULATIONS))
     def test_clean_dirty_churn_twins(self, pop):
         filters = POPULATIONS[pop]
-        on, off = _mk_twin_nodes(filters)
+        on, off = _mk_twin_nodes(filters, shape_cap=SHAPE_CAPS[pop])
         # clean snapshot, repeated (cache-hit rounds included)
         for rnd in range(3):
             _route_and_compare(on, off, TRAFFIC, b"r%d" % rnd)
-        if pop == "mixed":
-            st = on[0].device_engine.stats()
-            assert st["cover"] and st["cover"]["covered"] > 0
+        st = on[0].device_engine.stats()
+        assert st["cover"] and st["cover"]["covered"] > 0
+        assert (st["backend"], st["cover_decision"]) == ("shapes",
+                                                         "engaged")
+        assert off[0].device_engine.stats()["backend"] == "trie"
         # dirty overlay: post-snapshot subscriptions — for the shapes
         # population "s/u/new" is covered by the built "s/#" (append
         # path on the on-twin); for mixed there is no covering root, so
@@ -287,7 +305,7 @@ class TestEngineTwins:
         """Shared-sub picks resolve on EXPANDED rows: a group on a
         covered filter must rotate identically across the twins."""
         filters = ["g/#", "g/+/t", "g/a/t"]
-        on, off = _mk_twin_nodes(filters)
+        on, off = _mk_twin_nodes(filters, shape_cap=2)
         for node, sinks, _sids in (on, off):
             a, bb = Sink(), Sink()
             node.broker.subscribe(node.broker.register(a, "m1"),
@@ -299,13 +317,15 @@ class TestEngineTwins:
             _route_and_compare(
                 on, off, ["g/a/t", "g/b/t", "g/c", "g/a/t"],
                 b"s%d" % rnd)
+        assert on[0].device_engine.stats()["cover"]["covered"] == 2
 
     def test_unsubscribe_covered_filter(self):
         """Deleting a covered filter must stop its deliveries on both
         twins identically (tombstone against the expanded set)."""
         filters = ["s/#", "s/+/t", "s/u/t"]
-        on, off = _mk_twin_nodes(filters)
+        on, off = _mk_twin_nodes(filters, shape_cap=2)
         _route_and_compare(on, off, ["s/u/t"])
+        assert on[0].device_engine.stats()["cover"]["covered"] == 2
         for node, _sinks, sids in (on, off):
             node.broker.unsubscribe(sids["s/+/t"], "s/+/t")
         _route_and_compare(on, off, ["s/u/t", "s/x/t"])
@@ -314,8 +334,11 @@ class TestEngineTwins:
 # ---------------- append path & cache invalidation ----------------
 
 class TestAppendAndCache:
-    def _node(self, **conf):
+    def _node(self, shape_cap=2, **conf):
+        """A covering node whose shape-hash table holds the roots of
+        these cases' filters (1-2 shapes) and not the full set (2-3)."""
         node = Node({"broker": dict(conf, subscription_covering=True)})
+        node.device_engine.shape_cap = shape_cap
         return node
 
     def test_covered_new_sub_is_csr_append_not_rebuild(self):
@@ -341,7 +364,7 @@ class TestAppendAndCache:
         """The cached-topic drop must test the EXPANDED set: a cached
         topic whose row came from a covering root must be dropped when
         an appended filter matches it."""
-        node = self._node()
+        node = self._node(shape_cap=1)
         s = Sink()
         sid = node.broker.register(s, "base")
         for f in ("s/#", "s/+/t"):
@@ -426,9 +449,12 @@ class TestKnobAndSurfaces:
         for f in ("s/#", "s/+/t", "s/u/t"):
             node.broker.subscribe(sid, f, {"qos": 0})
         eng = node.device_engine
+        eng.shape_cap = 2       # 3 shapes in the full set, 1 root
         eng.rebuild()
         st = eng.stats()
         assert st["subscription_covering"] is True
+        assert st["cover_decision"] == "engaged"
+        assert node.metrics.val("routing.cover.skipped_builds") == 0
         cov = st["cover"]
         assert cov["roots"] >= 1 and cov["covered"] == 2
         assert cov["reduction"] == pytest.approx(3.0)
@@ -449,6 +475,101 @@ class TestKnobAndSurfaces:
         st = node.device_engine.stats()
         assert st["subscription_covering"] is False
         assert st["cover"] is None
+        assert st["cover_decision"] == "off"
+
+
+# ---------------- the engage rule (PR 25) ----------------
+
+class TestEngageRule:
+    """Covering engages only where the full set does not fit the
+    shape-hash backend (`ops/cover.covering_decision`)."""
+
+    @pytest.mark.parametrize("ns_full,shape_cap,L,want", [
+        (3, 32, 6, (False, "fits_shapes")),
+        (32, 32, 20, (False, "fits_shapes")),    # at the cap: fits
+        (33, 32, 6, (True, "engaged")),          # over the cap: trie
+        (1, 0, 2, (True, "engaged")),            # shape_cap 0: trie
+        (3, 32, 21, (True, "engaged")),          # too deep for shapes
+        (3, 32, 24, (True, "engaged")),
+        (3, 32, 25, (False, "too_deep")),        # too deep for the key
+    ])
+    def test_decision_function(self, ns_full, shape_cap, L, want):
+        assert C.covering_decision(ns_full, shape_cap, L) == want
+
+    # one engine per outcome the build reports: (filters, knob,
+    # shape_cap) -> cover_decision, cover state?, skipped_builds
+    OUTCOMES = {
+        "off": (["s/#", "s/+/t", "s/u/t"], False, 2, False, 0),
+        "fits_shapes": (["s/#", "s/+/t", "s/u/t"], True, None, False, 1),
+        "none_covered": (["a/+/x", "b/y", "c/#"], True, 2, False, 0),
+        "engaged": (["s/#", "s/+/t", "s/u/t"], True, 2, True, 0),
+    }
+
+    @pytest.mark.parametrize("want", sorted(OUTCOMES))
+    def test_build_reports_its_decision(self, want, monkeypatch):
+        filters, knob, cap, has_cover, skipped = self.OUTCOMES[want]
+        node = Node({"broker": {"subscription_covering": knob}})
+        s = Sink()
+        sid = node.broker.register(s, "c")
+        for f in filters:
+            node.broker.subscribe(sid, f, {"qos": 0})
+        eng = node.device_engine
+        if cap is not None:
+            eng.shape_cap = cap
+        if want in ("off", "fits_shapes"):
+            # decided BEFORE detection: it must not even run
+            def boom(*a, **k):
+                raise AssertionError("detect_covers ran")
+            monkeypatch.setattr(C, "detect_covers", boom)
+        eng.rebuild()
+        st = eng.stats()
+        assert st["cover_decision"] == want
+        assert (st["cover"] is not None) is has_cover
+        assert node.metrics.val("routing.cover.skipped_builds") == skipped
+        # the consume companions are padded only where covering engaged
+        assert (len(eng._built.seg_np) > len(filters)) is has_cover
+
+    def test_site_plus_builds_cover_free(self, monkeypatch):
+        """The benchmark cell's population at rehearse size: family C
+        covers family B, but 3 shapes fit the shape-hash table — the
+        snapshot is cover-free, no window runs the expansion, and the
+        deliveries equal the covering-off twin's."""
+        from benchmark.populations.site_plus import Population
+        pop = Population({"sites": 4, "lines": 3, "devs": 4, "meas": 5,
+                          "b_meas": 2}, conns=4)
+        filters = pop.filters()
+        intern = InternTable()
+        rows, lens, dollar = _encode(intern, filters)
+        covers, inc = C.detect_covers(rows, lens, dollar)
+        assert (C.assign_owners(covers, inc) >= 0).sum() == 4 * 3 * 2
+
+        def boom(*a, **k):
+            raise AssertionError("detect_covers ran")
+        monkeypatch.setattr(C, "detect_covers", boom)
+        on, off = _mk_twin_nodes(filters)
+        topics = [pop.topic(k) for k in range(4 * 3 * 4 * 5)]
+        for rnd in range(2):
+            counts = _route_and_compare(on, off, topics, b"p%d" % rnd)
+        # fan-out 2, 3 where family B matches too (m < b_meas)
+        assert sorted(set(counts)) == [2, 3]
+        node = on[0]
+        st = node.device_engine.stats()
+        assert st["backend"] == "shapes"
+        assert st["cover"] is None
+        assert st["cover_decision"] == "fits_shapes"
+        assert node.metrics.val("routing.device.windows") >= 2
+        assert node.metrics.val("pipeline.cover.windows") == 0
+        assert node.metrics.val("routing.cover.skipped_builds") >= 1
+        # a post-snapshot subscription under a would-be cover rides the
+        # delta overlay, as on any cover-free snapshot
+        for n2, sinks, _sids in (on, off):
+            s = Sink()
+            n2.broker.subscribe(n2.broker.register(s, "late"),
+                                "site/s0/line/l0/+/m4", {"qos": 0})
+            sinks["late"] = s
+        _route_and_compare(on, off, topics, b"late")
+        assert node.metrics.val("routing.cover.appends") == 0
+        assert node.device_engine.stats()["delta_filters"] == 1
 
 
 # ---------------- workloads generator ----------------

@@ -341,6 +341,44 @@ class TestAdaptiveDeviceChoice:
         b._since_probe = BM._PROBE_EVERY
         assert b._device_worth_it(4)
 
+    def test_cost_sample_never_spans_an_idle_gap(self):
+        """Completion-to-completion is the pipelined cost only while
+        windows queue behind each other: the first window of a burst
+        started AFTER the last completion, so it samples its own
+        latency, not the pause before it (which, taken twice, used to
+        write the device off at the start of a flood)."""
+        b, _ = self._batcher()
+        b._observe_device_cost(100.0, 100.02, 1, False)
+        assert b._dev_batch_s == pytest.approx(0.02)
+        # pipeline busy, window started before the last completion:
+        # completion-to-completion / width
+        b._observe_device_cost(100.01, 100.10, 4, True)
+        assert b._dev_batch_s == pytest.approx(0.02)
+        assert b._last_dev_done == 100.10
+        # 5 s pause, then a burst: busy, but started after the pause
+        for k in range(3):
+            t0 = 105.10 + 0.08 * k
+            b._observe_device_cost(t0, t0 + 0.08, 4, True)
+        assert b._dev_batch_s == pytest.approx(0.02)
+        assert b._dev_spike == 0
+
+    def test_device_reprobe_sample_is_adopted(self):
+        """A pessimized device estimate recovers on the scheduled
+        re-try's own sample, not at alpha a probe period."""
+        from emqx_tpu.broker import batcher as BM
+        b, node = self._batcher()
+        b._dev_batch_s, b._host_msg_s = 0.400, 0.00017
+        assert not b._device_worth_it(1024)         # host regime
+        b._since_probe = BM._PROBE_EVERY
+        assert b._device_worth_it(1024)             # the re-try
+        assert node.metrics.val("routing.chooser.device_probe") == 1
+        b._observe_device_cost(10.0, 10.06, 1, False)
+        assert b._dev_batch_s == pytest.approx(0.06)
+        assert b._device_worth_it(1024)             # back on the device
+        # only the re-try's sample: the next one blends in as ever
+        b._observe_device_cost(10.06, 10.16, 1, False)
+        assert b._dev_batch_s == pytest.approx(0.8 * 0.06 + 0.2 * 0.10)
+
     def test_ewma_pessimizes_fast_optimizes_slow(self):
         """Cost estimates pessimize fast but not on ONE bad sample: a
         first >3x outlier folds in smoothly and arms the streak; a
