@@ -3,9 +3,12 @@
 The benchmark's own runs never use these. `run.py --control <name>`
 (and the tests under `benchmark/tests/`) switch one on to show that the
 comparison which decides `correct` comes out false when the broker
-loses, duplicates or reorders a delivery. Each tampers where a delivery
-is produced: `Session.deliver`, which every route path (host, device,
-lanes) ends in.
+loses, duplicates or reorders a delivery, or picks a group's member at
+random instead of in turn. The first three tamper where a delivery is
+produced: `Session.deliver`, which every route path (host, device,
+lanes) ends in; `random_pick` switches the node's
+`shared_subscription_strategy` to `random`, which the host pick and the
+device's route programs both read at every window.
 """
 
 from __future__ import annotations
@@ -13,9 +16,16 @@ from __future__ import annotations
 ONE_IN = 997          # a delivery in this many is tampered with
 
 
-def apply(name: str):
+def apply(name: str, node):
     """Patch the program for control `name`; returns an undo function."""
     from emqx_tpu.broker.session import Session
+    if name == "random_pick":
+        broker = node.broker
+        was, broker.shared_strategy = broker.shared_strategy, "random"
+
+        def turn_back():
+            broker.shared_strategy = was
+        return turn_back
     real = Session.deliver
     state = {"n": 0, "held": {}}
 

@@ -337,7 +337,7 @@ class Publishers:
                                      self.key_spec)
 
     def note_expected(self, keys: np.ndarray) -> None:
-        self.expected += int((self.pop.expect(keys) >= 0).sum())
+        self.expected += populations.expected_count(self.pop, keys)
 
     def frames(self, p: int, keys, due_ns) -> tuple:
         """Serialise one publisher's next PUBLISHes and log them; returns

@@ -6,6 +6,7 @@ chip routed nothing in the traced span."""
 
 from __future__ import annotations
 
+from benchmark import populations
 from benchmark.readers import route_bytes, xplane
 
 
@@ -24,7 +25,7 @@ def read(ctx, match):
     pub = ctx["pub"]
     keys = pub["key"][(pub["send_ns"] >= w["t0_ns"])
                       & (pub["send_ns"] < w["t1_ns"])]
-    per_msg = float((pop.expect(keys) >= 0).sum()) / max(1, len(keys))
+    per_msg = populations.expected_count(pop, keys) / max(1, len(keys))
     if not per_msg:
         return 0.0
     need = delivered / per_msg * route_bytes.message_bytes(

@@ -20,6 +20,7 @@ import os
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
+CHIP_PREFIX = "#Chip"           # the profiler's own planes of a chip it watched
 SPAN = "bench:"                 # prefix of the harness's own annotations
 WINDOW_SPAN = "bench:trace_window"
 
@@ -47,9 +48,19 @@ def from_file(path: str) -> dict:
 
 
 def device_planes(trace: dict) -> list:
-    return [p for p in trace["planes"]
+    """The TPU planes that hold an ops line. Where there is none but
+    the profiler watched a chip, nothing ran on it while the trace was
+    on, and one plane without an operation stands for it: an idle trace
+    is a reading, not a fault. (A v5e on which nothing was dispatched
+    leaves `#Chip0 Host Interface` and `#Chip0 Misc` and no
+    `/device:TPU:0` at all: my chip run, PR 26.)"""
+    busy = [p for p in trace["planes"]
             if p["name"].startswith(DEVICE_PREFIX)
             and any(ln["name"] == OPS_LINE for ln in p["lines"])]
+    if busy or not any(p["name"].startswith((DEVICE_PREFIX, CHIP_PREFIX))
+                       for p in trace["planes"]):
+        return busy
+    return [{"name": "idle chip", "lines": []}]
 
 
 def _rehearsal_plane(trace: dict) -> list:
@@ -123,8 +134,7 @@ def reduce(trace: dict, top: int = 10, any_device: bool = False) -> dict:
     if not planes and any_device:
         planes = _rehearsal_plane(trace)
     if not planes:
-        raise ValueError("the trace holds no TPU plane with an "
-                         f"{OPS_LINE!r} line")
+        raise ValueError("the trace holds no plane of a TPU")
     # innermost (shortest) first: a gap's time goes to the harness span
     # that covers it most closely, and the rest to "host:other"
     spans = sorted(((n, max(s, t0), min(e, t1))

@@ -322,7 +322,7 @@ class Run:
         def batch(n):
             nonlocal seq
             keys = traffic_gen.draw_keys(rng, n, pop.dims, pub["keys"])
-            self.warm_expected += int((pop.expect(keys) >= 0).sum())
+            self.warm_expected += populations.expected_count(pop, keys)
             out = []
             for k in keys:
                 qos = 1 if every and seq % every == 0 else 0
@@ -410,7 +410,8 @@ class Run:
         w["deliveries"] = int(((sub["recv_ns"][live] >= w["t0_ns"])
                                & (sub["recv_ns"][live] < w["t1_ns"])).sum())
         t = time.monotonic()
-        verdict = checker.check(self.pop, pub, sub, self.args.seed)
+        verdict = checker.check(self.pop, pub, sub, self.args.seed,
+                                limits=self.cell.config.get("limits"))
         say(f"checked {verdict['attempted']} PUBLISHes, "
             f"{verdict['info']['deliveries']} deliveries in "
             f"{time.monotonic() - t:.1f}s: {json.dumps(verdict['info'])}")
@@ -484,7 +485,7 @@ async def drive(cell, args, node, device: dict) -> dict:
             # a control run: one segment per control, none a measurement
             if k:
                 await run.reset_logs()
-            undo = controls.apply(name)
+            undo = controls.apply(name, node)
             try:
                 await run.window()
                 verdict = await run.drain_and_check(settle_s=10.0)
@@ -660,6 +661,10 @@ def main(argv=None) -> int:
         out["rehearsal_values"] = out.pop("metrics")
         out["metrics"] = {}
     print(json.dumps(out))
+    sys.stdout.flush()
+    for name, c in out["compared"].items():
+        print(f"compared {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
     return 0
 
 

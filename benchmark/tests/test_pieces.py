@@ -7,7 +7,10 @@
   both populations' closed forms agree with brute force;
 - the ledger check passes a sound log and fails on a lost, a duplicated,
   a reordered and a twice-picked delivery, and on an unacknowledged
-  QoS 1 PUBLISH;
+  QoS 1 PUBLISH; with `$share` groups, on a message both members, neither
+  or a non-member got, on a downgraded QoS and on picks out of turn;
+- the key draws are the seed's: `uniform` bit for bit what it was, `zipf`
+  by its law;
 - the trace reduction on a hand-made trace and on the small recorded one;
 - the loader refuses a cell whose per-layer metric lacks its `moves` metric.
 """
@@ -20,8 +23,8 @@ import zlib
 import numpy as np
 import pytest
 
-from benchmark import check, codec, manifest, plain, traffic_gen
-from benchmark.populations import site_plus
+from benchmark import check, codec, manifest, plain, populations, traffic_gen
+from benchmark.populations import device_share, site_plus
 from benchmark.readers import counter, telemetry, xplane
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -146,6 +149,63 @@ def test_brute_force_sees_a_wrong_closed_form():
     assert check.brute_force(pop, np.arange(240), 64, seed=1) > 0
 
 
+SMALL_SHARE = {"ids": 8, "nums": 25, "shared_pct": 50, "group": "bg",
+               "members": 2}
+
+
+def _share_pop(conns=6, **over):
+    return device_share.Population({**SMALL_SHARE, **over}, conns)
+
+
+def test_closed_form_equals_brute_force_with_groups():
+    for pop in (_share_pop(), _share_pop(conns=16, members=3)):
+        keys = np.arange(int(np.prod(pop.dims)))
+        assert check.brute_force(pop, keys, len(keys), seed=5) == 0
+        shared = pop.expect_shared(keys)
+        in_group = (shared >= 0).any(axis=(1, 2))
+        assert in_group.sum() == len(keys) // 2
+        # a key is a plain filter's or a group's, never both or neither
+        assert ((pop.expect(keys) >= 0).sum(axis=1) + in_group == 1).all()
+        assert populations.expected_count(pop, keys) == len(keys)
+        assert (pop.group_ids(keys)[:, 0] >= 0).sum() == in_group.sum()
+        assert len(pop.filters()) == len(keys)
+        subs = sum(len(pop.subscriptions(c)) for c in range(pop.conns))
+        assert subs == len(keys) // 2 * (1 + pop.members)
+    # the rehearsal's size is SMALL_SHARE's
+    with open(os.path.join(manifest.HERE, "configs",
+                           "share50-250k.json")) as f:
+        cfg = json.load(f)
+    assert cfg["rehearse"]["population"] == {
+        k: SMALL_SHARE[k] for k in ("ids", "nums")}
+
+
+@pytest.mark.parametrize("fault", ["second_member", "plain_as_group"])
+def test_brute_force_sees_a_wrong_closed_form_of_the_groups(fault):
+    pop = _share_pop()
+    real = pop.expect_shared
+    if fault == "second_member":
+        def wrong(keys):
+            out = real(keys)
+            out[:, 0, 1] = np.where(out[:, 0, 1] >= 0,
+                                    (out[:, 0, 1] + 1) % pop.conns, -1)
+            return out
+    else:
+        def wrong(keys):
+            return np.full((len(keys), 1, 2), -1)
+    pop.expect_shared = wrong
+    assert check.brute_force(pop, np.arange(200), 64, seed=1) > 0
+
+
+def test_plain_splits_a_shared_subscription_as_the_specification_says():
+    assert plain.split_share("$share/bg/device/d1/+/n2/#") == \
+        ("bg", "device/d1/+/n2/#")
+    assert plain.split_share("$share/g//a") == ("g", "/a")
+    assert plain.split_share("device/$share/x") == (None, "device/$share/x")
+    for bad in ("$share/g", "$share//a", "$share/g+/a", "$share/#/a"):
+        with pytest.raises(ValueError):
+            plain.split_share(bad)
+
+
 def test_full_size_populations_have_the_stated_counts():
     with open(os.path.join(manifest.HERE, "configs", "plus-100k.json")) as f:
         cfg = json.load(f)
@@ -154,6 +214,17 @@ def test_full_size_populations_have_the_stated_counts():
     assert cfg["filters"] == cfg["subscriptions"] == 100_000
     assert sum(len(pop.subscriptions(c)) for c in range(pop.conns)) == 100_000
     assert int(np.prod(pop.dims)) == 3_600_000
+    with open(os.path.join(manifest.HERE, "configs",
+                           "share50-250k.json")) as f:
+        cfg = json.load(f)
+    pop = populations.load(cfg)
+    assert cfg["filters"] == len(pop.filters()) == 250_000
+    assert sum(len(pop.subscriptions(c)) for c in range(pop.conns)) \
+        == cfg["subscriptions"] == 375_000
+    keys = np.arange(250_000)
+    assert (pop.expect_shared(keys) >= 0).any(axis=(1, 2)).sum() == 125_000
+    assert pop.topic(499 * 500 + 7) == "device/d499/x/n7/t"
+    assert plain.match(pop.topic(1234), pop.filters()[1234])
 
 
 def test_traffic_is_the_seeds():
@@ -171,12 +242,62 @@ def test_traffic_is_the_seeds():
                               {"dist": "other"})
 
 
+def test_the_uniform_draw_is_bit_for_bit_the_parents():
+    """Pinned from the parent's `draw_keys` (PR 25's tree): the same
+    seed gives `plus-100k.flood` the same topics as before."""
+    keys = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 9, 1), 1000,
+                                 (100, 30, 30, 40), {"dist": "uniform"})
+    assert keys.dtype == np.int64
+    assert keys[:4].tolist() == PARENT_UNIFORM_HEAD
+    assert zlib.crc32(keys.tobytes()) == PARENT_UNIFORM_CRC
+
+
+PARENT_UNIFORM_HEAD = [3016026, 216480, 3046960, 2367873]
+PARENT_UNIFORM_CRC = 2879118003
+
+
+def _zeta(s, n=10**6):
+    k = np.arange(1, n + 1, dtype=np.float64)
+    return float((k ** -s).sum() + n ** (1 - s) / (s - 1) - 0.5 * n ** -s)
+
+
+def test_the_zipf_draw_is_the_seeds_and_follows_its_law():
+    spec = {"dist": "zipf", "s": 1.3, "dim": 0}
+    n, d = 100_000, 500
+    a = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 9, 1), n,
+                              (d, 500), spec)
+    b = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 9, 1), n,
+                              (d, 500), spec)
+    c = traffic_gen.draw_keys(traffic_gen.rng_for(2**31 + 10, 1), n,
+                              (d, 500), spec)
+    assert (a == b).all() and (a != c).any()
+    ids, nums = np.divmod(a, 500)
+    assert ids.min() == 0 and ids.max() == d - 1
+    # min(zipf(s) - 1, d - 1): rank r has mass (r + 1)^-s / zeta(s), and
+    # the last index takes the whole tail beyond it
+    z = _zeta(1.3)
+    first = 1.0 / z
+    last = 1.0 - float((np.arange(1, d, dtype=np.float64) ** -1.3).sum()) / z
+    for got, p in (((ids == 0).mean(), first), ((ids == 1).mean(),
+                   2 ** -1.3 / z), ((ids == d - 1).mean(), last)):
+        assert abs(got - p) < 5 * np.sqrt(p * (1 - p) / n), (got, p)
+    assert 0.12 < last < 0.14 and 0.25 < first < 0.26
+    # the other dimension is uniform
+    assert abs(nums.mean() - 249.5) < 5 * 144.3 / np.sqrt(n)
+    # the skewed dimension is the one the file names
+    other = traffic_gen.draw_keys(traffic_gen.rng_for(3, 1), n, (500, d),
+                                  {"dist": "zipf", "s": 1.3, "dim": 1})
+    assert abs((other % d == 0).mean() - first) < 0.01
+    assert abs((other // d == 0).mean() - 1 / 500) < 0.002
+
+
 # ------------------------------------------------------------- the ledger
 
-def sound_logs(pop, n=4000, pubs=4, seed=2):
+def sound_logs(pop, n=4000, pubs=4, seed=2, dist=None):
     """Logs of a broker that keeps every guarantee."""
     rng = np.random.default_rng(seed)
-    keys = traffic_gen.draw_keys(rng, n, pop.dims, {"dist": "uniform"})
+    keys = traffic_gen.draw_keys(rng, n, pop.dims,
+                                 dist or {"dist": "uniform"})
     pub = {"pub": np.repeat(np.arange(pubs), n // pubs).astype(np.int32),
            "seq": np.tile(np.arange(n // pubs), pubs).astype(np.int64),
            "key": keys, "qos": np.zeros(n, np.int8)}
@@ -185,13 +306,23 @@ def sound_logs(pop, n=4000, pubs=4, seed=2):
     pub["send_ns"] = pub["due_ns"] + 5
     pub["ack_ns"] = np.where(pub["qos"] == 1, pub["due_ns"] + 900, 0)
     want = pop.expect(keys)
+    shared = populations.members(pop, keys)
+    asks = getattr(pop, "sub_qos", {"plain": 1, "shared": 1})
+    turn: dict = {}              # group -> picks so far: members in turn
     rows = []
     for m in range(n):
-        to = [int(c) for c in want[m] if c >= 0]
+        to = [(int(c), asks["plain"]) for c in want[m] if c >= 0]
+        for g in range(0 if shared is None else shared.shape[1]):
+            who = [int(c) for c in shared[m, g] if c >= 0]
+            if who:
+                gid = int(pop.group_ids(keys[m:m + 1])[0, g])
+                turn[gid] = turn.get(gid, -1) + 1
+                to.append((who[turn[gid] % len(who)], asks["shared"]))
         crc = zlib.crc32(pop.topic(int(keys[m])).encode())
-        for c in to:
+        for c, ask in to:
             rows.append((c, pub["pub"][m], pub["seq"][m], pub["due_ns"][m],
-                         pub["due_ns"][m] + 700, pub["qos"][m], False, crc))
+                         pub["due_ns"][m] + 700, min(pub["qos"][m], ask),
+                         False, crc))
     cols = list(zip(*rows))
     sub = {"sub": np.array(cols[0], np.int16),
            "pub": np.array(cols[1], np.int32),
@@ -291,6 +422,122 @@ def test_a_retransmission_marked_dup_is_not_a_second_delivery():
     assert v["correct"] and v["info"]["dup_redeliveries"] == 3
 
 
+# ------------------------------------------------- the ledger, with groups
+
+ZIPF = {"dist": "zipf", "s": 1.3, "dim": 0}
+LIMITS = {"rr_excess_vs_random": 0.3}
+
+
+def test_sound_logs_with_groups_pass():
+    for pop in (_share_pop(), _share_pop(conns=16, members=3)):
+        pub, sub = sound_logs(pop, dist=ZIPF)
+        v = check.check(pop, pub, sub, seed=1, limits=LIMITS)
+        assert v["correct"], v["numbers"]
+        assert v["failed"] == 0 and v["attempted"] == 4000
+        assert v["numbers"]["rr_excess_vs_random"] == (0.0, 0.3)
+        assert v["info"]["rr_excess_share"] == 0.0
+        assert all(val == 0 for val, _lim in v["numbers"].values())
+        assert v["info"]["deliveries"] == v["info"]["expected_deliveries"] \
+            == 4000
+        assert (sub["qos"] == 1).any() and (sub["qos"] == 0).any()
+
+
+def test_without_limits_every_limit_is_zero():
+    with open(os.path.join(manifest.HERE, "configs", "plus-100k.json")) as f:
+        assert "limits" not in json.load(f)
+    for pop in (_pop(), _share_pop()):
+        v = check.check(pop, *sound_logs(pop), seed=1, limits=None)
+        assert v["correct"]
+        assert {lim for _val, lim in v["numbers"].values()} == {0}
+    # the group numbers are compared where there are groups, only there
+    assert "rr_excess_vs_random" not in check.check(
+        _pop(), *sound_logs(_pop()), seed=1)["numbers"]
+    with pytest.raises(ValueError, match="does not compare"):
+        check.check(_pop(), *sound_logs(_pop()), seed=1, limits=LIMITS)
+    # one pick out of turn is over a limit of 0, and under the file's
+    pop = _share_pop()
+    pub, sub, _number = tamper_one_member_takes_all(pop, *sound_logs(pop),
+                                                    groups=1)
+    assert not check.check(pop, pub, sub, seed=1)["correct"]
+    assert check.check(pop, pub, sub, seed=1, limits=LIMITS)["correct"]
+
+
+def _shared_rows(pop, pub, sub):
+    """Rows of the receive log that are a group's deliveries, with the
+    members of that group and the message's key."""
+    per_pub = int(pub["seq"].max()) + 1
+    keys = pub["key"][sub["pub"].astype(np.int64) * per_pub + sub["seq"]]
+    who = pop.expect_shared(keys)[:, 0, :]
+    rows = np.flatnonzero(who[:, 0] >= 0)
+    return rows, who[rows], keys[rows]
+
+
+def tamper_both_members(pop, pub, sub):
+    rows, who, _keys = _shared_rows(pop, pub, sub)
+    out = {k: np.concatenate([v, v[rows[:1]]]) for k, v in sub.items()}
+    out["sub"][-1] = who[0][who[0] != sub["sub"][rows[0]]][0]
+    return pub, out, "wrong_delivery_sets"
+
+
+def tamper_non_member(pop, pub, sub):
+    rows, who, _keys = _shared_rows(pop, pub, sub)
+    out = {k: v.copy() for k, v in sub.items()}
+    out["sub"][rows[3]] = (who[3].max() + 2) % pop.conns
+    assert out["sub"][rows[3]] not in who[3]
+    return pub, out, "wrong_delivery_sets"
+
+
+def tamper_neither_member(pop, pub, sub):
+    rows, _who, _keys = _shared_rows(pop, pub, sub)
+    return pub, _drop(sub, rows[5:6]), "wrong_delivery_sets"
+
+
+def tamper_one_member_takes_all(pop, pub, sub, groups=None):
+    """Every pick of a group (of the first `groups` ones) goes to its
+    first member: each delivery set is lawful, the turns are not."""
+    rows, who, keys = _shared_rows(pop, pub, sub)
+    out = {k: v.copy() for k, v in sub.items()}
+    keep = np.isin(keys, np.unique(keys)[:groups])
+    out["sub"][rows[keep]] = who[keep, 0]
+    return pub, out, "rr_excess_vs_random"
+
+
+def tamper_downgraded_qos(pop, pub, sub):
+    rows, _who, _keys = _shared_rows(pop, pub, sub)
+    out = {k: v.copy() for k, v in sub.items()}
+    out["qos"][rows[sub["qos"][rows] == 1][0]] = 0
+    return pub, out, "delivery_qos_mismatches"
+
+
+@pytest.mark.parametrize("tamper", [
+    tamper_both_members, tamper_non_member, tamper_neither_member,
+    tamper_one_member_takes_all, tamper_downgraded_qos, tamper_lost,
+    tamper_duplicated, tamper_reordered, tamper_unacknowledged],
+    ids=lambda f: f.__name__[7:])
+def test_a_weakened_guarantee_fails_the_check_with_groups(tamper):
+    pop = _share_pop()
+    pub, sub, number = tamper(pop, *sound_logs(pop, dist=ZIPF))
+    v = check.check(pop, pub, sub, seed=1, limits=LIMITS)
+    assert not v["correct"]
+    assert v["failed"] >= 1
+    value, limit = v["numbers"][number]
+    assert value > limit, v["numbers"]
+    if number == "rr_excess_vs_random":
+        # the sets are lawful: only the turns give it away
+        assert v["numbers"]["wrong_delivery_sets"][0] == 0 and value > 1.5
+        assert v["info"]["rr_excess_share"] > 0.3
+
+
+def test_two_groups_of_one_message_may_not_share_a_member():
+    pop = _share_pop()
+    real = pop.expect_shared
+    pop.expect_shared = lambda keys: np.concatenate(
+        [real(keys), real(keys)], axis=1)
+    pop.group_ids = lambda keys: np.zeros((len(keys), 2), np.int64)
+    with pytest.raises(ValueError, match="share a member"):
+        check.check(pop, *sound_logs(_share_pop()), seed=1)
+
+
 # ------------------------------------------------------------ the readers
 
 def small_trace():
@@ -331,11 +578,55 @@ def test_trace_reduction_on_a_hand_made_trace():
     assert (seconds, n) == (pytest.approx(300e-6), 1)
 
 
-def test_trace_without_a_device_plane_is_refused():
-    t = small_trace()
+def _no_tpu_plane(t):
     t["planes"] = t["planes"][1:]
-    with pytest.raises(ValueError):
-        xplane.reduce(t)
+
+
+def _tpu_plane_without_lines(t):
+    t["planes"][0]["lines"] = []
+
+
+def _only_the_profilers_own_planes_of_the_chip(t):
+    """What an idle v5e leaves (my chip run, PR 26)."""
+    t["planes"][0] = {"name": "#Chip0 Misc", "lines": []}
+    t["planes"].insert(0, {"name": "/device:CUSTOM:Megascale Trace",
+                           "lines": []})
+
+
+def _nothing_ran_in_the_window(t):
+    for ln in t["planes"][0]["lines"]:
+        ln["events"] = [ev for ev in ln["events"] if ev[0] == "late"]
+
+
+@pytest.mark.parametrize("cut,idle", [
+    (_no_tpu_plane, None), (_tpu_plane_without_lines, 100.0),
+    (_only_the_profilers_own_planes_of_the_chip, 100.0),
+    (_nothing_ran_in_the_window, 100.0)], ids=lambda x: getattr(
+        x, "__name__", None))
+def test_trace_without_a_device_plane_is_refused(cut, idle):
+    """No plane of a TPU at all: refused. A chip the profiler watched
+    and on which no operation ran in the window: a reading, idle 100 %."""
+    from benchmark.readers import trace_idle, trace_program, trace_spans
+    t = small_trace()
+    cut(t)
+    if idle is None:
+        with pytest.raises(ValueError, match="no plane of a TPU"):
+            xplane.reduce(t)
+        return
+    r = xplane.reduce(t)
+    assert r["busy_s"] == 0.0 and r["device_ops"] == [] and r["chips"] == 1
+    assert r["window_s"] == pytest.approx(1e-3)
+    # the whole window is a gap, shared out among the harness's spans
+    gaps = dict(r["idle_gaps"])
+    assert sum(gaps.values()) == pytest.approx(1e-3)
+    assert gaps["bench:dispatch"] == pytest.approx(60e-6)
+    ctx = {"trace": t, "trace_reduced": r,
+           "trace_m0": {"routing.device.batches": 7},
+           "trace_m1": {"routing.device.batches": 7}}
+    assert trace_idle.read(ctx) == idle
+    # per-window device metrics: their denominators did not move
+    assert trace_program.read(ctx, ["route"]) == 0.0
+    assert trace_spans.read(ctx, unnamed=True) == pytest.approx(1000.0)
 
 
 def test_trace_reduction_on_the_recorded_trace():

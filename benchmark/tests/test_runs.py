@@ -60,13 +60,16 @@ def test_rehearsal_of_each_cell_end_to_end(cell):
     assert out["split"]["window"]["publishes"] > 0
 
 
-@pytest.mark.parametrize("control,number", [
-    ("lose", "wrong_delivery_sets"),
-    ("duplicate", "wrong_delivery_sets"),
-    ("reorder", "order_breaks"),
+@pytest.mark.parametrize("control,number,cell", [
+    ("lose", "wrong_delivery_sets", "plus-100k.flood"),
+    ("duplicate", "wrong_delivery_sets", "plus-100k.flood"),
+    ("reorder", "order_breaks", "plus-100k.flood"),
+    ("lose", "wrong_delivery_sets", "share50-250k.flood"),
+    ("random_pick", "rr_excess_vs_random", "share50-250k.flood"),
 ])
-def test_a_run_with_the_timed_path_broken_is_not_correct(control, number):
-    r, out = run_cell("--workload", CELLS[0], "--seed", "29", "--seconds", "1",
+def test_a_run_with_the_timed_path_broken_is_not_correct(control, number,
+                                                         cell):
+    r, out = run_cell("--workload", cell, "--seed", "29", "--seconds", "1",
                       "--trace", "0", "--rehearse", "--control", control)
     assert r.returncode == 0, r.stderr[-2000:]
     assert out["correct"] is False and out["failed"] > 0

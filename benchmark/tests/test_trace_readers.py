@@ -339,6 +339,19 @@ def test_every_new_metric_file_reads_the_recorded_trace(recorded):
         "chooser_margin.flood": 0.8125,
         "chooser_probe_share.flood": 100.0 * 8 / 100,
     }
+    # PR 26's three, listed for the `$share` cell alone
+    ctx["m0"].update({"match_cache.hits": 100, "match_cache.misses": 50,
+                      "routing.device.windows": 10})
+    ctx["m1"].update({"match_cache.hits": 740, "match_cache.misses": 410,
+                      "routing.device.windows": 50,
+                      "routing.device.cached_windows": 8,
+                      "packets.puback.sent": 102_000})
+    of_share = {
+        "match_cache_hit_share.flood": 64.0,
+        "cached_window_share.flood": 20.0,
+        "puback_per_s.flood": 2000.0,
+    }
+    want.update(of_share)
     with open(os.path.join(os.path.dirname(os.path.dirname(HERE)),
                            "BENCHMARK.json")) as f:
         manifest = {m["name"]: m for m in json.load(f)["per_layer"]}
@@ -347,6 +360,7 @@ def test_every_new_metric_file_reads_the_recorded_trace(recorded):
                                name + ".json")) as f:
             spec = json.load(f)
         assert spec["unit"] == manifest[name]["unit"]
-        assert manifest[name]["workloads"] == ["plus-100k.flood"]
+        assert manifest[name]["workloads"] == ["plus-100k.flood"] * (
+            name not in of_share) + ["share50-250k.flood"]
         assert read_metric(ctx, spec["reader"], spec["args"]) == \
             pytest.approx(value), name

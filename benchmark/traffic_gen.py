@@ -18,8 +18,15 @@ def draw_keys(rng: np.random.Generator, n: int, dims: tuple,
               spec: dict) -> np.ndarray:
     """n key indices over the population's key space `dims`.
 
-    {"dist": "uniform"}: every dimension uniform."""
-    if spec["dist"] != "uniform":
+    {"dist": "uniform"}: every dimension uniform.
+    {"dist": "zipf", "s": 1.3, "dim": 0}: dimension `dim` is
+    min(zipf(s) - 1, d - 1), rank 0 the hottest and the tail beyond the
+    dimension folded onto its last index, as `chip_smoke.Traffic` and
+    `bench.py` draw a device id; the others uniform."""
+    if spec["dist"] not in ("uniform", "zipf"):
         raise ValueError(f"unknown key distribution {spec['dist']!r}")
-    cols = [rng.integers(0, d, n) for d in dims]
+    skewed = int(spec.get("dim", 0)) if spec["dist"] == "zipf" else None
+    cols = [np.minimum(rng.zipf(float(spec["s"]), n) - 1, d - 1)
+            if k == skewed else rng.integers(0, d, n)
+            for k, d in enumerate(dims)]
     return np.ravel_multi_index(cols, dims).astype(np.int64)
