@@ -3,7 +3,7 @@
 This is the piece that makes the TPU program THE broker hot path instead of
 a side-car demo: it compiles the live routing state (Router filter universe +
 Broker subscriber/shared-group membership) into the fused device tables
-(models.router_engine), runs `route_step`/`route_step_shapes` over publish
+(models.router_engine), runs its `route_window_*` programs over publish
 micro-batches, and consumes the `RouteResult` into actual session deliveries
 — replacing the reference's per-message publish path
 (emqx_broker.erl:199-308: match_routes → dispatch fold → shared pick).
@@ -538,7 +538,8 @@ class _Handle:
     sub has been finished or abandoned."""
 
     __slots__ = ("subs", "built", "dev_shared", "enc", "res", "np_res",
-                 "np_counts", "error", "refs", "t0", "plan", "cache_info",
+                 "np_counts", "np_mov", "error", "refs", "t0", "plan",
+                 "cache_info",
                  "pcap", "cres", "delta", "dres", "dcres", "np_delta",
                  "trace", "sub_traces")
 
@@ -549,6 +550,8 @@ class _Handle:
         self.res = None       # device RouteResult, fields [W, ...]
         self.np_res = None    # host views: dense tuple OR _CsrRes
         self.np_counts = None  # match_counts [W, B] (cache population)
+        self.np_mov = None    # match-stage overflow [W, B]: read back
+                              # only from a trie window with a flagged lane
         self.error = None
         self.refs = len(subs)
         self.t0 = None        # consumer-side window processing start
@@ -1446,8 +1449,7 @@ class DeviceRouteEngine:
             self._built = b
             self._tables = tables
             self._cursors = cursors
-            self._cur_sig = self._tables_sig(tables) \
-                if b.backend == "shapes" else ()
+            self._cur_sig = self._tables_sig(tables)
             # evict warmth of superseded signatures (unbounded set
             # otherwise under churn); a re-warm for a returning capacity
             # class is a jit-cache hit, not a fresh trace
@@ -1653,16 +1655,13 @@ class DeviceRouteEngine:
 
         import jax
 
-        from emqx_tpu.models.router_engine import (route_step,
-                                                   route_window_full)
+        from emqx_tpu.models.router_engine import route_window_full
         from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
         tele = getattr(self.node, "pipeline_telemetry", None)
         b, tables, cursors, _rich = result
         strat = np.int32(STRATEGY_ROUND_ROBIN)
+        kw = self._caps_kw(b.backend)
         for Wp, Bp in self._STD_CLASSES:
-            if Wp > 1 and b.backend != "shapes":
-                continue    # trie backend never fuses: (8, Bp) would
-                            # just redundantly re-run the (1, Bp) step
             ctx = tele.compile_context(f"warm W{Wp}xB{Bp}") \
                 if tele is not None else contextlib.nullcontext()
             enc = np.zeros((Wp, Bp, self.max_levels), np.int32)
@@ -1674,31 +1673,18 @@ class DeviceRouteEngine:
                 # dispatch (the donating twin at depth >= 2) with a
                 # throwaway cursors buffer — never the live one, which
                 # the twin would donate away (_warm_cursors)
-                if b.backend == "shapes":
-                    r = self._rt(route_window_full)(
-                        tables, self._warm_cursors(cursors), enc, lens,
-                        dollar, mh, strat,
-                        fanout_cap=self.fanout_cap,
-                        slot_cap=self.slot_cap)
-                else:
-                    r = self._rt(route_step)(
-                        tables, self._warm_cursors(cursors), enc[0],
-                        lens[0], dollar[0], mh[0], strat,
-                        frontier_cap=self.frontier_cap,
-                        match_cap=self.match_cap,
-                        fanout_cap=self.fanout_cap,
-                        slot_cap=self.slot_cap)
+                r = self._rt(route_window_full)(
+                    tables, self._warm_cursors(cursors), enc, lens,
+                    dollar, mh, strat, **kw)
                 jax.block_until_ready(r.match_counts)
-                if b.backend == "shapes":
-                    self._last_cursors(r)
-        if b.backend == "shapes":
-            # this snapshot's classes are warm: once IT is serving, the
-            # batcher may dispatch/fuse (readiness is per shape
-            # signature, so an old snapshot still serving cannot run
-            # into cold shapes)
-            sig = self._tables_sig(tables)
-            for Wp, Bp in self._STD_CLASSES:
-                self._warm_classes.add((sig, Wp, Bp))
+                self._last_cursors(r)
+        # this snapshot's classes are warm: once IT is serving, the
+        # batcher may dispatch/fuse (readiness is per shape
+        # signature, so an old snapshot still serving cannot run
+        # into cold shapes)
+        sig = self._tables_sig(tables)
+        for Wp, Bp in self._STD_CLASSES:
+            self._warm_classes.add((sig, Wp, Bp))
 
     def _try_swap(self) -> None:
         """Apply a finished background build if no dispatch is in flight
@@ -1756,18 +1742,8 @@ class DeviceRouteEngine:
         # program is kept deliberately (off-path; a cold compile here
         # never stalls serving)
         cur = self._warm_cursors(self._cursors)
-        if self._built.backend == "shapes":
-            r = RE.route_window_full(self._tables, cur, enc,
-                                     z, zb, z, strat,
-                                     fanout_cap=self.fanout_cap,
-                                     slot_cap=self.slot_cap)
-        else:
-            r = RE.route_step(self._tables, cur, enc[0], z[0],
-                              zb[0], z[0], strat,
-                              frontier_cap=self.frontier_cap,
-                              match_cap=self.match_cap,
-                              fanout_cap=self.fanout_cap,
-                              slot_cap=self.slot_cap)
+        r = RE.route_window_full(self._tables, cur, enc, z, zb, z, strat,
+                                 **self._caps_kw(self._built.backend))
         jax.block_until_ready(r.match_counts)
 
     def _probe_materialize(self) -> None:
@@ -1955,11 +1931,6 @@ class DeviceRouteEngine:
         the plain path's readback must still seed the cache, or a cold
         hot-set would never start hitting)."""
         Wp, Bp, L = enc4.shape
-        if b.backend != "shapes" and Wp > 1:
-            # trie never fuses, so a multi-batch trie window only exists
-            # for direct callers — no plan, and no point paying the
-            # hash/unique analysis either
-            return None, None
         if Wp == 1 and Bp <= self._STD_CLASSES[0][1]:
             # a single window at the smallest batch class can never
             # engage (Bm floors at that same class, so Bm < Bp is
@@ -2044,8 +2015,7 @@ class DeviceRouteEngine:
             # serving path: a cold cached (W, Bp, Bm[, dC]) class would
             # stall on an in-path XLA compile — dispatch the warm plain
             # program instead and let the background warm bring the
-            # class online (same policy as batch_class_warm; trie
-            # classes are keyed under the empty signature)
+            # class online (same policy as batch_class_warm)
             self._wanted_cached.add((Wp, Bp, Bm, dC))
             self._kick_class_warm()
             self.node.metrics.inc("routing.device.cold_cached_class")
@@ -2121,13 +2091,22 @@ class DeviceRouteEngine:
     def max_fuse(self) -> int:
         """How many batches the serving path may fuse per dispatch right
         now: 1 until the CURRENT snapshot's fused window class is warm,
-        then the largest class. Trie-backend snapshots never fuse (no
-        window program — sequential dispatch amortizes nothing)."""
+        then the largest class (either backend: the window programs
+        scan the trie NFA's step as they do the shape hash's)."""
         W, Bp = self._STD_CLASSES[-1]
-        if self._built is None or self._built.backend != "shapes" \
+        if self._built is None \
                 or (self._cur_sig, W, Bp) not in self._warm_classes:
             return 1
         return W
+
+    def _caps_kw(self, backend: str) -> dict:
+        """The static caps a route program of `backend` is traced with
+        (the trie NFA's own only where the trie matches)."""
+        kw = dict(fanout_cap=self.fanout_cap, slot_cap=self.slot_cap)
+        if backend != "shapes":
+            kw.update(frontier_cap=self.frontier_cap,
+                      match_cap=self.match_cap)
+        return kw
 
     def _batch_class(self, n_msgs: int) -> int:
         """Quantize a batch size onto the standard Bp ladder (derived
@@ -2144,10 +2123,6 @@ class DeviceRouteEngine:
         otherwise, so serving never stalls on an XLA compile."""
         if self._built is None:
             return False
-        if self._built.backend != "shapes":
-            # trie backend has no background warm path for every class;
-            # first use compiles in-path as it always has (rare fallback)
-            return True
         Bp = self._batch_class(n_msgs)
         if (self._cur_sig, 1, Bp) in self._warm_classes:
             return True
@@ -2249,21 +2224,17 @@ class DeviceRouteEngine:
         """Warm every standard (W, Bp) class AND every demand-registered
         cached / delta-overlay / compact program class the CURRENT
         snapshot is missing, off the serving path. Re-kicks after a
-        failure and after any swap to unwarmed capacity classes. The
-        standard ladder is shapes-only (trie compiles its plain step
-        in-path, as ever); cached/delta/compact classes warm for BOTH
-        backends — the gates hold each program variant back until its
-        class is warm."""
+        failure and after any swap to unwarmed capacity classes. Both
+        backends alike: the gates hold each program variant back until
+        its class is warm."""
         import asyncio
         if self._fuse_warm_task is not None or self._built is None:
             return
         backend = self._built.backend
         ck = self._class_key
-        missing = []
-        if backend == "shapes":
-            wanted = self._STD_CLASSES + tuple(sorted(self._extra_classes))
-            missing = [(W, Bp) for W, Bp in wanted
-                       if (self._cur_sig, W, Bp) not in self._warm_classes]
+        wanted = self._STD_CLASSES + tuple(sorted(self._extra_classes))
+        missing = [(W, Bp) for W, Bp in wanted
+                   if (self._cur_sig, W, Bp) not in self._warm_classes]
         delta_missing = [
             e for e in sorted(self._wanted_delta)
             if ck(self._cur_sig, e[0], e[1], dC=e[2])
@@ -2298,14 +2269,15 @@ class DeviceRouteEngine:
             import jax
 
             from emqx_tpu.models.router_engine import (
-                route_step_cached, route_step_delta,
-                route_step_delta_cached, route_window_cached,
-                route_window_delta, route_window_delta_cached,
-                route_window_full)
+                route_window_cached, route_window_delta,
+                route_window_delta_cached, route_window_full)
             from emqx_tpu.ops.delta import empty_delta_tables
             from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
             strat = np.int32(STRATEGY_ROUND_ROBIN)
             rt = self._rt
+            caps = self._caps_kw(backend)
+            dcaps = dict(delta_match_cap=_DELTA_MATCH_CAP,
+                         delta_fanout_cap=_DELTA_FANOUT_CAP)
 
             def wc():
                 # fresh throwaway cursors per program call: the
@@ -2330,11 +2302,10 @@ class DeviceRouteEngine:
             for Wp, Bp in missing:
                 enc = np.zeros((Wp, Bp, self.max_levels), np.int32)
                 z = np.zeros((Wp, Bp), np.int32)
+                zb = np.zeros((Wp, Bp), bool)
                 with ctx_of(f"warm W{Wp}xB{Bp}"):
                     r = rt(route_window_full)(
-                        tables, wc(), enc, z, np.zeros((Wp, Bp), bool),
-                        z, strat, fanout_cap=self.fanout_cap,
-                        slot_cap=self.slot_cap)
+                        tables, wc(), enc, z, zb, z, strat, **caps)
                     jax.block_until_ready(r.match_counts)
                     self._last_cursors(r)
                 self._warm_classes.add((sig, Wp, Bp))
@@ -2347,22 +2318,9 @@ class DeviceRouteEngine:
                 z = np.zeros((Wp, Bp), np.int32)
                 zb = np.zeros((Wp, Bp), bool)
                 with ctx_of(f"warm W{Wp}xB{Bp}d{dC}"):
-                    if backend == "shapes":
-                        r = rt(route_window_delta)(
-                            tables, dt, wc(), enc, z, zb, z, strat,
-                            fanout_cap=self.fanout_cap,
-                            slot_cap=self.slot_cap,
-                            delta_match_cap=_DELTA_MATCH_CAP,
-                            delta_fanout_cap=_DELTA_FANOUT_CAP)
-                    else:   # trie delta dispatches are single-batch
-                        r = rt(route_step_delta)(
-                            tables, dt, wc(), enc[0], z[0], zb[0],
-                            z[0], strat, frontier_cap=self.frontier_cap,
-                            match_cap=self.match_cap,
-                            fanout_cap=self.fanout_cap,
-                            slot_cap=self.slot_cap,
-                            delta_match_cap=_DELTA_MATCH_CAP,
-                            delta_fanout_cap=_DELTA_FANOUT_CAP)
+                    r = rt(route_window_delta)(
+                        tables, dt, wc(), enc, z, zb, z, strat,
+                        **caps, **dcaps)
                     jax.block_until_ready(r.res.match_counts)
                 self._warm_classes.add(ck(sig, Wp, Bp, dC=dC))
             # demand-driven cached-dispatch classes: the serving path
@@ -2379,49 +2337,24 @@ class DeviceRouteEngine:
                 pos = (np.full(Bm, Bp, np.int32),)   # pad = Bp: dropped
                 label = f"warm W{Wp}xB{Bp}mB{Bm}" \
                     + (f"d{dC}" if dC is not None else "")
+                inv = np.zeros((Wp, Bp), np.int32)
+                mh = np.zeros((Wp, Bp), np.int32)
                 with ctx_of(label):
-                    if backend == "shapes":
-                        inv = np.zeros((Wp, Bp), np.int32)
-                        mh = np.zeros((Wp, Bp), np.int32)
-                        if dC is None:
-                            r = rt(route_window_cached)(
-                                tables, wc(), *args, *pos, inv, mh,
-                                strat, fanout_cap=self.fanout_cap,
-                                slot_cap=self.slot_cap)
-                        else:
-                            r = rt(route_window_delta_cached)(
-                                tables, dummy_delta(dC), wc(), *args,
-                                *dargs, *pos, inv, mh, strat,
-                                fanout_cap=self.fanout_cap,
-                                slot_cap=self.slot_cap,
-                                delta_match_cap=_DELTA_MATCH_CAP,
-                                delta_fanout_cap=_DELTA_FANOUT_CAP).res
+                    if dC is None:
+                        r = rt(route_window_cached)(
+                            tables, wc(), *args, *pos, inv, mh, strat,
+                            **caps)
                     else:
-                        # trie plans are single-batch (Wp == 1)
-                        inv = np.zeros(Bp, np.int32)
-                        mh = np.zeros(Bp, np.int32)
-                        kw = dict(frontier_cap=self.frontier_cap,
-                                  match_cap=self.match_cap,
-                                  fanout_cap=self.fanout_cap,
-                                  slot_cap=self.slot_cap)
-                        if dC is None:
-                            r = rt(route_step_cached)(
-                                tables, wc(), *args, *pos, inv, mh,
-                                strat, **kw)
-                        else:
-                            r = rt(route_step_delta_cached)(
-                                tables, dummy_delta(dC), wc(), *args,
-                                *dargs, *pos, inv, mh, strat, **kw,
-                                delta_match_cap=_DELTA_MATCH_CAP,
-                                delta_fanout_cap=_DELTA_FANOUT_CAP).res
+                        r = rt(route_window_delta_cached)(
+                            tables, dummy_delta(dC), wc(), *args,
+                            *dargs, *pos, inv, mh, strat, **caps,
+                            **dcaps).res
                     jax.block_until_ready(r.match_counts)
                 self._warm_classes.add(ck(sig, Wp, Bp, Bm=Bm, dC=dC))
             # demand-driven compact-readback classes (ISSUE 3): each
             # (W, Bp[, Bm][, dC], P) is one program; the serving path
             # reads back dense until its class lands here
             from emqx_tpu.models.router_engine import (
-                route_step_cached_compact, route_step_compact,
-                route_step_delta_cached_compact, route_step_delta_compact,
                 route_window_cached_compact, route_window_delta_compact,
                 route_window_delta_cached_compact,
                 route_window_full_compact)
@@ -2429,8 +2362,8 @@ class DeviceRouteEngine:
                 label = f"warm W{Wp}xB{Bp}" \
                     + (f"mB{Bm}" if Bm is not None else "") \
                     + (f"d{dC}" if dC is not None else "") + f"c{P}"
-                dkw = dict(delta_match_cap=_DELTA_MATCH_CAP,
-                           delta_fanout_cap=_DELTA_FANOUT_CAP,
+                kw = dict(caps, payload_cap=P)
+                dkw = dict(dcaps,
                            d_payload_cap=self._delta_payload_cap(Bp))
                 with ctx_of(label):
                     if Bm is None:
@@ -2438,35 +2371,13 @@ class DeviceRouteEngine:
                                        np.int32)
                         z = np.zeros((Wp, Bp), np.int32)
                         zb = np.zeros((Wp, Bp), bool)
-                        if backend == "shapes":
-                            if dC is None:
-                                r = rt(route_window_full_compact)(
-                                    tables, wc(), enc, z, zb, z,
-                                    strat, fanout_cap=self.fanout_cap,
-                                    slot_cap=self.slot_cap,
-                                    payload_cap=P)
-                            else:
-                                r = rt(route_window_delta_compact)(
-                                    tables, dummy_delta(dC), wc(),
-                                    enc, z, zb, z, strat,
-                                    fanout_cap=self.fanout_cap,
-                                    slot_cap=self.slot_cap,
-                                    payload_cap=P, **dkw)
-                        else:   # trie compact plans are single-batch
-                            kw = dict(frontier_cap=self.frontier_cap,
-                                      match_cap=self.match_cap,
-                                      fanout_cap=self.fanout_cap,
-                                      slot_cap=self.slot_cap,
-                                      payload_cap=P)
-                            if dC is None:
-                                r = rt(route_step_compact)(
-                                    tables, wc(), enc[0], z[0],
-                                    zb[0], z[0], strat, **kw)
-                            else:
-                                r = rt(route_step_delta_compact)(
-                                    tables, dummy_delta(dC), wc(),
-                                    enc[0], z[0], zb[0], z[0], strat,
-                                    **kw, **dkw)
+                        if dC is None:
+                            r = rt(route_window_full_compact)(
+                                tables, wc(), enc, z, zb, z, strat, **kw)
+                        else:
+                            r = rt(route_window_delta_compact)(
+                                tables, dummy_delta(dC), wc(), enc, z,
+                                zb, z, strat, **kw, **dkw)
                     else:
                         args = (np.full((Bm, self.max_levels), I.PAD,
                                         np.int32),
@@ -2480,41 +2391,17 @@ class DeviceRouteEngine:
                                     np.int32),
                             np.zeros(Bp, np.int32), np.zeros(Bp, bool))
                         pos = (np.full(Bm, Bp, np.int32),)
-                        if backend == "shapes":
-                            inv = np.zeros((Wp, Bp), np.int32)
-                            mh = np.zeros((Wp, Bp), np.int32)
-                            if dC is None:
-                                r = rt(route_window_cached_compact)(
-                                    tables, wc(), *args, *pos, inv,
-                                    mh, strat,
-                                    fanout_cap=self.fanout_cap,
-                                    slot_cap=self.slot_cap,
-                                    payload_cap=P)
-                            else:
-                                r = rt(
-                                    route_window_delta_cached_compact)(
-                                    tables, dummy_delta(dC), wc(),
-                                    *args, *dargs, *pos, inv, mh,
-                                    strat, fanout_cap=self.fanout_cap,
-                                    slot_cap=self.slot_cap,
-                                    payload_cap=P, **dkw)
+                        inv = np.zeros((Wp, Bp), np.int32)
+                        mh = np.zeros((Wp, Bp), np.int32)
+                        if dC is None:
+                            r = rt(route_window_cached_compact)(
+                                tables, wc(), *args, *pos, inv, mh,
+                                strat, **kw)
                         else:
-                            inv = np.zeros(Bp, np.int32)
-                            mh = np.zeros(Bp, np.int32)
-                            kw = dict(frontier_cap=self.frontier_cap,
-                                      match_cap=self.match_cap,
-                                      fanout_cap=self.fanout_cap,
-                                      slot_cap=self.slot_cap,
-                                      payload_cap=P)
-                            if dC is None:
-                                r = rt(route_step_cached_compact)(
-                                    tables, wc(), *args, *pos, inv,
-                                    mh, strat, **kw)
-                            else:
-                                r = rt(route_step_delta_cached_compact)(
-                                    tables, dummy_delta(dC), wc(),
-                                    *args, *dargs, *pos, inv, mh,
-                                    strat, **kw, **dkw)
+                            r = rt(route_window_delta_cached_compact)(
+                                tables, dummy_delta(dC), wc(), *args,
+                                *dargs, *pos, inv, mh, strat, **kw,
+                                **dkw)
                     jax.block_until_ready(r.compact.offsets)
                 self._warm_classes.add(
                     ck(sig, Wp, Bp, Bm=Bm, dC=dC, P=P))
@@ -2632,26 +2519,20 @@ class DeviceRouteEngine:
             dol4[k, :n] = dollar
         h = _Handle(subs, b, self.device_shared_active())
         h.enc = (enc4, len4, dol4)
-        seq_trie = b.backend != "shapes" and Wp > 1
         # degradation ladder rung 1 (ISSUE 6): with the cache_insert or
         # overlay_apply breaker open, the reuse layers stand down and
         # this window dispatches the PLAIN program — device-plain is
         # the middle rung between full-featured and host-trie
         degraded = self.sup is not None and not self.sup.reuse_enabled()
-        if not seq_trie and not degraded:
+        if not degraded:
             # delta overlay for this dispatch (None = host fallback for
-            # post-snapshot filters, exactly the pre-overlay behavior).
-            # The sequential multi-batch trie window has no single fused
-            # program to hang the overlay on — rare direct-caller path.
+            # post-snapshot filters, exactly the pre-overlay behavior)
             h.delta = self._gate_delta(Wp, Bp, gate_cold)
         if self.dedup and not degraded:
             h.plan, h.cache_info = self._plan_window(b, enc4, len4, dol4,
                                                      gate_cold, h.delta)
-        if not degraded and not (seq_trie and h.plan is None):
-            # CSR readback class for this dispatch (None = dense). The
-            # excluded case is the rare plain multi-batch trie window,
-            # which dispatches sequential steps and stacks host-side —
-            # no single fused program to hang the compaction on.
+        if not degraded:
+            # CSR readback class for this dispatch (None = dense)
             h.pcap = self._gate_compact(Wp, Bp, h.plan, gate_cold,
                                         h.delta)
         self._outstanding += 1
@@ -2662,6 +2543,14 @@ class DeviceRouteEngine:
             self.ledger.note_window()
             self.ledger.pin(id(h), h)
         self.node.metrics.inc("routing.device.windows")
+        if b.backend != "shapes":
+            # matched by the trie NFA (ops/match.match_batch), over
+            # every real lane or, under a dedup plan, its misses only
+            self.node.metrics.inc("routing.device.nfa_windows")
+            self.node.metrics.inc(
+                "routing.device.nfa_lanes",
+                h.plan.n_miss if h.plan is not None
+                else sum(len(msgs) for msgs in lives))
         self.node.metrics.inc("routing.device.window_subs", W)
         b = self._built
         if b is not None and b.cover is not None:
@@ -2870,11 +2759,7 @@ class DeviceRouteEngine:
         strat = np.int32(strat_id)
         p, P, ov = h.plan, h.pcap, h.delta
         dC = ov.cap if ov is not None else None
-        shapes = h.built.backend == "shapes"
-        kw = dict(fanout_cap=self.fanout_cap, slot_cap=self.slot_cap)
-        if not shapes:
-            kw.update(frontier_cap=self.frontier_cap,
-                      match_cap=self.match_cap)
+        kw = self._caps_kw(h.built.backend)
         dkw = {} if ov is None else dict(
             delta_match_cap=_DELTA_MATCH_CAP,
             delta_fanout_cap=_DELTA_FANOUT_CAP)
@@ -2882,80 +2767,44 @@ class DeviceRouteEngine:
         if P is not None and ov is not None:
             ckw["d_payload_cap"] = self._delta_payload_cap(Bp)
 
-        if not shapes and p is None and ov is None and P is None:
-            # plain trie: no window variant — dispatch sub-batches
-            # sequentially and stack (rare path: >SHAPE_CAP distinct
-            # shapes with every fused dimension disabled or cold)
-            import jax.numpy as jnp
-            outs = []
-            step_fn = self._rt(RE.route_step)
-            for k in range(Wp):
-                r = step_fn(tables, cursors, enc4[k],
-                            len4[k], dol4[k], msg_hash[k], strat,
-                            **kw)
-                cursors = r.new_cursors
-                outs.append(r)
-            if self._tables is tables:   # no swap raced this dispatch
-                # adopted cursors are fresh jit outputs, not the held
-                # device_put array — re-register so the ledger's
-                # cursor bytes track the LIVE array across dispatches
-                self._cursors = self._hold("snapshot_cursors", cursors)
-            h.res = type(outs[0])(*[jnp.stack([getattr(o, f)
-                                              for o in outs])
-                                    for f in outs[0]._fields])
-            return
-
         if p is not None:
             # deduplicated dispatch: match only the miss lanes, merge
             # with the cache-hit base rows, scatter back to window width
-            # before the cursor-dependent post stage (trie plans are
-            # single-batch: _plan_window guarantees Wp == 1 there)
+            # before the cursor-dependent post stage
             base = (p.miss_topics, p.miss_lens, p.miss_dollar,
                     p.base_m, p.base_c, p.base_o)
             dbase = () if ov is None else (p.base_dm, p.base_dc,
                                            p.base_do)
-            tail = (p.miss_pos, p.inv if shapes else p.inv[0],
-                    msg_hash if shapes else msg_hash[0], strat)
+            tail = (p.miss_pos, p.inv, msg_hash, strat)
             if ov is not None:
-                fn = (RE.route_window_delta_cached_compact
-                      if P is not None
-                      else RE.route_window_delta_cached) if shapes else \
-                    (RE.route_step_delta_cached_compact if P is not None
-                     else RE.route_step_delta_cached)
+                fn = RE.route_window_delta_cached_compact \
+                    if P is not None else RE.route_window_delta_cached
                 out = self._rt(fn)(tables, ov.dev, cursors, *base,
                                    *dbase, *tail, **kw, **dkw, **ckw)
             else:
-                fn = (RE.route_window_cached_compact if P is not None
-                      else RE.route_window_cached) if shapes else \
-                    (RE.route_step_cached_compact if P is not None
-                     else RE.route_step_cached)
+                fn = RE.route_window_cached_compact if P is not None \
+                    else RE.route_window_cached
                 out = self._rt(fn)(tables, cursors, *base, *tail,
                                    **kw, **ckw)
             self.node.metrics.inc("routing.device.cached_windows")
             warm_key = self._class_key(sig, Wp, Bp, Bm=p.Bm,
                                        dC=dC, P=P)
         else:
-            args4 = (enc4, len4, dol4, msg_hash) if shapes else \
-                (enc4[0], len4[0], dol4[0], msg_hash[0])
+            args4 = (enc4, len4, dol4, msg_hash)
             if ov is not None:
-                fn = (RE.route_window_delta_compact if P is not None
-                      else RE.route_window_delta) if shapes else \
-                    (RE.route_step_delta_compact if P is not None
-                     else RE.route_step_delta)
+                fn = RE.route_window_delta_compact if P is not None \
+                    else RE.route_window_delta
                 out = self._rt(fn)(tables, ov.dev, cursors, *args4,
                                    strat, **kw, **dkw, **ckw)
             else:
-                fn = (RE.route_window_full_compact if P is not None
-                      else RE.route_window_full) if shapes else \
-                    RE.route_step_compact   # plain trie without P
-                                            # returned above
+                fn = RE.route_window_full_compact if P is not None \
+                    else RE.route_window_full
                 out = self._rt(fn)(tables, cursors, *args4, strat,
                                    **kw, **ckw)
             warm_key = self._class_key(sig, Wp, Bp, dC=dC,
                                        P=P)
 
-        # unwrap the result family; every remaining variant is
-        # window-shaped except the bare cached trie step
+        # unwrap the result family; every variant is window-shaped
         if isinstance(out, RE.CompactDeltaRouteResult):
             res = out.dres.res
             h.dres = out.dres.dp
@@ -2969,10 +2818,6 @@ class DeviceRouteEngine:
             h.cres = out.compact
         else:
             res = out
-            if not shapes and p is not None:
-                import jax.numpy as jnp
-                res = type(res)(*[jnp.stack([getattr(res, f)])
-                                  for f in res._fields])
         if self._tables is tables:   # no swap raced this dispatch
             self._cursors = self._hold("snapshot_cursors",
                                        self._last_cursors(res))
@@ -3112,6 +2957,7 @@ class DeviceRouteEngine:
                 occur = np.asarray(res.occur)
                 pay = np.asarray(cp.payload)
                 h.np_res = _CsrRes(off, c3, pay, overflow, occur)
+                self._read_match_overflow(h, overflow)
                 metrics.inc("pipeline.readback.bytes.compact",
                             off.nbytes + c3.nbytes + pay.nbytes
                             + overflow.nbytes + occur.nbytes
@@ -3146,6 +2992,7 @@ class DeviceRouteEngine:
                     np.asarray(res.opts), np.asarray(res.shared_sids),
                     np.asarray(res.shared_rows), np.asarray(res.shared_opts),
                     np.asarray(res.overflow), np.asarray(res.occur))
+        self._read_match_overflow(h, h.np_res[6])
         dense_bytes = sum(a.nbytes for a in h.np_res) + csr_probe_bytes \
             + delta_bytes
         info = h.cache_info
@@ -3174,6 +3021,24 @@ class DeviceRouteEngine:
         metrics.inc("pipeline.readback.windows.dense")
         if corrupt:
             self._corrupt_readback(h)
+
+    @staticmethod
+    def _read_match_overflow(h, overflow: np.ndarray) -> None:
+        """Which of a trie window's flagged lanes the NFA itself gave up
+        on (frontier or match_cap), for routing.device.match_overflow.
+        One more small plane, and only when a lane was flagged at all:
+        a window without overflow reads nothing."""
+        if h.built.backend != "shapes" and overflow.any() \
+                and h.res.match_overflow is not None:
+            h.np_mov = np.asarray(h.res.match_overflow)
+
+    def _note_host_fallback(self, h, k: int, i: int) -> None:
+        """Lane i of sub-batch k goes to the host trie (too deep, or a
+        device capacity overflowed): count it, and separately the lanes
+        the NFA's own caps sent there."""
+        self.node.metrics.inc("routing.device.host_fallback")
+        if h.np_mov is not None and h.np_mov[k][i]:
+            self.node.metrics.inc("routing.device.match_overflow")
 
     def _corrupt_readback(self, h) -> None:
         """Apply the injected corrupt-shape fault: truncate the window
@@ -3313,7 +3178,7 @@ class DeviceRouteEngine:
                         too_long, overflow_k, dev_shared, ov, pending))
                     continue
                 if too_long[i] or overflow_k[i]:
-                    metrics.inc("routing.device.host_fallback")
+                    self._note_host_fallback(h, k, i)
                     counts.append(broker._route(
                         msg, self.router.match(msg.topic)))
                     continue
@@ -3365,7 +3230,7 @@ class DeviceRouteEngine:
         behind the plan barrier; the handle is pinned until then)."""
         def run() -> int:
             if too_long[i] or overflow_k[i]:
-                self.node.metrics.inc("routing.device.host_fallback")
+                self._note_host_fallback(h, k, i)
                 return self.broker._route(
                     msg, self.router.match(msg.topic))
             if csr:

@@ -59,6 +59,11 @@ class RouteResult(NamedTuple):
     overflow: jax.Array       # [B] any capacity overflow → host fallback
     new_cursors: jax.Array    # [G]
     occur: jax.Array          # [G] shared-slot occurrences this batch
+    # [B] the match stage's own part of `overflow` (the NFA's frontier
+    # or match_cap; a cached row's stored flag): what
+    # routing.device.match_overflow counts. None where a program does
+    # not report it (the mesh's sharded step)
+    match_overflow: jax.Array = None
 
 
 class ExchangeAux(NamedTuple):
@@ -102,7 +107,8 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
         matches=mr.matches, match_counts=mr.counts,
         rows=fr.rows, opts=fr.opts, fan_counts=fr.counts,
         shared_sids=sids, shared_rows=sp.rows, shared_opts=sp.opts,
-        overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur)
+        overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur,
+        match_overflow=mr.overflow)
 
 
 @functools.partial(
@@ -134,61 +140,77 @@ def route_step_shapes(tables: ShapeRouterTables, cursors: jax.Array,
                       fanout_cap=fanout_cap, slot_cap=slot_cap)
 
 
+def _is_trie(tables) -> bool:
+    """Which matcher a window program traces: the tables' type is part
+    of the jit key (a pytree structure), so one window program serves
+    both backends and each compiles only its own matcher."""
+    return isinstance(tables, RouterTables)
+
+
+def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
+                        lens: jax.Array, is_dollar: jax.Array, *,
+                        frontier_cap: int, match_cap: int) -> MatchResult:
+    """`match_batch` for one sub-batch of a fused window, skipped where
+    the sub-batch is the window class's padding. A window is padded to
+    its class's W, and the NFA gathers for every lane of every level
+    whether a topic is there or not (76 ms of a 93 ms step at 1024
+    lanes on a v5e; my chip run, PR 28): a sub-batch without a topic
+    matches nothing, so it is handed the empty result `match_batch`
+    returns for it (no frontier, nothing emitted, and for a covering
+    snapshot nothing for `cover_expand` to re-expand: its row is as
+    wide as the cover's output) without the walk."""
+    B = topics.shape[0]
+    M = match_cap if trie.cover is None else trie.cover.out_pad.shape[0]
+    return jax.lax.cond(
+        (lens > 0).any(),
+        lambda: match_batch(trie, topics, lens, is_dollar,
+                            frontier_cap=frontier_cap,
+                            match_cap=match_cap),
+        lambda: MatchResult(
+            matches=jnp.full((B, M), -1, jnp.int32),
+            counts=jnp.zeros(B, jnp.int32),
+            overflow=jnp.zeros(B, bool)))
+
+
+def _match_stage(tables, topics: jax.Array, lens: jax.Array,
+                 is_dollar: jax.Array, *, frontier_cap: int,
+                 match_cap: int) -> MatchResult:
+    """The backend's matcher over [B] lanes: the trie NFA for
+    `RouterTables` (the caps are its own), one bucket gather per shape
+    for `ShapeRouterTables` (which takes no cap)."""
+    if _is_trie(tables):
+        return match_batch(tables.trie, topics, lens, is_dollar,
+                           frontier_cap=frontier_cap, match_cap=match_cap)
+    return shape_match(tables.shapes, topics, lens, is_dollar)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap"))
-def route_step_cached(tables: RouterTables, cursors: jax.Array,
-                      miss_topics: jax.Array, miss_lens: jax.Array,
-                      miss_dollar: jax.Array, base_matches: jax.Array,
-                      base_counts: jax.Array, base_overflow: jax.Array,
-                      miss_pos: jax.Array, inv: jax.Array,
-                      msg_hash: jax.Array, strategy: jax.Array, *,
-                      frontier_cap: int = 16, match_cap: int = 64,
-                      fanout_cap: int = 128,
-                      slot_cap: int = 16) -> RouteResult:
-    """Trie-NFA route step over a DEDUPLICATED batch with cached rows.
-
-    The match stage runs only on the [Bm] compacted miss lanes
-    (Bm quantized to the standard batch-class ladder); cache-hit unique
-    topics ride in as host-filled base_* rows ([U] per-unique-topic).
-    `inv` [B] scatters the merged unique MatchResult back to full batch
-    width before the cursor-dependent post stage, so fan-out, shared
-    picks and cursor threading are bit-identical to the un-deduplicated
-    `route_step` on the same batch (oracle-tested)."""
-    with jax.named_scope("match"):
-        mr = match_batch(tables.trie, miss_topics, miss_lens, miss_dollar,
-                         frontier_cap=frontier_cap, match_cap=match_cap)
-        um = merge_match_results(base_matches, base_counts,
-                                 base_overflow, mr, miss_pos)
-        full = MatchResult(matches=um.matches[inv], counts=um.counts[inv],
-                           overflow=um.overflow[inv])
-    return post_match(tables.subs, full, cursors, msg_hash, strategy,
-                      fanout_cap=fanout_cap, slot_cap=slot_cap)
-
-
-@functools.partial(jax.jit, static_argnames=("fanout_cap", "slot_cap"))
-def route_window_cached(tables: ShapeRouterTables, cursors: jax.Array,
+def route_window_cached(tables, cursors: jax.Array,
                         miss_topics: jax.Array, miss_lens: jax.Array,
                         miss_dollar: jax.Array, base_matches: jax.Array,
                         base_counts: jax.Array, base_overflow: jax.Array,
                         miss_pos: jax.Array, inv: jax.Array,
                         msg_hash: jax.Array, strategy: jax.Array, *,
+                        frontier_cap: int = 16, match_cap: int = 64,
                         fanout_cap: int = 128,
                         slot_cap: int = 16) -> RouteResult:
-    """Shape-hash window step over a DEDUPLICATED window with cached rows.
+    """Window step over a DEDUPLICATED window with cached rows (either
+    backend: `_match_stage`).
 
-    One dispatch routes W sub-batches while the shape-hash match runs
+    One dispatch routes W sub-batches while the match runs
     ONCE over the [Bm] compacted miss lanes (every other lane of the
     [W, B] window is either a duplicate of a miss lane, a cache hit
     served from base_* rows, or padding collapsed onto the shared
     sentinel row). `inv` [W, B] gathers the merged unique rows back to
     full window width per scan step; cursors thread through the scan
-    exactly as W sequential `route_step_shapes` calls, so the stacked
-    RouteResult is bit-identical to `route_window_full` on the same
-    window (oracle-tested)."""
+    exactly as W sequential `route_step_shapes` / `route_step` calls, so
+    the stacked RouteResult is bit-identical to `route_window_full` on
+    the same window (oracle-tested)."""
     with jax.named_scope("match"):
-        mr = shape_match(tables.shapes, miss_topics, miss_lens,
-                         miss_dollar)
+        mr = _match_stage(tables, miss_topics, miss_lens, miss_dollar,
+                          frontier_cap=frontier_cap, match_cap=match_cap)
         um = merge_match_results(base_matches, base_counts,
                                  base_overflow, mr, miss_pos)
 
@@ -215,8 +237,7 @@ class CompactRouteResult(NamedTuple):
     the host reads them back only when `compact.row_overflow` fires
     (payload class too small for this window) — the dense fallback needs
     no re-dispatch. Every per-topic plane in `res` is window-shaped
-    ([W, ...]) for ALL variants, including the single-batch trie steps
-    (W = 1), so the consume path is uniform."""
+    ([W, ...]): a single batch is a window of W = 1."""
     res: RouteResult
     compact: "CompactPlanes"  # noqa: F821 — imported lazily below
 
@@ -226,8 +247,7 @@ def _with_compact(r: RouteResult, payload_cap: int,
     """match_holes=True for the shape-hash backend (matches carry
     interior holes at unmatched shape slots), False for the trie NFA
     (emissions are densely packed already — the hole-closing stage
-    compiles away). The engine's window variants are shapes-only and
-    the step variants trie-only, so each hardcodes its flag."""
+    compiles away): a window program reads it off its tables' type."""
     from emqx_tpu.ops.compact import compact_result
     with jax.named_scope("compact"):
         cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
@@ -237,81 +257,33 @@ def _with_compact(r: RouteResult, payload_cap: int,
     return CompactRouteResult(res=r, compact=cp)
 
 
-def _stack1(r: RouteResult) -> RouteResult:
-    """Lift a single-batch RouteResult to window form (W = 1)."""
-    return RouteResult(*[x[None] for x in r])
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "payload_cap"))
-def route_step_compact(tables: RouterTables, cursors: jax.Array,
-                       topics: jax.Array, lens: jax.Array,
-                       is_dollar: jax.Array, msg_hash: jax.Array,
-                       strategy: jax.Array, *, frontier_cap: int = 16,
-                       match_cap: int = 64, fanout_cap: int = 128,
-                       slot_cap: int = 16,
-                       payload_cap: int = 4096) -> CompactRouteResult:
-    """Trie-NFA route step with the fused CSR readback (window-shaped)."""
-    r = route_step(tables, cursors, topics, lens, is_dollar, msg_hash,
-                   strategy, frontier_cap=frontier_cap,
-                   match_cap=match_cap, fanout_cap=fanout_cap,
-                   slot_cap=slot_cap)
-    return _with_compact(_stack1(r), payload_cap, match_holes=False)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "payload_cap"))
-def route_step_cached_compact(tables: RouterTables, cursors: jax.Array,
-                              miss_topics: jax.Array,
-                              miss_lens: jax.Array,
-                              miss_dollar: jax.Array,
-                              base_matches: jax.Array,
-                              base_counts: jax.Array,
-                              base_overflow: jax.Array,
-                              miss_pos: jax.Array, inv: jax.Array,
-                              msg_hash: jax.Array, strategy: jax.Array,
-                              *, frontier_cap: int = 16,
-                              match_cap: int = 64, fanout_cap: int = 128,
-                              slot_cap: int = 16,
-                              payload_cap: int = 4096
-                              ) -> CompactRouteResult:
-    """Deduplicated trie step + fused CSR readback (window-shaped)."""
-    r = route_step_cached(tables, cursors, miss_topics, miss_lens,
-                          miss_dollar, base_matches, base_counts,
-                          base_overflow, miss_pos, inv, msg_hash,
-                          strategy, frontier_cap=frontier_cap,
-                          match_cap=match_cap, fanout_cap=fanout_cap,
-                          slot_cap=slot_cap)
-    return _with_compact(_stack1(r), payload_cap, match_holes=False)
-
-
 @functools.partial(jax.jit,
-                   static_argnames=("fanout_cap", "slot_cap",
+                   static_argnames=("frontier_cap", "match_cap",
+                                    "fanout_cap", "slot_cap",
                                     "payload_cap"))
-def route_window_full_compact(tables: ShapeRouterTables,
-                              cursors: jax.Array, topics: jax.Array,
+def route_window_full_compact(tables, cursors: jax.Array,
+                              topics: jax.Array,
                               lens: jax.Array, is_dollar: jax.Array,
                               msg_hash: jax.Array, strategy: jax.Array,
-                              *, fanout_cap: int = 128,
+                              *, frontier_cap: int = 16,
+                              match_cap: int = 64,
+                              fanout_cap: int = 128,
                               slot_cap: int = 16,
                               payload_cap: int = 4096
                               ) -> CompactRouteResult:
     """route_window_full + fused CSR readback in the same dispatch."""
     r = route_window_full(tables, cursors, topics, lens, is_dollar,
-                          msg_hash, strategy, fanout_cap=fanout_cap,
+                          msg_hash, strategy, frontier_cap=frontier_cap,
+                          match_cap=match_cap, fanout_cap=fanout_cap,
                           slot_cap=slot_cap)
-    return _with_compact(r, payload_cap, match_holes=True)
+    return _with_compact(r, payload_cap, match_holes=not _is_trie(tables))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("fanout_cap", "slot_cap",
+                   static_argnames=("frontier_cap", "match_cap",
+                                    "fanout_cap", "slot_cap",
                                     "payload_cap"))
-def route_window_cached_compact(tables: ShapeRouterTables,
-                                cursors: jax.Array,
+def route_window_cached_compact(tables, cursors: jax.Array,
                                 miss_topics: jax.Array,
                                 miss_lens: jax.Array,
                                 miss_dollar: jax.Array,
@@ -321,6 +293,8 @@ def route_window_cached_compact(tables: ShapeRouterTables,
                                 miss_pos: jax.Array, inv: jax.Array,
                                 msg_hash: jax.Array,
                                 strategy: jax.Array, *,
+                                frontier_cap: int = 16,
+                                match_cap: int = 64,
                                 fanout_cap: int = 128,
                                 slot_cap: int = 16,
                                 payload_cap: int = 4096
@@ -329,18 +303,18 @@ def route_window_cached_compact(tables: ShapeRouterTables,
     r = route_window_cached(tables, cursors, miss_topics, miss_lens,
                             miss_dollar, base_matches, base_counts,
                             base_overflow, miss_pos, inv, msg_hash,
-                            strategy, fanout_cap=fanout_cap,
+                            strategy, frontier_cap=frontier_cap,
+                            match_cap=match_cap, fanout_cap=fanout_cap,
                             slot_cap=slot_cap)
-    return _with_compact(r, payload_cap, match_holes=True)
+    return _with_compact(r, payload_cap, match_holes=not _is_trie(tables))
 
 
 class DeltaRouteResult(NamedTuple):
     """A route result with its fused delta-overlay planes (ops.delta).
 
-    `res` is the main-snapshot RouteResult, window-shaped [W, ...] for
-    every variant (single-batch trie steps lift to W = 1 like the
-    compact twins); `dp` carries the overlay's match + fan-out planes,
-    each [W, B, ...]. The two fid spaces are disjoint by construction:
+    `res` is the main-snapshot RouteResult, window-shaped [W, ...];
+    `dp` carries the overlay's match + fan-out planes, each
+    [W, B, ...]. The two fid spaces are disjoint by construction:
     `res.matches` are built-snapshot fids, `dp.fids` are the engine's
     delta fids — the host consume walks both, so a filter subscribed
     one window ago delivers from THIS dispatch instead of host-routing
@@ -398,49 +372,22 @@ def _cached_delta(delta: DeltaTables, miss_topics, miss_lens, miss_dollar,
         return DeltaPlanes(*[x[inv] for x in dp_u])
 
 
-def _stack1_dp(dp: DeltaPlanes) -> DeltaPlanes:
-    return DeltaPlanes(*[x[None] for x in dp])
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("frontier_cap", "match_cap", "fanout_cap",
                      "slot_cap", "delta_match_cap", "delta_fanout_cap"))
-def route_step_delta(tables: RouterTables, delta: DeltaTables,
-                     cursors: jax.Array, topics: jax.Array,
-                     lens: jax.Array, is_dollar: jax.Array,
-                     msg_hash: jax.Array, strategy: jax.Array, *,
-                     frontier_cap: int = 16, match_cap: int = 64,
-                     fanout_cap: int = 128, slot_cap: int = 16,
-                     delta_match_cap: int = 16,
-                     delta_fanout_cap: int = 64) -> DeltaRouteResult:
-    """Trie-NFA route step + delta overlay in one dispatch (W = 1)."""
-    r = route_step(tables, cursors, topics, lens, is_dollar, msg_hash,
-                   strategy, frontier_cap=frontier_cap,
-                   match_cap=match_cap, fanout_cap=fanout_cap,
-                   slot_cap=slot_cap)
-    with jax.named_scope("delta"):
-        dp = delta_expand(delta,
-                          delta_match(delta, topics, lens, is_dollar,
-                                      match_cap=delta_match_cap),
-                          fanout_cap=delta_fanout_cap)
-    return DeltaRouteResult(res=_stack1(r), dp=_stack1_dp(dp))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("fanout_cap", "slot_cap", "delta_match_cap",
-                     "delta_fanout_cap"))
-def route_window_delta(tables: ShapeRouterTables, delta: DeltaTables,
+def route_window_delta(tables, delta: DeltaTables,
                        cursors: jax.Array, topics: jax.Array,
                        lens: jax.Array, is_dollar: jax.Array,
                        msg_hash: jax.Array, strategy: jax.Array, *,
+                       frontier_cap: int = 16, match_cap: int = 64,
                        fanout_cap: int = 128, slot_cap: int = 16,
                        delta_match_cap: int = 16,
                        delta_fanout_cap: int = 64) -> DeltaRouteResult:
     """route_window_full + delta overlay fused in the same dispatch."""
     r = route_window_full(tables, cursors, topics, lens, is_dollar,
-                          msg_hash, strategy, fanout_cap=fanout_cap,
+                          msg_hash, strategy, frontier_cap=frontier_cap,
+                          match_cap=match_cap, fanout_cap=fanout_cap,
                           slot_cap=slot_cap)
     dp = _window_delta(delta, topics, lens, is_dollar,
                        dmatch_cap=delta_match_cap,
@@ -452,41 +399,7 @@ def route_window_delta(tables: ShapeRouterTables, delta: DeltaTables,
     jax.jit,
     static_argnames=("frontier_cap", "match_cap", "fanout_cap",
                      "slot_cap", "delta_match_cap", "delta_fanout_cap"))
-def route_step_delta_cached(tables: RouterTables, delta: DeltaTables,
-                            cursors: jax.Array, miss_topics: jax.Array,
-                            miss_lens: jax.Array, miss_dollar: jax.Array,
-                            base_matches: jax.Array,
-                            base_counts: jax.Array,
-                            base_overflow: jax.Array,
-                            base_dm: jax.Array, base_dc: jax.Array,
-                            base_do: jax.Array, miss_pos: jax.Array,
-                            inv: jax.Array, msg_hash: jax.Array,
-                            strategy: jax.Array, *,
-                            frontier_cap: int = 16, match_cap: int = 64,
-                            fanout_cap: int = 128, slot_cap: int = 16,
-                            delta_match_cap: int = 16,
-                            delta_fanout_cap: int = 64
-                            ) -> DeltaRouteResult:
-    """Deduplicated trie step + delta overlay (cached base rows carry
-    BOTH fid spaces; see _cached_delta for the merge contract)."""
-    r = route_step_cached(tables, cursors, miss_topics, miss_lens,
-                          miss_dollar, base_matches, base_counts,
-                          base_overflow, miss_pos, inv, msg_hash,
-                          strategy, frontier_cap=frontier_cap,
-                          match_cap=match_cap, fanout_cap=fanout_cap,
-                          slot_cap=slot_cap)
-    dp = _cached_delta(delta, miss_topics, miss_lens, miss_dollar,
-                       base_dm, base_dc, base_do, miss_pos, inv,
-                       dmatch_cap=delta_match_cap,
-                       dfan_cap=delta_fanout_cap)
-    return DeltaRouteResult(res=_stack1(r), dp=_stack1_dp(dp))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("fanout_cap", "slot_cap", "delta_match_cap",
-                     "delta_fanout_cap"))
-def route_window_delta_cached(tables: ShapeRouterTables,
+def route_window_delta_cached(tables,
                               delta: DeltaTables, cursors: jax.Array,
                               miss_topics: jax.Array,
                               miss_lens: jax.Array,
@@ -498,6 +411,8 @@ def route_window_delta_cached(tables: ShapeRouterTables,
                               base_do: jax.Array, miss_pos: jax.Array,
                               inv: jax.Array, msg_hash: jax.Array,
                               strategy: jax.Array, *,
+                              frontier_cap: int = 16,
+                              match_cap: int = 64,
                               fanout_cap: int = 128, slot_cap: int = 16,
                               delta_match_cap: int = 16,
                               delta_fanout_cap: int = 64
@@ -506,7 +421,8 @@ def route_window_delta_cached(tables: ShapeRouterTables,
     r = route_window_cached(tables, cursors, miss_topics, miss_lens,
                             miss_dollar, base_matches, base_counts,
                             base_overflow, miss_pos, inv, msg_hash,
-                            strategy, fanout_cap=fanout_cap,
+                            strategy, frontier_cap=frontier_cap,
+                            match_cap=match_cap, fanout_cap=fanout_cap,
                             slot_cap=slot_cap)
     dp = _cached_delta(delta, miss_topics, miss_lens, miss_dollar,
                        base_dm, base_dc, base_do, miss_pos, inv,
@@ -545,33 +461,10 @@ def _with_delta_compact(dres: DeltaRouteResult, payload_cap: int,
     static_argnames=("frontier_cap", "match_cap", "fanout_cap",
                      "slot_cap", "delta_match_cap", "delta_fanout_cap",
                      "payload_cap", "d_payload_cap"))
-def route_step_delta_compact(tables, delta, cursors, topics, lens,
-                             is_dollar, msg_hash, strategy, *,
-                             frontier_cap: int = 16, match_cap: int = 64,
-                             fanout_cap: int = 128, slot_cap: int = 16,
-                             delta_match_cap: int = 16,
-                             delta_fanout_cap: int = 64,
-                             payload_cap: int = 4096,
-                             d_payload_cap: int = 1024
-                             ) -> CompactDeltaRouteResult:
-    """route_step_delta + fused CSR readbacks (both plane families)."""
-    dres = route_step_delta(tables, delta, cursors, topics, lens,
-                            is_dollar, msg_hash, strategy,
-                            frontier_cap=frontier_cap,
-                            match_cap=match_cap, fanout_cap=fanout_cap,
-                            slot_cap=slot_cap,
-                            delta_match_cap=delta_match_cap,
-                            delta_fanout_cap=delta_fanout_cap)
-    return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=False)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("fanout_cap", "slot_cap", "delta_match_cap",
-                     "delta_fanout_cap", "payload_cap", "d_payload_cap"))
 def route_window_delta_compact(tables, delta, cursors, topics, lens,
                                is_dollar, msg_hash, strategy, *,
+                               frontier_cap: int = 16,
+                               match_cap: int = 64,
                                fanout_cap: int = 128, slot_cap: int = 16,
                                delta_match_cap: int = 16,
                                delta_fanout_cap: int = 64,
@@ -581,11 +474,13 @@ def route_window_delta_compact(tables, delta, cursors, topics, lens,
     """route_window_delta + fused CSR readbacks (both plane families)."""
     dres = route_window_delta(tables, delta, cursors, topics, lens,
                               is_dollar, msg_hash, strategy,
+                              frontier_cap=frontier_cap,
+                              match_cap=match_cap,
                               fanout_cap=fanout_cap, slot_cap=slot_cap,
                               delta_match_cap=delta_match_cap,
                               delta_fanout_cap=delta_fanout_cap)
     return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=True)
+                               match_holes=not _is_trie(tables))
 
 
 @functools.partial(
@@ -593,44 +488,15 @@ def route_window_delta_compact(tables, delta, cursors, topics, lens,
     static_argnames=("frontier_cap", "match_cap", "fanout_cap",
                      "slot_cap", "delta_match_cap", "delta_fanout_cap",
                      "payload_cap", "d_payload_cap"))
-def route_step_delta_cached_compact(tables, delta, cursors, miss_topics,
-                                    miss_lens, miss_dollar, base_matches,
-                                    base_counts, base_overflow, base_dm,
-                                    base_dc, base_do, miss_pos, inv,
-                                    msg_hash, strategy, *,
-                                    frontier_cap: int = 16,
-                                    match_cap: int = 64,
-                                    fanout_cap: int = 128,
-                                    slot_cap: int = 16,
-                                    delta_match_cap: int = 16,
-                                    delta_fanout_cap: int = 64,
-                                    payload_cap: int = 4096,
-                                    d_payload_cap: int = 1024
-                                    ) -> CompactDeltaRouteResult:
-    """Deduplicated trie step + overlay + both CSR readbacks."""
-    dres = route_step_delta_cached(
-        tables, delta, cursors, miss_topics, miss_lens, miss_dollar,
-        base_matches, base_counts, base_overflow, base_dm, base_dc,
-        base_do, miss_pos, inv, msg_hash, strategy,
-        frontier_cap=frontier_cap, match_cap=match_cap,
-        fanout_cap=fanout_cap, slot_cap=slot_cap,
-        delta_match_cap=delta_match_cap,
-        delta_fanout_cap=delta_fanout_cap)
-    return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=False)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("fanout_cap", "slot_cap", "delta_match_cap",
-                     "delta_fanout_cap", "payload_cap", "d_payload_cap"))
 def route_window_delta_cached_compact(tables, delta, cursors,
                                       miss_topics, miss_lens,
                                       miss_dollar, base_matches,
                                       base_counts, base_overflow,
                                       base_dm, base_dc, base_do,
                                       miss_pos, inv, msg_hash, strategy,
-                                      *, fanout_cap: int = 128,
+                                      *, frontier_cap: int = 16,
+                                      match_cap: int = 64,
+                                      fanout_cap: int = 128,
                                       slot_cap: int = 16,
                                       delta_match_cap: int = 16,
                                       delta_fanout_cap: int = 64,
@@ -642,11 +508,12 @@ def route_window_delta_cached_compact(tables, delta, cursors,
         tables, delta, cursors, miss_topics, miss_lens, miss_dollar,
         base_matches, base_counts, base_overflow, base_dm, base_dc,
         base_do, miss_pos, inv, msg_hash, strategy,
+        frontier_cap=frontier_cap, match_cap=match_cap,
         fanout_cap=fanout_cap, slot_cap=slot_cap,
         delta_match_cap=delta_match_cap,
         delta_fanout_cap=delta_fanout_cap)
     return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=True)
+                               match_holes=not _is_trie(tables))
 
 
 def route_digest(r: RouteResult) -> jax.Array:
@@ -699,21 +566,37 @@ def route_window_shapes(tables: ShapeRouterTables, cursors: jax.Array,
     return new_cursors, digests
 
 
-@functools.partial(jax.jit, static_argnames=("fanout_cap", "slot_cap"))
-def route_window_full(tables: ShapeRouterTables, cursors: jax.Array,
+@functools.partial(
+    jax.jit,
+    static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap"))
+def route_window_full(tables, cursors: jax.Array,
                       topics: jax.Array, lens: jax.Array,
                       is_dollar: jax.Array, msg_hash: jax.Array,
-                      strategy: jax.Array, *, fanout_cap: int = 128,
+                      strategy: jax.Array, *, frontier_cap: int = 16,
+                      match_cap: int = 64, fanout_cap: int = 128,
                       slot_cap: int = 16) -> RouteResult:
     """W fused route steps in ONE dispatch, returning the FULL stacked
     RouteResult (every field [W, ...]) — the serving path's window
     variant (route_window_shapes returns digests only, for benches).
     Cursors thread through the scan exactly as W sequential calls, so
-    `new_cursors`/`occur` in row k reflect state after sub-batch k."""
+    `new_cursors`/`occur` in row k reflect state after sub-batch k.
+    Either backend: a `RouterTables` window scans `route_step`'s two
+    stages (the trie NFA, which takes `frontier_cap` / `match_cap` and
+    is skipped for a sub-batch of padding), a `ShapeRouterTables`
+    window `route_step_shapes`."""
     def step(cur, batch):
         t, l, d, h = batch
-        r = route_step_shapes(tables, cur, t, l, d, h, strategy,
-                              fanout_cap=fanout_cap, slot_cap=slot_cap)
+        if _is_trie(tables):
+            with jax.named_scope("match"):
+                mr = _nfa_unless_padding(tables.trie, t, l, d,
+                                         frontier_cap=frontier_cap,
+                                         match_cap=match_cap)
+            r = post_match(tables.subs, mr, cur, h, strategy,
+                           fanout_cap=fanout_cap, slot_cap=slot_cap)
+        else:
+            r = route_step_shapes(tables, cur, t, l, d, h, strategy,
+                                  fanout_cap=fanout_cap,
+                                  slot_cap=slot_cap)
         return r.new_cursors, r
 
     with jax.named_scope("scan"):
@@ -732,13 +615,10 @@ def compile_stats() -> dict[str, int]:
     programs lives in `cost_stats()` (the ISSUE-8 cost registry)."""
     out = {}
     for fn in (route_step, route_step_shapes, route_window_shapes,
-               route_window_full, route_step_cached, route_window_cached,
-               route_step_compact, route_step_cached_compact,
+               route_window_full, route_window_cached,
                route_window_full_compact, route_window_cached_compact,
-               route_step_delta, route_window_delta,
-               route_step_delta_cached, route_window_delta_cached,
-               route_step_delta_compact, route_window_delta_compact,
-               route_step_delta_cached_compact,
+               route_window_delta, route_window_delta_cached,
+               route_window_delta_compact,
                route_window_delta_cached_compact):
         try:
             out[fn.__name__] = fn._cache_size()
@@ -979,22 +859,14 @@ route_step = _with_cost_registry(route_step)
 route_step_shapes = _with_cost_registry(route_step_shapes)
 route_window_shapes = _with_cost_registry(route_window_shapes)
 route_window_full = _with_cost_registry(route_window_full)
-route_step_cached = _with_cost_registry(route_step_cached)
 route_window_cached = _with_cost_registry(route_window_cached)
-route_step_compact = _with_cost_registry(route_step_compact)
-route_step_cached_compact = _with_cost_registry(route_step_cached_compact)
 route_window_full_compact = _with_cost_registry(route_window_full_compact)
 route_window_cached_compact = \
     _with_cost_registry(route_window_cached_compact)
-route_step_delta = _with_cost_registry(route_step_delta)
 route_window_delta = _with_cost_registry(route_window_delta)
-route_step_delta_cached = _with_cost_registry(route_step_delta_cached)
 route_window_delta_cached = _with_cost_registry(route_window_delta_cached)
-route_step_delta_compact = _with_cost_registry(route_step_delta_compact)
 route_window_delta_compact = \
     _with_cost_registry(route_window_delta_compact)
-route_step_delta_cached_compact = \
-    _with_cost_registry(route_step_delta_cached_compact)
 route_window_delta_cached_compact = \
     _with_cost_registry(route_window_delta_cached_compact)
 
@@ -1022,53 +894,26 @@ route_window_delta_cached_compact = \
 # a fresh device_put zeros cursors (the engine's _warm_cursors), or the
 # first serving dispatch would re-trace in-path.
 
-_DONATE_STATICS = {
-    "route_step": ("frontier_cap", "match_cap", "fanout_cap",
-                   "slot_cap"),
-    "route_step_shapes": ("fanout_cap", "slot_cap"),
-    "route_window_full": ("fanout_cap", "slot_cap"),
-    "route_step_cached": ("frontier_cap", "match_cap", "fanout_cap",
-                          "slot_cap"),
-    "route_window_cached": ("fanout_cap", "slot_cap"),
-    "route_step_compact": ("frontier_cap", "match_cap", "fanout_cap",
-                           "slot_cap", "payload_cap"),
-    "route_step_cached_compact": ("frontier_cap", "match_cap",
-                                  "fanout_cap", "slot_cap",
-                                  "payload_cap"),
-    "route_window_full_compact": ("fanout_cap", "slot_cap",
-                                  "payload_cap"),
-    "route_window_cached_compact": ("fanout_cap", "slot_cap",
-                                    "payload_cap"),
-    "route_step_delta": ("frontier_cap", "match_cap", "fanout_cap",
-                         "slot_cap", "delta_match_cap",
-                         "delta_fanout_cap"),
-    "route_window_delta": ("fanout_cap", "slot_cap", "delta_match_cap",
-                           "delta_fanout_cap"),
-    "route_step_delta_cached": ("frontier_cap", "match_cap",
-                                "fanout_cap", "slot_cap",
-                                "delta_match_cap", "delta_fanout_cap"),
-    "route_window_delta_cached": ("fanout_cap", "slot_cap",
-                                  "delta_match_cap",
-                                  "delta_fanout_cap"),
-    "route_step_delta_compact": ("frontier_cap", "match_cap",
-                                 "fanout_cap", "slot_cap",
-                                 "delta_match_cap", "delta_fanout_cap",
-                                 "payload_cap", "d_payload_cap"),
-    "route_window_delta_compact": ("fanout_cap", "slot_cap",
-                                   "delta_match_cap",
-                                   "delta_fanout_cap", "payload_cap",
-                                   "d_payload_cap"),
-    "route_step_delta_cached_compact": ("frontier_cap", "match_cap",
-                                        "fanout_cap", "slot_cap",
-                                        "delta_match_cap",
-                                        "delta_fanout_cap",
-                                        "payload_cap", "d_payload_cap"),
-    "route_window_delta_cached_compact": ("fanout_cap", "slot_cap",
-                                          "delta_match_cap",
-                                          "delta_fanout_cap",
-                                          "payload_cap",
-                                          "d_payload_cap"),
+_TRIE_CAPS = ("frontier_cap", "match_cap")
+_POST_CAPS = ("fanout_cap", "slot_cap")
+_DELTA_CAPS = ("delta_match_cap", "delta_fanout_cap")
+# what a window program adds to the caps, by its suffix after
+# `route_window`; every one takes the trie NFA's caps too: it serves
+# either backend (`_match_stage`) and a shape-hash window leaves them
+# alone
+_WINDOW_STATICS = {
+    "_full": (),
+    "_cached": (),
+    "_full_compact": ("payload_cap",),
+    "_cached_compact": ("payload_cap",),
+    "_delta": _DELTA_CAPS,
+    "_delta_cached": _DELTA_CAPS,
+    "_delta_compact": _DELTA_CAPS + ("payload_cap", "d_payload_cap"),
+    "_delta_cached_compact": _DELTA_CAPS + ("payload_cap",
+                                            "d_payload_cap"),
 }
+_DONATE_STATICS = {"route_window" + _suffix: _TRIE_CAPS + _POST_CAPS + _extra
+                   for _suffix, _extra in _WINDOW_STATICS.items()}
 
 _donating_cache: dict[str, object] = {}
 _donating_lock = threading.Lock()

@@ -1,0 +1,28 @@
+"""Tier-1 runs the benchmark's own tests too.
+
+`benchmark/tests/` holds the harness's cases (codec, plain matcher,
+populations, the comparison that decides `correct`, the trace readers,
+and whole rehearsal runs of every cell on the CPU backend). The tier-1
+command collects `tests/` only, so they are brought in here by name:
+each still counts as a case of its own, and a change to the program
+that breaks the harness fails tier-1 and not only the next chip run.
+The whole runs of `test_runs.py` are in `test_benchmark_runs.py`, a
+file of their own, so that another worker takes them.
+"""
+
+import pytest
+
+from benchmark.tests import test_trace_readers as _readers
+from benchmark.tests.test_mixed_zipf import *       # noqa: F401,F403
+from benchmark.tests.test_pieces import *           # noqa: F401,F403
+from benchmark.tests.test_trace_readers import *    # noqa: F401,F403
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "benchmark/tests/test_trace_readers.py pins the `workloads` lists "
+    "of PRs 24-26's metrics to PR 26's two cells letter for letter; "
+    "PR 28 appends `mixed-zipf.flood` to them and may not edit that "
+    "file: the pin is a `benchmark` PR's to move (CHANGES.md, PR 28)"))
+def test_every_new_metric_file_reads_the_recorded_trace(  # noqa: F811
+        recorded):                                  # noqa: F405
+    _readers.test_every_new_metric_file_reads_the_recorded_trace(recorded)

@@ -101,7 +101,8 @@ class TestCompactOracle:
 
     def test_trie_backend(self):
         """The trie-NFA fallback backend compacts through
-        route_step_compact / route_step_cached_compact, bit-identically."""
+        route_window_full_compact / route_window_cached_compact at W = 1,
+        bit-identically."""
         def setup(broker):
             s = Sink()
             sid = broker.register(s, "c")

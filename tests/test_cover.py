@@ -301,6 +301,51 @@ class TestEngineTwins:
         assert off[0].device_engine.stats()["backend"] == "trie"
         assert on[0].device_engine.stats()["cover"]["covered"] > 0
 
+    def test_padded_window_over_a_covering_trie(self):
+        """A fused window over a cover-carrying trie skips the NFA and
+        `cover_expand` for a sub-batch of padding, and returns for it,
+        at the cover's output width, what the walk returns: every plane
+        bit-equal to sequential `route_step` calls on the same tables."""
+        import jax
+
+        from emqx_tpu.models import router_engine as RE
+        from emqx_tpu.ops.match import encode_topics_str
+        on, _off = _mk_twin_nodes(POPULATIONS["shapes"])
+        eng = on[0].device_engine
+        eng.shape_cap = 0
+        eng.route_batch([mkmsg(t) for t in TRAFFIC])
+        tables = eng._tables
+        assert tables.trie.cover is not None
+        W, B = 4, 16
+        enc = np.zeros((W, B, eng.max_levels), np.int32)
+        lens = np.zeros((W, B), np.int32)
+        dol = np.zeros((W, B), bool)
+        e, l, d, too_long = encode_topics_str(eng.intern, TRAFFIC,
+                                              eng.max_levels)
+        assert not too_long.any()
+        n = len(TRAFFIC)
+        for k in (0, 2):            # sub-batches 1 and 3 are padding
+            enc[k, :n], lens[k, :n], dol[k, :n] = e, l, d
+        mh = np.zeros((W, B), np.int32)
+        kw = eng._caps_kw("trie")
+        cur = np.asarray(eng._cursors)
+        got = RE.route_window_full(tables, cur, enc, lens, dol, mh,
+                                   np.int32(0), **kw)
+        assert got.matches.shape[-1] == \
+            tables.trie.cover.out_pad.shape[0]
+        assert (np.asarray(got.match_counts)[[1, 3]] == 0).all()
+        assert np.asarray(got.match_counts)[0].sum() > 0
+        for k in range(W):
+            want = RE.route_step(tables, cur, enc[k], lens[k], dol[k],
+                                 mh[k], np.int32(0), **kw)
+            for a, b in zip(jax.tree.leaves(want),
+                            jax.tree.leaves(RE.RouteResult(
+                                *[x[k] for x in got]))):
+                a, b = np.asarray(a), np.asarray(b)
+                assert a.dtype == b.dtype and a.shape == b.shape
+                assert (a == b).all()
+            cur = want.new_cursors
+
     def test_shared_groups_post_expansion(self):
         """Shared-sub picks resolve on EXPANDED rows: a group on a
         covered filter must rotate identically across the twins."""

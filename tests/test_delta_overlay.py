@@ -147,7 +147,7 @@ class TestChurnOracle:
         oracle.device_engine.rebuild()
         _route_both(ov, oracle, ["a/b", "x/y/z/w", "a/q/c"])
         assert ov.device_engine.stats()["backend"] == "trie"
-        # churn: new filter matched on device via route_step_delta
+        # churn: new filter matched on device via route_window_delta
         for node in (ov, oracle):
             b = node.broker
             sid2 = b.register(Sink(), "t2")

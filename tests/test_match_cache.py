@@ -231,7 +231,8 @@ class TestDedupOracle:
 
     def test_trie_backend_dedup_and_cache(self):
         """The trie-NFA fallback backend gets the same reuse layers
-        (route_step_cached), bit-identical to the plain trie step."""
+        (route_window_cached, W = 1), bit-identical to the plain trie
+        step."""
         def setup(broker):
             s = Sink()
             sid = broker.register(s, "c")

@@ -484,17 +484,12 @@ class TestCompileAccounting:
         named = {k for k in st if not k.startswith("exchange_step_")}
         assert named <= {"route_step", "route_step_shapes",
                            "route_window_shapes", "route_window_full",
-                           "route_step_cached", "route_window_cached",
-                           "route_step_compact",
-                           "route_step_cached_compact",
+                           "route_window_cached",
                            "route_window_full_compact",
                            "route_window_cached_compact",
-                           "route_step_delta", "route_window_delta",
-                           "route_step_delta_cached",
+                           "route_window_delta",
                            "route_window_delta_cached",
-                           "route_step_delta_compact",
                            "route_window_delta_compact",
-                           "route_step_delta_cached_compact",
                            "route_window_delta_cached_compact"}
         assert all(isinstance(v, int) for v in st.values())
 

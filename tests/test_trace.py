@@ -1056,7 +1056,8 @@ def _plain_steps(fx, match):
             opts=fr.opts, fan_counts=fr.counts, shared_sids=sids,
             shared_rows=sp.rows, shared_opts=sp.opts,
             overflow=mr.overflow | fr.overflow | so,
-            new_cursors=sp.new_cursors, occur=sp.occur))
+            new_cursors=sp.new_cursors, occur=sp.occur,
+            match_overflow=mr.overflow))
         cur = sp.new_cursors
     return RE.RouteResult(*[np.stack([np.asarray(r[i]) for r in out])
                             for i in range(len(out[0]))])
@@ -1075,12 +1076,16 @@ def _same(got, want):
 
 @pytest.mark.parametrize("family", [
     "step", "step_shapes", "window_full", "window_cached",
-    "window_full_compact", "window_delta_compact"])
+    "window_full_compact", "window_delta_compact",
+    "window_full_trie", "window_cached_trie", "window_full_compact_trie",
+    "window_full_padded_trie"])
 def test_route_outputs_bit_equal_with_scopes(family):
     """jax.named_scope changes HLO metadata only: each route program
     family, scopes and all, returns bit for bit what the same ops
     return when called one by one with no scope around them; and the
-    scopes are in the lowered program's metadata."""
+    scopes are in the lowered program's metadata. A window program
+    serves either backend: the `_trie` families hand it `RouterTables`
+    and hold it to W sequential NFA steps."""
     import numpy as np
 
     from emqx_tpu.ops.compact import compact_result
@@ -1100,6 +1105,18 @@ def test_route_outputs_bit_equal_with_scopes(family):
         return shape_match(fx["shapes"].shapes, e, l, d)
 
     scopes = {"match", "fanout", "shared"}
+    trie = family.endswith("_trie")
+    family = family.removesuffix("_trie")
+    if family == "window_full_padded":
+        # the last two sub-batches are the window class's padding: a
+        # trie window skips the NFA there and returns what it returns
+        family, fx = "window_full", dict(fx, lens=fx["lens"].copy())
+        fx["lens"][2:] = 0
+        win = (fx["enc"], fx["lens"], fx["dol"], fx["hash"], fx["strat"])
+    tables, by = (fx["trie"], by_trie) if trie else (fx["shapes"],
+                                                     by_shapes)
+    if trie:
+        caps = dict(caps, frontier_cap=16, match_cap=64)
     if family == "step":
         fn, args, kw = RE.route_step, (fx["trie"], fx["cur"]) + tuple(
             a[0] if getattr(a, "ndim", 0) else a for a in win), dict(
@@ -1114,34 +1131,34 @@ def test_route_outputs_bit_equal_with_scopes(family):
             dict(fx, W=1), by_shapes)])
     elif family == "window_full":
         fn, args, kw = RE.route_window_full, (
-            fx["shapes"], fx["cur"]) + win, caps
-        want = _plain_steps(fx, by_shapes)
+            tables, fx["cur"]) + win, caps
+        want = _plain_steps(fx, by)
         scopes |= {"scan"}
     elif family == "window_cached":
         # every lane a miss of its own: the plan's degenerate case
         U = W * B
         base = (np.full((U, 64), -1, np.int32), np.zeros(U, np.int32),
                 np.zeros(U, bool))
-        probe = by_shapes(fx["enc"].reshape(U, -1),
-                          fx["lens"].reshape(U), fx["dol"].reshape(U))
+        probe = by(fx["enc"].reshape(U, -1),
+                   fx["lens"].reshape(U), fx["dol"].reshape(U))
         base = (np.full((U,) + probe.matches.shape[1:], -1, np.int32),
                 base[1], base[2])
         fn, kw = RE.route_window_cached, caps
-        args = (fx["shapes"], fx["cur"], fx["enc"].reshape(U, -1),
+        args = (tables, fx["cur"], fx["enc"].reshape(U, -1),
                 fx["lens"].reshape(U), fx["dol"].reshape(U)) + base + (
             np.arange(U, dtype=np.int32),
             np.arange(U, dtype=np.int32).reshape(W, B),
             fx["hash"], fx["strat"])
-        want = _plain_steps(fx, by_shapes)
+        want = _plain_steps(fx, by)
         scopes |= {"scan"}
     elif family == "window_full_compact":
         fn, args, kw = RE.route_window_full_compact, (
-            fx["shapes"], fx["cur"]) + win, dict(caps, payload_cap=256)
-        r = _plain_steps(fx, by_shapes)
+            tables, fx["cur"]) + win, dict(caps, payload_cap=256)
+        r = _plain_steps(fx, by)
         want = RE.CompactRouteResult(res=r, compact=compact_result(
             r.matches, r.rows, r.opts, r.fan_counts, r.shared_sids,
             r.shared_rows, r.shared_opts, payload_cap=256,
-            match_holes=True))
+            match_holes=not trie))
         scopes |= {"scan", "compact"}
     else:
         fn = RE.route_window_delta_compact
