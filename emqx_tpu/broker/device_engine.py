@@ -2648,6 +2648,8 @@ class DeviceRouteEngine:
                     res.occur]
             if h.cache_info is not None and self._match_cache is not None:
                 out.append(res.match_counts)
+        if res.nfa_wide_steps is not None:
+            out.append(res.nfa_wide_steps)
         return out
 
     def _start_readback(self, h) -> None:
@@ -2936,6 +2938,7 @@ class DeviceRouteEngine:
             corrupt = self.sup.fire("materialize", corrupt_ok=True)
         res = h.res
         cp = h.cres
+        self._count_nfa_steps(h)
         delta_bytes = self._materialize_delta(h)
         csr_probe_bytes = 0
         if cp is not None:
@@ -3031,6 +3034,24 @@ class DeviceRouteEngine:
         if h.built.backend != "shapes" and overflow.any() \
                 and h.res.match_overflow is not None:
             h.np_mov = np.asarray(h.res.match_overflow)
+
+    def _count_nfa_steps(self, h) -> None:
+        """How many level steps the NFA took for a trie window, and how
+        many of them at a narrow width (ops/match.NARROW_WIDTHS): the
+        program's [W] plane holds the steps that ran at `frontier_cap`.
+        A plain window walks once for every sub-batch that holds a
+        topic (`_nfa_unless_padding`), a match-cache plan once over its
+        miss lanes."""
+        wide = h.res.nfa_wide_steps
+        if wide is None:        # a shape-hash program has no such plane
+            return
+        enc4, len4, _dol4 = h.enc
+        walks = 1 if h.plan is not None \
+            else int((len4 > 0).any(axis=1).sum())
+        steps = walks * (enc4.shape[-1] + 1)
+        self.node.metrics.inc("routing.device.nfa_steps", steps)
+        self.node.metrics.inc("routing.device.nfa_narrow_steps",
+                              steps - int(np.asarray(wide).sum()))
 
     def _note_host_fallback(self, h, k: int, i: int) -> None:
         """Lane i of sub-batch k goes to the host trie (too deep, or a
@@ -3745,6 +3766,9 @@ class DeviceRouteEngine:
             **(getattr(self.node, "device_info", None) or {}),
             "built": b is not None,
             "backend": b.backend if b else None,
+            "nfa_steps": self.node.metrics.val("routing.device.nfa_steps"),
+            "nfa_narrow_steps": self.node.metrics.val(
+                "routing.device.nfa_narrow_steps"),
             "filters": len(b.fid_filter) if b else 0,
             "shared_slots": b.n_slots if b else 0,
             "churn": self.staleness(),

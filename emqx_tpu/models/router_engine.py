@@ -64,6 +64,11 @@ class RouteResult(NamedTuple):
     # routing.device.match_overflow counts. None where a program does
     # not report it (the mesh's sharded step)
     match_overflow: jax.Array = None
+    # trie programs only: level steps of the sub-batch's NFA walk that
+    # ran at `frontier_cap` (`MatchResult.wide_steps`; 0 for a padding
+    # sub-batch, whose walk is skipped). [W] from a window program,
+    # whose cached form walks once and reports it in row 0
+    nfa_wide_steps: jax.Array = None
 
 
 class ExchangeAux(NamedTuple):
@@ -108,7 +113,7 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
         rows=fr.rows, opts=fr.opts, fan_counts=fr.counts,
         shared_sids=sids, shared_rows=sp.rows, shared_opts=sp.opts,
         overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur,
-        match_overflow=mr.overflow)
+        match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps)
 
 
 @functools.partial(
@@ -152,13 +157,15 @@ def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
                         frontier_cap: int, match_cap: int) -> MatchResult:
     """`match_batch` for one sub-batch of a fused window, skipped where
     the sub-batch is the window class's padding. A window is padded to
-    its class's W, and the NFA gathers for every lane of every level
-    whether a topic is there or not (76 ms of a 93 ms step at 1024
-    lanes on a v5e; my chip run, PR 28): a sub-batch without a topic
-    matches nothing, so it is handed the empty result `match_batch`
-    returns for it (no frontier, nothing emitted, and for a covering
-    snapshot nothing for `cover_expand` to re-expand: its row is as
-    wide as the cover's output) without the walk."""
+    its class's W, and the NFA steps through every level whether a
+    topic is there or not (13.5 ms at 1024 lanes on a v5e, at the
+    narrowest of `ops/match.NARROW_WIDTHS`, which an empty frontier
+    takes; 82.7 ms at `frontier_cap`; my chip runs, PR 29): a sub-batch
+    without a topic matches nothing, so it is handed the empty result
+    `match_batch` returns for it (no frontier, nothing emitted, no wide
+    step, and for a covering snapshot nothing for `cover_expand` to
+    re-expand: its row is as wide as the cover's output) without the
+    walk."""
     B = topics.shape[0]
     M = match_cap if trie.cover is None else trie.cover.out_pad.shape[0]
     return jax.lax.cond(
@@ -169,7 +176,7 @@ def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
         lambda: MatchResult(
             matches=jnp.full((B, M), -1, jnp.int32),
             counts=jnp.zeros(B, jnp.int32),
-            overflow=jnp.zeros(B, bool)))
+            overflow=jnp.zeros(B, bool), wide_steps=jnp.int32(0)))
 
 
 def _match_stage(tables, topics: jax.Array, lens: jax.Array,
@@ -226,6 +233,9 @@ def route_window_cached(tables, cursors: jax.Array,
 
     with jax.named_scope("scan"):
         _, stacked = jax.lax.scan(step, cursors, (inv, msg_hash))
+    if _is_trie(tables):
+        stacked = stacked._replace(nfa_wide_steps=jnp.zeros(
+            inv.shape[0], jnp.int32).at[0].set(mr.wide_steps))
     return stacked
 
 

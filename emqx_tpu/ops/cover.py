@@ -530,7 +530,7 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     count = cand_valid.sum(axis=1, dtype=jnp.int32)
     overflow = mr.overflow | cand_oflow | (count > M)
     return MatchResult(matches=out, counts=jnp.minimum(count, M),
-                       overflow=overflow)
+                       overflow=overflow, wide_steps=mr.wide_steps)
 
 
 # ---- host-side cover lookup (append path) --------------------------------

@@ -1057,10 +1057,17 @@ def _plain_steps(fx, match):
             shared_rows=sp.rows, shared_opts=sp.opts,
             overflow=mr.overflow | fr.overflow | so,
             new_cursors=sp.new_cursors, occur=sp.occur,
-            match_overflow=mr.overflow))
+            match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps))
         cur = sp.new_cursors
-    return RE.RouteResult(*[np.stack([np.asarray(r[i]) for r in out])
-                            for i in range(len(out[0]))])
+    return RE.RouteResult(*[
+        None if out[0][i] is None
+        else np.stack([np.asarray(r[i]) for r in out])
+        for i in range(len(out[0]))])
+
+
+def _first(stacked):
+    """Sub-batch 0 of a window-stacked RouteResult."""
+    return type(stacked)(*[x if x is None else x[0] for x in stacked])
 
 
 def _same(got, want):
@@ -1085,7 +1092,9 @@ def test_route_outputs_bit_equal_with_scopes(family):
     return when called one by one with no scope around them; and the
     scopes are in the lowered program's metadata. A window program
     serves either backend: the `_trie` families hand it `RouterTables`
-    and hold it to W sequential NFA steps."""
+    and hold it to W sequential NFA steps, `nfa_wide_steps` included,
+    which a shape-hash program's result does not have."""
+    import jax
     import numpy as np
 
     from emqx_tpu.ops.compact import compact_result
@@ -1121,14 +1130,12 @@ def test_route_outputs_bit_equal_with_scopes(family):
         fn, args, kw = RE.route_step, (fx["trie"], fx["cur"]) + tuple(
             a[0] if getattr(a, "ndim", 0) else a for a in win), dict(
             caps, frontier_cap=16, match_cap=64)
-        want = RE.RouteResult(*[x[0] for x in _plain_steps(
-            dict(fx, W=1), by_trie)])
+        want = _first(_plain_steps(dict(fx, W=1), by_trie))
     elif family == "step_shapes":
         fn, args, kw = RE.route_step_shapes, (
             fx["shapes"], fx["cur"]) + tuple(
             a[0] if getattr(a, "ndim", 0) else a for a in win), caps
-        want = RE.RouteResult(*[x[0] for x in _plain_steps(
-            dict(fx, W=1), by_shapes)])
+        want = _first(_plain_steps(dict(fx, W=1), by_shapes))
     elif family == "window_full":
         fn, args, kw = RE.route_window_full, (
             tables, fx["cur"]) + win, caps
@@ -1150,6 +1157,9 @@ def test_route_outputs_bit_equal_with_scopes(family):
             np.arange(U, dtype=np.int32).reshape(W, B),
             fx["hash"], fx["strat"])
         want = _plain_steps(fx, by)
+        if trie:    # one walk over the miss lanes, reported in row 0
+            want = want._replace(nfa_wide_steps=np.array(
+                [probe.wide_steps] + [0] * (W - 1), np.int32))
         scopes |= {"scan"}
     elif family == "window_full_compact":
         fn, args, kw = RE.route_window_full_compact, (
@@ -1184,7 +1194,12 @@ def test_route_outputs_bit_equal_with_scopes(family):
             dres=RE.DeltaRouteResult(res=r, dp=dp), compact=cp,
             d_compact=dcp)
         scopes |= {"scan", "compact", "delta"}
-    _same(fn(*args, **kw), want)
+    got = fn(*args, **kw)
+    _same(got, want)
+    (res,) = [x for x in jax.tree.leaves(
+        got, is_leaf=lambda x: isinstance(x, RE.RouteResult))
+        if isinstance(x, RE.RouteResult)]
+    assert (res.nfa_wide_steps is not None) == (trie or family == "step")
     ops = [ln for ln in fn.lower(*args, **kw).compile().as_text()
            .splitlines() if "op_name=" in ln]
     for name in scopes:
@@ -1235,3 +1250,71 @@ def test_no_span_is_opened_per_message(tmp_path):
     spans = [e for evs in lines.values() for e in evs
              if e[0].startswith("emqx:")]
     assert len(spans) < n / 4
+
+
+# ---------- ISSUE 29: the NFA's steps, and how many ran narrow ----------
+
+def test_engine_counts_the_nfa_steps_and_the_narrow_ones():
+    """`routing.device.nfa_steps` is `max_levels + 1` a sub-batch the
+    NFA walked (none for a padding sub-batch, whose walk is skipped; one
+    walk for a window that took the match-cache plan),
+    `nfa_narrow_steps` those that ran below `frontier_cap`: all of them
+    where a topic has a few live paths, not where `+`s fan a topic out
+    over more than `ops/match.NARROW_WIDTHS` holds."""
+    from tools.workloads import shape_spread_filters
+    spread = shape_spread_filters(24, tail_hash=True)
+    # a tail of its own each, so that none covers another
+    fan = ["w/" + "/".join(x if (m >> i) & 1 else "+"
+                           for i, x in enumerate("abc")) + f"/t{m}"
+           for m in range(8)]
+    deep = [make("p", 0, f.replace("+", "x").replace("#", "y"), b"")
+            for f in spread]
+    wide = make("p", 0, "w/a/b/c/t7", b"")   # 8 live paths into level 4
+
+    def trie_node(**conf):
+        node = _mk_node(**conf)
+        node.device_engine.shape_cap = 1      # before the first build
+        sid = node.broker.register(Sink(), "c")
+        for f in spread + fan:
+            node.broker.subscribe(sid, f, {"qos": 0})
+        node.device_engine.rebuild()
+        assert node.device_engine.stats()["backend"] == "trie"
+        return node, node.device_engine
+
+    def route(eng, window):
+        h = eng.prepare_window(window, gate_cold=False)
+        eng.dispatch(h)
+        eng.materialize(h)
+        for k in range(len(h.subs)):
+            eng.finish_sub(h, k)
+        return h
+
+    def counted(node):
+        st = node.device_engine.stats()
+        got = (node.metrics.val("routing.device.nfa_steps"),
+               node.metrics.val("routing.device.nfa_narrow_steps"))
+        assert (st["nfa_steps"], st["nfa_narrow_steps"]) == got
+        return got
+    node, eng = trie_node(topic_dedup=False)
+    steps = eng.max_levels + 1
+    assert counted(node) == (0, 0)
+    # three sub-batches in a window class of eight: five walks skipped
+    h = route(eng, [deep[:8], deep[8:16], deep[16:]])
+    assert h.enc[0].shape[0] == 8
+    assert list(h.res.nfa_wide_steps) == [0] * 8
+    assert counted(node) == (3 * steps, 3 * steps)
+    h = route(eng, [[wide, deep[0]]])
+    assert list(h.res.nfa_wide_steps) == [1]
+    assert counted(node) == (4 * steps, 4 * steps - 1)
+    # the match-cache plan walks once, over the window's distinct topics
+    node, eng = trie_node()
+    h = route(eng, [[wide] * 40 + [deep[0]] * 40])
+    assert h.plan is not None
+    assert counted(node) == (steps, steps - 1)
+    # a shape-hash snapshot counts none
+    node = _mk_node()
+    _subscribe(node)
+    node.device_engine.route_batch([make("p", 0, "t/1/x", b"")])
+    assert node.device_engine.stats()["backend"] == "shapes"
+    assert node.metrics.val("routing.device.windows") == 1
+    assert counted(node) == (0, 0)

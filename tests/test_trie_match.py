@@ -192,3 +192,109 @@ class TestRandomizedEquivalence:
         topics = [f"device/{i}/x/{n}/tail" for i in range(8) for n in range(16)]
         got = fx.device_match(topics)
         assert got == [brute_force(t, filters) for t in topics]
+
+
+# ---- ISSUE 29: a level step as wide as the live frontier ----------------
+
+def _plus_family(root, depth):
+    """Every filter `root/x1/../xdepth` with x_i a literal or '+': a
+    topic under `root` has 2**i live paths after i more levels."""
+    lits = "abcdefgh"
+    return ["/".join([root] + [lits[i] if (m >> i) & 1 else "+"
+                               for i in range(depth)])
+            for m in range(1 << depth)]
+
+
+# `w/a/b/c/x/y/z`: 1, 1, 2, 4 live paths into levels 0-3 (narrow), 8 into
+# level 4 (the one wide step), then the one filter with a tail (narrow)
+PLUS_HEAVY = _plus_family("w", 3) + ["w/a/b/c/x/y/z", "w/+/b/c/x/#"]
+
+
+def _walk_case(name):
+    """(filters, topics, caps) of one case of the adaptive walk."""
+    if name.startswith("random"):
+        rng = random.Random(int(name[6:]))
+        filters = sorted({rand_filter(rng)
+                          for _ in range(rng.randint(5, 120))})
+        return filters, [rand_topic(rng) for _ in range(64)], {}
+    if name == "deep":
+        rng = random.Random(5)
+        return (["+/+/+/+/+/+/+/+", "a/#", "a/a/a/a/a/a/a/a", "#",
+                 "a/+/a/+/a/+/a/+"],
+                ["/".join(rng.choice(["a", "b"]) for _ in range(8))
+                 for _ in range(32)], {"frontier_cap": 32})
+    if name == "bench_shape":
+        return ([f"device/{i}/+/{n}/#" for i in range(8)
+                 for n in range(16)],
+                [f"device/{i}/x/{n}/tail" for i in range(8)
+                 for n in range(16)], {})
+    topics = ["w/a/b/c/x/y/z", "w/a/b", "w/q/b/c/x/y", "a/b/c", "$sys/w"]
+    caps = {"plus_heavy": {},
+            "plus_heavy_cover_caps": {"frontier_cap": 32, "match_cap": 128},
+            "frontier_cap4": {"frontier_cap": 4},
+            "frontier_cap1_match_cap1": {"frontier_cap": 1, "match_cap": 1},
+            "frontier_cap8_match_cap2": {"frontier_cap": 8, "match_cap": 2},
+            }[name]
+    return PLUS_HEAVY + ["a/b/c", "a/#", "$sys/#"], topics, caps
+
+
+@pytest.mark.parametrize("name", [
+    "random7", "random21", "random42", "random1001", "deep", "bench_shape",
+    "plus_heavy", "plus_heavy_cover_caps", "frontier_cap4",
+    "frontier_cap1_match_cap1", "frontier_cap8_match_cap2"])
+def test_adaptive_walk_is_the_wide_walk_bit_for_bit(name):
+    """The walk that narrows its step to the live frontier returns the
+    walk at `frontier_cap`'s `matches` (order included), `counts` and
+    `overflow`, with two padding rows in the batch; and it counts the
+    steps it ran wide."""
+    filters, topics, caps = _walk_case(name)
+    fx = Fixture(filters)
+    L = fx.max_levels
+    enc, lens, dollar, too_long = encode_topics(
+        fx.intern, [T.words(t) for t in topics], L)
+    assert not too_long.any()
+    enc = np.concatenate([enc, np.full((2, L), I.PAD, np.int32)])
+    lens = np.concatenate([lens, np.zeros(2, np.int32)])
+    dollar = np.concatenate([dollar, np.zeros(2, bool)])
+    got = match_batch(fx.tables, enc, lens, dollar, **caps)
+    want = match_batch(fx.tables, enc, lens, dollar, _rungs=(), **caps)
+    for a, b in zip(got[:3], want[:3]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(want.wide_steps) == L + 1
+    assert int(got.counts[-2:].sum()) == 0 and not got.overflow[-2:].any()
+    F = caps.get("frontier_cap", 16)
+    wide = int(got.wide_steps)
+    if F < 4:                       # no rung below the cap: all wide
+        assert wide == L + 1
+    elif name == "bench_shape":     # two live paths at most
+        assert wide == 0
+    elif name.startswith("plus_heavy"):
+        assert wide == 1            # 8 live paths into level 4 alone
+    if name.startswith(("random", "plus_heavy")) and not got.overflow.any():
+        for i, t in enumerate(topics):
+            assert sorted(int(x) for x in got.matches[i][:int(got.counts[i])]) \
+                == brute_force(t, filters)
+
+
+def test_detect_covers_is_what_the_wide_walk_detects(monkeypatch):
+    import functools
+
+    from emqx_tpu.ops import cover as C
+    from emqx_tpu.ops import match as M
+    from tools.workloads import cover_heavy_filters
+    filters = sorted(set(cover_heavy_filters(300, cover_ratio=0.5)))
+    intern = I.InternTable()
+    rows = np.zeros((len(filters), 16), np.int32)
+    lens = np.zeros(len(filters), np.int64)
+    for fid, f in enumerate(filters):
+        w = intern.encode_filter(T.words(f))
+        rows[fid, :len(w)] = w
+        lens[fid] = len(w)
+    dollar = np.array([f.startswith("$") for f in filters])
+    got, got_inc = C.detect_covers(rows, lens, dollar, batch=512)
+    monkeypatch.setattr(M, "match_batch",
+                        functools.partial(M.match_batch, _rungs=()))
+    want, want_inc = C.detect_covers(rows, lens, dollar, batch=512)
+    assert (got_inc == want_inc).all() and sum(map(len, want)) > 100
+    for a, b in zip(got, want):
+        assert list(a) == list(b)
