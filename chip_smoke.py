@@ -29,7 +29,6 @@ import struct
 import subprocess
 import sys
 import time
-import warnings
 
 import numpy as np
 
@@ -821,18 +820,6 @@ def main() -> int:
     say(f"compile cache: {cache_dir} ({cache_before} entries before)")
     build_native()
 
-    donated: list = []
-    show = warnings.showwarning
-
-    def on_warning(message, category, filename, lineno, *a, **kw):
-        if "donated buffers were not usable" in str(message):
-            donated.append(str(message).splitlines()[0])
-        else:
-            show(message, category, filename, lineno, *a, **kw)
-
-    warnings.showwarning = on_warning
-    warnings.simplefilter("always")
-
     from emqx_tpu.broker.node import Node
     failures: list = []
     if args.mesh:
@@ -873,8 +860,6 @@ def main() -> int:
             check_shards(node, fails)
         failures += fails
 
-    say(f"'Some donated buffers were not usable' fired "
-        f"{len(donated)} time(s): {sorted(set(donated))[:4]}")
     say(f"compile cache: {compile_cache_entries(cache_dir)} entries after "
         f"({cache_before} before)")
     if failures:

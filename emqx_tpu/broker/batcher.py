@@ -44,11 +44,10 @@ always completed first), so per-publisher ordering and the journal
 discipline are bit-identical to the synchronous loop. Knob:
 ``broker.dispatch_depth`` / ``EMQX_TPU_DISPATCH_DEPTH`` (config beats
 env beats default 2); ``=1`` restores the pre-ISSUE-9 synchronous
-consumer EXACTLY — same code path, same jit programs (no cursor
-donation), the A/B baseline. Supervision: each in-flight window's
-stage awaits are bounded by the watchdog deadlines INDEPENDENTLY (one
-stage task per window), and a mid-pipeline death replays exactly the
-journaled windows it touched through the host rung.
+consumer EXACTLY — same code path, the A/B baseline. Supervision: each
+in-flight window's stage awaits are bounded by the watchdog deadlines
+INDEPENDENTLY (one stage task per window), and a mid-pipeline death
+replays exactly the journaled windows it touched through the host rung.
 
 Ordering: submissions are FIFO; batches complete in arrival order; within a
 batch messages are consumed in order — MQTT's per-publisher-per-topic
@@ -75,10 +74,10 @@ _PROBE_EVERY = 64
 def resolve_dispatch_depth(configured=None) -> int:
     """The one dispatch-depth resolution (ISSUE 9): config
     (``broker.dispatch_depth``) beats ``EMQX_TPU_DISPATCH_DEPTH`` beats
-    the built-in 2. ``=1`` restores the synchronous consumer loop (and
-    the non-donating jit programs) exactly — the A/B baseline every
-    depth-twin test compares. Must be a positive integer; anything else
-    is a deployment error worth failing loudly on."""
+    the built-in 2. ``=1`` restores the synchronous consumer loop
+    exactly — the A/B baseline every depth-twin test compares. Must be
+    a positive integer; anything else is a deployment error worth
+    failing loudly on."""
     if configured is None:
         env = os.environ.get("EMQX_TPU_DISPATCH_DEPTH")
         if env is None:
@@ -148,7 +147,7 @@ class PublishBatcher:
         self.dispatch_depth = resolve_dispatch_depth(dispatch_depth)
         self.host_probe_every = host_probe_every
         # under sustained load, up to this many consecutive batches fuse
-        # into ONE device dispatch (route_window_full) — the per-dispatch
+        # into ONE device dispatch (route_window) — the per-dispatch
         # cost is paid once per window, the same amortization bench.py
         # measures with BENCH_FUSE
         self.window_fuse = max(1, min(window_fuse, 8))

@@ -3,7 +3,8 @@
 This is the piece that makes the TPU program THE broker hot path instead of
 a side-car demo: it compiles the live routing state (Router filter universe +
 Broker subscriber/shared-group membership) into the fused device tables
-(models.router_engine), runs its `route_window_*` programs over publish
+(models.router_engine), runs its one window program, `route_window`,
+with the optional stages a window's class asks for, over publish
 micro-batches, and consumes the `RouteResult` into actual session deliveries
 — replacing the reference's per-message publish path
 (emqx_broker.erl:199-308: match_routes → dispatch fold → shared pick).
@@ -74,11 +75,12 @@ until the next rebuild.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import time
 import zlib
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -241,32 +243,21 @@ def _topic_keys(enc: np.ndarray, lens: np.ndarray,
 
 
 class _CachePlan:
-    """Device-side inputs of one deduplicated (optionally cache-backed)
-    dispatch: the compacted miss lanes, the host-filled base rows, and
-    the scatter/gather indexing that rebuilds full window width."""
+    """One deduplicated (optionally cache-backed) dispatch: the device
+    inputs (the compacted miss lanes, the host-filled base rows, and the
+    scatter/gather indexing that rebuilds full window width) and what
+    the host keeps about them."""
 
-    __slots__ = ("miss_topics", "miss_lens", "miss_dollar", "base_m",
-                 "base_c", "base_o", "miss_pos", "inv", "Bm", "n_miss",
-                 "n_hit", "base_dm", "base_dc", "base_do")
+    __slots__ = ("dev", "dbase", "Bm", "n_miss", "n_hit")
 
-    def __init__(self, miss_topics, miss_lens, miss_dollar, base_m,
-                 base_c, base_o, miss_pos, inv, Bm, n_miss, n_hit):
-        self.miss_topics = miss_topics
-        self.miss_lens = miss_lens
-        self.miss_dollar = miss_dollar
-        self.base_m = base_m
-        self.base_c = base_c
-        self.base_o = base_o
-        self.miss_pos = miss_pos
-        self.inv = inv
+    def __init__(self, dev, dbase, Bm, n_miss, n_hit):
+        self.dev = dev        # router_engine.WindowPlan of numpy arrays
+        # delta-overlay base rows (overlay ROW-index space): None unless
+        # the window fuses the overlay (ISSUE 4)
+        self.dbase = dbase
         self.Bm = Bm
         self.n_miss = n_miss
         self.n_hit = n_hit
-        # delta-overlay base rows (overlay ROW-index space; filled only
-        # when the window fuses the overlay — ISSUE 4)
-        self.base_dm = None
-        self.base_dc = None
-        self.base_do = None
 
 
 class _CacheInfo:
@@ -322,6 +313,47 @@ class _Overlay:
         self.version = version    # overlay clock stamp at build
         self.cap = cap            # row class (jit signature component)
         self.n = n                # live rows
+
+
+class _WindowClass(NamedTuple):
+    """One compiled class of `router_engine.route_window`: what the jit
+    key of a dispatch is made of, and so what warmth, demand and the
+    cold gates are tracked by. `sig` is the snapshot's shape signature
+    (`_tables_sig`), (W, Bp) the padded window; the last three are the
+    program's optional stages, None when the stage is off: `Bm` the
+    match-cache plan's miss class, `dC` the delta overlay's row class,
+    `P` the CSR readback's payload class."""
+    sig: tuple
+    W: int
+    Bp: int
+    Bm: Optional[int] = None
+    dC: Optional[int] = None
+    P: Optional[int] = None
+
+    @property
+    def plain(self) -> bool:
+        """No optional stage: `match → scan(fanout, shared)` alone."""
+        return self.Bm is None and self.dC is None and self.P is None
+
+    @property
+    def label(self) -> str:
+        """The class in a compile-context label (`warm W8xB1024mB256d16
+        c4096`): the key space of snapshot()["compiles"]["by_shape"]
+        and of the cost registry's rows."""
+        return (f"W{self.W}xB{self.Bp}"
+                + (f"mB{self.Bm}" if self.Bm is not None else "")
+                + (f"d{self.dC}" if self.dC is not None else "")
+                + (f"c{self.P}" if self.P is not None else ""))
+
+    @property
+    def warm_order(self) -> tuple:
+        """Where the background warm takes a demanded class: plain
+        (oversized batch) classes, then overlay-only, then planned,
+        then compact ones, each group in ascending sizes."""
+        group = 3 if self.P is not None else 2 if self.Bm is not None \
+            else 1 if self.dC is not None else 0
+        return (group, self.W, self.Bp, self.Bm or 0, self.P or 0,
+                self.dC or 0)
 
 
 class _DeltaRes:
@@ -626,13 +658,13 @@ class DeviceRouteEngine:
         # traffic for seconds (observed: 5s+ first-QoS1-ack under a
         # cold-start flood). Classes become warm via background warm
         # tasks or any successful dispatch (route_batch warmups).
-        self._warm_classes: set = set()      # {(sig, W, Bp[, Bm])}
-        self._extra_classes: set = set()     # non-standard (W, Bp) wanted
-        # cached-dispatch (W, Bp, Bm) classes the serving path asked for:
-        # demand-driven (a dedup plan whose class is cold falls back to
-        # the plain warm program and registers here), warmed by the same
+        self._warm_classes: set[_WindowClass] = set()
+        # classes beyond the standard ladder that the serving path asked
+        # for: demand-driven (a window whose plan, overlay or payload
+        # class is cold dispatches without that stage and registers the
+        # class here; so does an oversized batch), warmed by the same
         # background thread as the standard ladder
-        self._wanted_cached: set = set()
+        self._wanted: set[_WindowClass] = set()
         self._cur_sig: tuple = ()
         self._fuse_warm_task = None
         # background rebuild machinery (round-2 weak #7)
@@ -664,9 +696,6 @@ class DeviceRouteEngine:
             compact_readback = _ENV_COMPACT
         self.compact_readback = bool(compact_readback)
         self._pay_ewma: dict[int, float] = {}   # Bp -> peak entry total
-        # compact (W, Bp[, Bm], P[, Cd]) classes the serving path asked
-        # for, warmed by the same background thread as the cached ladder
-        self._wanted_compact: set = set()
 
         # delta overlay (ISSUE 4 tentpole): post-snapshot filters match
         # ON DEVICE via a small linear overlay table fused into the
@@ -694,14 +723,12 @@ class DeviceRouteEngine:
         self._cover_churn = 0
 
         # double-buffered window pipeline (ISSUE 9 tentpole): at
-        # dispatch_depth >= 2 the serving dispatch (a) threads cursors
-        # through the DONATING program twins so the ping-pong buffers
-        # reuse HBM (models.router_engine.donating), and (b) starts the
+        # dispatch_depth >= 2 the serving dispatch starts the
         # device→host transfers of every readback plane at dispatch
         # return (copy_to_host_async-style), so materialize is
         # consume-on-arrival under the next window's dispatch. Depth 1
-        # restores the pre-ISSUE-9 programs and synchronous readback
-        # exactly — the A/B baseline. Config beats env beats default 2.
+        # restores the synchronous readback exactly — the A/B
+        # baseline. Config beats env beats default 2.
         from emqx_tpu.broker.batcher import resolve_dispatch_depth
         self.dispatch_depth = resolve_dispatch_depth(dispatch_depth)
         self._pipelined = self.dispatch_depth > 1
@@ -713,7 +740,6 @@ class DeviceRouteEngine:
         # older than the entry has stale fan rows for that fid, so
         # consume delivers it host-side (the overlay's dirty_filters)
         self._fid_member_clock: dict[int, int] = {}
-        self._wanted_delta: set = set()  # (W, Bp, Cd) plain delta classes
         # journal-driven incremental capture (ISSUE 4): the previous
         # build's capture + the set of filters touched since it — a
         # compaction refreshes only the touched filters instead of
@@ -1453,15 +1479,16 @@ class DeviceRouteEngine:
             # evict warmth of superseded signatures (unbounded set
             # otherwise under churn); a re-warm for a returning capacity
             # class is a jit-cache hit, not a fresh trace
-            self._warm_classes = {e for e in self._warm_classes
-                                  if e[0] == self._cur_sig}
-            # demand for cached classes resets with the snapshot too:
-            # classes still in use re-register on their next plan, and
+            self._warm_classes = {c for c in self._warm_classes
+                                  if c.sig == self._cur_sig}
+            # demand for a stage's classes resets with the snapshot too:
+            # classes still in use re-register on their next window, and
             # stale ones must not be background-recompiled after every
-            # swap for the rest of the process lifetime
-            self._wanted_cached.clear()
-            self._wanted_compact.clear()
-            self._wanted_delta.clear()
+            # swap for the rest of the process lifetime. An oversized
+            # batch class stays wanted: the batcher's setting made it
+            self._wanted = {
+                c._replace(sig=self._cur_sig) for c in self._wanted
+                if c.plain}
         # match-cache invalidation: wholesale, HERE — and, with the
         # delta overlay on, at overlay inserts/deletes where ONLY the
         # cached topics matching the changed filter drop
@@ -1646,45 +1673,20 @@ class DeviceRouteEngine:
             self.node.metrics.inc("routing.device.rebuild_failed")
 
     def _warm_compile(self, result) -> None:
-        """Pre-jit the route step for the new tables' shapes across the
-        common (window, batch) classes, so neither the swap nor a later
-        first-use of a bigger class stalls serving on an XLA
+        """Pre-jit the route window for the new tables' shapes across
+        the standard (window, batch) classes, so neither the swap nor a
+        later first-use of a bigger class stalls serving on an XLA
         trace/compile (tracing holds the GIL even on an executor thread;
         cached compiles don't)."""
-        import contextlib
-
-        import jax
-
-        from emqx_tpu.models.router_engine import route_window_full
-        from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
-        tele = getattr(self.node, "pipeline_telemetry", None)
         b, tables, cursors, _rich = result
-        strat = np.int32(STRATEGY_ROUND_ROBIN)
-        kw = self._caps_kw(b.backend)
-        for Wp, Bp in self._STD_CLASSES:
-            ctx = tele.compile_context(f"warm W{Wp}xB{Bp}") \
-                if tele is not None else contextlib.nullcontext()
-            enc = np.zeros((Wp, Bp, self.max_levels), np.int32)
-            lens = np.zeros((Wp, Bp), np.int32)
-            dollar = np.zeros((Wp, Bp), bool)
-            mh = np.zeros((Wp, Bp), np.int32)
-            with ctx:
-                # warm the program the serving path will actually
-                # dispatch (the donating twin at depth >= 2) with a
-                # throwaway cursors buffer — never the live one, which
-                # the twin would donate away (_warm_cursors)
-                r = self._rt(route_window_full)(
-                    tables, self._warm_cursors(cursors), enc, lens,
-                    dollar, mh, strat, **kw)
-                jax.block_until_ready(r.match_counts)
-                self._last_cursors(r)
+        std = self._std_classes(self._tables_sig(tables))
+        for c in std:
+            self._warm_class(c, b, tables, cursors)
         # this snapshot's classes are warm: once IT is serving, the
         # batcher may dispatch/fuse (readiness is per shape
         # signature, so an old snapshot still serving cannot run
         # into cold shapes)
-        sig = self._tables_sig(tables)
-        for Wp, Bp in self._STD_CLASSES:
-            self._warm_classes.add((sig, Wp, Bp))
+        self._warm_classes.update(std)
 
     def _try_swap(self) -> None:
         """Apply a finished background build if no dispatch is in flight
@@ -1719,32 +1721,17 @@ class DeviceRouteEngine:
     #      checks the half-open breaker runs on an executor thread) ----
     def _probe_dispatch(self) -> None:
         """End-to-end health check of the dispatch stage: run the plain
-        route program over an all-pad batch against the live tables —
-        the same shape the demand-warm calls already execute from
-        executor threads, so thread-safety and jit-cache behavior are
-        identical. Matches nothing, advances nothing (the probe's
-        new_cursors are dropped; an all-pad batch has zero occur)."""
-        if self._built is None or self._tables is None:
+        route window over an all-pad batch against the live tables —
+        the same call the demand-warm passes already make from executor
+        threads, so thread-safety and jit-cache behavior are identical.
+        Matches nothing, advances nothing (the probe's new_cursors are
+        dropped; an all-pad batch has zero occur)."""
+        b, tables, cursors = self._built, self._tables, self._cursors
+        if b is None or tables is None:
             return      # nothing to probe: vacuous health
-        import jax
-
-        from emqx_tpu.models import router_engine as RE
-        from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
-        Bp = self._STD_CLASSES[0][1]
-        enc = np.zeros((1, Bp, self.max_levels), np.int32)
-        z = np.zeros((1, Bp), np.int32)
-        zb = np.zeros((1, Bp), bool)
-        strat = np.int32(STRATEGY_ROUND_ROBIN)
-        # ISSUE 9: at dispatch_depth >= 2 the serving dispatch DONATES
-        # the live cursors buffer, so the probe must not hand it to a
-        # concurrent call — it probes with a throwaway device buffer
-        # (the probe's cursor state is discarded anyway); the PLAIN
-        # program is kept deliberately (off-path; a cold compile here
-        # never stalls serving)
-        cur = self._warm_cursors(self._cursors)
-        r = RE.route_window_full(self._tables, cur, enc, z, zb, z, strat,
-                                 **self._caps_kw(self._built.backend))
-        jax.block_until_ready(r.match_counts)
+        c = self._class_of(*self._STD_CLASSES[0])
+        jax.block_until_ready(
+            self._run_window(c, b, tables, cursors).match_counts)
 
     def _probe_materialize(self) -> None:
         """Health check of the readback stage: one small device→host
@@ -1891,11 +1878,8 @@ class DeviceRouteEngine:
         ov = self._overlay
         if ov is None:
             return None
-        key = (self._cur_sig, Wp, Bp, f"d{ov.cap}")
-        if gate_cold and key not in self._warm_classes:
-            self._wanted_delta.add((Wp, Bp, ov.cap))
-            self._kick_class_warm()
-            self.node.metrics.inc("routing.device.cold_delta_class")
+        if self._cold(self._class_of(Wp, Bp, ov=ov), gate_cold,
+                      "routing.device.cold_delta_class"):
             return None
         return ov
 
@@ -2007,23 +1991,12 @@ class DeviceRouteEngine:
         # traces for its class).
         if not (Bm < Bp or Wp > 1):
             return None, info
-        dsuf = (f"d{ov.cap}",) if ov is not None else ()
-        dC = ov.cap if ov is not None else None
-        if gate_cold \
-                and (self._cur_sig, Wp, Bp, Bm) + dsuf \
-                not in self._warm_classes:
-            # serving path: a cold cached (W, Bp, Bm[, dC]) class would
-            # stall on an in-path XLA compile — dispatch the warm plain
-            # program instead and let the background warm bring the
-            # class online (same policy as batch_class_warm)
-            self._wanted_cached.add((Wp, Bp, Bm, dC))
-            self._kick_class_warm()
-            self.node.metrics.inc("routing.device.cold_cached_class")
+        if self._cold(self._class_of(Wp, Bp, ov=ov)._replace(Bm=Bm),
+                      gate_cold, "routing.device.cold_cached_class"):
             return None, info
         base_m = np.full((Bp, b.match_width), -1, np.int32)
         base_c = np.zeros(Bp, np.int32)
         base_o = np.zeros(Bp, bool)
-        base_dm = base_dc = base_do = None
         if ov is not None:
             base_dm = np.full((Bp, _DELTA_MATCH_CAP), -1, np.int32)
             base_dc = np.zeros(Bp, np.int32)
@@ -2057,12 +2030,13 @@ class DeviceRouteEngine:
             miss_lens[:n_miss] = lenf[src]
             miss_dollar[:n_miss] = dolf[src]
             miss_pos[:n_miss] = miss_u
-        plan = _CachePlan(miss_topics, miss_lens, miss_dollar, base_m,
-                          base_c, base_o, miss_pos,
-                          inv.reshape(Wp, Bp).astype(np.int32), Bm,
-                          n_miss, n_hit)
-        plan.base_dm, plan.base_dc, plan.base_do = base_dm, base_dc, \
-            base_do
+        from emqx_tpu.models.router_engine import WindowPlan
+        plan = _CachePlan(
+            WindowPlan(miss_topics, miss_lens, miss_dollar, base_m, base_c,
+                       base_o, miss_pos,
+                       inv.reshape(Wp, Bp).astype(np.int32)),
+            (base_dm, base_dc, base_do) if ov is not None else None,
+            Bm, n_miss, n_hit)
         # telemetry is recorded ONLY for engaged plans, so the exported
         # dedup ratio / hit rate describe match work actually removed
         # from dispatches — not lookups whose window went plain (those
@@ -2095,7 +2069,7 @@ class DeviceRouteEngine:
         scan the trie NFA's step as they do the shape hash's)."""
         W, Bp = self._STD_CLASSES[-1]
         if self._built is None \
-                or (self._cur_sig, W, Bp) not in self._warm_classes:
+                or self._class_of(W, Bp) not in self._warm_classes:
             return 1
         return W
 
@@ -2124,15 +2098,20 @@ class DeviceRouteEngine:
         if self._built is None:
             return False
         Bp = self._batch_class(n_msgs)
-        if (self._cur_sig, 1, Bp) in self._warm_classes:
+        c = self._class_of(1, Bp)
+        if c in self._warm_classes:
             return True
         if Bp > self._STD_CLASSES[-1][1]:
             # oversized batch class (max_publish_batch > 1024): queue it
             # for the background warm, or it would be locked out forever
-            self._extra_classes.add((1, Bp))
+            self._wanted.add(c)
         return False
 
     _STD_CLASSES = ((1, 64), (1, 256), (1, 1024), (8, 1024))
+
+    def _std_classes(self, sig: tuple) -> list:
+        """The standard ladder as classes of the snapshot signed `sig`."""
+        return [_WindowClass(sig, W, Bp) for W, Bp in self._STD_CLASSES]
 
     # payload classes are multiples of the batch class Bp (entries per
     # message budget): 8 covers trickle fan-out, 32 the fan-out ≤ ~10
@@ -2193,218 +2172,143 @@ class DeviceRouteEngine:
         pcap = self._choose_payload_cap(Bp)
         if pcap is None:
             return None
-        dsuf = (f"d{ov.cap}",) if ov is not None else ()
-        key = (self._cur_sig, Wp, Bp) \
-            + ((plan.Bm,) if plan is not None else ()) + dsuf \
-            + (f"c{pcap}",)
-        if gate_cold and key not in self._warm_classes:
-            # same policy as the cached ladder: a cold compact class
-            # would stall serving on an in-path XLA compile — dispatch
-            # with the dense readback and let the background warm bring
-            # the class online
-            self._wanted_compact.add(
-                (Wp, Bp, plan.Bm if plan is not None else None, pcap,
-                 ov.cap if ov is not None else None))
-            self._kick_class_warm()
-            self.node.metrics.inc("routing.device.cold_compact_class")
+        if self._cold(self._class_of(Wp, Bp, plan, ov, pcap), gate_cold,
+                      "routing.device.cold_compact_class"):
             return None
         return pcap
 
-    @staticmethod
-    def _class_key(sig, Wp, Bp, Bm=None, dC=None, P=None) -> tuple:
-        """The one warm-class key layout: (sig, W, Bp[, Bm][, dN][, cP])
-        — dedup miss class, delta-overlay row class and compact payload
-        class are each optional program dimensions."""
-        return ((sig, Wp, Bp)
-                + ((Bm,) if Bm is not None else ())
-                + ((f"d{dC}",) if dC is not None else ())
-                + ((f"c{P}",) if P is not None else ()))
+    def _class_of(self, Wp: int, Bp: int, plan=None,
+                  ov: Optional[_Overlay] = None,
+                  P: Optional[int] = None) -> _WindowClass:
+        """The class of the CURRENT snapshot that a (Wp, Bp) window
+        with these stages (a `_CachePlan`, the pinned `_Overlay`, a
+        payload class) dispatches into."""
+        return _WindowClass(self._cur_sig, Wp, Bp,
+                            plan.Bm if plan is not None else None,
+                            ov.cap if ov is not None else None, P)
+
+    def _cold(self, c: _WindowClass, gate_cold: bool, counter: str) -> bool:
+        """True where dispatching class `c` would stall the serving
+        path on an in-path XLA compile: the window then runs without
+        the stage that asked (its warm fallback: the plain match, the
+        host delta trie, the dense readback), counted under `counter`,
+        and the class is registered for the background warm to bring
+        online (same policy as batch_class_warm)."""
+        if not gate_cold or c in self._warm_classes:
+            return False
+        self._wanted.add(c)
+        self._kick_class_warm()
+        self.node.metrics.inc(counter)
+        return True
+
+    def _window_call(self, c: _WindowClass, b: _Built, live=None) -> tuple:
+        """`route_window`'s arguments after (tables, cursors), and its
+        statics, for class `c` of snapshot `b`: from a live window
+        (`live` = (handle, msg_hash, strategy)) or, for a warm pass or
+        a probe, zero-filled of the class's shapes (all that matters
+        to the trace). The one place that knows how a class maps onto
+        the program, and the rule that goes with it: numpy and device
+        arguments do not share a jit fast-path entry, so a dummy is a
+        device array exactly where the live path passes one (the
+        overlay's tables, `device_put` by _overlay_sync_inner) and
+        numpy everywhere else, or the first live window of the class
+        would re-trace in-path."""
+        from emqx_tpu.models.router_engine import WindowDelta, WindowPlan
+        from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
+        L = self.max_levels
+        if live is not None:
+            h, msg_hash, strat = live
+            lanes = h.enc
+            plan, dbase = (h.plan.dev, h.plan.dbase) \
+                if h.plan is not None else (None, None)
+            dev = h.delta.dev if h.delta is not None else None
+        else:
+            lanes = (np.zeros((c.W, c.Bp, L), np.int32),
+                     np.zeros((c.W, c.Bp), np.int32),
+                     np.zeros((c.W, c.Bp), bool))
+            msg_hash = np.zeros((c.W, c.Bp), np.int32)
+            strat = np.int32(STRATEGY_ROUND_ROBIN)
+            plan = dbase = dev = None
+            if c.Bm is not None:
+                plan = WindowPlan(
+                    np.full((c.Bm, L), I.PAD, np.int32),
+                    np.zeros(c.Bm, np.int32), np.zeros(c.Bm, bool),
+                    np.full((c.Bp, b.match_width), -1, np.int32),
+                    np.zeros(c.Bp, np.int32), np.zeros(c.Bp, bool),
+                    np.full(c.Bm, c.Bp, np.int32),   # pad = Bp: dropped
+                    np.zeros((c.W, c.Bp), np.int32))
+                if c.dC is not None:
+                    dbase = (np.full((c.Bp, _DELTA_MATCH_CAP), -1, np.int32),
+                             np.zeros(c.Bp, np.int32), np.zeros(c.Bp, bool))
+            if c.dC is not None:
+                from emqx_tpu.ops.delta import empty_delta_tables
+                # an all-empty table of the row class is the cheapest
+                # valid instance
+                # hbm: transient — freed when the call it feeds returns
+                dev = jax.device_put(empty_delta_tables(
+                    c.dC, L, fan_per_row=_DELTA_FAN_PER_ROW))
+        if plan is not None:
+            lanes = (None, None, None)      # the plan holds the lanes
+        kw = self._caps_kw(b.backend)
+        delta = None
+        if dev is not None:
+            delta = WindowDelta(dev, dbase)
+            kw.update(delta_match_cap=_DELTA_MATCH_CAP,
+                      delta_fanout_cap=_DELTA_FANOUT_CAP)
+        if c.P is not None:
+            kw["payload_cap"] = c.P
+            if dev is not None:
+                kw["d_payload_cap"] = self._delta_payload_cap(c.Bp)
+        return lanes + (msg_hash, strat, plan, delta), kw
+
+    def _run_window(self, c: _WindowClass, b: _Built, tables, cursors,
+                    live=None):
+        """One call of the route window program for class `c`: a live
+        window's (`_dispatch_inner`), or the class's zero-filled one
+        (the warm passes, the dispatch probe)."""
+        from emqx_tpu.models.router_engine import route_window
+        args, kw = self._window_call(c, b, live)
+        return route_window(tables, cursors, *args, **kw)
+
+    def _warm_class(self, c: _WindowClass, b: _Built, tables,
+                    cursors) -> None:
+        """Compile class `c` off the serving path, under its `warm`
+        compile-context label."""
+        tele = getattr(self.node, "pipeline_telemetry", None)
+        ctx = tele.compile_context(f"warm {c.label}") \
+            if tele is not None else contextlib.nullcontext()
+        with ctx:
+            r = self._run_window(c, b, tables, cursors)
+            jax.block_until_ready(r.match_counts)
+            self._last_cursors(r)
 
     def _kick_class_warm(self) -> None:
         """Warm every standard (W, Bp) class AND every demand-registered
-        cached / delta-overlay / compact program class the CURRENT
-        snapshot is missing, off the serving path. Re-kicks after a
-        failure and after any swap to unwarmed capacity classes. Both
-        backends alike: the gates hold each program variant back until
-        its class is warm."""
+        class (a plan's, an overlay's, a payload's, an oversized
+        batch's) the CURRENT snapshot is missing, off the serving path.
+        Re-kicks after a failure and after any swap to unwarmed
+        capacity classes. Both backends alike: the gates hold each
+        stage back until its class is warm."""
         import asyncio
         if self._fuse_warm_task is not None or self._built is None:
             return
-        backend = self._built.backend
-        ck = self._class_key
-        wanted = self._STD_CLASSES + tuple(sorted(self._extra_classes))
-        missing = [(W, Bp) for W, Bp in wanted
-                   if (self._cur_sig, W, Bp) not in self._warm_classes]
-        delta_missing = [
-            e for e in sorted(self._wanted_delta)
-            if ck(self._cur_sig, e[0], e[1], dC=e[2])
-            not in self._warm_classes]
-        cached_missing = [
-            e for e in sorted(self._wanted_cached,
-                              key=lambda e: (e[0], e[1], e[2], e[3] or 0))
-            if ck(self._cur_sig, e[0], e[1], Bm=e[2], dC=e[3])
-            not in self._warm_classes]
-        compact_missing = [
-            e for e in sorted(self._wanted_compact,
-                              key=lambda e: (e[0], e[1], e[2] or 0, e[3],
-                                             e[4] or 0))
-            if ck(self._cur_sig, e[0], e[1], Bm=e[2], dC=e[4], P=e[3])
-            not in self._warm_classes]
-        if not missing and not delta_missing and not cached_missing \
-                and not compact_missing:
+        sig = self._cur_sig
+        wanted = self._std_classes(sig) \
+            + sorted((c for c in self._wanted if c.sig == sig),
+                     key=lambda c: c.warm_order)
+        missing = [c for c in wanted if c not in self._warm_classes]
+        if not missing:
             return
         try:
             loop = asyncio.get_running_loop()
         except RuntimeError:
             return
-        tables, cursors = self._tables, self._cursors
-        match_width = self._built.match_width
-        sig = self._cur_sig
-
-        tele = getattr(self.node, "pipeline_telemetry", None)
+        b, tables, cursors = self._built, self._tables, self._cursors
 
         def warm():
-            import contextlib
-
-            import jax
-
-            from emqx_tpu.models.router_engine import (
-                route_window_cached, route_window_delta,
-                route_window_delta_cached, route_window_full)
-            from emqx_tpu.ops.delta import empty_delta_tables
-            from emqx_tpu.ops.shared import STRATEGY_ROUND_ROBIN
-            strat = np.int32(STRATEGY_ROUND_ROBIN)
-            rt = self._rt
-            caps = self._caps_kw(backend)
-            dcaps = dict(delta_match_cap=_DELTA_MATCH_CAP,
-                         delta_fanout_cap=_DELTA_FANOUT_CAP)
-
-            def wc():
-                # fresh throwaway cursors per program call: the
-                # donating twins consume their input (_warm_cursors)
-                return self._warm_cursors(cursors)
-
-            def dummy_delta(dC):
-                # shapes are all that matter for the trace; an all-empty
-                # table of the class is the cheapest valid instance.
-                # device_put like the live overlay (_refresh_overlay):
-                # numpy and device arguments do not share a jit
-                # fast-path entry, so a numpy dummy would leave the
-                # first live dispatch of the class a re-trace in-path
-                # hbm: transient — freed when the warm call it feeds returns
-                return jax.device_put(empty_delta_tables(
-                    dC, self.max_levels, fan_per_row=_DELTA_FAN_PER_ROW))
-
-            def ctx_of(label):
-                return tele.compile_context(label) if tele is not None \
-                    else contextlib.nullcontext()
-
-            for Wp, Bp in missing:
-                enc = np.zeros((Wp, Bp, self.max_levels), np.int32)
-                z = np.zeros((Wp, Bp), np.int32)
-                zb = np.zeros((Wp, Bp), bool)
-                with ctx_of(f"warm W{Wp}xB{Bp}"):
-                    r = rt(route_window_full)(
-                        tables, wc(), enc, z, zb, z, strat, **caps)
-                    jax.block_until_ready(r.match_counts)
-                    self._last_cursors(r)
-                self._warm_classes.add((sig, Wp, Bp))
-            # demand-driven delta-overlay classes (ISSUE 4): each
-            # (W, Bp, dC) is one fused program; the serving path keeps
-            # the host delta fallback until its class lands here
-            for Wp, Bp, dC in delta_missing:
-                dt = dummy_delta(dC)
-                enc = np.zeros((Wp, Bp, self.max_levels), np.int32)
-                z = np.zeros((Wp, Bp), np.int32)
-                zb = np.zeros((Wp, Bp), bool)
-                with ctx_of(f"warm W{Wp}xB{Bp}d{dC}"):
-                    r = rt(route_window_delta)(
-                        tables, dt, wc(), enc, z, zb, z, strat,
-                        **caps, **dcaps)
-                    jax.block_until_ready(r.res.match_counts)
-                self._warm_classes.add(ck(sig, Wp, Bp, dC=dC))
-            # demand-driven cached-dispatch classes: the serving path
-            # registered every (W, Bp, Bm[, dC]) a dedup plan wanted and
-            # fell back to the plain program meanwhile
-            for Wp, Bp, Bm, dC in cached_missing:
-                args = (np.full((Bm, self.max_levels), I.PAD, np.int32),
-                        np.zeros(Bm, np.int32), np.zeros(Bm, bool),
-                        np.full((Bp, match_width), -1, np.int32),
-                        np.zeros(Bp, np.int32), np.zeros(Bp, bool))
-                dargs = () if dC is None else (
-                    np.full((Bp, _DELTA_MATCH_CAP), -1, np.int32),
-                    np.zeros(Bp, np.int32), np.zeros(Bp, bool))
-                pos = (np.full(Bm, Bp, np.int32),)   # pad = Bp: dropped
-                label = f"warm W{Wp}xB{Bp}mB{Bm}" \
-                    + (f"d{dC}" if dC is not None else "")
-                inv = np.zeros((Wp, Bp), np.int32)
-                mh = np.zeros((Wp, Bp), np.int32)
-                with ctx_of(label):
-                    if dC is None:
-                        r = rt(route_window_cached)(
-                            tables, wc(), *args, *pos, inv, mh, strat,
-                            **caps)
-                    else:
-                        r = rt(route_window_delta_cached)(
-                            tables, dummy_delta(dC), wc(), *args,
-                            *dargs, *pos, inv, mh, strat, **caps,
-                            **dcaps).res
-                    jax.block_until_ready(r.match_counts)
-                self._warm_classes.add(ck(sig, Wp, Bp, Bm=Bm, dC=dC))
-            # demand-driven compact-readback classes (ISSUE 3): each
-            # (W, Bp[, Bm][, dC], P) is one program; the serving path
-            # reads back dense until its class lands here
-            from emqx_tpu.models.router_engine import (
-                route_window_cached_compact, route_window_delta_compact,
-                route_window_delta_cached_compact,
-                route_window_full_compact)
-            for Wp, Bp, Bm, P, dC in compact_missing:
-                label = f"warm W{Wp}xB{Bp}" \
-                    + (f"mB{Bm}" if Bm is not None else "") \
-                    + (f"d{dC}" if dC is not None else "") + f"c{P}"
-                kw = dict(caps, payload_cap=P)
-                dkw = dict(dcaps,
-                           d_payload_cap=self._delta_payload_cap(Bp))
-                with ctx_of(label):
-                    if Bm is None:
-                        enc = np.zeros((Wp, Bp, self.max_levels),
-                                       np.int32)
-                        z = np.zeros((Wp, Bp), np.int32)
-                        zb = np.zeros((Wp, Bp), bool)
-                        if dC is None:
-                            r = rt(route_window_full_compact)(
-                                tables, wc(), enc, z, zb, z, strat, **kw)
-                        else:
-                            r = rt(route_window_delta_compact)(
-                                tables, dummy_delta(dC), wc(), enc, z,
-                                zb, z, strat, **kw, **dkw)
-                    else:
-                        args = (np.full((Bm, self.max_levels), I.PAD,
-                                        np.int32),
-                                np.zeros(Bm, np.int32),
-                                np.zeros(Bm, bool),
-                                np.full((Bp, match_width), -1, np.int32),
-                                np.zeros(Bp, np.int32),
-                                np.zeros(Bp, bool))
-                        dargs = () if dC is None else (
-                            np.full((Bp, _DELTA_MATCH_CAP), -1,
-                                    np.int32),
-                            np.zeros(Bp, np.int32), np.zeros(Bp, bool))
-                        pos = (np.full(Bm, Bp, np.int32),)
-                        inv = np.zeros((Wp, Bp), np.int32)
-                        mh = np.zeros((Wp, Bp), np.int32)
-                        if dC is None:
-                            r = rt(route_window_cached_compact)(
-                                tables, wc(), *args, *pos, inv, mh,
-                                strat, **kw)
-                        else:
-                            r = rt(route_window_delta_cached_compact)(
-                                tables, dummy_delta(dC), wc(), *args,
-                                *dargs, *pos, inv, mh, strat, **kw,
-                                **dkw)
-                    jax.block_until_ready(r.compact.offsets)
-                self._warm_classes.add(
-                    ck(sig, Wp, Bp, Bm=Bm, dC=dC, P=P))
+            for c in missing:
+                self._warm_class(c, b, tables, cursors)
+                self._warm_classes.add(c)
 
         async def run():
             try:
@@ -2422,7 +2326,6 @@ class DeviceRouteEngine:
         self._fuse_warm_task = guard_task(loop.create_task(run()),
                                           "device-class-warm",
                                           self.node.metrics)
-
 
     def preencode_burst(self, topics: list) -> None:
         """ISSUE 11: intern a read burst's topics in ONE vectorized
@@ -2461,7 +2364,7 @@ class DeviceRouteEngine:
     def prepare_window(self, lives: list[list[Message]],
                        gate_cold: bool = True):
         """Stage 1 (event loop): encode 1..W micro-batches as one fused
-        dispatch window (models.router_engine.route_window_full). The
+        dispatch window (models.router_engine.route_window). The
         per-dispatch cost — dominant on high-latency links — is paid
         once for the whole window. When dedup is on, the window is also
         compacted to unique topics + match-cache hits (_plan_window) so
@@ -2590,39 +2493,7 @@ class DeviceRouteEngine:
         except Exception:  # noqa: BLE001 — no session to stop
             pass
 
-    # ---- ISSUE 9: donation + async readback helpers ---------------------
-    def _rt(self, fn):
-        """The serving-path variant of a fused route program: at
-        dispatch_depth >= 2 the cursors slot is DONATED (the ping-pong
-        cursor buffers reuse HBM across windows; the output is
-        re-adopted under the snapshot identity guard in
-        _dispatch_inner). Depth 1 returns the plain program — the
-        pre-ISSUE-9 jit cache, bit-exact. The warm passes resolve
-        through this SAME chooser, so the program a class warms is the
-        program the serving path dispatches."""
-        if not self._pipelined:
-            return fn
-        from emqx_tpu.models.router_engine import donating
-        return donating(fn)
-
-    def _warm_cursors(self, cursors):
-        """Cursors argument for off-serving-path calls (class warms,
-        pre-swap warms): at dispatch_depth >= 2 the serving programs
-        donate their cursors slot, so a warm must never hand over a
-        live buffer — it passes a throwaway device_put zeros of the
-        same shape instead. Device-array inputs share the jit-cache
-        entry with the serving call's (device_put arrays and jit
-        outputs key identically; numpy inputs do NOT — measured), so
-        the warm still covers the serving class. Depth 1 passes the
-        live cursors through untouched, pre-ISSUE-9 exact. Reading
-        .shape is safe even when a racing dispatch already donated the
-        buffer away (aval metadata survives deletion)."""
-        if not self._pipelined:
-            return cursors
-        import jax
-        # hbm: transient — donated away by the warm call it feeds
-        return jax.device_put(np.zeros(cursors.shape, np.int32))
-
+    # ---- ISSUE 9: async readback helpers -------------------------------
     def _readback_planes(self, h) -> list:
         """The device arrays materialize will transfer for this handle
         — exactly those, so the async start never wastes link bandwidth
@@ -2663,8 +2534,7 @@ class DeviceRouteEngine:
         keep the synchronous transfer in materialize — the prefetch is
         an overlap optimization, never a correctness input."""
         if self.ledger is not None:
-            self._hold("pipeline_buffers",
-                       (h.res, h.cres, h.dres, h.dcres))
+            self._hold("pipeline_buffers", h.res)
         for a in self._readback_planes(h):
             try:
                 a.copy_to_host_async()
@@ -2729,8 +2599,8 @@ class DeviceRouteEngine:
         return [(id(m) >> 4) & 0x7FFFFFFF for m in msgs]  # random
 
     def _dispatch_inner(self, h) -> None:
-        """Select + run the fused program for this window: the plain
-        step/window, with up to three optional fused dimensions — dedup
+        """Run the route window program for this window's class: the
+        plain window, with up to three optional fused stages — dedup
         plan (ISSUE 2), CSR readback (ISSUE 3), delta overlay
         (ISSUE 4) — each independently warm-gated at prepare."""
         if self.sup is not None:
@@ -2739,7 +2609,6 @@ class DeviceRouteEngine:
             # window host-side and advances the dispatch breaker; a hang
             # is caught by the consumer's watchdog deadline
             self.sup.fire("dispatch")
-        from emqx_tpu.models import router_engine as RE
         from emqx_tpu.ops.shared import (STRATEGIES, STRATEGY_ROUND_ROBIN)
         broker = self.broker
         # pin the table/cursor pair ONCE for this whole dispatch: a
@@ -2750,80 +2619,26 @@ class DeviceRouteEngine:
         # identity guard at the end, mirroring the mesh's `_builts is
         # h.built` discipline in parallel/serving.py)
         tables, cursors = self._tables, self._cursors
-        sig = self._cur_sig
         enc4, len4, dol4 = h.enc
         Wp, Bp = enc4.shape[0], enc4.shape[1]
+        c = self._class_of(Wp, Bp, h.plan, h.delta, h.pcap)
         strat_id = STRATEGIES.get(broker.shared_strategy,
                                   STRATEGY_ROUND_ROBIN)
         msg_hash = np.zeros((Wp, Bp), np.int32)
         for k, (msgs, _w, _t) in enumerate(h.subs):
             msg_hash[k, :len(msgs)] = self._msg_hashes(msgs, strat_id)
-        strat = np.int32(strat_id)
-        p, P, ov = h.plan, h.pcap, h.delta
-        dC = ov.cap if ov is not None else None
-        kw = self._caps_kw(h.built.backend)
-        dkw = {} if ov is None else dict(
-            delta_match_cap=_DELTA_MATCH_CAP,
-            delta_fanout_cap=_DELTA_FANOUT_CAP)
-        ckw = {} if P is None else dict(payload_cap=P)
-        if P is not None and ov is not None:
-            ckw["d_payload_cap"] = self._delta_payload_cap(Bp)
-
-        if p is not None:
-            # deduplicated dispatch: match only the miss lanes, merge
-            # with the cache-hit base rows, scatter back to window width
-            # before the cursor-dependent post stage
-            base = (p.miss_topics, p.miss_lens, p.miss_dollar,
-                    p.base_m, p.base_c, p.base_o)
-            dbase = () if ov is None else (p.base_dm, p.base_dc,
-                                           p.base_do)
-            tail = (p.miss_pos, p.inv, msg_hash, strat)
-            if ov is not None:
-                fn = RE.route_window_delta_cached_compact \
-                    if P is not None else RE.route_window_delta_cached
-                out = self._rt(fn)(tables, ov.dev, cursors, *base,
-                                   *dbase, *tail, **kw, **dkw, **ckw)
-            else:
-                fn = RE.route_window_cached_compact if P is not None \
-                    else RE.route_window_cached
-                out = self._rt(fn)(tables, cursors, *base, *tail,
-                                   **kw, **ckw)
+        res = self._run_window(c, h.built, tables, cursors,
+                               live=(h, msg_hash, np.int32(strat_id)))
+        if h.plan is not None:
+            # deduplicated dispatch: matched the miss lanes only, merged
+            # with the cache-hit base rows, scattered back to window
+            # width before the cursor-dependent post stage
             self.node.metrics.inc("routing.device.cached_windows")
-            warm_key = self._class_key(sig, Wp, Bp, Bm=p.Bm,
-                                       dC=dC, P=P)
-        else:
-            args4 = (enc4, len4, dol4, msg_hash)
-            if ov is not None:
-                fn = RE.route_window_delta_compact if P is not None \
-                    else RE.route_window_delta
-                out = self._rt(fn)(tables, ov.dev, cursors, *args4,
-                                   strat, **kw, **dkw, **ckw)
-            else:
-                fn = RE.route_window_full_compact if P is not None \
-                    else RE.route_window_full
-                out = self._rt(fn)(tables, cursors, *args4, strat,
-                                   **kw, **ckw)
-            warm_key = self._class_key(sig, Wp, Bp, dC=dC,
-                                       P=P)
-
-        # unwrap the result family; every variant is window-shaped
-        if isinstance(out, RE.CompactDeltaRouteResult):
-            res = out.dres.res
-            h.dres = out.dres.dp
-            h.cres = out.compact
-            h.dcres = out.d_compact
-        elif isinstance(out, RE.DeltaRouteResult):
-            res = out.res
-            h.dres = out.dp
-        elif isinstance(out, RE.CompactRouteResult):
-            res = out.res
-            h.cres = out.compact
-        else:
-            res = out
+        h.dres, h.cres, h.dcres = res.delta, res.compact, res.d_compact
         if self._tables is tables:   # no swap raced this dispatch
             self._cursors = self._hold("snapshot_cursors",
                                        self._last_cursors(res))
-        self._warm_classes.add(warm_key)
+        self._warm_classes.add(c)
         h.res = res
 
     @staticmethod
@@ -3469,17 +3284,7 @@ class DeviceRouteEngine:
 
     def abandon(self, h) -> None:
         """Release a handle ENTIRELY (error path: the caller falls back
-        to the host route for every remaining sub-batch). Idempotent.
-
-        At dispatch_depth >= 2 the failed dispatch may have DONATED the
-        live cursors buffer before dying (jax invalidates donated
-        inputs at call time, success or not) and the adoption at the
-        end of _dispatch_inner never ran — without a reseed every
-        subsequent device dispatch would hit 'Array has been deleted'
-        until a snapshot swap happened to replace _cursors, permanently
-        degrading a static-subscription node to the host rung. The
-        reseed costs one round-robin fairness reset (same class of blip
-        as a swap racing a dispatch), never correctness."""
+        to the host route for every remaining sub-batch). Idempotent."""
         if h is not None and h.built is not None:
             h.refs = 0
             h.built = None
@@ -3488,19 +3293,6 @@ class DeviceRouteEngine:
                 self.ledger.unpin(id(h))
             if self._building:
                 self._try_swap()
-        if self._pipelined:
-            cur = self._cursors
-            try:
-                deleted = cur is not None and cur.is_deleted()
-            except Exception:  # noqa: BLE001 — non-jax placeholder
-                deleted = False
-            if deleted:
-                import jax
-                self._cursors = self._hold(
-                    "snapshot_cursors",
-                    # hbm: reseed — the donating call consumed the
-                    # buffer and the failure path skipped adoption
-                    jax.device_put(np.zeros(cur.shape, np.int32)))
 
     def route_batch(self, msgs: list[Message]) -> Optional[list[int]]:
         """Route+deliver a micro-batch through the fused device step,

@@ -70,7 +70,7 @@ class Node:
             perf.get("rebuild_threshold"))
         # double-buffered window pipeline depth (ISSUE 9): one
         # resolution shared by the batcher's settle ring and both
-        # engines' donation/async-readback gates. broker.dispatch_depth
+        # engines' async-readback gates. broker.dispatch_depth
         # / EMQX_TPU_DISPATCH_DEPTH, config beats env beats default 2;
         # =1 restores the synchronous pre-ISSUE-9 loop exactly.
         from emqx_tpu.broker.batcher import resolve_dispatch_depth

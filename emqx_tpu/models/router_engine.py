@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from emqx_tpu.ops.compact import CompactPlanes, compact_result
 from emqx_tpu.ops.delta import (DeltaPlanes, DeltaTables, delta_expand,
                                 delta_match)
 from emqx_tpu.ops.fanout import FanoutResult, SubTable, fanout_normal, shared_slots
@@ -66,9 +67,40 @@ class RouteResult(NamedTuple):
     match_overflow: jax.Array = None
     # trie programs only: level steps of the sub-batch's NFA walk that
     # ran at `frontier_cap` (`MatchResult.wide_steps`; 0 for a padding
-    # sub-batch, whose walk is skipped). [W] from a window program,
-    # whose cached form walks once and reports it in row 0
+    # sub-batch, whose walk is skipped). [W] from a window program; a
+    # window with a plan walks once and reports it in row 0
     nfa_wide_steps: jax.Array = None
+    # `route_window`'s optional stages, None where the stage did not
+    # run. The fid spaces of `matches` (built-snapshot fids) and
+    # `delta.fids` (the engine's delta fids) are disjoint by
+    # construction: the host consume walks both, so a filter subscribed
+    # one window ago delivers from THIS dispatch (ISSUE 4)
+    delta: DeltaPlanes = None         # overlay planes, each [W, B, ...]
+    compact: CompactPlanes = None     # CSR of the main planes
+    d_compact: CompactPlanes = None   # CSR of `delta`'s
+
+
+class WindowPlan(NamedTuple):
+    """The match cache's plan for one DEDUPLICATED window, as
+    `route_window` takes it: every lane of the [W, B] window is a
+    duplicate of a miss lane, a cache hit served from the base rows, or
+    padding collapsed onto the shared sentinel row."""
+    miss_topics: jax.Array    # [Bm, L] the compacted miss lanes
+    miss_lens: jax.Array      # [Bm]
+    miss_dollar: jax.Array    # [Bm]
+    base_matches: jax.Array   # [B, M] per-unique-topic rows: cache hits
+    base_counts: jax.Array    # [B]    filled by the host, the rest empty
+    base_overflow: jax.Array  # [B]
+    miss_pos: jax.Array       # [Bm] unique row of each miss lane (pad = B)
+    inv: jax.Array            # [W, B] unique row of each window lane
+
+
+class WindowDelta(NamedTuple):
+    """The delta overlay one window fuses: its tables and, under a
+    plan, the cache hits' overlay base rows (overlay ROW indices,
+    counts, MATCH-level overflow; each [B, ...])."""
+    tables: DeltaTables
+    base: tuple = None
 
 
 class ExchangeAux(NamedTuple):
@@ -181,170 +213,50 @@ def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
 
 def _match_stage(tables, topics: jax.Array, lens: jax.Array,
                  is_dollar: jax.Array, *, frontier_cap: int,
-                 match_cap: int) -> MatchResult:
+                 match_cap: int, sub_batch: bool = False) -> MatchResult:
     """The backend's matcher over [B] lanes: the trie NFA for
-    `RouterTables` (the caps are its own), one bucket gather per shape
-    for `ShapeRouterTables` (which takes no cap)."""
+    `RouterTables` (the caps are its own; `sub_batch` says the lanes are
+    one sub-batch of a padded window, which may hold no topic), one
+    bucket gather per shape for `ShapeRouterTables` (which takes no
+    cap)."""
     if _is_trie(tables):
-        return match_batch(tables.trie, topics, lens, is_dollar,
-                           frontier_cap=frontier_cap, match_cap=match_cap)
+        nfa = _nfa_unless_padding if sub_batch else match_batch
+        return nfa(tables.trie, topics, lens, is_dollar,
+                   frontier_cap=frontier_cap, match_cap=match_cap)
     return shape_match(tables.shapes, topics, lens, is_dollar)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap"))
-def route_window_cached(tables, cursors: jax.Array,
-                        miss_topics: jax.Array, miss_lens: jax.Array,
-                        miss_dollar: jax.Array, base_matches: jax.Array,
-                        base_counts: jax.Array, base_overflow: jax.Array,
-                        miss_pos: jax.Array, inv: jax.Array,
-                        msg_hash: jax.Array, strategy: jax.Array, *,
-                        frontier_cap: int = 16, match_cap: int = 64,
-                        fanout_cap: int = 128,
-                        slot_cap: int = 16) -> RouteResult:
-    """Window step over a DEDUPLICATED window with cached rows (either
-    backend: `_match_stage`).
+def _compact_stage(r: RouteResult, dp, payload_cap: int,
+                   d_payload_cap, match_holes: bool) -> tuple:
+    """The fused CSR readback (ops.compact) of a window's main planes
+    and, where the overlay ran, of its delta planes: (compact,
+    d_compact). The dense planes stay in the result as free outputs of
+    the same program; the host reads them back only when a CSR's
+    `row_overflow` fires (payload class too small for this window), so
+    the dense fallback needs no re-dispatch.
 
-    One dispatch routes W sub-batches while the match runs
-    ONCE over the [Bm] compacted miss lanes (every other lane of the
-    [W, B] window is either a duplicate of a miss lane, a cache hit
-    served from base_* rows, or padding collapsed onto the shared
-    sentinel row). `inv` [W, B] gathers the merged unique rows back to
-    full window width per scan step; cursors thread through the scan
-    exactly as W sequential `route_step_shapes` / `route_step` calls, so
-    the stacked RouteResult is bit-identical to `route_window_full` on
-    the same window (oracle-tested)."""
-    with jax.named_scope("match"):
-        mr = _match_stage(tables, miss_topics, miss_lens, miss_dollar,
-                          frontier_cap=frontier_cap, match_cap=match_cap)
-        um = merge_match_results(base_matches, base_counts,
-                                 base_overflow, mr, miss_pos)
-
-    def step(cur, xs):
-        inv_k, mh_k = xs
-        with jax.named_scope("match"):
-            full = MatchResult(matches=um.matches[inv_k],
-                               counts=um.counts[inv_k],
-                               overflow=um.overflow[inv_k])
-        r = post_match(tables.subs, full, cur, mh_k, strategy,
-                       fanout_cap=fanout_cap, slot_cap=slot_cap)
-        return r.new_cursors, r
-
-    with jax.named_scope("scan"):
-        _, stacked = jax.lax.scan(step, cursors, (inv, msg_hash))
-    if _is_trie(tables):
-        stacked = stacked._replace(nfa_wide_steps=jnp.zeros(
-            inv.shape[0], jnp.int32).at[0].set(mr.wide_steps))
-    return stacked
-
-
-class CompactRouteResult(NamedTuple):
-    """A route result with its fused CSR readback (ops.compact).
-
-    `res` carries the FULL window-stacked dense planes — they are
-    intermediates of the same program, so returning them costs nothing;
-    the host reads them back only when `compact.row_overflow` fires
-    (payload class too small for this window) — the dense fallback needs
-    no re-dispatch. Every per-topic plane in `res` is window-shaped
-    ([W, ...]): a single batch is a window of W = 1."""
-    res: RouteResult
-    compact: "CompactPlanes"  # noqa: F821 — imported lazily below
-
-
-def _with_compact(r: RouteResult, payload_cap: int,
-                  match_holes: bool) -> CompactRouteResult:
-    """match_holes=True for the shape-hash backend (matches carry
+    match_holes=True for the shape-hash backend (matches carry
     interior holes at unmatched shape slots), False for the trie NFA
-    (emissions are densely packed already — the hole-closing stage
-    compiles away): a window program reads it off its tables' type."""
-    from emqx_tpu.ops.compact import compact_result
+    (emissions are densely packed already, the hole-closing stage
+    compiles away). The delta family reuses `compact_result` with a
+    width-1 all-empty shared family (cs == 0 in every row), so
+    `csr_slices` decodes both with one code path; delta matches are
+    always prefix-compacted."""
     with jax.named_scope("compact"):
         cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
                             r.shared_sids, r.shared_rows, r.shared_opts,
                             payload_cap=payload_cap,
                             match_holes=match_holes)
-    return CompactRouteResult(res=r, compact=cp)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("frontier_cap", "match_cap",
-                                    "fanout_cap", "slot_cap",
-                                    "payload_cap"))
-def route_window_full_compact(tables, cursors: jax.Array,
-                              topics: jax.Array,
-                              lens: jax.Array, is_dollar: jax.Array,
-                              msg_hash: jax.Array, strategy: jax.Array,
-                              *, frontier_cap: int = 16,
-                              match_cap: int = 64,
-                              fanout_cap: int = 128,
-                              slot_cap: int = 16,
-                              payload_cap: int = 4096
-                              ) -> CompactRouteResult:
-    """route_window_full + fused CSR readback in the same dispatch."""
-    r = route_window_full(tables, cursors, topics, lens, is_dollar,
-                          msg_hash, strategy, frontier_cap=frontier_cap,
-                          match_cap=match_cap, fanout_cap=fanout_cap,
-                          slot_cap=slot_cap)
-    return _with_compact(r, payload_cap, match_holes=not _is_trie(tables))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("frontier_cap", "match_cap",
-                                    "fanout_cap", "slot_cap",
-                                    "payload_cap"))
-def route_window_cached_compact(tables, cursors: jax.Array,
-                                miss_topics: jax.Array,
-                                miss_lens: jax.Array,
-                                miss_dollar: jax.Array,
-                                base_matches: jax.Array,
-                                base_counts: jax.Array,
-                                base_overflow: jax.Array,
-                                miss_pos: jax.Array, inv: jax.Array,
-                                msg_hash: jax.Array,
-                                strategy: jax.Array, *,
-                                frontier_cap: int = 16,
-                                match_cap: int = 64,
-                                fanout_cap: int = 128,
-                                slot_cap: int = 16,
-                                payload_cap: int = 4096
-                                ) -> CompactRouteResult:
-    """route_window_cached + fused CSR readback in the same dispatch."""
-    r = route_window_cached(tables, cursors, miss_topics, miss_lens,
-                            miss_dollar, base_matches, base_counts,
-                            base_overflow, miss_pos, inv, msg_hash,
-                            strategy, frontier_cap=frontier_cap,
-                            match_cap=match_cap, fanout_cap=fanout_cap,
-                            slot_cap=slot_cap)
-    return _with_compact(r, payload_cap, match_holes=not _is_trie(tables))
-
-
-class DeltaRouteResult(NamedTuple):
-    """A route result with its fused delta-overlay planes (ops.delta).
-
-    `res` is the main-snapshot RouteResult, window-shaped [W, ...];
-    `dp` carries the overlay's match + fan-out planes, each
-    [W, B, ...]. The two fid spaces are disjoint by construction:
-    `res.matches` are built-snapshot fids, `dp.fids` are the engine's
-    delta fids — the host consume walks both, so a filter subscribed
-    one window ago delivers from THIS dispatch instead of host-routing
-    (the churn hole ISSUE 4 closes)."""
-    res: RouteResult
-    dp: DeltaPlanes           # every field [W, B, ...]
-
-
-class CompactDeltaRouteResult(NamedTuple):
-    """DeltaRouteResult + fused CSR readbacks for BOTH plane families.
-
-    `compact` is the main planes' CSR (ops.compact); `d_compact` the
-    overlay planes' CSR, reusing the same op with an empty shared
-    family (cs == 0 in every row) so `csr_slices` decodes both with one
-    code path. The dense planes stay in `dres` as free same-program
-    outputs — either CSR overflowing its payload class falls back to
-    the corresponding dense planes with no re-dispatch."""
-    dres: DeltaRouteResult
-    compact: "CompactPlanes"      # noqa: F821 — imported lazily
-    d_compact: "CompactPlanes"    # noqa: F821
+        if dp is None:
+            return cp, None
+        W, B = dp.fids.shape[:2]
+        no_slot = jnp.full((W, B, 1), -1, jnp.int32)
+        zero32 = jnp.zeros((W, B, 1), jnp.int32)
+        zero8 = jnp.zeros((W, B, 1), jnp.int8)
+        dcp = compact_result(dp.fids, dp.rows, dp.opts, dp.fan_counts,
+                             no_slot, zero32, zero8,
+                             payload_cap=d_payload_cap, match_holes=False)
+    return cp, dcp
 
 
 def _window_delta(delta: DeltaTables, topics: jax.Array, lens: jax.Array,
@@ -363,8 +275,7 @@ def _window_delta(delta: DeltaTables, topics: jax.Array, lens: jax.Array,
                              for x in dp])
 
 
-def _cached_delta(delta: DeltaTables, miss_topics, miss_lens, miss_dollar,
-                  base_dm, base_dc, base_do, miss_pos, inv, *,
+def _cached_delta(delta: DeltaTables, plan: WindowPlan, base: tuple, *,
                   dmatch_cap: int, dfan_cap: int) -> DeltaPlanes:
     """Overlay planes for a DEDUPLICATED dispatch: the linear matcher
     runs only on the [Bm] miss lanes; cache-hit unique topics ride in as
@@ -375,155 +286,119 @@ def _cached_delta(delta: DeltaTables, miss_topics, miss_lens, miss_dollar,
     no membership state and a subscriber change can never stale them —
     and `inv` gathers back to full width."""
     with jax.named_scope("delta"):
-        mr = delta_match(delta, miss_topics, miss_lens, miss_dollar,
-                         match_cap=dmatch_cap)
-        um = merge_match_results(base_dm, base_dc, base_do, mr, miss_pos)
+        mr = delta_match(delta, plan.miss_topics, plan.miss_lens,
+                         plan.miss_dollar, match_cap=dmatch_cap)
+        um = merge_match_results(*base, mr, plan.miss_pos)
         dp_u = delta_expand(delta, um, fanout_cap=dfan_cap)
-        return DeltaPlanes(*[x[inv] for x in dp_u])
+        return DeltaPlanes(*[x[plan.inv] for x in dp_u])
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "delta_match_cap", "delta_fanout_cap"))
-def route_window_delta(tables, delta: DeltaTables,
-                       cursors: jax.Array, topics: jax.Array,
-                       lens: jax.Array, is_dollar: jax.Array,
-                       msg_hash: jax.Array, strategy: jax.Array, *,
-                       frontier_cap: int = 16, match_cap: int = 64,
-                       fanout_cap: int = 128, slot_cap: int = 16,
-                       delta_match_cap: int = 16,
-                       delta_fanout_cap: int = 64) -> DeltaRouteResult:
-    """route_window_full + delta overlay fused in the same dispatch."""
-    r = route_window_full(tables, cursors, topics, lens, is_dollar,
-                          msg_hash, strategy, frontier_cap=frontier_cap,
-                          match_cap=match_cap, fanout_cap=fanout_cap,
-                          slot_cap=slot_cap)
-    dp = _window_delta(delta, topics, lens, is_dollar,
-                       dmatch_cap=delta_match_cap,
-                       dfan_cap=delta_fanout_cap)
-    return DeltaRouteResult(res=r, dp=dp)
+    static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap"))
+def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
+                 strategy, plan, *, frontier_cap, match_cap, fanout_cap,
+                 slot_cap) -> RouteResult:
+    """`route_window`'s `match → scan(fanout, shared)`, the part every
+    class of one (W, B[, Bm]) shares. A jit of its own inside the
+    program (XLA inlines it) only so that those classes share its
+    trace, as the parent's compact and delta twins shared the window
+    program they called: traced afresh for every payload class, a trie
+    snapshot's direct warm took 12.1–13.4 s for 9.5–10.1 on a v5e's
+    host (my chip runs, PR 30)."""
+    trie = _is_trie(tables)
+    nfa = dict(frontier_cap=frontier_cap, match_cap=match_cap)
+    if plan is None:
+        lanes = (topics, lens, is_dollar)
+
+        def matched(lane):
+            return _match_stage(tables, *lane, sub_batch=True, **nfa)
+    else:
+        with jax.named_scope("match"):
+            mr = _match_stage(tables, plan.miss_topics, plan.miss_lens,
+                              plan.miss_dollar, **nfa)
+            um = merge_match_results(plan.base_matches, plan.base_counts,
+                                     plan.base_overflow, mr, plan.miss_pos)
+        # the one walk over the miss lanes, reported in row 0
+        wide = jnp.zeros(plan.inv.shape[0], jnp.int32).at[0].set(
+            mr.wide_steps) if trie else None
+        lanes = (plan.inv, wide)
+
+        def matched(lane):
+            inv_k, wide_k = lane
+            return MatchResult(matches=um.matches[inv_k],
+                               counts=um.counts[inv_k],
+                               overflow=um.overflow[inv_k],
+                               wide_steps=wide_k)
+
+    def step(cur, xs):
+        lane, mh_k = xs
+        with jax.named_scope("match"):
+            mr_k = matched(lane)
+        r = post_match(tables.subs, mr_k, cur, mh_k, strategy,
+                       fanout_cap=fanout_cap, slot_cap=slot_cap)
+        return r.new_cursors, r
+
+    with jax.named_scope("scan"):
+        _, r = jax.lax.scan(step, cursors, (lanes, msg_hash))
+    return r
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "delta_match_cap", "delta_fanout_cap"))
-def route_window_delta_cached(tables,
-                              delta: DeltaTables, cursors: jax.Array,
-                              miss_topics: jax.Array,
-                              miss_lens: jax.Array,
-                              miss_dollar: jax.Array,
-                              base_matches: jax.Array,
-                              base_counts: jax.Array,
-                              base_overflow: jax.Array,
-                              base_dm: jax.Array, base_dc: jax.Array,
-                              base_do: jax.Array, miss_pos: jax.Array,
-                              inv: jax.Array, msg_hash: jax.Array,
-                              strategy: jax.Array, *,
-                              frontier_cap: int = 16,
-                              match_cap: int = 64,
-                              fanout_cap: int = 128, slot_cap: int = 16,
-                              delta_match_cap: int = 16,
-                              delta_fanout_cap: int = 64
-                              ) -> DeltaRouteResult:
-    """route_window_cached + delta overlay fused in the same dispatch."""
-    r = route_window_cached(tables, cursors, miss_topics, miss_lens,
-                            miss_dollar, base_matches, base_counts,
-                            base_overflow, miss_pos, inv, msg_hash,
-                            strategy, frontier_cap=frontier_cap,
-                            match_cap=match_cap, fanout_cap=fanout_cap,
-                            slot_cap=slot_cap)
-    dp = _cached_delta(delta, miss_topics, miss_lens, miss_dollar,
-                       base_dm, base_dc, base_do, miss_pos, inv,
-                       dmatch_cap=delta_match_cap,
-                       dfan_cap=delta_fanout_cap)
-    return DeltaRouteResult(res=r, dp=dp)
+    static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap",
+                     "delta_match_cap", "delta_fanout_cap", "payload_cap",
+                     "d_payload_cap"))
+def route_window(tables, cursors: jax.Array, topics: jax.Array,
+                 lens: jax.Array, is_dollar: jax.Array,
+                 msg_hash: jax.Array, strategy: jax.Array,
+                 plan: "WindowPlan | None" = None,
+                 delta: "WindowDelta | None" = None, *,
+                 frontier_cap: int = 16, match_cap: int = 64,
+                 fanout_cap: int = 128, slot_cap: int = 16,
+                 delta_match_cap: int = 16, delta_fanout_cap: int = 64,
+                 payload_cap: "int | None" = None,
+                 d_payload_cap: "int | None" = None) -> RouteResult:
+    """The served path's device window: W fused route steps in ONE
+    dispatch, `match → scan(fanout, shared)`, returning the stacked
+    RouteResult (every field [W, ...]; a single batch is a window of
+    W = 1). Cursors thread through the scan exactly as through W
+    sequential `route_step_shapes` / `route_step` calls, so
+    `new_cursors` / `occur` in row k reflect state after sub-batch k and
+    round-robin fairness holds across the whole window (oracle-tested
+    bit for bit, every combination of stages).
 
+    Either backend: the tables' type picks the matcher (`_is_trie`; the
+    trie NFA takes `frontier_cap` / `match_cap` and is skipped for a
+    sub-batch of padding). Up to three optional stages, each chosen by
+    what the caller passes; `None`-ness of a pytree argument and the
+    value of a static are part of the jit key like the tables' type, so
+    a class compiles only its own stages:
 
-def _with_delta_compact(dres: DeltaRouteResult, payload_cap: int,
-                        d_payload_cap: int,
-                        match_holes: bool) -> CompactDeltaRouteResult:
-    """Fuse both CSR compactions onto a delta route result. The delta
-    family reuses ops.compact.compact_result with a width-1 all-empty
-    shared family (cs == 0), so offsets/counts3/payload decode with the
-    same csr_slices as the main planes; delta matches are always
-    prefix-compacted (match_holes=False compiles the hole stage away)."""
-    from emqx_tpu.ops.compact import compact_result
-    r, dp = dres.res, dres.dp
-    with jax.named_scope("compact"):
-        cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
-                            r.shared_sids, r.shared_rows, r.shared_opts,
-                            payload_cap=payload_cap,
-                            match_holes=match_holes)
-        W, B = dp.fids.shape[:2]
-        no_slot = jnp.full((W, B, 1), -1, jnp.int32)
-        zero32 = jnp.zeros((W, B, 1), jnp.int32)
-        zero8 = jnp.zeros((W, B, 1), jnp.int8)
-        dcp = compact_result(dp.fids, dp.rows, dp.opts, dp.fan_counts,
-                             no_slot, zero32, zero8,
-                             payload_cap=d_payload_cap, match_holes=False)
-    return CompactDeltaRouteResult(dres=dres, compact=cp, d_compact=dcp)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "delta_match_cap", "delta_fanout_cap",
-                     "payload_cap", "d_payload_cap"))
-def route_window_delta_compact(tables, delta, cursors, topics, lens,
-                               is_dollar, msg_hash, strategy, *,
-                               frontier_cap: int = 16,
-                               match_cap: int = 64,
-                               fanout_cap: int = 128, slot_cap: int = 16,
-                               delta_match_cap: int = 16,
-                               delta_fanout_cap: int = 64,
-                               payload_cap: int = 4096,
-                               d_payload_cap: int = 1024
-                               ) -> CompactDeltaRouteResult:
-    """route_window_delta + fused CSR readbacks (both plane families)."""
-    dres = route_window_delta(tables, delta, cursors, topics, lens,
-                              is_dollar, msg_hash, strategy,
-                              frontier_cap=frontier_cap,
-                              match_cap=match_cap,
-                              fanout_cap=fanout_cap, slot_cap=slot_cap,
-                              delta_match_cap=delta_match_cap,
-                              delta_fanout_cap=delta_fanout_cap)
-    return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=not _is_trie(tables))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap",
-                     "slot_cap", "delta_match_cap", "delta_fanout_cap",
-                     "payload_cap", "d_payload_cap"))
-def route_window_delta_cached_compact(tables, delta, cursors,
-                                      miss_topics, miss_lens,
-                                      miss_dollar, base_matches,
-                                      base_counts, base_overflow,
-                                      base_dm, base_dc, base_do,
-                                      miss_pos, inv, msg_hash, strategy,
-                                      *, frontier_cap: int = 16,
-                                      match_cap: int = 64,
-                                      fanout_cap: int = 128,
-                                      slot_cap: int = 16,
-                                      delta_match_cap: int = 16,
-                                      delta_fanout_cap: int = 64,
-                                      payload_cap: int = 4096,
-                                      d_payload_cap: int = 1024
-                                      ) -> CompactDeltaRouteResult:
-    """Deduplicated window step + overlay + both CSR readbacks."""
-    dres = route_window_delta_cached(
-        tables, delta, cursors, miss_topics, miss_lens, miss_dollar,
-        base_matches, base_counts, base_overflow, base_dm, base_dc,
-        base_do, miss_pos, inv, msg_hash, strategy,
-        frontier_cap=frontier_cap, match_cap=match_cap,
-        fanout_cap=fanout_cap, slot_cap=slot_cap,
-        delta_match_cap=delta_match_cap,
-        delta_fanout_cap=delta_fanout_cap)
-    return _with_delta_compact(dres, payload_cap, d_payload_cap,
-                               match_holes=not _is_trie(tables))
+    - `plan` (the match cache's, `WindowPlan`): the match runs ONCE over
+      the [Bm] compacted miss lanes, merges with the cache-hit base rows
+      and `plan.inv` gathers the unique rows back to window width per
+      scan step. `topics` / `lens` / `is_dollar` are None then: the
+      plan holds the window's lanes.
+    - `delta` (`WindowDelta`): the overlay of post-snapshot filters
+      matches and expands in the same dispatch, into `RouteResult.delta`.
+    - `payload_cap` (with `d_payload_cap` where the overlay runs): the
+      CSR readback of `_compact_stage`, into `.compact` / `.d_compact`."""
+    r = _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
+                     strategy, plan, frontier_cap=frontier_cap,
+                     match_cap=match_cap, fanout_cap=fanout_cap,
+                     slot_cap=slot_cap)
+    dp = None
+    if delta is not None:
+        dcaps = dict(dmatch_cap=delta_match_cap, dfan_cap=delta_fanout_cap)
+        dp = _window_delta(delta.tables, topics, lens, is_dollar, **dcaps) \
+            if plan is None else \
+            _cached_delta(delta.tables, plan, delta.base, **dcaps)
+    cp = dcp = None
+    if payload_cap is not None:
+        cp, dcp = _compact_stage(r, dp, payload_cap, d_payload_cap,
+                                 match_holes=not _is_trie(tables))
+    return r._replace(delta=dp, compact=cp, d_compact=dcp)
 
 
 def route_digest(r: RouteResult) -> jax.Array:
@@ -553,6 +428,8 @@ def route_window_shapes(tables: ShapeRouterTables, cursors: jax.Array,
                         strategy: jax.Array, *, fanout_cap: int = 128,
                         slot_cap: int = 16):
     """W fused route steps in ONE dispatch: scan over a [W, B, ...] window.
+    `bench.py`'s raw window, digests only; the served path's is
+    `route_window`.
 
     Per-dispatch overhead (the runtime's launch cost) is paid once for W
     batches instead of W times. Cursors
@@ -576,45 +453,6 @@ def route_window_shapes(tables: ShapeRouterTables, cursors: jax.Array,
     return new_cursors, digests
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("frontier_cap", "match_cap", "fanout_cap", "slot_cap"))
-def route_window_full(tables, cursors: jax.Array,
-                      topics: jax.Array, lens: jax.Array,
-                      is_dollar: jax.Array, msg_hash: jax.Array,
-                      strategy: jax.Array, *, frontier_cap: int = 16,
-                      match_cap: int = 64, fanout_cap: int = 128,
-                      slot_cap: int = 16) -> RouteResult:
-    """W fused route steps in ONE dispatch, returning the FULL stacked
-    RouteResult (every field [W, ...]) — the serving path's window
-    variant (route_window_shapes returns digests only, for benches).
-    Cursors thread through the scan exactly as W sequential calls, so
-    `new_cursors`/`occur` in row k reflect state after sub-batch k.
-    Either backend: a `RouterTables` window scans `route_step`'s two
-    stages (the trie NFA, which takes `frontier_cap` / `match_cap` and
-    is skipped for a sub-batch of padding), a `ShapeRouterTables`
-    window `route_step_shapes`."""
-    def step(cur, batch):
-        t, l, d, h = batch
-        if _is_trie(tables):
-            with jax.named_scope("match"):
-                mr = _nfa_unless_padding(tables.trie, t, l, d,
-                                         frontier_cap=frontier_cap,
-                                         match_cap=match_cap)
-            r = post_match(tables.subs, mr, cur, h, strategy,
-                           fanout_cap=fanout_cap, slot_cap=slot_cap)
-        else:
-            r = route_step_shapes(tables, cur, t, l, d, h, strategy,
-                                  fanout_cap=fanout_cap,
-                                  slot_cap=slot_cap)
-        return r.new_cursors, r
-
-    with jax.named_scope("scan"):
-        _, stacked = jax.lax.scan(
-            step, cursors, (topics, lens, is_dollar, msg_hash))
-    return stacked
-
-
 def compile_stats() -> dict[str, int]:
     """Jit-cache entry counts per route-step program. Each entry is one
     compiled (shape, dtype, static-args) variant, so a growing number
@@ -625,20 +463,11 @@ def compile_stats() -> dict[str, int]:
     programs lives in `cost_stats()` (the ISSUE-8 cost registry)."""
     out = {}
     for fn in (route_step, route_step_shapes, route_window_shapes,
-               route_window_full, route_window_cached,
-               route_window_full_compact, route_window_cached_compact,
-               route_window_delta, route_window_delta_cached,
-               route_window_delta_compact,
-               route_window_delta_cached_compact):
+               route_window):
         try:
             out[fn.__name__] = fn._cache_size()
         except Exception:  # noqa: BLE001 — cache introspection is best-effort
             pass
-    # the ISSUE-9 donating twins compile in their own caches but are
-    # the same programs — fold their entry counts into the plain names
-    # so the exported stats stay one name space at any dispatch depth
-    for name, n in donating_compile_stats().items():
-        out[name] = out.get(name, 0) + n
     # the ISSUE-15 exchange programs live in parallel.sharded (one per
     # segment-capacity class); fold them in without forcing the import
     import sys
@@ -868,101 +697,10 @@ def _with_cost_registry(fn):
 route_step = _with_cost_registry(route_step)
 route_step_shapes = _with_cost_registry(route_step_shapes)
 route_window_shapes = _with_cost_registry(route_window_shapes)
-route_window_full = _with_cost_registry(route_window_full)
-route_window_cached = _with_cost_registry(route_window_cached)
-route_window_full_compact = _with_cost_registry(route_window_full_compact)
-route_window_cached_compact = \
-    _with_cost_registry(route_window_cached_compact)
-route_window_delta = _with_cost_registry(route_window_delta)
-route_window_delta_cached = _with_cost_registry(route_window_delta_cached)
-route_window_delta_compact = \
-    _with_cost_registry(route_window_delta_compact)
-route_window_delta_cached_compact = \
-    _with_cost_registry(route_window_delta_cached_compact)
-
-
-# ---- donating serving twins (ISSUE 9) -----------------------------------
-# At dispatch_depth >= 2 the serving dispatch threads its cursors through
-# the fused programs with the cursors slot DONATED (input-output aliasing:
-# the ping-pong cursor buffers reuse HBM instead of allocating one fresh
-# [G] array per window). Donation invalidates the caller's input buffer,
-# so these twins are used ONLY where the call site immediately re-adopts
-# the output under the snapshot identity guard (DeviceRouteEngine.
-# _dispatch_inner) and by the warm passes that feed them THROWAWAY
-# device_put buffers — never by tests/benches that reuse a cursors array
-# across calls (those keep the non-donating originals above). Each twin
-# shares the plain program's name in the cost registry (same program,
-# donated cursor slot) and its jit cache is counted into compile_stats
-# under the plain name. Stage-graph safe: donation is an annotation on
-# the public entry points, not a change to any stage composition —
-# ROADMAP item 2's builder can emit the same annotation per fused
-# program.
-#
-# Measured cache-key caveat this design encodes: numpy inputs and
-# device arrays do NOT share a jit-cache entry, while device_put arrays
-# and jit outputs DO — so every warm/probe call through a twin must pass
-# a fresh device_put zeros cursors (the engine's _warm_cursors), or the
-# first serving dispatch would re-trace in-path.
-
-_TRIE_CAPS = ("frontier_cap", "match_cap")
-_POST_CAPS = ("fanout_cap", "slot_cap")
-_DELTA_CAPS = ("delta_match_cap", "delta_fanout_cap")
-# what a window program adds to the caps, by its suffix after
-# `route_window`; every one takes the trie NFA's caps too: it serves
-# either backend (`_match_stage`) and a shape-hash window leaves them
-# alone
-_WINDOW_STATICS = {
-    "_full": (),
-    "_cached": (),
-    "_full_compact": ("payload_cap",),
-    "_cached_compact": ("payload_cap",),
-    "_delta": _DELTA_CAPS,
-    "_delta_cached": _DELTA_CAPS,
-    "_delta_compact": _DELTA_CAPS + ("payload_cap", "d_payload_cap"),
-    "_delta_cached_compact": _DELTA_CAPS + ("payload_cap",
-                                            "d_payload_cap"),
-}
-_DONATE_STATICS = {"route_window" + _suffix: _TRIE_CAPS + _POST_CAPS + _extra
-                   for _suffix, _extra in _WINDOW_STATICS.items()}
-
-_donating_cache: dict[str, object] = {}
-_donating_lock = threading.Lock()
-
-
-def donating(fn):
-    """The cursor-donating serving twin of a fused route program
-    (lazy, one jit per program for the process lifetime). `fn` is one
-    of the public programs above (cost-registry wrapped or not).
-    Locked: the dispatch executor and the background build/warm
-    threads both resolve twins through DeviceRouteEngine._rt — an
-    unlocked check-then-act could build rival twins and discard the
-    one whose jit cache the warm pass just populated (an in-path
-    recompile on the next serving dispatch)."""
-    name = fn.__name__
-    tw = _donating_cache.get(name)
-    if tw is None:
-        with _donating_lock:
-            tw = _donating_cache.get(name)
-            if tw is None:
-                raw = getattr(fn, "_fun", fn).__wrapped__
-                tw = _with_cost_registry(jax.jit(
-                    raw, static_argnames=_DONATE_STATICS[name],
-                    donate_argnames=("cursors",)))
-                _donating_cache[name] = tw
-    return tw
-
-
-def donating_compile_stats() -> dict[str, int]:
-    """Jit-cache entry counts of the instantiated donating twins,
-    keyed by the PLAIN program names (compile_stats merges them in —
-    one exported name space whatever depth the node serves at)."""
-    out = {}
-    for name, fn in _donating_cache.items():
-        try:
-            out[name] = fn._cache_size()
-        except Exception:  # noqa: BLE001 — introspection is best-effort
-            pass
-    return out
+route_window = _with_cost_registry(route_window)
+# `benchmark/populations/mixed_depth.py` asks the loaded program whether
+# `route_window_full` takes the trie NFA's `frontier_cap`
+route_window_full = route_window
 
 
 def empty_router_tables(filter_cap: int = 16) -> RouterTables:

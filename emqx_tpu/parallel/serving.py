@@ -251,10 +251,7 @@ class ShardedRouteServer:
         # reference; the copy-on-write _builts discipline already
         # supports N in-flight handles), and dispatch() starts the
         # device→host readback transfers at return so materialize is
-        # consume-on-arrival. The mesh step keeps NON-donating cursors:
-        # its cursor adopt runs under _lock against per-shard updates —
-        # the single-chip donation contract (sole ownership of the
-        # in-buffer) does not hold here.
+        # consume-on-arrival.
         from emqx_tpu.broker.batcher import resolve_dispatch_depth
         self.dispatch_depth = resolve_dispatch_depth(dispatch_depth)
         self._payload_mults = (8, 32, 128)

@@ -65,16 +65,16 @@ class TestDrive:
         eng = node.device_engine
         node.supervisor.injector = S.FaultInjector(
             S.parse_faults("dispatch:exception:count=3"))
-        real = eng._warm_cursors
+        real = eng._warm_class
         calls = {"n": 0}
 
-        def flaky_warm_cursors(cursors):
+        def flaky_warm_class(*a):
             calls["n"] += 1
             if calls["n"] == 1:
                 raise RuntimeError("injected warm-compile failure")
-            return real(cursors)
+            return real(*a)
 
-        eng._warm_cursors = flaky_warm_cursors
+        eng._warm_class = flaky_warm_class
         failures = "\n".join(_drive(smoke, node)["failures"])
         assert "routing.device.warm_failed == 0" in failures
         assert "routing.device.supervised_bypass == 0" in failures

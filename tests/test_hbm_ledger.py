@@ -566,8 +566,7 @@ class TestCostRegistry:
 
     def test_wrapper_is_transparent(self):
         import emqx_tpu.models.router_engine as R
-        for fn in (R.route_step, R.route_window_full,
-                   R.route_window_cached_compact):
+        for fn in (R.route_step, R.route_window, R.route_window_shapes):
             assert callable(fn.lower)
             assert isinstance(fn._cache_size(), int)
             assert fn.__name__.startswith("route_")

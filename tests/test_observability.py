@@ -482,15 +482,8 @@ class TestCompileAccounting:
         # ISSUE 15: mesh exchange programs (one per segment-capacity
         # class) fold into the same namespace under exchange_step_*
         named = {k for k in st if not k.startswith("exchange_step_")}
-        assert named <= {"route_step", "route_step_shapes",
-                           "route_window_shapes", "route_window_full",
-                           "route_window_cached",
-                           "route_window_full_compact",
-                           "route_window_cached_compact",
-                           "route_window_delta",
-                           "route_window_delta_cached",
-                           "route_window_delta_compact",
-                           "route_window_delta_cached_compact"}
+        assert named == {"route_step", "route_step_shapes",
+                         "route_window_shapes", "route_window"}
         assert all(isinstance(v, int) for v in st.values())
 
 

@@ -14,9 +14,8 @@ settles or in what order. These tests pin that contract:
   measurably in flight when the fault hit.
 - **Depth-1 guard** (tier-1): ``EMQX_TPU_DISPATCH_DEPTH=1`` restores
   the pre-ISSUE-9 synchronous consumer EXACTLY — the pipelined ring is
-  never entered, the donating program twins are never instantiated,
-  the live cursors buffer is passed through untouched, and the
-  flight-recorder span structure matches the synchronous shape.
+  never entered, and the flight-recorder span structure matches the
+  synchronous shape.
 - **Knob resolution**: config beats env beats default 2; malformed
   values fail loudly.
 """
@@ -90,9 +89,9 @@ def build_world(node: Node, mode: str) -> dict:
             sinks[sid] = s
             b.subscribe(sid, f"t/{i}/+", {"qos": q})
     if mode == "shared":
-        # shared groups exercise the donated-cursor state machine: the
+        # shared groups exercise the cursor state machine: the
         # round-robin pick of window W+1 depends on W's new_cursors, so
-        # any donation/readback race between in-flight windows would
+        # any adoption/readback race between in-flight windows would
         # show up as diverged picks between the depth twins
         for i in range(N_FILTERS):
             for m in range(2):
@@ -324,25 +323,16 @@ class TestMidPipelineFault:
 class TestDepth1Guard:
     def test_synchronous_loop_never_enters_the_ring(self, monkeypatch):
         """At depth 1 the pipelined consumer is dead code: entering it
-        (or instantiating a donating twin, or copying the live cursors)
         would mean the A/B baseline is no longer the pre-ISSUE-9 code
         path."""
-        from emqx_tpu.models import router_engine as RE
-
         def boom(self):
             raise AssertionError(
                 "depth-1 node entered _consume_pipelined")
         monkeypatch.setattr(PublishBatcher, "_consume_pipelined", boom)
-        twins_before = set(RE._donating_cache)
 
         node = build_node(1)
         eng = node.device_engine
         assert not eng._pipelined
-        # the program chooser and the cursors pass-through are
-        # identities at depth 1 — same jit cache, same live buffer
-        assert eng._rt(RE.route_window_full) is RE.route_window_full
-        sentinel = object()
-        assert eng._warm_cursors(sentinel) is sentinel
 
         sinks = build_world(node, "clean")
         wins = schedule(windows=4)
@@ -353,8 +343,6 @@ class TestDepth1Guard:
 
         counts = run(go())
         assert all(c == 2 for cs in counts for c in cs)
-        assert set(RE._donating_cache) == twins_before, \
-            "depth-1 run instantiated donating twins"
         assert node.metrics.val("supervise.task_errors") == 0
         assert len(sinks) == 2 * N_FILTERS
 

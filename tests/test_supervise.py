@@ -399,6 +399,13 @@ class TestMeshChaos:
                 if sup.breakers["mesh_exchange"].state == "closed" \
                         and sup.injector.faults[0].fired:
                     break
+            # the half-open probe runs on an executor thread: under a
+            # loaded machine it can outlast the ten rounds above, so
+            # wait for its verdict, not for the wall clock
+            deadline = _time.monotonic() + 60
+            while sup.breakers["mesh_exchange"].state != "closed" \
+                    and _time.monotonic() < deadline:
+                await asyncio.sleep(0.05)
             return outs
         outs = run(go(), timeout=180)
         assert all(c == 1 for c in outs)

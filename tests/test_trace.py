@@ -1081,30 +1081,82 @@ def _same(got, want):
         assert (a == b).all()
 
 
-@pytest.mark.parametrize("family", [
-    "step", "step_shapes", "window_full", "window_cached",
-    "window_full_compact", "window_delta_compact",
-    "window_full_trie", "window_cached_trie", "window_full_compact_trie",
-    "window_full_padded_trie"])
+def _dedup_plan(fx, by, dmatch=None):
+    """A match-cache plan over the fixture's window, as the engine's
+    `_plan_window` makes one: the lanes collapse to their unique
+    topics, every other unique topic is a cache hit (its base row
+    filled from the reference match, and from the overlay's matcher
+    where `dmatch` is given), the rest are the miss lanes. Returns
+    (WindowPlan, overlay base rows or None, the matcher's result on the
+    miss lanes)."""
+    import numpy as np
+    RE, W, B = fx["RE"], fx["W"], fx["B"]
+    flat = (fx["enc"].reshape(W * B, -1), fx["lens"].reshape(W * B),
+            fx["dol"].reshape(W * B))
+    keys = np.concatenate([flat[0], flat[1][:, None],
+                           flat[2][:, None].astype(np.int32)], axis=1)
+    _u, first, inv = np.unique(keys, axis=0, return_index=True,
+                               return_inverse=True)
+    Bu = len(first)
+    assert 2 <= Bu <= B
+    uniq = tuple(a[first] for a in flat)
+    hit = np.arange(Bu) % 2 == 0
+    miss_u = np.flatnonzero(~hit)
+    Bm = B
+    miss = (np.zeros((Bm,) + flat[0].shape[1:], np.int32),
+            np.zeros(Bm, np.int32), np.zeros(Bm, bool))
+    for dst, src in zip(miss, uniq):
+        dst[:len(miss_u)] = src[miss_u]
+    pos = np.full(Bm, B, np.int32)              # pad = B: dropped
+    pos[:len(miss_u)] = miss_u
+
+    def base_rows(match):
+        mr = match(*uniq)
+        m = np.full((B,) + mr.matches.shape[1:], -1, np.int32)
+        c, o = np.zeros(B, np.int32), np.zeros(B, bool)
+        m[:Bu][hit] = np.asarray(mr.matches)[hit]
+        c[:Bu][hit] = np.asarray(mr.counts)[hit]
+        o[:Bu][hit] = np.asarray(mr.overflow)[hit]
+        return m, c, o
+    plan = RE.WindowPlan(*miss, *base_rows(by), pos,
+                         inv.reshape(W, B).astype(np.int32))
+    return (plan, None if dmatch is None else base_rows(dmatch),
+            by(*miss))
+
+
+def _window_cases():
+    out = ["step", "step_shapes"]
+    for backend in ("shapes", "trie"):
+        for plan in ("", "_plan"):
+            for delta in ("", "_delta"):
+                for compact in ("", "_compact"):
+                    out.append(f"window{plan}{delta}{compact}.{backend}")
+    return out + ["window_padded.trie"]
+
+
+@pytest.mark.parametrize("family", _window_cases())
 def test_route_outputs_bit_equal_with_scopes(family):
-    """jax.named_scope changes HLO metadata only: each route program
-    family, scopes and all, returns bit for bit what the same ops
-    return when called one by one with no scope around them; and the
-    scopes are in the lowered program's metadata. A window program
-    serves either backend: the `_trie` families hand it `RouterTables`
-    and hold it to W sequential NFA steps, `nfa_wide_steps` included,
-    which a shape-hash program's result does not have."""
+    """jax.named_scope changes HLO metadata only, and `route_window`'s
+    optional stages compose without touching one another: the two step
+    programs and every combination of the window's stages (the match
+    cache's plan x the delta overlay x the CSR readback, on either
+    backend, and a trie window with padding sub-batches) return bit for
+    bit what the same ops return when called one by one with no scope
+    around them (W sequential steps threading the cursors, then the
+    unfused `delta_overlay` and `compact_result`); and the scopes are
+    in the lowered program's metadata. A trie program reports
+    `nfa_wide_steps` (a window with a plan walks once, row 0), which a
+    shape-hash program's result does not have."""
     import jax
     import numpy as np
 
     from emqx_tpu.ops.compact import compact_result
-    from emqx_tpu.ops.delta import delta_overlay
+    from emqx_tpu.ops.delta import delta_match, delta_overlay
     from emqx_tpu.ops.match import match_batch
     from emqx_tpu.ops.shapes import shape_match
     fx = _scope_fixture()
     RE, W, B = fx["RE"], fx["W"], fx["B"]
     caps = dict(fanout_cap=8, slot_cap=4)
-    win = (fx["enc"], fx["lens"], fx["dol"], fx["hash"], fx["strat"])
 
     def by_trie(e, l, d):
         return match_batch(fx["trie"].trie, e, l, d, frontier_cap=16,
@@ -1114,92 +1166,76 @@ def test_route_outputs_bit_equal_with_scopes(family):
         return shape_match(fx["shapes"].shapes, e, l, d)
 
     scopes = {"match", "fanout", "shared"}
-    trie = family.endswith("_trie")
-    family = family.removesuffix("_trie")
-    if family == "window_full_padded":
-        # the last two sub-batches are the window class's padding: a
-        # trie window skips the NFA there and returns what it returns
-        family, fx = "window_full", dict(fx, lens=fx["lens"].copy())
-        fx["lens"][2:] = 0
-        win = (fx["enc"], fx["lens"], fx["dol"], fx["hash"], fx["strat"])
-    tables, by = (fx["trie"], by_trie) if trie else (fx["shapes"],
-                                                     by_shapes)
-    if trie:
-        caps = dict(caps, frontier_cap=16, match_cap=64)
-    if family == "step":
-        fn, args, kw = RE.route_step, (fx["trie"], fx["cur"]) + tuple(
-            a[0] if getattr(a, "ndim", 0) else a for a in win), dict(
-            caps, frontier_cap=16, match_cap=64)
-        want = _first(_plain_steps(dict(fx, W=1), by_trie))
-    elif family == "step_shapes":
-        fn, args, kw = RE.route_step_shapes, (
-            fx["shapes"], fx["cur"]) + tuple(
-            a[0] if getattr(a, "ndim", 0) else a for a in win), caps
-        want = _first(_plain_steps(dict(fx, W=1), by_shapes))
-    elif family == "window_full":
-        fn, args, kw = RE.route_window_full, (
-            tables, fx["cur"]) + win, caps
-        want = _plain_steps(fx, by)
-        scopes |= {"scan"}
-    elif family == "window_cached":
-        # every lane a miss of its own: the plan's degenerate case
-        U = W * B
-        base = (np.full((U, 64), -1, np.int32), np.zeros(U, np.int32),
-                np.zeros(U, bool))
-        probe = by(fx["enc"].reshape(U, -1),
-                   fx["lens"].reshape(U), fx["dol"].reshape(U))
-        base = (np.full((U,) + probe.matches.shape[1:], -1, np.int32),
-                base[1], base[2])
-        fn, kw = RE.route_window_cached, caps
-        args = (tables, fx["cur"], fx["enc"].reshape(U, -1),
-                fx["lens"].reshape(U), fx["dol"].reshape(U)) + base + (
-            np.arange(U, dtype=np.int32),
-            np.arange(U, dtype=np.int32).reshape(W, B),
-            fx["hash"], fx["strat"])
-        want = _plain_steps(fx, by)
-        if trie:    # one walk over the miss lanes, reported in row 0
-            want = want._replace(nfa_wide_steps=np.array(
-                [probe.wide_steps] + [0] * (W - 1), np.int32))
-        scopes |= {"scan"}
-    elif family == "window_full_compact":
-        fn, args, kw = RE.route_window_full_compact, (
-            tables, fx["cur"]) + win, dict(caps, payload_cap=256)
-        r = _plain_steps(fx, by)
-        want = RE.CompactRouteResult(res=r, compact=compact_result(
-            r.matches, r.rows, r.opts, r.fan_counts, r.shared_sids,
-            r.shared_rows, r.shared_opts, payload_cap=256,
-            match_holes=not trie))
-        scopes |= {"scan", "compact"}
+    if family in ("step", "step_shapes"):
+        trie = family == "step"
+        fn = RE.route_step if trie else RE.route_step_shapes
+        kw = dict(caps, frontier_cap=16, match_cap=64) if trie else caps
+        args = (fx["trie" if trie else "shapes"], fx["cur"],
+                fx["enc"][0], fx["lens"][0], fx["dol"][0], fx["hash"][0],
+                fx["strat"])
+        want = _first(_plain_steps(dict(fx, W=1),
+                                   by_trie if trie else by_shapes))
     else:
-        fn = RE.route_window_delta_compact
-        args = (fx["shapes"], fx["delta"], fx["cur"]) + win
-        kw = dict(caps, delta_match_cap=4, delta_fanout_cap=8,
-                  payload_cap=256, d_payload_cap=64)
-        r = _plain_steps(fx, by_shapes)
-        dp = delta_overlay(fx["delta"], fx["enc"].reshape(W * B, -1),
-                           fx["lens"].reshape(W * B),
-                           fx["dol"].reshape(W * B), match_cap=4,
-                           fanout_cap=8)
-        dp = type(dp)(*[np.asarray(x).reshape((W, B) + x.shape[1:])
-                        for x in dp])
-        cp = compact_result(r.matches, r.rows, r.opts, r.fan_counts,
-                            r.shared_sids, r.shared_rows, r.shared_opts,
-                            payload_cap=256, match_holes=True)
-        dcp = compact_result(
-            dp.fids, dp.rows, dp.opts, dp.fan_counts,
-            np.full((W, B, 1), -1, np.int32),
-            np.zeros((W, B, 1), np.int32), np.zeros((W, B, 1), np.int8),
-            payload_cap=64, match_holes=False)
-        want = RE.CompactDeltaRouteResult(
-            dres=RE.DeltaRouteResult(res=r, dp=dp), compact=cp,
-            d_compact=dcp)
-        scopes |= {"scan", "compact", "delta"}
+        stages, backend = family.split(".")
+        trie = backend == "trie"
+        if "_padded" in stages:
+            # the last two sub-batches are the window class's padding: a
+            # trie window skips the NFA there and returns what it returns
+            fx = dict(fx, lens=fx["lens"].copy())
+            fx["lens"][2:] = 0
+        tables, by = (fx["trie"], by_trie) if trie \
+            else (fx["shapes"], by_shapes)
+        kw = dict(caps, frontier_cap=16, match_cap=64) if trie else caps
+        flat = (fx["enc"].reshape(W * B, -1), fx["lens"].reshape(W * B),
+                fx["dol"].reshape(W * B))
+        want = _plain_steps(fx, by)
+        scopes |= {"scan"}
+        lanes, plan, delta, dbase = (fx["enc"], fx["lens"], fx["dol"]), \
+            None, None, None
+        if "_plan" in stages:
+            dev_delta = jax.device_put(fx["delta"])
+            plan, dbase, probe = _dedup_plan(
+                fx, by, (lambda e, l, d: delta_match(
+                    dev_delta, e, l, d, match_cap=4))
+                if "_delta" in stages else None)
+            lanes = (None, None, None)
+            if trie:    # one walk over the miss lanes, reported in row 0
+                want = want._replace(nfa_wide_steps=np.array(
+                    [probe.wide_steps] + [0] * (W - 1), np.int32))
+        if "_delta" in stages:
+            delta = RE.WindowDelta(fx["delta"], dbase)
+            kw = dict(kw, delta_match_cap=4, delta_fanout_cap=8)
+            dp = delta_overlay(fx["delta"], *flat, match_cap=4,
+                               fanout_cap=8)
+            want = want._replace(delta=type(dp)(*[
+                np.asarray(x).reshape((W, B) + x.shape[1:]) for x in dp]))
+            scopes |= {"delta"}
+        if "_compact" in stages:
+            kw = dict(kw, payload_cap=256)
+            r = want
+            want = want._replace(compact=compact_result(
+                r.matches, r.rows, r.opts, r.fan_counts, r.shared_sids,
+                r.shared_rows, r.shared_opts, payload_cap=256,
+                match_holes=not trie))
+            if delta is not None:
+                kw["d_payload_cap"] = 64
+                dp = want.delta
+                want = want._replace(d_compact=compact_result(
+                    dp.fids, dp.rows, dp.opts, dp.fan_counts,
+                    np.full((W, B, 1), -1, np.int32),
+                    np.zeros((W, B, 1), np.int32),
+                    np.zeros((W, B, 1), np.int8),
+                    payload_cap=64, match_holes=False))
+            scopes |= {"compact"}
+        fn = RE.route_window
+        args = (tables, fx["cur"]) + lanes + (fx["hash"], fx["strat"],
+                                              plan, delta)
     got = fn(*args, **kw)
+    assert isinstance(got, RE.RouteResult)
+    for name, g, w in zip(got._fields, got, want):
+        assert (g is None) == (w is None), name
     _same(got, want)
-    (res,) = [x for x in jax.tree.leaves(
-        got, is_leaf=lambda x: isinstance(x, RE.RouteResult))
-        if isinstance(x, RE.RouteResult)]
-    assert (res.nfa_wide_steps is not None) == (trie or family == "step")
+    assert (got.nfa_wide_steps is not None) == trie
     ops = [ln for ln in fn.lower(*args, **kw).compile().as_text()
            .splitlines() if "op_name=" in ln]
     for name in scopes:
