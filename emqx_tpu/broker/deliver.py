@@ -441,6 +441,9 @@ class DeliveryPlan:
             counts = self.counts
             for i in range(len(self.msgs)):
                 self.target[i] = int(counts[i])
+            # the LaneCounts points back at this plan: let go, or every
+            # delivered window's messages wait for a full collection
+            self.target = None
         # no-subscriber bookkeeping for lane-owned messages (the slow
         # closures did their own inside the inline consume)
         metrics = pool.metrics

@@ -132,8 +132,11 @@ class Node:
         self.spans = Spans(self.pipeline_telemetry, self.flight_recorder)
         # the interpreter's collections as counters (runtime.gc.*) and,
         # for generation 2, as spans; installed while a listener or the
-        # housekeeping timer runs
+        # housekeeping timer runs. Meanwhile a generation-2 collection
+        # that spent long on what survived it freezes those survivors
+        # (trace.HeapFreeze, one a process)
         self.gc_watch = GcWatch(self.metrics, self.spans)
+        self.stats.register_stats_fun(self.gc_watch.stats_fun)
         # fault-domain supervision (ISSUE 6): the per-node supervision
         # tree every pipeline stage plugs into — fault injection points,
         # per-stage circuit breakers driving the degradation ladder
@@ -450,6 +453,11 @@ class Node:
             # the post-recovery flags on the same tick
             self.overload_governor.poll()
         self.stats.sample()
+        # churn among what the collector no longer walks: past a share
+        # of the frozen heap this pass pays one full collection for it
+        self.gc_watch.housekeeping(
+            self.metrics.val("client.disconnected"),
+            self.stats.getstat("subscriptions.count"))
         for app in self._apps:
             tick = getattr(app, "tick", None)
             if tick is not None:

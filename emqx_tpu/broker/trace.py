@@ -20,8 +20,10 @@ coroutines' work to itself. Waits (enqueue, lane_admit, lane_drain, the
 window and message roll-ups) are recorded in retrospect
 (`Spans.record`): histogram and ring only. `GcWatch` counts the
 interpreter's collections (`runtime.gc.*`) and makes a generation-2
-collection a span (`emqx:gc`). With ``broker.trace`` off the ring is
-absent and sinks (a) and (c) remain.
+collection a span (`emqx:gc`), and reports each of those to the
+process's `HeapFreeze`, which moves a heap that a long full collection
+found alive into the collector's permanent generation. With
+``broker.trace`` off the ring is absent and sinks (a) and (c) remain.
 
 **The flight recorder.** PR 1's stage histograms aggregate away exactly
 what the device-e2e gap diagnosis needs: CAUSALITY (which admit fed
@@ -76,6 +78,8 @@ the shared Metrics registry.
 
 from __future__ import annotations
 
+import asyncio
+import gc
 import itertools
 import json
 import os
@@ -470,6 +474,177 @@ def spans_of(node) -> Spans:
     return sp
 
 
+# ---- the long-lived heap, out of the collector's way (ISSUE 31) --------
+#
+# CPython re-walks the whole old generation every time the objects
+# promoted into it pass a quarter of its size, to find that a broker's
+# subscription table, router, host trie and session maps are all still
+# alive. Sized on Python 3.12 (PR 31): a JAX-loaded process with a
+# `Node` and no subscription tracks 89,000 containers and a full
+# collection takes 27-29 ms; `mixed-zipf`'s 125,000 subscriptions make
+# that 2.76M containers (22 a subscription) and 542-578 ms, 0.197 us a
+# container; the benchmark's cells read 260-1,187 ms. After
+# `gc.freeze()` the same collection takes 0.04 ms.
+
+# A full collection's pause is time on what survived plus time on what
+# it reclaimed; this is the second, a reclaimed object. Read off the
+# cells' windows (my chip runs, PR 31): collections of `plus-100k.flood`
+# that reclaimed nothing took 20.7-29 ms, those that reclaimed 24,672
+# objects 26-36 ms; `mixed-zipf.flood` 20-31 ms at 3,000 and 47 ms at
+# 72,000. (Those were the delivered windows: a finished `DeliveryPlan`
+# and its `LaneCounts` pointed at each other until the same PR.)
+FREEZE_RECLAIM_S = 0.3e-6
+# Once the time on survivors is this far above what it was right after
+# the last freeze, the heap has proven long-lived: above the bare
+# process's 27-29 ms, a fifth of the shortest pause the cells read.
+# What is in flight (8,192 PUBLISHes and their deliveries: 20-25 ms a
+# collection in `plus-100k.flood`) is walked every time, frozen or not,
+# so it is the base and not the heap: a freeze that took nothing off
+# the pause is not tried again.
+FREEZE_PAUSE_FLOOR_S = 0.050
+# With the table frozen, the collector's own rule for the old generation
+# (collect it once the objects promoted since the last time pass a
+# quarter of it) is always met, because the quarter is of what is not
+# frozen: a full collection then ran every 11th generation-1
+# collection, 1.5-2.4 a second under a flood, 77-122 in a 51 s window,
+# each walking what is in flight for 20-30 ms to find nothing (my chip
+# runs, PR 31). While the heap is frozen the old generation waits for
+# this many generation-1 collections instead of the interpreter's 10:
+# one full collection every ~3 s at the cells' rates. The young
+# thresholds are left alone, and the interpreter's own come back when
+# the heap is thawed.
+FROZEN_OLD_THRESHOLD = 50
+# Only a reference cycle among frozen objects is never reclaimed (a
+# closed connection's channel and session; everything else still dies
+# by reference count). One unfreeze + full collection + freeze is worth
+# its pause once the connections closed and subscriptions removed since
+# the last freeze, at 22 containers each, reach this share of the
+# frozen count.
+REEVALUATE_CHURN_SHARE = 0.10
+CONTAINERS_PER_SUBSCRIPTION = 22
+
+
+class HeapFreeze:
+    """The process's one owner of `gc.freeze()`.
+
+    A generation-2 collection that spent long on what survived it has
+    proven the heap long-lived: the survivors move into the collector's
+    permanent generation on the loop's next turn, so later full
+    collections walk only what was allocated since. The subscription
+    table grows through ten or more such collections, so the policy
+    engages during SUBSCRIBE and again whenever the heap has grown a
+    new long-lived part. "Long" is the pause less the time its
+    reclaimed objects took, measured from the first full collection
+    after the last freeze: what is in flight is walked every time and
+    no freeze takes it off the pause, so a freeze that did not help is
+    not repeated and the policy converges. Decided from what the
+    collector reports; there is no setting.
+
+    The freeze is process-global and so is this object (`HEAP`): every
+    started `GcWatch` attaches, every attached watch counts each freeze
+    in its own node's metrics, and the last to detach unfreezes, so a
+    process that embeds a node gets its heap, and its collector's
+    thresholds, back. `collector` is the `gc` module or a stand-in with
+    its `freeze`, `unfreeze`, `collect`, `get_freeze_count`,
+    `get_threshold` and `set_threshold`."""
+
+    def __init__(self, collector=gc):
+        self.gc = collector
+        self._watches: list = []
+        self._due = None        # the watch whose loop owes a freeze
+        self._base = 0.0        # survivor time right after the last one
+        self._busy = False      # inside a collection of our own
+        self.frozen = 0         # `get_freeze_count()` after the last one
+        self.churn = 0          # closed + removed since the last one
+        self._thresholds = None  # the interpreter's, while frozen
+
+    def attach(self, watch) -> None:
+        self._watches.append(watch)
+
+    def detach(self, watch) -> None:
+        self._watches.remove(watch)
+        if self._due is watch:
+            self._due = None
+        if not self._watches and self._thresholds is not None:
+            self.gc.unfreeze()
+            self.gc.set_threshold(*self._thresholds)
+            self._thresholds = None
+            self.frozen = self.churn = 0
+            self._base = 0.0
+
+    def collected(self, watch, pause_s: float, reclaimed: int) -> None:
+        """A generation-2 collection ended; every attached watch reports
+        it from its `gc.callbacks` entry, on whichever thread ran it.
+        The first report of one that was long on survivors schedules
+        the freeze on that watch's loop; the first after a freeze is
+        the base the next are measured from."""
+        if self._due is not None or self._busy:
+            return
+        survived_s = pause_s - reclaimed * FREEZE_RECLAIM_S
+        if self._base is None:
+            self._base = survived_s
+        elif survived_s - self._base >= FREEZE_PAUSE_FLOOR_S:
+            # owed before it is scheduled: a collection on an executor
+            # thread (a snapshot build) races the loop to the next line
+            self._due = watch
+            if not watch.call_soon(self._freeze, pause_s):
+                self._due = None
+
+    def _freeze(self, pause_s: float) -> None:
+        if self._due is None:       # detached meanwhile
+            return
+        self._due = None
+        self._refreeze(thaw=False)
+        for w in self._watches:
+            w.note("freezes", "gc_freeze", self.frozen,
+                   pause_ms=round(pause_s * 1e3, 1))
+
+    def _refreeze(self, thaw: bool) -> None:
+        """`gc.freeze()`, over the thawed and collected heap where
+        `thaw`. Then one full collection of the little that is younger
+        than the freeze: it resets the collector's count of the old
+        generation, which a bare `gc.freeze()` leaves at the frozen
+        heap's size, so that the next full collection would wait for a
+        quarter of that to be promoted, cycles piling up meanwhile, and
+        be taken for the base long after the heap had grown again."""
+        self._busy = True
+        try:
+            if thaw:
+                self.gc.unfreeze()
+                self.gc.collect()
+            self.gc.freeze()
+            self.gc.collect()
+        finally:
+            self._busy = False
+        if self._thresholds is None:
+            self._thresholds = young0, young1, _old = self.gc.get_threshold()
+            self.gc.set_threshold(young0, young1, FROZEN_OLD_THRESHOLD)
+        self._base = None
+        self.frozen = self.gc.get_freeze_count()    # a walk, ~12 ms a million
+        self.churn = 0
+
+    def churned(self, n: int) -> None:
+        """`n` more connections closed or subscriptions removed (a
+        node's housekeeping pass): re-evaluate when it is worth it."""
+        self.churn += n
+        if self.frozen and self.churn * CONTAINERS_PER_SUBSCRIPTION \
+                >= REEVALUATE_CHURN_SHARE * self.frozen:
+            self.reevaluate()
+
+    def reevaluate(self) -> None:
+        """One full collection over the thawed heap, then freeze what
+        survived: the only way a cycle among frozen objects is ever
+        reclaimed. One pause of the size the freeze took away."""
+        before = self.frozen
+        self._refreeze(thaw=True)
+        for w in self._watches:
+            w.note("reevaluations", "gc_reevaluate", self.frozen,
+                   frozen_before=before)
+
+
+HEAP = HeapFreeze()
+
+
 class GcWatch:
     """The interpreter's collections, counted where they happen: one
     `gc.callbacks` entry while the node serves (`start`/`stop` are
@@ -479,27 +654,42 @@ class GcWatch:
     heap, with every thread stopped) is also a span: `emqx:gc` on the
     profiler timeline, entered in the callback's start phase and left
     in its stop phase (the same thread by construction), and a `gc`
-    span on the ring's node trace."""
+    span on the ring's node trace. Each one is also reported to the
+    process's `HeapFreeze` (`heap`), whose freezes and re-evaluations
+    come back as `runtime.gc.freezes` / `.reevaluations`, the gauge
+    `runtime.gc.frozen_objects` and `gc_freeze` / `gc_reevaluate`
+    events on the node trace."""
 
     def __init__(self, metrics, spans: Spans):
         self.metrics = metrics
         self.spans = spans
+        self.heap = HEAP
         self._users = 0
+        self._loop = None
         self._t0 = 0.0
         self._ann = None
+        self._closed = self._subs = 0
 
     def start(self) -> None:
         self._users += 1
+        if self._loop is None:
+            try:
+                self._loop = asyncio.get_running_loop()
+            except RuntimeError:
+                pass            # counted, never frozen, until a loop runs
         if self._users == 1:
-            import gc
+            for name in ("freezes", "reevaluations"):
+                self.metrics.inc(f"runtime.gc.{name}", 0)
             gc.callbacks.append(self._on_gc)
+            self.heap.attach(self)
 
     def stop(self) -> None:
         if self._users == 0:
             return
         self._users -= 1
         if self._users == 0:
-            import gc
+            self._loop = None
+            self.heap.detach(self)
             try:
                 gc.callbacks.remove(self._on_gc)
             except ValueError:
@@ -520,10 +710,42 @@ class GcWatch:
         m = self.metrics
         m.inc(f"runtime.gc.pauses.gen{gen}")
         m.inc("runtime.gc.pause_us", round((t1 - self._t0) * 1e6))
+        if gen != 2:
+            return
         rec = self.spans.rec
-        if gen == 2 and rec is not None:
+        if rec is not None:
             rec.record(NODE_TRACE, "gc", self._t0, t1, track="runtime",
                        meta={"generation": 2})
+        self.heap.collected(self, t1 - self._t0, info.get("collected", 0))
+
+    def call_soon(self, fn, *args) -> bool:
+        """Run `fn` on the next turn of the loop this watch was started
+        on, from any thread; False where there is none to run it."""
+        loop = self._loop
+        if loop is None or loop.is_closed():
+            return False
+        loop.call_soon_threadsafe(fn, *args)
+        return True
+
+    def note(self, counter: str, event: str, frozen: int, **meta) -> None:
+        """The heap was frozen or re-evaluated while this node served."""
+        self.metrics.inc(f"runtime.gc.{counter}")
+        rec = self.spans.rec
+        if rec is not None:
+            rec.event(NODE_TRACE, event, track="runtime",
+                      meta=dict(meta, frozen_objects=frozen))
+
+    def housekeeping(self, closed: int, subscriptions: int) -> None:
+        """The node's housekeeping pass: connections closed so far and
+        subscriptions held now; what closed or went since the last pass
+        is churn among what may be frozen."""
+        churn = closed - self._closed + max(0, self._subs - subscriptions)
+        self._closed, self._subs = closed, subscriptions
+        if self._users and churn > 0:
+            self.heap.churned(churn)
+
+    def stats_fun(self, stats) -> None:
+        stats.setstat("runtime.gc.frozen_objects", self.heap.frozen)
 
 
 # ---- the overlap/bubble analyzer (pure functions, reusable offline) ----
