@@ -13,7 +13,7 @@ into $share groups (BASELINE.md config 4).
 One process holds the chip: this script starts JAX itself, names the
 device in its JSON and exits non-zero when JAX finds no TPU (`--phase0`
 and the full run; the `--latency-probe` / `--skew` / `--churn` / `--cover`
-/ `--fanout` modes are CPU correctness drives and run anywhere). The
+modes are CPU correctness drives and run anywhere). The
 full run's `cpu_*` rows are `JAX_PLATFORMS=cpu` children — correctness
 rows, never speeds; they keep the chip free for this process. One JSON
 line is printed on stdout; a failed phase records `<phase>_error` in it
@@ -1271,13 +1271,11 @@ _CPU_ROWS_LATE = (
     ("cpu_skew", os.path.join(_TOOLS, "skew_bench.py"), (), 600),
     ("cpu_churn", os.path.join(_TOOLS, "churn_bench.py"), (), 600),
     ("cpu_cover", os.path.join(_TOOLS, "cover_bench.py"), (), 600),
-    ("cpu_fanout", os.path.join(_TOOLS, "fanout_bench.py"), (), 600),
     ("cpu_ingress", os.path.join(_TOOLS, "ingress_bench.py"), (), 1500),
     ("cpu_overload", os.path.join(_TOOLS, "overload_bench.py"), (), 1200),
 )
 # bulky sub-sections a row drops before it is embedded
-_CPU_ROW_DROP = {"cpu_skew": ("telemetry",), "cpu_churn": ("overlay",),
-                 "cpu_fanout": ("deliver",)}
+_CPU_ROW_DROP = {"cpu_skew": ("telemetry",), "cpu_churn": ("overlay",)}
 
 
 def _cpu_row(key: str, script: str, argv: tuple, timeout_s: int) -> dict:
@@ -1328,8 +1326,7 @@ def main() -> int:
         return 0
     # CPU correctness microbenches; the harnesses live in tools/
     for flag, mod in (("--skew", "skew_bench"), ("--churn", "churn_bench"),
-                      ("--cover", "cover_bench"),
-                      ("--fanout", "fanout_bench")):
+                      ("--cover", "cover_bench")):
         if flag in sys.argv:
             __import__(mod).main()
             return 0

@@ -19,7 +19,13 @@ Round-2 rework:
   a consumer task completes them strictly in FIFO order, so per-publisher
   ordering holds even when device- and host-routed batches interleave
   (host batches ride the same in-order queue and are routed at consume
-  time, never early).
+  time, never early). What is in flight is also bounded in DELIVERIES
+  (`_ROWS_IN_FLIGHT`, by the last plans' rows a message): no window
+  forms while the formed windows and the lanes' plans stand for more,
+  `max_pending` shrinks by the same measure and a host probe is cut to
+  `_PROBE_ROWS`, so at a fan-out of 1,000 the publishers feel the
+  lanes instead of 100k messages queueing; at a fan-out of 2 the
+  bounds are idle.
 - **Adaptive with live probes both ways**: the device/host choice compares
   measured EWMA costs. The host cost is refreshed by an ACTIVE probe every
   `host_probe_every` device batches (round 2's estimator starved: under
@@ -69,6 +75,14 @@ from emqx_tpu.broker.message import Message
 # batches, so a transiently slow device (cold compile, a stall) is not
 # written off forever
 _PROBE_EVERY = 64
+# deliveries that may stand between the batch queue and the sockets (the
+# windows formed and not yet settled, by their recent fan-out, plus the
+# lanes' plans), and in the batch queue itself: one sub-batch of 1,024
+# at a fan-out of 128, fifty at 2.5, which is more than a flood's closed
+# loop holds, so at a narrow fan-out the bound is idle
+_ROWS_IN_FLIGHT = 1 << 17
+# deliveries the chooser's host probe may stand for (`_probe_cap`)
+_PROBE_ROWS = 1 << 13
 
 
 def resolve_dispatch_depth(configured=None) -> int:
@@ -156,6 +170,14 @@ class PublishBatcher:
         # the chooser bypasses — early windows stay small so a slow
         # device is discovered after ~1 batch of regret, not 8
         self._fuse_cwnd = 1
+        # deliveries a routed message, as the last plans had them: what
+        # is in flight is bounded in deliveries (`_ROWS_IN_FLIGHT`)
+        self._rows_per_msg = 1.0
+        # messages formed into entries / taken up by the consumer for
+        # settle (both only grow): their difference waits in
+        # `_inflight` or the settle ring
+        self._formed = 0
+        self._taken = 0
         # fire-and-forget backpressure bound: beyond this, enqueue() refuses
         # and the caller must await submit() (stalling its read loop)
         self.max_pending = max_pending or 8 * max_batch
@@ -220,7 +242,7 @@ class PublishBatcher:
         caller must fall back to awaiting submit()."""
         if self._shed_qos0(msg):
             return True      # accepted-and-shed: no fallback submit
-        if len(self._queue) >= self.max_pending:
+        if len(self._queue) >= self._pending_limit():
             return False
         self._queue.append((msg, None))
         self._q_times.append(time.perf_counter())
@@ -250,7 +272,7 @@ class PublishBatcher:
         q = self._queue
         qt = self._q_times
         now = time.perf_counter()
-        over = len(q) + len(rows) > self.max_pending
+        over = len(q) + len(rows) > self._pending_limit()
         last = len(rows) - 1
         for i, (msg, need) in enumerate(rows):
             if not need and self._shed_qos0(msg):
@@ -312,6 +334,7 @@ class PublishBatcher:
                     self.engine.abandon(entry["handle"])
                 if self.sup is not None:
                     self.sup.journal_settle(entry.get("wid"))
+        self._taken = self._formed      # nothing waits any more
         self._task = None
         self._consumer = None
 
@@ -347,6 +370,7 @@ class PublishBatcher:
                             if sampled is None:
                                 sampled = []
                             sampled.append((len(batch) - 1, tq))
+                    self._formed += len(batch)
                     entry = {"batch": batch, "handle": None, "sub": 0,
                              "dispatch_fut": None, "live": None,
                              "live_idx": None, "t_enq": t_enq}
@@ -378,7 +402,21 @@ class PublishBatcher:
                         entry["wid"] = self.sup.journal_admit(batch)
                     return entry
 
-                group = [form_entry()]
+                # what is in flight is bounded in deliveries: a window
+                # that waits its settle turn holds no pipeline slot, so
+                # with the lanes slower than the stages ahead of them
+                # the consumer took in whatever was published, which
+                # counted in messages is nothing at a fan-out of 2 and
+                # minutes of delivery at 1,000. Held here, the queue
+                # passes its limit and the read loops stall: the
+                # publishers feel the lanes
+                pool = getattr(self.node, "deliver_lanes", None)
+                while pool is not None \
+                        and self._rows_in_flight() > _ROWS_IN_FLIGHT:
+                    await pool.progress()   # a plan done, or `_take`
+                # a host probe is one batch at host speed, a delivery
+                # at a time: bounded in deliveries too
+                group = [form_entry(self._probe_cap())]
                 try:
                     await self._fold_hooks(group[0])
                     if self.engine is not None:
@@ -442,7 +480,9 @@ class PublishBatcher:
                                 self._fuse_cwnd)
                         while (len(group) < fuse_cap
                                and len(self._queue)
-                               >= self.device_min_batch):
+                               >= self.device_min_batch
+                               and self._rows_in_flight()
+                               <= _ROWS_IN_FLIGHT):
                             # later sub-batches must stay inside the
                             # window class too
                             e2 = form_entry(cap=b_std)
@@ -555,6 +595,7 @@ class PublishBatcher:
                     # trickle rates stays where the pre-pipeline drain had
                     # it (SURVEY §7 hard-part 2's dedicated small-batch
                     # path)
+                    self._take(group[0])
                     try:
                         await self._complete_host(group[0])
                     except asyncio.CancelledError:
@@ -811,6 +852,7 @@ class PublishBatcher:
                 if self._park_ok():
                     return
                 continue
+            self._take(entry)
             self._consuming = True
             try:
                 routed = None
@@ -943,6 +985,7 @@ class PublishBatcher:
                 if not ring:
                     continue
                 entry = ring.popleft()
+                self._take(entry)
                 # pipelined-cost sampling hint: more windows behind us
                 # means the completion-to-completion sample is the
                 # amortized rate (same rule as the depth-1 queue check)
@@ -974,6 +1017,47 @@ class PublishBatcher:
                 self._fail_entry(e, err)
             self._consuming = False
             raise
+
+    def _take(self, entry: dict) -> None:
+        """The consumer takes `entry` up for settle: its messages no
+        longer wait in `_inflight` or the ring, and a producer held by
+        `_ROWS_IN_FLIGHT` looks again."""
+        self._taken += len(entry["batch"])
+        pool = getattr(self.node, "deliver_lanes", None)
+        if pool is not None:
+            pool.nudge()
+
+    def _rows_in_flight(self) -> float:
+        """Deliveries between the batch queue and the sockets: the
+        messages formed into windows and not yet taken up for settle,
+        by their recent fan-out, plus the rows of the lanes' plans."""
+        pool = getattr(self.node, "deliver_lanes", None)
+        return (self._formed - self._taken) * self._rows_per_msg \
+            + (pool.live_rows if pool is not None else 0)
+
+    def _probe_cap(self) -> Optional[int]:
+        """How many messages the next batch may hold where it is due
+        to be the chooser's host probe (`_device_worth_it`) and a full
+        batch would stand for more than `_PROBE_ROWS` deliveries on
+        the host route: the host's cost is a message's, so a probe of
+        74 messages at a fan-out of 110 measures what one of 1,024
+        does, in a fourteenth of the seconds. None: a full batch."""
+        if self._dev_batch_s is None or not (
+                self._host_msg_s is None
+                or self._since_host_probe >= self.host_probe_every):
+            return None
+        cap = max(self.device_min_batch,
+                  int(_PROBE_ROWS / self._rows_per_msg))
+        return cap if cap < self.max_batch else None
+
+    def _pending_limit(self) -> int:
+        """`max_pending`, cut where the queue alone would stand for
+        more than `_ROWS_IN_FLIGHT` deliveries (never below one full
+        batch)."""
+        rpm = self._rows_per_msg
+        if rpm * self.max_pending <= _ROWS_IN_FLIGHT:
+            return self.max_pending
+        return max(self.max_batch, int(_ROWS_IN_FLIGHT / rpm))
 
     async def _complete_device(self, entry: dict, loop) -> Optional[list]:
         """Await dispatch + readback off-loop, consume on-loop. Returns the
@@ -1053,6 +1137,12 @@ class PublishBatcher:
                 self._note_replay_span(entry, "consume",
                                        type(e).__name__)
                 return None
+        plan = getattr(counts, "plan", None)
+        if plan is not None and plan.msgs:
+            # up at once, down by halves: it bounds what is in flight
+            rpm = plan.n_rows / len(plan.msgs)
+            self._rows_per_msg = max(
+                rpm, (rpm + self._rows_per_msg) / 2)
         pool = getattr(self.node, "deliver_lanes", None)
         if pool is not None and pool.active():
             # backpressure: too many plans queued in the delivery lanes

@@ -95,6 +95,9 @@ class Channel:
         self.conninfo = conninfo        # peername, sockname, ws?, zone
         self.send = send
         self.close = close
+        # the transport's write of frames that are serialized already
+        # (`_send_shared`), where it has one: `Connection` sets it
+        self.send_frames: Optional[Callable[[bytes], None]] = None
         self.conn_state = CONN_IDLE
         self.zone = conninfo.get("zone")
         self.mqtt = node.config.mqtt(self.zone)
@@ -983,6 +986,8 @@ class Channel:
             return True
         if self.session is None:
             return False
+        if self._send_shared(((topic_filter, msg),)):
+            return True
         subopts = msg.headers.get("subopts", {})
         if (self.mqtt.get("ignore_loop_deliver")
                 and msg.from_ == self.clientid):
@@ -1013,6 +1018,8 @@ class Channel:
             return len(items)
         if self.session is None:
             return 0
+        if self._send_shared(items):
+            return len(items)
         metrics = self.node.metrics
         ignore_loop = self.mqtt.get("ignore_loop_deliver")
         pairs = []
@@ -1032,6 +1039,38 @@ class Channel:
             else:
                 self._send_deliveries(self.session.deliver(pairs))
         return len(items)
+
+    def _send_shared(self, items) -> bool:
+        """ROADMAP Speed 1: a run of lane deliveries (`DeliveryView`s)
+        that are each one frame for every subscriber goes out as those
+        frames, joined, with the counting `Session.deliver`,
+        `_send_deliveries` and `_send` do a message. False, and nothing
+        sent, where any of them or this connection needs a copy of its
+        own (QoS above 0, no-local on the publisher, an expiry, a
+        topic alias, a mountpoint, a client that is not connected)."""
+        raw = self.send_frames
+        if raw is None or self.conn_state != CONN_CONNECTED \
+                or self.alias_out_max or self.mountpoint or self._aborted \
+                or self.session.conf.upgrade_qos \
+                or self.mqtt.get("ignore_loop_deliver"):
+            return False
+        ver, me = self.proto_ver, self.clientid
+        frames = []
+        for _f, msg in items:
+            wire = getattr(msg, "wire_qos0", None)
+            data = wire(ver, me) if wire is not None else None
+            if data is None:
+                return False
+            frames.append(data)
+        n = len(frames)
+        self.session.deliver_count += n
+        metrics = self.node.metrics
+        metrics.inc("messages.sent", n)
+        metrics.inc("messages.qos0.sent", n)
+        metrics.inc("packets.sent", n)
+        metrics.inc("packets.publish.sent", n)
+        raw(frames[0] if n == 1 else b"".join(frames))
+        return True
 
     def _send_deliveries(self, out: list) -> None:
         pkts = []

@@ -90,6 +90,7 @@ class Connection:
             node, {"peername": peer, "sockname": sock, "zone": zone,
                    "peercert": peercert},
             send=self._send_packets, close=self._request_close)
+        self.channel.send_frames = self._send_frames
         self.last_rx = time.monotonic()
         self._closing: Optional[str] = None
         self._timer_task: Optional[asyncio.Task] = None
@@ -118,9 +119,14 @@ class Connection:
 
     # ---- outbound ----
     def _send_packets(self, pkts: list[P.Packet]) -> None:
+        if not self.writer.is_closing():
+            self._send_frames(b"".join(
+                serialize(p, self.channel.proto_ver) for p in pkts))
+
+    def _send_frames(self, data: bytes) -> None:
+        """Frames serialized already (`Channel._send_shared`)."""
         if self.writer.is_closing():
             return
-        data = b"".join(serialize(p, self.channel.proto_ver) for p in pkts)
         self.node.metrics.inc("bytes.sent", len(data))
         self.writer.write(data)
 

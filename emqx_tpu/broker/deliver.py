@@ -220,7 +220,7 @@ class DeliveryView:
 
     __slots__ = ("topic", "payload", "qos", "from_", "id", "ts", "extra",
                  "_base_flags", "_base_headers", "_subopts", "_flags",
-                 "_headers")
+                 "_headers", "_wire")
 
     def __init__(self, msg, subopts: dict):
         self.topic = msg.topic
@@ -235,6 +235,44 @@ class DeliveryView:
         self._subopts = subopts
         self._flags = None
         self._headers = None
+        self._wire = None       # protocol version -> shared frame | None
+
+    # -- the frame every subscriber gets (ROADMAP Speed 1) --
+    def wire_qos0(self, ver: int, clientid: str) -> Optional[bytes]:
+        """This delivery's PUBLISH frame at protocol `ver` where it is
+        the same bytes for every subscriber the view is shared by: QoS
+        0 after the subscription's cap (no packet id), no expiry to
+        recompute at send time, nothing written through the view.
+        Serialized once a (view, version). None where a subscriber's
+        own copy has to be built: the caller then takes the path every
+        delivery took before (`Session.deliver` + `_to_publish`)."""
+        so = self._subopts
+        if so.get("nl") and self.from_ == clientid:
+            return None
+        w = self._wire
+        if w is None:
+            w = self._wire = {}
+        elif ver in w:
+            return w[ver]
+        data = None
+        props = self._base_headers.get("properties")
+        if min(self.qos, so.get("qos", 0)) == 0 \
+                and self._headers is None and self._flags is None \
+                and "subid" not in so \
+                and not (props and "message_expiry_interval" in props):
+            from emqx_tpu.mqtt import packet as P
+            from emqx_tpu.mqtt.frame import serialize
+            flags = self._base_flags
+            # Session._enrich: the retain bit survives only with
+            # retain-as-published or on a retained-store replay
+            retain = bool(flags.get("retain")) and bool(
+                so.get("rap") or flags.get("retained"))
+            data = serialize(P.Publish(
+                topic=self.topic, payload=self.payload, qos=0,
+                retain=retain, dup=bool(flags.get("dup")), packet_id=0,
+                properties=dict(props or {}) if ver == 5 else None), ver)
+        w[ver] = data
+        return data
 
     # -- copy-on-write materialization --
     def _materialize_headers(self) -> dict:
@@ -351,7 +389,8 @@ class DeliveryPlan:
     __slots__ = ("pool", "msgs", "counts", "fast_idx", "slow_items",
                  "filters", "_chunks", "routed_device", "pending",
                  "done", "target", "_cbs", "s_midx", "s_sid", "s_opt",
-                 "s_fid", "_barrier_left", "_barrier_evt", "trace")
+                 "s_fid", "_barrier_left", "_barrier_evt", "trace",
+                 "n_rows")
 
     def __init__(self, pool: "DeliveryLanePool", msgs: list):
         self.pool = pool
@@ -361,6 +400,7 @@ class DeliveryPlan:
         self.slow_items: list[tuple[int, Callable[[], int]]] = []
         self.filters = None         # fid -> topic-filter string
         self._chunks: list[tuple] = []
+        self.n_rows = 0             # fast rows handed to the lanes
         self.routed_device = False
         self.pending = 0            # outstanding lane parts
         self.done = False
@@ -520,6 +560,10 @@ class DeliveryLanePool:
         self._gate: Optional[asyncio.Event] = None
         self._paused = False
         self._live_plans = 0
+        # fast rows of the plans in flight: the batcher bounds what it
+        # forms by them (`_rows_in_flight`), since a plan is 1,000 rows
+        # or 100,000 by the fan-out of what was published
+        self.live_rows = 0
         self._plans: list[DeliveryPlan] = []     # in-flight, FIFO
         self._lane_items: list[int] = [0] * n_lanes  # real work per lane
         # same-sid coalescing yields one drain per run; chunk big slices
@@ -571,6 +615,7 @@ class DeliveryLanePool:
             # callbacks release pinned snapshot handles — leaking one
             # would block every future swap on this engine
             self._live_plans = len(orphans)
+            self.live_rows = sum(p.n_rows for p in orphans)
             for p in orphans:
                 p.pending = 0
                 p._finalize()
@@ -641,6 +686,7 @@ class DeliveryLanePool:
                     continue
                 parts += 1
                 slices.append((ln, lo, hi))
+            plan.n_rows = len(order)
             self.metrics.inc("pipeline.deliver.rows", len(order))
         if plan.slow_items:
             parts += 1
@@ -651,6 +697,7 @@ class DeliveryLanePool:
         # wedge drain()/admit() forever)
         plan.pending = parts
         self._live_plans += 1
+        self.live_rows += plan.n_rows
         for ln, lo, hi in slices:
             self._lane_items[ln] += 1
             self._queues[ln].put_nowait(("slice", plan, lo, hi))
@@ -667,12 +714,32 @@ class DeliveryLanePool:
         else:
             self._plans.append(plan)
 
+    def deliver_now(self, msgs: list, midx, sid, opt, fid,
+                    filters) -> np.ndarray:
+        """The synchronous form, for a caller that needs its counts on
+        return (`finish_sub(defer=False)`): the rows of one sub-batch,
+        walked session by session on the caller's stack exactly as a
+        lane walks its slice (shared views, one `deliver_batch` a
+        session). Stable in the session id, so every session sees its
+        rows in the order given. Returns the deliveries a message."""
+        plan = DeliveryPlan(self, msgs)
+        plan.routed_device = True
+        plan.filters = filters
+        order = np.argsort(sid, kind="stable")
+        plan.s_midx = np.asarray(midx)[order].tolist()
+        plan.s_sid = np.asarray(sid)[order].tolist()
+        plan.s_opt = np.asarray(opt)[order].tolist()
+        plan.s_fid = np.asarray(fid)[order].tolist()
+        self._deliver_rows(plan, 0, len(order))
+        return plan.counts
+
     def _plan_done(self, plan: DeliveryPlan) -> None:
         try:
             self._plans.remove(plan)
         except ValueError:
             pass    # zero-part plans finalize before tracking
         self._live_plans -= 1
+        self.live_rows -= plan.n_rows
         if self._wake is not None:
             self._wake.set()
         if self._live_plans == 0:
@@ -712,6 +779,22 @@ class DeliveryLanePool:
         while self._live_plans > 0:
             self._wake.clear()
             await self._wait_wake()
+
+    async def progress(self) -> None:
+        """One wait for the lanes to move: a plan done, or a `nudge`.
+        For a caller that holds work back by `live_rows` (the batcher's
+        `_ROWS_IN_FLIGHT`) and looks again after each."""
+        if self._wake is None:
+            await asyncio.sleep(0)
+            return
+        self._wake.clear()
+        await self._wait_wake()
+
+    def nudge(self) -> None:
+        """Something ahead of the lanes moved (the batcher's consumer
+        took a window up): whoever waits in `progress` looks again."""
+        if self._wake is not None:
+            self._wake.set()
 
     async def _wait_wake(self) -> None:
         """One bounded wait on lane progress. With a supervisor
@@ -771,6 +854,7 @@ class DeliveryLanePool:
             "lanes": self.n_lanes,
             "depth_limit": self.depth,
             "live_plans": self._live_plans,
+            "live_rows": self.live_rows,
             "queued_items": self.queued_items(),
             "lane_depth": self.lane_depth(),
             "paused": self._paused,

@@ -525,7 +525,7 @@ class _Built:
     __slots__ = ("fid_of", "fid_filter", "seg_len", "slot_of", "slot_key",
                  "n_slots", "backend", "remote_members", "seg_np",
                  "fid_shared", "fid_rich", "sid", "match_width", "cover",
-                 "cover_decision")
+                 "cover_decision", "sub_start", "sub_row", "sub_opts")
 
     def __init__(self):
         self.fid_of: dict[str, int] = {}
@@ -549,6 +549,14 @@ class _Built:
         self.seg_np = np.zeros(0, np.int64)       # seg_len as an array
         self.fid_shared = np.zeros(0, bool)       # fid has shared groups
         self.fid_rich = np.zeros(0, bool)         # fid has rich subopts
+        # the device SubTable's normal-subscriber CSR as the build made
+        # it (fid -> sub_start[fid]..[fid + 1] of sub_row / sub_opts; 5 B
+        # a subscription): a filter with more than `fanout_cap`
+        # subscribers travels by reference (ops/fanout.fanout_normal),
+        # and consume reads its rows from here
+        self.sub_start = np.zeros(1, np.int32)
+        self.sub_row = np.zeros(0, np.int32)
+        self.sub_opts = np.zeros(0, np.int8)
         # subscription covering (ISSUE 18): _CoverState when this
         # snapshot matched the covering set only, else None. With
         # covering engaged, seg_np/fid_shared/fid_rich are padded to
@@ -570,8 +578,8 @@ class _Handle:
     sub has been finished or abandoned."""
 
     __slots__ = ("subs", "built", "dev_shared", "enc", "res", "np_res",
-                 "np_counts", "np_mov", "error", "refs", "t0", "plan",
-                 "cache_info",
+                 "np_counts", "np_mov", "np_fov", "error", "refs", "t0",
+                 "plan", "cache_info",
                  "pcap", "cres", "delta", "dres", "dcres", "np_delta",
                  "trace", "sub_traces")
 
@@ -584,6 +592,8 @@ class _Handle:
         self.np_counts = None  # match_counts [W, B] (cache population)
         self.np_mov = None    # match-stage overflow [W, B]: read back
                               # only from a trie window with a flagged lane
+        self.np_fov = None    # fan-out stage overflow [W, B], read back
+                              # only from a window with a flagged lane
         self.error = None
         self.refs = len(subs)
         self.t0 = None        # consumer-side window processing start
@@ -1371,6 +1381,8 @@ class DeviceRouteEngine:
             sub_rows_cap=_next_pow2(max(1, total_subs)),
             fs_rows_cap=_next_pow2(max(1, b.n_slots)),
             member_rows_cap=_next_pow2(max(1, total_members)))
+        b.sub_start, b.sub_row, b.sub_opts = \
+            subs_tbl.sub_start, subs_tbl.sub_row, subs_tbl.sub_opts
 
         tables = None
         if cover_np is not None:
@@ -1990,6 +2002,14 @@ class DeviceRouteEngine:
         # program would only add gather overhead (and pointless warm
         # traces for its class).
         if not (Bm < Bp or Wp > 1):
+            return None, info
+        # ... and only where repeats and hits together take two fifths
+        # of the lanes off the match: a window whose topics are mostly
+        # new ones (a quarter broadcasts on a few hot topics, the rest
+        # per-device commands) would run the plan's gathers, and ask
+        # for a program of its own class, to skip little. The cells
+        # whose plan pays remove more than half (Zipf keys: 55-64 %)
+        if 5 * (real - n_miss) < 2 * real:
             return None, info
         if self._cold(self._class_of(Wp, Bp, ov=ov)._replace(Bm=Bm),
                       gate_cold, "routing.device.cold_cached_class"):
@@ -2775,7 +2795,7 @@ class DeviceRouteEngine:
                 occur = np.asarray(res.occur)
                 pay = np.asarray(cp.payload)
                 h.np_res = _CsrRes(off, c3, pay, overflow, occur)
-                self._read_match_overflow(h, overflow)
+                self._read_overflow_stages(h, overflow)
                 metrics.inc("pipeline.readback.bytes.compact",
                             off.nbytes + c3.nbytes + pay.nbytes
                             + overflow.nbytes + occur.nbytes
@@ -2810,7 +2830,7 @@ class DeviceRouteEngine:
                     np.asarray(res.opts), np.asarray(res.shared_sids),
                     np.asarray(res.shared_rows), np.asarray(res.shared_opts),
                     np.asarray(res.overflow), np.asarray(res.occur))
-        self._read_match_overflow(h, h.np_res[6])
+        self._read_overflow_stages(h, h.np_res[6])
         dense_bytes = sum(a.nbytes for a in h.np_res) + csr_probe_bytes \
             + delta_bytes
         info = h.cache_info
@@ -2841,14 +2861,20 @@ class DeviceRouteEngine:
             self._corrupt_readback(h)
 
     @staticmethod
-    def _read_match_overflow(h, overflow: np.ndarray) -> None:
-        """Which of a trie window's flagged lanes the NFA itself gave up
-        on (frontier or match_cap), for routing.device.match_overflow.
-        One more small plane, and only when a lane was flagged at all:
-        a window without overflow reads nothing."""
-        if h.built.backend != "shapes" and overflow.any() \
+    def _read_overflow_stages(h, overflow: np.ndarray) -> None:
+        """Which stage flagged a window's flagged lanes: the NFA itself
+        (frontier or match_cap; a trie window's), for
+        routing.device.match_overflow, and the fan-out stage, for
+        routing.device.fanout_overflow. One more small plane each, and
+        only when a lane was flagged at all: a window without overflow
+        reads nothing."""
+        if not overflow.any():
+            return
+        if h.built.backend != "shapes" \
                 and h.res.match_overflow is not None:
             h.np_mov = np.asarray(h.res.match_overflow)
+        if h.res.fanout_overflow is not None:
+            h.np_fov = np.asarray(h.res.fanout_overflow)
 
     def _count_nfa_steps(self, h) -> None:
         """How many level steps the NFA took for a trie window, and how
@@ -2871,10 +2897,14 @@ class DeviceRouteEngine:
     def _note_host_fallback(self, h, k: int, i: int) -> None:
         """Lane i of sub-batch k goes to the host trie (too deep, or a
         device capacity overflowed): count it, and separately the lanes
-        the NFA's own caps sent there."""
+        the NFA's own caps sent there and those whose narrow fan-out
+        rows alone passed `fanout_cap` (a filter wider than the cap is
+        not among them: it travels by reference)."""
         self.node.metrics.inc("routing.device.host_fallback")
         if h.np_mov is not None and h.np_mov[k][i]:
             self.node.metrics.inc("routing.device.match_overflow")
+        if h.np_fov is not None and h.np_fov[k][i]:
+            self.node.metrics.inc("routing.device.fanout_overflow")
 
     def _corrupt_readback(self, h) -> None:
         """Apply the injected corrupt-shape fault: truncate the window
@@ -3153,23 +3183,65 @@ class DeviceRouteEngine:
                                   d_counts_k, plan=plan)
 
     @staticmethod
-    def _attribute_rows(mi_f, fids_f, seg, total: int):
+    def _attribute_rows(mi_f, fids_f, seg, total: int, plane_seg=None):
         """Row attribution shared by the inline loop and the lane plan:
         within each message the fan-out rows are the concatenation of
         per-filter CSR segments in match order. Returns (row_msg, col,
-        row_fid) — for every fan-out row, its message index, its column
-        within that message's fan-out, and the filter it came from."""
+        row_fid, row_local): for every fan-out row, its message index,
+        its column within that message's fan-out PLANE, the filter it
+        came from and its place in that filter's segment. `plane_seg`
+        is what each match's segment takes up of the plane where that
+        is not `seg`: 0 for a segment that travelled by reference,
+        whose rows are in no plane (their `col` is not a place to
+        read) and are read at `row_local` of the snapshot's own CSR."""
         csum = np.cumsum(seg) - seg                # global exclusive
         starts = np.flatnonzero(np.r_[True, mi_f[1:] != mi_f[:-1]])
-        base = np.repeat(csum[starts], np.diff(np.r_[starts,
-                                                     mi_f.size]))
-        within = csum - base                       # offset inside msg
         row_msg = np.repeat(mi_f, seg)
-        ar = np.arange(total)
-        row_local = ar - np.repeat(csum, seg)
-        col = np.repeat(within, seg) + row_local
+        row_local = np.arange(total) - np.repeat(csum, seg)
         row_fid = np.repeat(fids_f, seg)
-        return row_msg, col, row_fid
+        # a match's offset in its message's plane: the expanded
+        # segments before it
+        psum = csum if plane_seg is None \
+            else np.cumsum(plane_seg) - plane_seg
+        base = np.repeat(psum[starts], np.diff(np.r_[starts,
+                                                     mi_f.size]))
+        col = np.repeat(psum - base, seg) + row_local
+        return row_msg, col, row_fid, row_local
+
+    def _fast_rows(self, mi_f, fids_f, fetch, b):
+        """Every fan-out row of the clean messages' matches (`mi_f`,
+        `fids_f`: message index and filter id, in match order), as
+        (row_msg, sid, opt, row_fid) arrays in delivery order and
+        whether any segment was wide, or (None, False) when there is
+        no row. A narrow segment's rows come from the device planes
+        through `fetch`; a segment wider than `fanout_cap` travelled by
+        reference and is read from the snapshot's own CSR, at its place
+        in match order."""
+        seg = b.seg_np[fids_f]
+        total = int(seg.sum())
+        if not total:
+            return None, False
+        wide = seg > self.fanout_cap
+        any_wide = bool(wide.any())
+        row_msg, col, row_fid, row_local = self._attribute_rows(
+            mi_f, fids_f, seg, total,
+            np.where(wide, 0, seg) if any_wide else None)
+        if not any_wide:
+            sid, opt = fetch(row_msg, col)
+        else:
+            by_ref = np.repeat(wide, seg)
+            narrow = ~by_ref
+            at = b.sub_start[row_fid[by_ref]] + row_local[by_ref]
+            sid = np.empty(total, np.int32)
+            opt = np.empty(total, np.int32)
+            sid[by_ref], opt[by_ref] = b.sub_row[at], b.sub_opts[at]
+            sid[narrow], opt[narrow] = fetch(row_msg[narrow], col[narrow])
+            metrics = self.node.metrics
+            metrics.inc("routing.device.wide_segments", int(wide.sum()))
+            metrics.inc("routing.device.wide_rows", len(at))
+        valid = sid >= 0
+        return (row_msg[valid], sid[valid], opt[valid],
+                row_fid[valid]), any_wide
 
     def _fast_deliver(self, msgs, mi, fids, too_long, overflow_k,
                       shared_any, fetch, dev_shared: bool, b,
@@ -3210,31 +3282,29 @@ class DeviceRouteEngine:
         if not fast_ok.any():
             return out
         keep = fast_ok[mi]
-        mi_f, fids_f = mi[keep], fids[keep]
-        seg = b.seg_np[fids_f]
-        total = int(seg.sum())
+        rows, any_wide = self._fast_rows(mi[keep], fids[keep], fetch, b)
         if plan is not None:
             # lane hand-off: one gather pass, zero Python per-row work
             # here — the lanes deliver these messages off this stage
             fast_idx = np.flatnonzero(fast_ok)
             plan.register_fast(fast_idx)
-            if total:
-                row_msg, col, row_fid = self._attribute_rows(
-                    mi_f, fids_f, seg, total)
-                sid, opt = fetch(row_msg, col)
-                valid = sid >= 0
-                plan.add_rows(row_msg[valid], sid[valid], opt[valid],
-                              row_fid[valid], b.fid_filter)
+            if rows is not None:
+                plan.add_rows(*rows, b.fid_filter)
             for i in fast_idx.tolist():
                 out[i] = DEFERRED
             return out
         counts = np.zeros(B, np.int64)
         delivered = 0
-        if total:
-            row_msg, col, row_fid = self._attribute_rows(
-                mi_f, fids_f, seg, total)
-            sid, opt = fetch(row_msg, col)
-            valid = sid >= 0
+        pool = getattr(self.node, "deliver_lanes", None) if any_wide \
+            else None
+        if pool is not None:
+            # a sync caller's window with a wide filter in it: hundreds
+            # of rows a message, so one row at a time (a message copy
+            # and a socket write each) is what the lanes exist to
+            # avoid. The same rows, walked session by session as a lane
+            # slice is: per-session order is the inline loop's
+            counts = pool.deliver_now(msgs, *rows, b.fid_filter)
+        elif rows is not None:
             fid_filter = b.fid_filter
             deliver = broker._deliver
             # the 64-entry OPT_TABLE replaces the old per-call
@@ -3242,10 +3312,7 @@ class DeviceRouteEngine:
             # this inline path because _deliver plants the dict into
             # the delivered copy's headers — the lane path instead
             # shares the frozen table entry through the DeliveryView
-            for bi, s, ob, fd in zip(row_msg[valid].tolist(),
-                                     sid[valid].tolist(),
-                                     opt[valid].tolist(),
-                                     row_fid[valid].tolist()):
+            for bi, s, ob, fd in zip(*(a.tolist() for a in rows)):
                 if deliver(s, fid_filter[fd], msgs[bi],
                            dict(OPT_TABLE[ob & 0x3F])):
                     counts[bi] += 1
@@ -3368,21 +3435,32 @@ class DeviceRouteEngine:
             f = b.fid_filter[fid]
             seg = b.seg_len[fid]
             matched.append(f)
+            # a segment wider than the lane's cap travelled by
+            # reference: its rows are in the snapshot's own CSR, not in
+            # `r_row`, whose columns the narrow segments alone take up
+            wide = seg > self.fanout_cap
+            if wide:
+                lo, r_f, o_f = int(b.sub_start[fid]), b.sub_row, b.sub_opts
+            else:
+                lo, r_f, o_f = off, r_row, o_row
+                off += seg
             # rich-ness is snapshot state: read it from the handle's
             # pinned _Built (fid_rich), never from engine-level state —
             # one source of truth shared with the vectorized fast path
             if f in self.dirty_filters or b.fid_rich[fid]:
                 n += broker.dispatch(f, msg)
-            else:
-                for k in range(off, off + seg):
-                    sid = int(r_row[k])
-                    if sid < 0:
-                        continue
-                    if broker._deliver(sid, f, msg,
-                                       _unpack_opts(int(o_row[k]))):
-                        n += 1
-                        metrics.inc("messages.routed.device")
-            off += seg
+                continue
+            if wide:
+                metrics.inc("routing.device.wide_segments")
+                metrics.inc("routing.device.wide_rows", seg)
+            for k in range(lo, lo + seg):
+                sid = int(r_f[k])
+                if sid < 0:
+                    continue
+                if broker._deliver(sid, f, msg,
+                                   _unpack_opts(int(o_f[k]))):
+                    n += 1
+                    metrics.inc("messages.routed.device")
 
         # filters added since the snapshot (ISSUE 4): the fused overlay
         # planes deliver them from device rows; only uncovered filters
@@ -3561,6 +3639,14 @@ class DeviceRouteEngine:
             "nfa_steps": self.node.metrics.val("routing.device.nfa_steps"),
             "nfa_narrow_steps": self.node.metrics.val(
                 "routing.device.nfa_narrow_steps"),
+            # filters wider than `fanout_cap`, served from the device
+            # window by reference, and the lanes fan-out still sent to
+            # the host route
+            "wide_segments": self.node.metrics.val(
+                "routing.device.wide_segments"),
+            "wide_rows": self.node.metrics.val("routing.device.wide_rows"),
+            "fanout_overflow": self.node.metrics.val(
+                "routing.device.fanout_overflow"),
             "filters": len(b.fid_filter) if b else 0,
             "shared_slots": b.n_slots if b else 0,
             "churn": self.staleness(),

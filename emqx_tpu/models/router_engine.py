@@ -70,6 +70,10 @@ class RouteResult(NamedTuple):
     # sub-batch, whose walk is skipped). [W] from a window program; a
     # window with a plan walks once and reports it in row 0
     nfa_wide_steps: jax.Array = None
+    # [B] the fan-out stage's own part of `overflow` (the expanded rows
+    # passed `fanout_cap`): what routing.device.fanout_overflow counts.
+    # None where a program does not report it (the mesh's sharded step)
+    fanout_overflow: jax.Array = None
     # `route_window`'s optional stages, None where the stage did not
     # run. The fid spaces of `matches` (built-snapshot fids) and
     # `delta.fids` (the engine's delta fids) are disjoint by
@@ -129,11 +133,15 @@ class ExchangeResult(NamedTuple):
 
 def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
                msg_hash: jax.Array, strategy: jax.Array, *,
-               fanout_cap: int, slot_cap: int) -> RouteResult:
-    """Fan-out + shared-sub selection on a MatchResult (backend-agnostic)."""
+               fanout_cap: int, slot_cap: int,
+               wide_by_ref: bool = False) -> RouteResult:
+    """Fan-out + shared-sub selection on a MatchResult (backend-agnostic).
+    `wide_by_ref`: `ops.fanout.fanout_normal`'s; the caller's host side
+    then serves a filter wider than `fanout_cap` from its fid."""
     with jax.named_scope("fanout"):
         fr: FanoutResult = fanout_normal(subs, mr.matches,
-                                         fanout_cap=fanout_cap)
+                                         fanout_cap=fanout_cap,
+                                         wide_by_ref=wide_by_ref)
     with jax.named_scope("shared"):
         sids, slot_oflow = shared_slots(subs, mr.matches,
                                         slot_cap=slot_cap)
@@ -145,7 +153,8 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
         rows=fr.rows, opts=fr.opts, fan_counts=fr.counts,
         shared_sids=sids, shared_rows=sp.rows, shared_opts=sp.opts,
         overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur,
-        match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps)
+        match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps,
+        fanout_overflow=fr.overflow)
 
 
 @functools.partial(
@@ -336,7 +345,8 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
         with jax.named_scope("match"):
             mr_k = matched(lane)
         r = post_match(tables.subs, mr_k, cur, mh_k, strategy,
-                       fanout_cap=fanout_cap, slot_cap=slot_cap)
+                       fanout_cap=fanout_cap, slot_cap=slot_cap,
+                       wide_by_ref=True)
         return r.new_cursors, r
 
     with jax.named_scope("scan"):
@@ -366,7 +376,14 @@ def route_window(tables, cursors: jax.Array, topics: jax.Array,
     sequential `route_step_shapes` / `route_step` calls, so
     `new_cursors` / `occur` in row k reflect state after sub-batch k and
     round-robin fairness holds across the whole window (oracle-tested
-    bit for bit, every combination of stages).
+    bit for bit, every combination of stages). One difference from
+    those step programs: a filter with more than `fanout_cap`
+    subscribers is not a lane overflow here. It travels by reference
+    (`ops.fanout.fanout_normal(wide_by_ref=True)`): its fid is among
+    `matches` (and in the CSR payload's match section), its rows are in
+    none of the fan-out planes, and the engine reads them from the
+    snapshot's own CSR on the host, at the segment's place in match
+    order.
 
     Either backend: the tables' type picks the matcher (`_is_trie`; the
     trie NFA takes `frontier_cap` / `match_cap` and is skipped for a

@@ -39,6 +39,15 @@ concatenation of per-filter segments over valid matches in match order
 exactly that concatenation. `cm` equals `match_counts` for both
 backends, so delivery decisions and cache rows are unchanged.
 
+A filter wider than the lane's `fanout_cap` puts its fid into the match
+section and nothing into the fan-out sections (the served window
+program expands by `ops/fanout.fanout_normal(wide_by_ref=True)`): `cf`
+counts the narrow segments' rows alone, so a message's rows are the
+concatenation of its matched filters' segments in match order with
+each wide filter's segment read, at its place, from the CSR the host
+kept from the same build. A 1,280-subscriber message is 4 bytes of
+payload.
+
 Capacity P is a static arg (one XLA program per payload class); callers
 quantize it onto a small pow2-multiple ladder sized by an EWMA of recent
 window totals (broker/device_engine.py) so recompiles stay bounded.

@@ -5,8 +5,11 @@ Replaces the reference's per-message fold over ETS subscriber bags
 special-case in emqx_broker_helper.erl) with a batched CSR segment-gather:
 subscribers live in one columnar table (filter-id → contiguous row range);
 fan-out for a whole topic batch is a vmapped searchsorted over per-topic
-segment offsets. No shard special-case is needed — capacity is explicit and
-overflow topics fall back to the host CSR (numpy) path.
+segment offsets. No shard special-case is needed: capacity is explicit, a
+segment wider than it travels by reference where the caller asks
+(`fanout_normal(wide_by_ref=True)`: the host, which keeps the same CSR,
+reads that filter's rows from its own copy), and a topic whose narrow
+segments alone pass the capacity falls back to the host route.
 
 Outputs are *session rows* (int32 indices into the host session registry) +
 packed subscription options, not pids: the host delivers to sockets.
@@ -49,23 +52,32 @@ class SubTable(NamedTuple):
 class FanoutResult(NamedTuple):
     rows: jax.Array      # [B, D] session rows, -1 padded
     opts: jax.Array      # [B, D] packed subopts
-    counts: jax.Array    # [B] true delivery count (may exceed D)
+    counts: jax.Array    # [B] true count of the expanded rows (may
+    #                      exceed D; without the segments left by reference)
     overflow: jax.Array  # [B] bool
 
 
 def _segment_expand(starts: jax.Array, values: jax.Array, seg_ids: jax.Array,
-                    cap: int):
+                    cap: int, skip_wide: bool = False):
     """Expand CSR segments selected per batch row into fixed-width outputs.
 
     starts: [F+1] CSR. values: [S]. seg_ids: [B, M] segment (filter) ids, -1
     padded. Returns (out [B, cap] gathered values (-1 pad), idx [B, cap] flat
     indices into `values` (-1 pad), counts [B], overflow [B]).
+
+    `skip_wide` (static): a segment longer than `cap` could never fit a
+    row, so it is left out of the expansion (zero length: it takes no
+    slot, moves no later segment's rows and raises no overflow) for a
+    caller that serves it from the segment id alone.
     """
     B, M = seg_ids.shape
     valid = seg_ids >= 0
     safe = jnp.clip(seg_ids, 0, starts.shape[0] - 2)
     seg_lo = jnp.where(valid, starts[safe], 0)
     seg_len = jnp.where(valid, starts[safe + 1] - seg_lo, 0)  # [B, M]
+    if skip_wide:
+        with jax.named_scope("wide"):
+            seg_len = jnp.where(seg_len > cap, 0, seg_len)
     # exclusive prefix of segment lengths per row → output offsets
     ends = jnp.cumsum(seg_len, axis=1)            # [B, M] inclusive
     offs = ends - seg_len                         # [B, M] exclusive
@@ -84,15 +96,27 @@ def _segment_expand(starts: jax.Array, values: jax.Array, seg_ids: jax.Array,
     return out, idx, total.astype(jnp.int32), total > cap
 
 
-@functools.partial(jax.jit, static_argnames=("fanout_cap",))
+@functools.partial(jax.jit, static_argnames=("fanout_cap", "wide_by_ref"))
 def fanout_normal(table: SubTable, matches: jax.Array, *,
-                  fanout_cap: int = 128) -> FanoutResult:
+                  fanout_cap: int = 128,
+                  wide_by_ref: bool = False) -> FanoutResult:
     """Gather normal (non-shared) subscriber rows for matched filters.
 
     matches: [B, M] matched filter ids from match_batch, -1 padded.
+
+    `wide_by_ref`: a filter with more than `fanout_cap` subscribers
+    travels by reference. Its fid is in `matches` already and its
+    segment is `sub_start[fid] .. sub_start[fid + 1]` of a CSR the host
+    built, so its rows are not expanded here: `rows` / `opts` are the
+    concatenation of the OTHER matched filters' segments in match
+    order, `counts` counts those, and `overflow` is raised only when
+    they alone pass `fanout_cap`. The served window program asks for
+    it (`models/router_engine.route_window`); without it a wide filter
+    overflows its lane, as the mesh's programs still have it.
     """
     rows, idx, counts, overflow = _segment_expand(
-        table.sub_start, table.sub_row, matches, fanout_cap)
+        table.sub_start, table.sub_row, matches, fanout_cap,
+        skip_wide=wide_by_ref)
     opts = jnp.where(idx >= 0, table.sub_opts[jnp.clip(idx, 0)],
                      jnp.int8(0))
     return FanoutResult(rows=rows, opts=opts, counts=counts, overflow=overflow)

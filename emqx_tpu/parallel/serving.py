@@ -1662,7 +1662,7 @@ class ShardedRouteServer:
             total = int(seg.sum())
             if not total:
                 continue
-            row_msg, col, row_fid = DeviceRouteEngine._attribute_rows(
+            row_msg, col, row_fid, _ = DeviceRouteEngine._attribute_rows(
                 mi_f, fids_f, seg, total)
             sid, opt = fetch(row_msg, col)
             valid = sid >= 0

@@ -13,6 +13,7 @@ file of their own, so that another worker takes them.
 import pytest
 
 from benchmark.tests import test_trace_readers as _readers
+from benchmark.tests.test_fleet_bcast import *      # noqa: F401,F403
 from benchmark.tests.test_mixed_zipf import *       # noqa: F401,F403
 from benchmark.tests.test_pieces import *           # noqa: F401,F403
 from benchmark.tests.test_trace_readers import *    # noqa: F401,F403

@@ -164,9 +164,10 @@ class TestCompactOracle:
         counts match the dense engine."""
         def setup(broker):
             sinks = [Sink() for _ in range(8)]
+            # two filters of four: narrow rows alone pass the cap of 4
             for i, s in enumerate(sinks):
-                broker.subscribe(broker.register(s, f"o{i}"), "big/+",
-                                 {"qos": 0})
+                broker.subscribe(broker.register(s, f"o{i}"),
+                                 "big/+" if i < 4 else "big/#", {"qos": 0})
             return sinks
 
         comp, cs, dense, ds = _twin_nodes(setup, fanout_cap=4)
@@ -363,3 +364,188 @@ class TestMeshCompact:
         assert len(lone.got) == n_before, \
             "stale pick delivered to a member that left the group"
         assert counts == [0] * 4
+
+
+# ---------- ISSUE 32: a wide filter travels by reference ----------
+
+N_WIDE_SINKS = 2000
+# (filter, subscribers, subopts); widths 1..2,000 around the default
+# fanout_cap of 128. `subid` is a rich option (host dict path), `nl` /
+# `rap` ride the packed byte
+WIDE_SPEC = [
+    ("fleet/#", 2000, {"qos": 0}),
+    ("fleet/+/x", 1280, {"qos": 1}),
+    ("fleet/a/#", 160, {"qos": 0}),
+    ("fleet/a/x", 129, {"qos": 2}),
+    ("+/a/x", 1, {"qos": 0}),
+    ("fleet/+/y", 128, {"qos": 0}),
+    ("fleet/b/y", 127, {"qos": 1}),
+    ("fleet/b/+", 3, {"qos": 0}),
+    ("edge/+", 126, {"qos": 0}),
+    ("edge/#", 129, {"qos": 0, "nl": 1, "rap": 1}),
+    ("edge/k", 2, {"qos": 1, "nl": 1}),
+    ("rich/#", 200, {"qos": 1, "subid": 7}),
+    ("rich/+", 2, {"qos": 0}),
+]
+# clean topics first, then the slow ones (narrow rows of 128 + 127 + 3
+# that pass the cap, which a roomier twin serves as clean; then a rich
+# filter): the device path delivers a window's clean messages before
+# its slow ones
+WIDE_TOPICS = ["fleet/a/x", "fleet/c/y", "fleet/b/z", "edge/k", "edge/q",
+               "fleet/q/x", "none/at/all"]
+SLOW_TOPICS = ["fleet/b/y", "rich/r"]
+
+
+class OptSink:
+    """Records (filter, topic, payload, packed subopts) a delivery."""
+
+    def __init__(self):
+        self.got = []
+
+    def deliver(self, topic_filter, msg):
+        o = msg.headers.get("subopts", {})
+        self.got.append((topic_filter, msg.topic, bytes(msg.payload),
+                         tuple(int(o.get(k, 0) or 0)
+                               for k in ("qos", "nl", "rap", "rh"))))
+        return True
+
+
+def _setup_wide(seed, extra=()):
+    def setup(broker):
+        rng = np.random.RandomState(seed)
+        sinks = [OptSink() for _ in range(N_WIDE_SINKS)]
+        sids = [broker.register(s, f"w{i}") for i, s in enumerate(sinks)]
+        spec = WIDE_SPEC + [
+            (f"rnd/{k}/#", int(rng.randint(1, 2001)), {"qos": k % 3})
+            for k in range(3)] + list(extra)
+        for f, width, opts in spec:
+            for i in rng.choice(N_WIDE_SINKS, size=width, replace=False):
+                broker.subscribe(sids[int(i)], f, dict(opts))
+        return sinks
+    return setup
+
+
+def _wide_window(seed, n=96):
+    rng = np.random.RandomState(seed + 1)
+    fast = WIDE_TOPICS + [f"rnd/{k}/t" for k in range(3)]
+    topics = [fast[rng.randint(len(fast))] for _ in range(n - 8)] \
+        + sorted(SLOW_TOPICS[rng.randint(2)] for _ in range(8))
+    return [make("w5" if i % 7 == 0 else "pub", 0, t, b"m%04d" % i)
+            for i, t in enumerate(topics)]
+
+
+def _on_device(got):
+    """A session's log without the lanes the device path hands to the
+    host route (narrow rows past the cap): there the filters of one
+    message come in the host trie's order, not in match order."""
+    return [d for d in got if d[1] != "fleet/b/y"]
+
+
+def _by_message(got):
+    """A session's log as (payload, sorted deliveries of that message)
+    runs: the sequence of messages, and each message's set."""
+    out = []
+    for f, _t, p, o in got:
+        if out and out[-1][0] == p:
+            out[-1][1].append((f, o))
+        else:
+            out.append((p, [(f, o)]))
+    return [(p, sorted(d)) for p, d in out]
+
+
+class TestWideByReference:
+    @pytest.mark.parametrize("seed", [5, 2**31 + 11])
+    def test_widths_1_to_2000_equal_the_host_route(self, seed):
+        """Widths 1..2,000 mixed in one window (several wide filters on
+        one topic, wide and narrow interleaved in match order, 127 /
+        128 / 129, a wide filter with `nl` / `rap`, a rich one): the
+        device path, CSR or dense, delivers what a twin with a cap no
+        filter passes delivers, delivery for delivery in every
+        session's order, and what the host route delivers (the same
+        messages in the same order a session, the same (filter, opts)
+        set a message); only the lanes whose NARROW rows pass the cap
+        go to the host."""
+        setup = _setup_wide(seed)
+        comp, cs, dense, ds = _twin_nodes(setup)
+        roomy, rs, host, hs = _twin_nodes(setup, fanout_cap=4096)
+        # two windows: the first one's totals size the payload class
+        n_slow, total = 0, 0
+        for rnd in range(2):
+            msgs = _wide_window(seed + rnd)
+
+            def clone():
+                return [make(m.from_, 0, m.topic, m.payload) for m in msgs]
+            want = [host.broker._route(m,
+                                       host.broker.router.match(m.topic))
+                    for m in clone()]
+            hc, hd, hr = (_route_csr(n, clone())
+                          for n in (comp, dense, roomy))
+            assert not isinstance(hd.np_res, _CsrRes)
+            slow = sum(m.topic == "fleet/b/y" for m in msgs)
+            assert int(hd.np_res[6].sum()) == slow > 0
+            assert not hr.np_res[6].any()
+            for node, h in ((comp, hc), (dense, hd), (roomy, hr)):
+                assert _finish_all(node, h) == want
+            n_slow, total = n_slow + slow, total + sum(want)
+        assert isinstance(hc.np_res, _CsrRes)
+        for a, b, c, d in zip(cs, ds, rs, hs):
+            assert a.got == b.got
+            assert _on_device(a.got) == _on_device(c.got)
+            assert _by_message(a.got) == _by_message(c.got) \
+                == _by_message(d.got)
+        assert total > 100_000
+        for node in (comp, dense):
+            m = node.metrics
+            assert m.val("routing.device.host_fallback") == n_slow
+            assert m.val("routing.device.fanout_overflow") == n_slow
+            # everything but the host-fallback lanes and the rich
+            # filter's dict walk came off the device window
+            assert m.val("routing.device.wide_rows") > 0.8 * total
+            assert m.val("routing.device.wide_segments") > 2 * len(msgs)
+            st = node.device_engine.stats()
+            assert st["wide_rows"] == m.val("routing.device.wide_rows")
+            assert st["fanout_overflow"] == n_slow
+        assert roomy.metrics.val("routing.device.wide_segments") == 0
+        # 4 B a wide filter: 2,000 + 1,280 + 160 + 129 subscribers a
+        # message and the window's payload is what its narrow rows take
+        assert max(comp.device_engine._pay_ewma.values()) < 96 * 260
+
+    def test_fused_window_and_cached_rows(self):
+        """A fused window of three sub-batches, twice (the second from
+        the match cache's rows): wide segments are attributed inside
+        each sub-batch and deliveries equal the roomy twin's."""
+        setup = _setup_wide(7)
+        comp, cs, roomy, rs = _twin_nodes(setup)
+        roomy.device_engine.fanout_cap = 4096
+        for rnd in range(2):
+            lives = [_wide_window(20 + k, n=40) for k in range(3)]
+            hc = _route_csr(comp, None, window=lives)
+            hr = _route_csr(roomy, None, window=[
+                [make(m.from_, 0, m.topic, m.payload) for m in sub]
+                for sub in lives])
+            assert _finish_all(comp, hc) == _finish_all(roomy, hr), rnd
+        assert [_on_device(s.got) for s in cs] == \
+            [_on_device(s.got) for s in rs]
+        assert [_by_message(s.got) for s in cs] == \
+            [_by_message(s.got) for s in rs]
+        assert comp.device_engine.stats()["match_cache"]["hits"] > 0
+        assert comp.metrics.val("routing.device.wide_segments") > 0
+
+    @pytest.mark.parametrize("width,wide", [(127, 0), (128, 0), (129, 1)])
+    def test_the_cap_itself_is_narrow(self, width, wide):
+        def setup(broker):
+            sinks = [OptSink() for _ in range(width)]
+            for i, s in enumerate(sinks):
+                broker.subscribe(broker.register(s, f"e{i}"), "t/+",
+                                 {"qos": 0})
+            return sinks
+        comp, cs, dense, ds = _twin_nodes(setup)
+        for node in (comp, dense):
+            assert node.device_engine.route_batch(
+                [mkmsg("t/a"), mkmsg("t/b")]) == [width, width]
+            m = node.metrics
+            assert m.val("routing.device.wide_segments") == 2 * wide
+            assert m.val("routing.device.wide_rows") == 2 * wide * width
+            assert m.val("routing.device.host_fallback") == 0
+            assert m.val("messages.routed.device") == 2 * width
+        assert all(len(s.got) == 2 for s in cs + ds)

@@ -213,6 +213,145 @@ class TestOrderProperty:
         assert snap["coalesce_ratio"] > 0
 
 
+# ---------- ISSUE 32: a wide filter's rows come by reference ----------
+
+# (filter, subscribers, subopts) over the 600 sessions of the world;
+# `_node`'s fanout_cap is 16, so 17 is the first width that is wide
+_WIDE_SPEC = [("b/#", 600, {"qos": 0}), ("b/+/x", 300, {"qos": 1}),
+              ("b/g/#", 40, {"qos": 0, "nl": 1, "rap": 1}),
+              ("b/g/x", 17, {"qos": 2}), ("+/g/x", 15, {"qos": 0}),
+              ("b/g/+", 1, {"qos": 0}), ("u/+", 15, {"qos": 0}),
+              ("r/#", 60, {"qos": 1, "subid": 9})]
+_WIDE_TOPICS = ["b/g/x", "b/q", "b/h/x", "u/1", "none/q", "b/g/y"]
+
+
+def _wide_world(node, rng, sink_cls=Rec):
+    b = node.broker
+    sinks = {}
+    sids = []
+    for i in range(600):
+        s = sink_cls()
+        sid = b.register(s, f"w{i}")
+        sinks[sid] = s
+        sids.append(sid)
+    for f, width, opts in _WIDE_SPEC:
+        for i in rng.choice(600, size=width, replace=False):
+            b.subscribe(sids[int(i)], f, dict(opts))
+    return sinks, sids
+
+
+def _wide_schedule(rng, n_windows=5, batch=40):
+    wins, seq = [], 0
+    for _w in range(n_windows):
+        # the rich filter's messages last: they are the slow ones, and
+        # a window's clean messages are delivered before them
+        topics = [_WIDE_TOPICS[rng.randint(len(_WIDE_TOPICS))]
+                  for _ in range(batch - 4)] + ["r/z"] * 4
+        wins.append([(t, b"m%06d" % (seq + i))
+                     for i, t in enumerate(topics)])
+        seq += batch
+    return wins
+
+
+def _wide_churn(joined):
+    """After the snapshot: a session joins the 600-wide filter (window
+    1) and a 40-wide one (window 2), then leaves the first (window 3):
+    a dirty filter's match stands and its delivery comes from the live
+    host dict, whatever its width."""
+    def join_wide(node):
+        s = Rec()
+        sid = node.broker.register(s, "late")
+        joined[id(node)] = (sid, s)
+        node.broker.subscribe(sid, "b/#", {"qos": 1})
+
+    def join_mid(node):
+        node.broker.subscribe(joined[id(node)][0], "b/g/#", {"qos": 0})
+
+    def leave_wide(node):
+        node.broker.unsubscribe(joined[id(node)][0], "b/#")
+
+    return {1: join_wide, 2: join_mid, 3: leave_wide}
+
+
+class TestWideFanoutThroughTheLanes:
+    @pytest.mark.parametrize("lanes", [0, 4])
+    def test_wide_filters_equal_the_host_route(self, lanes):
+        """Filters of 17..600 subscribers beside narrow ones in every
+        window, a wide one with `nl` / `rap`, a rich one, several on
+        one topic: with the lanes on (and inline), every session gets
+        every topic's messages in the host route's order, each with
+        the host route's (filter) set, nothing is routed by the host
+        for its width, and a subscriber that joins or leaves a wide
+        filter after the snapshot is delivered / not delivered."""
+        rng = np.random.RandomState(13)
+        windows = _wide_schedule(rng)
+        node, host = _node(lanes), _node(0)
+        rng_w = np.random.RandomState(14)
+        sinks, _ = _wide_world(node, rng_w)
+        rng_w = np.random.RandomState(14)
+        hsinks, _ = _wide_world(host, rng_w)
+        joined = {}
+        churn = _churn = _wide_churn(joined)
+        counts = run(_drive(node, windows, churn))
+        want = []
+        for w, msgs in enumerate(windows):
+            if w in _churn:
+                _churn[w](host)
+            want.append([host.broker._route(
+                m, host.broker.router.match(m.topic))
+                for m in (mkmsg(t, p) for t, p in msgs)])
+        assert counts == want
+
+        def by_msg(got):
+            """topic -> its messages in order, each with its filters
+            (a dirty filter's messages are a window's slow ones: the
+            order MQTT keeps is a topic's)."""
+            out = {}
+            for f, t, p in got:
+                seq = out.setdefault(t, [])
+                if seq and seq[-1][0] == p:
+                    seq[-1][1].append(f)
+                else:
+                    seq.append((p, [f]))
+            return {t: [(p, sorted(fs)) for p, fs in seq]
+                    for t, seq in out.items()}
+        sinks[joined[id(node)][0]] = joined[id(node)][1]
+        hsinks[joined[id(host)][0]] = joined[id(host)][1]
+        assert sinks.keys() == hsinks.keys()
+        for sid in sinks:
+            assert by_msg(sinks[sid].got) == by_msg(hsinks[sid].got), sid
+        late = joined[id(node)][1].got
+        in_win = {p: w for w, msgs in enumerate(windows) for _t, p in msgs}
+        assert {in_win[p] for f, _t, p in late if f == "b/#"} == {1, 2}
+        assert {in_win[p] for f, _t, p in late if f == "b/g/#"} == {2, 3, 4}
+        m = node.metrics
+        assert m.val("routing.device.host_fallback") == 0
+        assert m.val("routing.device.wide_segments") > 100
+        # window 0 whole; from window 1 on the 600-wide filter is dirty
+        # and its rows come from the live dict
+        assert m.val("routing.device.wide_rows") > sum(want[0])
+        if lanes:
+            # window 0 went through the lanes but for its rich tail
+            assert m.val("pipeline.deliver.rows") >= sum(want[0]) - 4 * 60
+
+    def test_lanes_equal_inline_delivery_for_delivery(self):
+        """The order contract with wide segments in the plan: every
+        session's deliveries under four lanes are the inline loop's,
+        filter for filter (a message's rows are its matched filters'
+        segments in match order, a wide one at its place)."""
+        rng = np.random.RandomState(21)
+        windows = _wide_schedule(rng, n_windows=3)
+        logs = []
+        for lanes in (0, 4):
+            node = _node(lanes)
+            sinks, _ = _wide_world(node, np.random.RandomState(22),
+                                   sink_cls=RecBatch if lanes else Rec)
+            counts = run(_drive(node, windows, {}))
+            logs.append(([s.got for s in sinks.values()], counts))
+        assert logs[0] == logs[1]
+        assert max(len(g) for g in logs[0][0]) > 60
+
+
 class TestBackpressure:
     def test_blocked_lane_stalls_admit_not_drops(self):
         """A paused (blocked) lane must stall admit() — the hook the
@@ -301,6 +440,252 @@ class TestBackpressure:
         # only assert the hold when the lanes actually carried it
         if saw_pending:
             assert node.metrics.val("messages.dropped") == 0
+
+
+class TestSyncCallerWithWideFilters:
+    def test_finish_sub_undeferred_equals_the_host_route(self):
+        """A sync caller (`finish_sub(defer=False)`, what `route_batch`
+        and a harness's direct warm use) of a window with wide filters
+        in it, lanes configured: the rows are walked session by session
+        on the caller's stack (`DeliveryLanePool.deliver_now`), counts
+        are final on return, and every session gets what the host
+        route gives it, in the host route's order a topic."""
+        windows = _wide_schedule(np.random.RandomState(31), n_windows=2)
+        node, host = _node(4), _node(0)
+        sinks, _ = _wide_world(node, np.random.RandomState(32),
+                               sink_cls=RecBatch)
+        hsinks, _ = _wide_world(host, np.random.RandomState(32))
+        eng = node.device_engine
+        eng.rebuild()
+        got, want = [], []
+        for msgs in windows:
+            batch = [mkmsg(t, p) for t, p in msgs]
+            h = eng.prepare(batch, gate_cold=False)
+            eng.dispatch(h)
+            eng.materialize(h)
+            counts = eng.finish_sub(h, 0, defer=False)
+            assert not hasattr(counts, "plan")
+            got.append(list(counts))
+            want.append([host.broker._route(
+                m, host.broker.router.match(m.topic))
+                for m in (mkmsg(t, p) for t, p in msgs)])
+        assert got == want
+
+        def per_topic(log):
+            out = {}
+            for f, t, p in log:
+                out.setdefault(t, []).append((p, f))
+            return out
+        for sid in sinks:
+            a, b = per_topic(sinks[sid].got), per_topic(hsinks[sid].got)
+            assert a.keys() == b.keys()
+            for t in a:
+                assert sorted(a[t]) == sorted(b[t]), (sid, t)
+                # a topic's messages in publish order
+                assert [p for p, _f in a[t]] == sorted(
+                    p for p, _f in a[t]), (sid, t)
+        m = node.metrics
+        assert m.val("routing.device.wide_rows") > 1000
+        assert m.val("routing.device.host_fallback") == 0
+        # the walk coalesced: far fewer drains than rows
+        assert m.val("pipeline.deliver.drains") \
+            < m.val("pipeline.deliver.deliveries") / 2
+
+
+class TestRowsInFlight:
+    def test_pending_limit_follows_the_fan_out(self):
+        from emqx_tpu.broker import batcher as bm
+        node = _node(2)
+        b = node.publish_batcher
+        assert b._pending_limit() == b.max_pending
+        b._rows_per_msg = bm._ROWS_IN_FLIGHT / b.max_pending
+        assert b._pending_limit() == b.max_pending
+        b._rows_per_msg = 100.0
+        assert b._pending_limit() == max(
+            b.max_batch, int(bm._ROWS_IN_FLIGHT / 100))
+        b._rows_per_msg = 1e6
+        assert b._pending_limit() == b.max_batch
+
+    def test_a_host_probe_is_bounded_in_deliveries(self):
+        """The chooser's host probe routes one batch a delivery at a
+        time: where a full batch stands for more than `_PROBE_ROWS`
+        deliveries the probe's batch is cut to what stands for that
+        many; at a narrow fan-out, or where no probe is due, it is a
+        full batch."""
+        from emqx_tpu.broker import batcher as bm
+        b = _node(2).publish_batcher
+        assert b._probe_cap() is None           # nothing measured yet
+        b._dev_batch_s, b._host_msg_s = 0.01, 1e-5
+        b._since_host_probe = b.host_probe_every
+        b._rows_per_msg = 2.5
+        assert b._probe_cap() is None           # 1,024 x 2.5 is small
+        b._rows_per_msg = 110.75
+        assert b._probe_cap() == int(bm._PROBE_ROWS / 110.75) == 73
+        b._rows_per_msg = 1e6
+        assert b._probe_cap() == b.device_min_batch
+        b._since_host_probe = 0
+        assert b._probe_cap() is None           # no probe due
+        b._host_msg_s = None                    # the first probe
+        assert b._probe_cap() == b.device_min_batch
+
+    def test_windows_wait_for_the_lanes_rows_not_their_count(
+            self, monkeypatch):
+        """600 deliveries a message, the lanes blocked: the batcher
+        stops forming windows once the deliveries in flight pass the
+        bound (three plans here, where the lanes' own bound in plans
+        would take nine and the settle ring any number), the queue
+        then passes its limit and `enqueue` refuses; on resume every
+        delivery lands, in order."""
+        from emqx_tpu.broker import batcher as bm
+        monkeypatch.setattr(bm, "_ROWS_IN_FLIGHT", 10_000)
+        node = _node(2)
+        b = node.broker
+        sinks = []
+        for i in range(600):
+            s = RecBatch()
+            sinks.append(s)
+            b.subscribe(b.register(s, f"w{i}"), "wide/#", {"qos": 0})
+        bat = node.publish_batcher
+        bat._device_worth_it = lambda n: True
+        bat.max_batch = 8
+        # as the first settled window leaves it (until then the
+        # estimate is 1 and up to `pipeline_depth` windows form)
+        bat._rows_per_msg = 600.0
+
+        async def go():
+            eng = node.device_engine
+            eng.rebuild()
+            eng._kick_class_warm()
+            if eng._fuse_warm_task is not None:
+                await eng._fuse_warm_task
+            pool = node.deliver_lanes
+            pool.ensure_loop()
+            pool.pause()
+            sent = 0
+            for _ in range(400):
+                for _k in range(8):
+                    if bat.enqueue(mkmsg(f"wide/{sent % 7}",
+                                         b"m%06d" % sent)):
+                        sent += 1
+                await asyncio.sleep(0.002)
+            held = (bat._rows_in_flight(), pool.live_rows,
+                    len(bat._queue), bat._pending_limit())
+            pool.resume()
+            for _ in range(2000):
+                if not bat._queue and not pool.busy() \
+                        and bat._formed == bat._taken:
+                    break
+                await asyncio.sleep(0.005)
+            await pool.drain()
+            return sent, held
+
+        sent, (rows, live, queued, limit) = run(go())
+        m = node.metrics
+        if m.val("routing.device.batches") == 0:
+            pytest.skip("the device path never engaged")
+        # one window past the bound at most, and the queue at its
+        # (fan-out-aware) limit, far under max_pending
+        assert rows <= 10_000 + 2 * 8 * 600, (rows, live)
+        assert limit == 10_000 // 600 and queued <= limit + 8
+        assert sent < 400 * 8
+        for s in sinks:
+            assert [p for _f, _t, p in s.got] == \
+                [b"m%06d" % i for i in range(sent)]
+
+
+class TestSharedFrame:
+    """ROADMAP Speed 1: a QoS 0 delivery with nothing of the
+    subscriber's in it goes out as one frame, serialized once a
+    (message, options, protocol version)."""
+
+    CASES = {
+        # name: (message qos, flags, properties, subopts, shared?)
+        "plain": (0, {}, None, {"qos": 0}, True),
+        "sub_qos1_msg_qos0": (0, {}, None, {"qos": 1}, True),
+        "msg_qos1_sub_qos0": (1, {}, None, {"qos": 0}, True),
+        "retain_rap": (0, {"retain": True}, None,
+                       {"qos": 0, "rap": 1}, True),
+        "retain_no_rap": (0, {"retain": True}, None, {"qos": 0}, True),
+        "retained_replay": (0, {"retain": True, "retained": True}, None,
+                            {"qos": 0}, True),
+        "dup": (0, {"dup": True}, None, {"qos": 0}, True),
+        "user_props": (0, {}, {"user_property": [("k", "v")],
+                               "content_type": "t"}, {"qos": 0}, True),
+        "qos1": (1, {}, None, {"qos": 1}, False),
+        "expiry": (0, {}, {"message_expiry_interval": 60},
+                   {"qos": 0}, False),
+        "no_local_own": (0, {}, None, {"qos": 0, "nl": 1}, False),
+    }
+
+    @pytest.mark.parametrize("ver", [4, 5])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bytes_and_counters_equal_the_copy_path(self, case, ver):
+        from emqx_tpu.broker.connection import Listener
+        from emqx_tpu.client import Client
+        qos, flags, props, so, shared = self.CASES[case]
+        so = dict(OPT_TABLE[0], **so)
+        node = Node({"broker": {"deliver_lanes": 2}})
+        names = ("messages.sent", "messages.qos0.sent", "packets.sent",
+                 "packets.publish.sent", "bytes.sent",
+                 "delivery.dropped")
+
+        class Tap:
+            def __init__(self):
+                self.data = b""
+
+            def write(self, data):
+                self.data += data
+
+            def is_closing(self):
+                return False
+
+        async def go():
+            lst = Listener(node, bind="127.0.0.1", port=0)
+            await lst.start()
+            c = Client(port=lst.port, clientid="me", proto_ver=ver)
+            await c.connect()
+            await c.subscribe("t/#", qos=1)
+            ch = next(iter(node.broker._subscribers.values()))
+            conn = ch.send.__self__
+            out = []
+            for use_shared in (True, False):
+                tap = conn.writer = Tap()
+                ch.send_frames = conn._send_frames if use_shared \
+                    else None
+                before = {n: node.metrics.val(n) for n in names}
+                n0 = ch.session.deliver_count
+                msgs = [Message(
+                    topic=f"t/{i}", payload=b"p%d" % i, qos=qos,
+                    from_="me" if case == "no_local_own" else "pub",
+                    flags=dict(flags),
+                    headers={"properties": dict(props)} if props
+                    else {}) for i in range(3)]
+                views = [DeliveryView(m, so) for m in msgs]
+                assert (views[0].wire_qos0(ver, "me") is not None) \
+                    == shared
+                got = ch.deliver_batch([("t/#", v) for v in views[:2]])
+                ok = ch.deliver("t/#", views[2])
+                if qos and so["qos"]:
+                    # packet ids differ run to run: strip them
+                    ch.session.inflight.clear() if hasattr(
+                        ch.session.inflight, "clear") else None
+                out.append((tap.data, got, ok,
+                            {n: node.metrics.val(n) - before[n]
+                             for n in names},
+                            ch.session.deliver_count - n0))
+            await lst.stop()
+            return out
+
+        with_frames, with_copies = run(go())
+        if case == "qos1":
+            # the packet ids move on: same length, same counters
+            assert len(with_frames[0]) == len(with_copies[0])
+            assert with_frames[1:] == with_copies[1:]
+        else:
+            assert with_frames == with_copies
+        assert with_frames[1] == 2 and with_frames[2] is True
+        if case != "no_local_own":
+            assert with_frames[0]
 
 
 class TestDeliveryView:

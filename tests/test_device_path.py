@@ -219,13 +219,17 @@ class TestEngineDirect:
         node.device_engine.fanout_cap = 4   # force tiny capacity
         b = node.broker
         sinks = [Sink() for _ in range(8)]
+        # two filters of four: each fits a lane's row, together they
+        # pass it (one filter of eight would travel by reference)
         for i, s in enumerate(sinks):
-            b.subscribe(b.register(s, f"c{i}"), "big/+", {"qos": 0})
+            b.subscribe(b.register(s, f"c{i}"),
+                        "big/+" if i < 4 else "big/#", {"qos": 0})
         counts = node.device_engine.route_batch(
             [mkmsg("big/t"), mkmsg("big/u")])
         assert counts == [8, 8]
         assert all(len(s.got) == 2 for s in sinks)
         assert node.metrics.val("routing.device.host_fallback") == 2
+        assert node.metrics.val("routing.device.fanout_overflow") == 2
 
     def test_deep_topic_falls_back_host(self, node):
         b = node.broker

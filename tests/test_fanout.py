@@ -68,6 +68,18 @@ class TestFanout:
         assert bool(fr.overflow[0])
         assert int(fr.counts[0]) == 40  # true count still reported
 
+    def test_fanout_overflow_stays_without_wide_by_ref(self):
+        """Off (the mesh's programs, the step programs): a filter wider
+        than the cap overflows its lane as before."""
+        subs = build_subtable(2, {0: [(i, 0) for i in range(40)],
+                                  1: [(99, 1)]}, {}, {})
+        m = np.array([[0, 1, -1]], np.int32)
+        off = fanout_normal(subs, m, fanout_cap=16)
+        assert bool(off.overflow[0]) and int(off.counts[0]) == 41
+        on = fanout_normal(subs, m, fanout_cap=16, wide_by_ref=True)
+        assert not bool(on.overflow[0]) and int(on.counts[0]) == 1
+        assert [int(r) for r in on.rows[0] if r >= 0] == [99]
+
     def test_empty_filter_no_subscribers(self):
         filters = ["a", "b"]
         normal = {0: [(1, 0)]}  # filter 1 has no subscribers
@@ -76,6 +88,105 @@ class TestFanout:
         mr = match_batch(tables.trie, enc, lens, dollar)
         fr = fanout_normal(tables.subs, mr.matches)
         assert int(fr.counts[0]) == 0
+
+
+def _random_csr(rng, widths):
+    """A SubTable whose filter k has `widths[k]` subscribers, with the
+    python dict it was built from."""
+    normal, row = {}, 0
+    for fid, w in enumerate(widths):
+        normal[fid] = [(row + j, int(rng.randint(0, 64))) for j in range(w)]
+        row += w
+    return build_subtable(len(widths), normal, {}, {}), normal
+
+
+def _rows_by_reference(fr, b, matches, normal, cap):
+    """Lane b's deliveries as the host rebuilds them: the plane's rows
+    for each narrow matched filter, the CSR's own for a wide one, at
+    the filter's place in match order."""
+    out, col = [], 0
+    rows, opts = np.asarray(fr.rows[b]), np.asarray(fr.opts[b])
+    for fid in matches[b]:
+        if fid < 0:
+            continue
+        seg = normal[int(fid)]
+        if len(seg) > cap:
+            out += seg
+        else:
+            out += list(zip(rows[col:col + len(seg)].tolist(),
+                            opts[col:col + len(seg)].tolist()))
+            col += len(seg)
+    assert (rows[col:] == -1).all()
+    return out
+
+
+class TestWideByReference:
+    """`fanout_normal(wide_by_ref=True)`: a filter with more than
+    `fanout_cap` subscribers takes no slot of its lane's row, and the
+    lane's deliveries, rebuilt from the planes and the CSR, are still
+    the concatenation of the matched filters' segments in match
+    order."""
+
+    @pytest.mark.parametrize("seed", [3, 17, 2**31 + 5])
+    def test_widths_1_to_2000_mixed_in_one_batch(self, seed):
+        rng = np.random.RandomState(seed % (2**32))
+        cap = 128
+        widths = [1, 2, 5, 127, 128, 129, 160, 1280, 2000] + \
+            [int(w) for w in rng.randint(1, 2001, size=9)] + [3, 40, 60]
+        subs, normal = _random_csr(rng, widths)
+        B, M = 48, 8
+        matches = np.full((B, M), -1, np.int32)
+        for b in range(B):
+            k = rng.randint(0, M + 1)
+            at = np.sort(rng.choice(M, size=k, replace=False))
+            matches[b, at] = rng.choice(len(widths), size=k, replace=False)
+        # several wide filters on one topic, wide and narrow interleaved
+        matches[0] = [7, 0, 8, -1, 1, 6, 2, -1]
+        matches[1] = [3, 5, -1, -1, -1, -1, -1, -1]     # 127, 129
+        matches[2] = [5, -1, 4, -1, -1, -1, -1, -1]     # 129, 128
+        fr = fanout_normal(subs, matches, fanout_cap=cap, wide_by_ref=True)
+        for b in range(B):
+            fids = [int(f) for f in matches[b] if f >= 0]
+            narrow = sum(widths[f] for f in fids if widths[f] <= cap)
+            assert int(fr.counts[b]) == narrow
+            assert bool(fr.overflow[b]) == (narrow > cap)
+            if narrow > cap:
+                continue        # the lane goes to the host route
+            want = [e for f in fids for e in normal[f]]
+            assert _rows_by_reference(fr, b, matches, normal, cap) == want
+        assert not np.asarray(fr.overflow[:3]).any()
+        assert [int(c) for c in fr.counts[:3]] == [1 + 2 + 5, 127, 128]
+
+    @pytest.mark.parametrize("width,wide", [(127, False), (128, False),
+                                            (129, True)])
+    def test_the_cap_itself_is_narrow(self, width, wide):
+        rng = np.random.RandomState(width)
+        subs, normal = _random_csr(rng, [width, 1])
+        m = np.array([[1, 0]], np.int32)
+        fr = fanout_normal(subs, m, fanout_cap=128, wide_by_ref=True)
+        assert int(fr.counts[0]) == (1 if wide else width + 1)
+        # 128 + 1 rows of narrow filters pass the row: still an overflow
+        assert bool(fr.overflow[0]) == (width == 128)
+        if width != 128:
+            assert _rows_by_reference(fr, 0, m, normal, 128) == \
+                normal[1] + normal[0]
+
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_narrow_lanes_are_bit_equal_with_and_without(self, seed):
+        """No matched filter wider than the cap: every plane is what
+        the parent's program returned (overflow lanes included)."""
+        rng = np.random.RandomState(seed)
+        widths = [int(w) for w in rng.randint(0, 9, size=40)]
+        subs, _normal = _random_csr(rng, widths)
+        matches = rng.randint(-30, 40, size=(64, 6)).clip(-1) \
+            .astype(np.int32)
+        a = fanout_normal(subs, matches, fanout_cap=16)
+        b = fanout_normal(subs, matches, fanout_cap=16, wide_by_ref=True)
+        assert np.asarray(a.overflow).any() \
+            and not np.asarray(a.overflow).all()
+        for x, y in zip(a, b):
+            x, y = np.asarray(x), np.asarray(y)
+            assert x.dtype == y.dtype and (x == y).all()
 
 
 class TestSharedPick:

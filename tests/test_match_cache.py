@@ -144,9 +144,10 @@ class TestDedupOracle:
         scatter and the cache round trip unchanged."""
         def setup(broker):
             sinks = [Sink() for _ in range(8)]
+            # two filters of four: narrow rows alone pass the cap of 4
             for i, s in enumerate(sinks):
-                broker.subscribe(broker.register(s, f"o{i}"), "big/+",
-                                 {"qos": 0})
+                broker.subscribe(broker.register(s, f"o{i}"),
+                                 "big/+" if i < 4 else "big/#", {"qos": 0})
             return sinks
 
         fast, _, plain, _ = _twin_nodes(setup, fanout_cap=4)
@@ -192,17 +193,51 @@ class TestDedupOracle:
         cp = _finish_all(plain, hp)
         assert cf == cp == [1] * 255
 
+    def test_a_plan_engages_only_where_it_takes_two_fifths_off(self):
+        """100 real lanes of a 256 class whose misses fit the 64 class:
+        with 62 distinct new topics (38 % repeats) the window dispatches
+        plain and still seeds the cache; with 58 (42 %) it engages; and
+        the first topics, now hits, engage whatever their repeats."""
+        def setup(broker):
+            # every device's name a word some filter holds: a word no
+            # filter has encodes as one "unknown", and topics that
+            # differ only there are one topic to the match
+            sink = Sink()
+            sid = broker.register(sink, "c0")
+            for i in range(160):
+                broker.subscribe(sid, f"dev/{i}/temp", {"qos": 0})
+            return [sink]
+
+        fast, _fs, plain, _ps = _twin_nodes(setup)
+
+        def msgs(base, uniq):
+            return [mkmsg(f"dev/{base + i % uniq}/temp")
+                    for i in range(100)]
+
+        for base, uniq, engaged in ((0, 62, False), (100, 58, True),
+                                    (0, 62, True)):
+            hf, hp = _np_res(fast, msgs(base, uniq)), \
+                _np_res(plain, msgs(base, uniq))
+            assert (hf.plan is not None) == engaged, (base, uniq)
+            if base == 0 and not engaged:
+                assert hf.cache_info is not None    # misses still seed
+            _assert_bit_identical(hf, hp)
+            _finish_all(fast, hf)
+            _finish_all(plain, hp)
+
     def test_underfilled_window_pads_collapse(self):
         """Fused window with an under-filled sub-batch: every padding
         lane collapses onto one sentinel entry and the stacked
         RouteResult still equals the plain window program's."""
         fast, fs, plain, ps = _twin_nodes(self._setup)
-        win = [[mkmsg("dev/7/temp"), mkmsg("dev/9/temp")],
-               [mkmsg("dev/7/temp")]]
+        # half of the real lanes repeat: a plan engages only where it
+        # takes two fifths of them off the match
+        win = [[mkmsg("dev/7/temp"), mkmsg("dev/9/temp"),
+                mkmsg("dev/7/temp")], [mkmsg("dev/7/temp")]]
         hf = _np_res(fast, [m for w in win for m in w], window=win)
         hp = _np_res(plain, None, window=win)
         assert hf.plan is not None
-        # 3 real lanes + the pad sentinel
+        # 4 real lanes on 2 topics + the pad sentinel
         assert hf.plan.n_miss + hf.plan.n_hit == 2
         _assert_bit_identical(hf, hp)
         _finish_all(fast, hf)

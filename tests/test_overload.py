@@ -827,7 +827,15 @@ class TestCongestionHysteresis:
                           min_alarm_sustain_duration=sustain)
         return node, writer, cong
 
-    def test_rearm_on_every_congested_observation(self):
+    def test_rearm_on_every_congested_observation(self, monkeypatch):
+        # the module's clock, stepped by hand: a sleep of 0.10 s that
+        # the scheduler stretched past the sustain failed this case
+        from types import SimpleNamespace
+
+        from emqx_tpu.broker import congestion
+        clock = SimpleNamespace(now=1000.0)
+        clock.monotonic = lambda: clock.now
+        monkeypatch.setattr(congestion, "time", clock)
         node, writer, cong = self._cong(sustain=0.15)
         writer.transport.pending = 100
         cong.check()
@@ -836,13 +844,13 @@ class TestCongestionHysteresis:
         # congested again right before the sustain would have elapsed:
         # the deactivation clock RESTARTS (re-arm on every congested
         # observation — emqx_congestion's WontClearIn)
-        time.sleep(0.10)
+        clock.now += 0.10
         cong.check()                       # still congested: re-arms
         writer.transport.pending = 0
-        time.sleep(0.10)                   # 0.10 < sustain since last
+        clock.now += 0.10                  # 0.10 < sustain since last
         cong.check()                       # congested observation
         assert node.alarms.is_active(name)
-        time.sleep(0.06)                   # now 0.16 >= sustain clean
+        clock.now += 0.06                  # now 0.16 >= sustain clean
         cong.check()
         assert not node.alarms.is_active(name)
 

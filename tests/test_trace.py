@@ -1057,7 +1057,8 @@ def _plain_steps(fx, match):
             shared_rows=sp.rows, shared_opts=sp.opts,
             overflow=mr.overflow | fr.overflow | so,
             new_cursors=sp.new_cursors, occur=sp.occur,
-            match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps))
+            match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps,
+            fanout_overflow=fr.overflow))
         cur = sp.new_cursors
     return RE.RouteResult(*[
         None if out[0][i] is None

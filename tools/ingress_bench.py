@@ -14,11 +14,11 @@ broker (Listener → Connection → FrameParser → Channel → PublishBatcher
 This is the IoT-broker-benchmarking framing (arXiv:2603.21600,
 PAPERS.md): committed messages per second under realistic
 many-connection traffic, not isolated match throughput. Each
-configuration runs in its OWN subprocess (same discipline as
-fanout_bench: a config must not inherit the previous one's GC pressure
-or jit caches). The child reports msgs/s plus the stage decomposition
-(pipeline telemetry snapshot) and the `ingress` section, so a missed
-speedup target still ships the evidence of where the wall is.
+configuration runs in its OWN subprocess (a config must not inherit
+the previous one's GC pressure or jit caches). The child reports msgs/s
+plus the stage decomposition (pipeline telemetry snapshot) and the
+`ingress` section, so a missed speedup target still ships the evidence
+of where the wall is.
 
 Correctness rides along: a subscriber counts its deliveries and the
 parent asserts the columnar/per-packet twins delivered identical
