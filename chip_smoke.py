@@ -189,7 +189,7 @@ class Ledger:
         self.got.setdefault((pub, seq), []).append(sub)
         # per (publisher, topic, delivered qos) the sequence numbers only
         # grow — MQTT's ordered-topic guarantee. Across topics the engine
-        # delivers a batch's clean rows before its shared / dirty rows,
+        # delivers a batch's clean rows before its dirty / rich rows,
         # and a QoS 0 message may pass a QoS 1 message parked behind a
         # full inflight window, so neither is asserted.
         last = self.order[sub]

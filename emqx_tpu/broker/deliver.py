@@ -16,13 +16,18 @@ pipeline stage:
   same-session deliveries are contiguous for coalescing) and hands each
   lane a contiguous slice. A session always hashes to the same lane,
   so per-session FIFO — the MQTT ordering invariant — holds by
-  construction. Slow-path messages (shared groups, rich subopts,
-  delta-matched, dirty filters, host fallbacks) ride the SAME plan as
-  ordered closures behind an all-lanes barrier: every lane finishes its
-  fast slices first, exactly one worker runs the slow closures in batch
-  order, and no lane proceeds past the barrier meanwhile — the
-  per-session interleaving is bit-identical to the inline loop
-  (fast rows first, then slow rows, per window).
+  construction. A `$share` member the device picked is such a row too
+  (ISSUE 35), behind its message's plain rows and with `share=<group>`
+  in its subopts (`GroupPicks`); a pick that cannot be honoured at
+  delivery (the member left) goes once through the host's dispatch of
+  the group (`_repick`). Slow-path messages (rich subopts,
+  delta-matched, dirty filters, host fallbacks; of the shared ones
+  those whose group changed since the snapshot, was created after it
+  or has a member on another node, and every message under a cluster)
+  ride the SAME plan as ordered closures behind an all-lanes barrier:
+  every lane finishes its fast slices first, exactly one worker runs
+  the slow closures in batch order, and no lane proceeds past the
+  barrier meanwhile (fast rows first, then slow messages, per window).
 
 - **DeliveryLanePool**: a small pool of asyncio lane workers (config
   `broker.deliver_lanes` / env `EMQX_TPU_DELIVER_LANES`, default
@@ -52,7 +57,12 @@ the delivered sequence under `deliver_lanes=N` is identical to the
 inline `deliver_lanes=0` sequence. Within a window the inline order is
 "all fast rows, then slow messages in batch order"; lanes reproduce it
 with the slice-then-barrier queueing above, and windows serialize
-per-lane because plans enqueue in consume (FIFO) order.
+per-lane because plans enqueue in consume (FIFO) order. The one place
+the two differ: the inline loop has no shared rows (a shared message
+is a slow one there), so a session that holds plain and `$share`
+subscriptions sees a window's clean shared messages among its plain
+ones, in message order, where the inline loop delivers them after;
+a topic's messages keep their order either way, which is MQTT's.
 """
 
 from __future__ import annotations
@@ -105,6 +115,39 @@ def resolve_deliver_lanes(configured=None) -> int:
     if val < 0:
         raise ValueError(f"deliver_lanes must be >= 0, got {val}")
     return val
+
+
+class GroupPicks:
+    """What the lanes need of a snapshot to deliver a `$share` member
+    the device picked as a row of a plan. Such a row's `opt` word holds,
+    above the 6 bits of packed subopts, 1 + the slot of its group (a
+    plain row holds 0 there): `keys` is slot -> (filter, group name),
+    `subopts` the frozen dict a word stands for, the packed options
+    with `share=<group>` beside them as the host's dispatch gives them
+    (one dict a (packed opts, group), not one a delivery), `redispatch`
+    the host's own dispatch of one group, (filter, group, message) ->
+    delivered, for a pick that cannot be honoured."""
+
+    __slots__ = ("keys", "redispatch", "_subopts")
+
+    def __init__(self, keys: list, redispatch: Callable):
+        self.keys = keys
+        self.redispatch = redispatch
+        self._subopts: dict[tuple, dict] = {}
+
+    @staticmethod
+    def words(opt: np.ndarray, slot: np.ndarray) -> np.ndarray:
+        return (opt & 0x3F) | ((slot + 1) << 6)
+
+    def key(self, word: int) -> tuple:
+        return self.keys[(word >> 6) - 1]
+
+    def subopts(self, word: int) -> dict:
+        key = (word & 0x3F, self.key(word)[1])
+        so = self._subopts.get(key)
+        if so is None:
+            so = self._subopts[key] = dict(OPT_TABLE[key[0]], share=key[1])
+        return so
 
 
 class _ViewHeaders:
@@ -390,7 +433,7 @@ class DeliveryPlan:
                  "filters", "_chunks", "routed_device", "pending",
                  "done", "target", "_cbs", "s_midx", "s_sid", "s_opt",
                  "s_fid", "_barrier_left", "_barrier_evt", "trace",
-                 "n_rows")
+                 "n_rows", "picks")
 
     def __init__(self, pool: "DeliveryLanePool", msgs: list):
         self.pool = pool
@@ -399,6 +442,7 @@ class DeliveryPlan:
         self.fast_idx: list[int] = []
         self.slow_items: list[tuple[int, Callable[[], int]]] = []
         self.filters = None         # fid -> topic-filter string
+        self.picks: Optional[GroupPicks] = None   # of its `$share` rows
         self._chunks: list[tuple] = []
         self.n_rows = 0             # fast rows handed to the lanes
         self.routed_device = False
@@ -421,12 +465,15 @@ class DeliveryPlan:
         no-subscriber drop bookkeeping moves to finalize)."""
         self.fast_idx.extend(int(i) for i in indices)
 
-    def add_rows(self, midx, sid, opt, fid, filters) -> None:
+    def add_rows(self, midx, sid, opt, fid, filters, slot=None,
+                 picks: Optional[GroupPicks] = None) -> None:
         """One vectorized chunk of fast deliveries: parallel arrays of
         (message index, session id, packed opts, filter id) plus the
         fid -> filter-string table they index (the pinned snapshot's
         `fid_filter` for the single-chip engine; a plan-local list for
-        the mesh)."""
+        the mesh). `slot`, where the chunk has rows of device-picked
+        `$share` members: each row's group as a slot of `picks.keys`,
+        -1 on a plain row."""
         if self.filters is None:
             self.filters = filters
         elif self.filters is not filters:
@@ -434,9 +481,14 @@ class DeliveryPlan:
             base = len(self.filters)
             self.filters = list(self.filters) + list(filters)
             fid = np.asarray(fid) + base
+        opt = np.asarray(opt, np.int64)
+        if slot is None:
+            opt = opt & 0x3F
+        else:
+            self.picks = picks
+            opt = GroupPicks.words(opt, slot)
         self._chunks.append((np.asarray(midx, np.int64),
-                             np.asarray(sid, np.int64),
-                             np.asarray(opt, np.int64),
+                             np.asarray(sid, np.int64), opt,
                              np.asarray(fid, np.int64)))
 
     def add_rows_py(self, msg_idx: int, rows: list[tuple]) -> None:
@@ -451,7 +503,7 @@ class DeliveryPlan:
         n = len(rows)
         midx = np.full(n, msg_idx, np.int64)
         sid = np.fromiter((r[0] for r in rows), np.int64, n)
-        opt = np.fromiter((r[1] for r in rows), np.int64, n)
+        opt = np.fromiter((r[1] & 0x3F for r in rows), np.int64, n)
         fidx = np.arange(base, base + n, dtype=np.int64)
         self.filters.extend(r[2] for r in rows)
         self._chunks.append((midx, sid, opt, fidx))
@@ -692,6 +744,9 @@ class DeliveryLanePool:
             parts += 1
             plan._barrier_left = self.n_lanes
             plan._barrier_evt = asyncio.Event()
+            self.metrics.inc("pipeline.deliver.barriers")
+            self.metrics.inc("pipeline.deliver.slow_msgs",
+                             len(plan.slow_items))
         # all fallible work is done: go live, then enqueue (put_nowait
         # on unbounded queues cannot raise — a half-enqueued plan would
         # wedge drain()/admit() forever)
@@ -728,7 +783,7 @@ class DeliveryLanePool:
         order = np.argsort(sid, kind="stable")
         plan.s_midx = np.asarray(midx)[order].tolist()
         plan.s_sid = np.asarray(sid)[order].tolist()
-        plan.s_opt = np.asarray(opt)[order].tolist()
+        plan.s_opt = (np.asarray(opt) & 0x3F)[order].tolist()
         plan.s_fid = np.asarray(fid)[order].tolist()
         self._deliver_rows(plan, 0, len(order))
         return plan.counts
@@ -1043,16 +1098,20 @@ class DeliveryLanePool:
             if hooks is not None else ()
         msgs = plan.msgs
         filters = plan.filters
+        picks = plan.picks
         sids, opts = plan.s_sid, plan.s_opt
         fids, midx = plan.s_fid, plan.s_midx
         delivered = 0
         drains = 0
-        # one DeliveryView per (message, packed subopts), shared across
+        # one DeliveryView per (message, subopts word), shared across
         # the fan-out: at fan-out F this builds 1 view instead of F. The
         # share is safe by the copy-on-write contract — every mutation
         # path on the view (set_header/set_flag/update_expiry/copy)
         # materializes private state, and delivered messages are
         # read-only by protocol (Subscriber docstring in pubsub.py).
+        # A `$share` row's word names its group (GroupPicks): a session
+        # a message reaches by a plain filter and by a group gets two
+        # views, the second with `share=<group>` in its subopts.
         vcache: dict[int, DeliveryView] = {}
         delivered_midx: list[int] = []
         i = lo
@@ -1063,18 +1122,24 @@ class DeliveryLanePool:
                 j += 1
             sub = registry.get(sid)
             if sub is None:
+                if picks is not None:
+                    delivered_midx.extend(
+                        self._repick(plan, sid, range(i, j)))
                 i = j
                 continue
             items = []
             for k in range(i, j):
-                vk = (midx[k] << 6) | (opts[k] & 0x3F)
+                word = opts[k]
+                vk = (midx[k] << 32) | word
                 view = vcache.get(vk)
                 if view is None:
                     view = vcache[vk] = DeliveryView(
-                        msgs[midx[k]], OPT_TABLE[opts[k] & 0x3F])
+                        msgs[midx[k]], OPT_TABLE[word] if word < 64
+                        else picks.subopts(word))
                 items.append((filters[fids[k]], view))
             batch_fn = getattr(sub, "deliver_batch", None) \
                 if j - i > 1 else None
+            nacked = ()
             # Deliberate divergence from the inline loop: a raising
             # subscriber/hook here is contained to ITS deliveries
             # (logged + counted) instead of failing the whole batch's
@@ -1098,6 +1163,8 @@ class DeliveryLanePool:
                         for _f, v in items:
                             hooks.run("message.delivered",
                                       (meta.get(sid), v))
+                else:
+                    nacked = range(i, j)
             else:
                 drains += j - i
                 for k, (f, view) in zip(range(i, j), items):
@@ -1114,6 +1181,10 @@ class DeliveryLanePool:
                         if delivered_cbs:
                             hooks.run("message.delivered",
                                       (meta.get(sid), view))
+                    else:
+                        nacked += (k,)
+            if nacked and picks is not None:
+                delivered_midx.extend(self._repick(plan, sid, nacked))
             i = j
         if delivered_midx:
             np.add.at(plan.counts, delivered_midx, 1)
@@ -1131,6 +1202,35 @@ class DeliveryLanePool:
             metrics.hist("pipeline.deliver.coalesce.ratio",
                          lo=1.0 / 256, n_buckets=9,
                          unit="ratio").observe(1.0 - drains / n_rows)
+
+    def _repick(self, plan: DeliveryPlan, sid: int, rows) -> list[int]:
+        """The `$share` rows among `rows` (one session's run, in message
+        order) that their picked member did not take: each goes once
+        more through the host's dispatch of its group where the host's
+        own pick would (`DeviceRouteEngine._consume_one`): the member
+        has left the group, or the ack protocol is on; a nack from a
+        live member without it is final. Returns the message indices
+        delivered so. The host's dispatch counts its own
+        `messages.delivered` and runs its own hooks."""
+        picks, broker = plan.picks, self.broker
+        opts, midx = plan.s_opt, plan.s_midx
+        out = []
+        for k in rows:
+            if opts[k] < 64:
+                continue
+            f, gname = picks.key(opts[k])
+            grp = broker.shared.get(f, {}).get(gname)
+            if not (grp is None or sid not in grp.members
+                    or broker.shared_dispatch_ack):
+                continue
+            self.metrics.inc("routing.device.shared_repick")
+            try:
+                if picks.redispatch(f, gname, plan.msgs[midx[k]]):
+                    out.append(midx[k])
+            except Exception:  # noqa: BLE001 — as a raising delivery
+                log.exception("shared re-dispatch failed %s/%s", gname, f)
+                self.metrics.inc("pipeline.deliver.deliver_errors")
+        return out
 
     async def _run_slow(self, plan: DeliveryPlan, sp) -> None:
         """The ordering-safe serialized tail: slow-path messages in

@@ -85,7 +85,8 @@ from typing import NamedTuple, Optional
 import jax
 import numpy as np
 
-from emqx_tpu.broker.deliver import DEFERRED, OPT_TABLE, LaneCounts
+from emqx_tpu.broker.deliver import (DEFERRED, OPT_TABLE, GroupPicks,
+                                     LaneCounts)
 from emqx_tpu.broker.match_cache import DEFAULT_CAPACITY, MatchCache
 from emqx_tpu.broker.message import Message
 from emqx_tpu.ops.compact import csr_slices
@@ -525,7 +526,8 @@ class _Built:
     __slots__ = ("fid_of", "fid_filter", "seg_len", "slot_of", "slot_key",
                  "n_slots", "backend", "remote_members", "seg_np",
                  "fid_shared", "fid_rich", "sid", "match_width", "cover",
-                 "cover_decision", "sub_start", "sub_row", "sub_opts")
+                 "cover_decision", "sub_start", "sub_row", "sub_opts",
+                 "slot_fid", "picks")
 
     def __init__(self):
         self.fid_of: dict[str, int] = {}
@@ -533,7 +535,11 @@ class _Built:
         self.seg_len: list[int] = []
         self.slot_of: dict[tuple, int] = {}       # (filter, group) -> slot
         self.slot_key: list[tuple] = []           # slot -> (filter, group)
+        self.slot_fid = np.zeros(0, np.int64)     # slot -> its filter's fid
         self.n_slots = 0
+        # what the delivery lanes need to serve a device-picked member
+        # of these groups as a row of a plan (broker/deliver.GroupPicks)
+        self.picks = None
         # remote shared members: device sid _REMOTE_SID_BASE+i -> (origin,
         # remote_sid); consume forwards picks for these over RPC
         self.remote_members: list[tuple] = []
@@ -1260,6 +1266,7 @@ class DeviceRouteEngine:
         filter_slots: dict[int, list] = {}
         shared_members: dict[int, list] = {}
         cursors0: list[int] = []
+        slot_fid: list[int] = []
         rich: set[str] = set()
         seg_len = [0] * n
         for f, fid in b.fid_of.items():
@@ -1277,6 +1284,7 @@ class DeviceRouteEngine:
                 slot = len(b.slot_key)
                 b.slot_of[(f, g)] = slot
                 b.slot_key.append((f, g))
+                slot_fid.append(fid)
                 members = []
                 for sid, opts in members_raw:
                     if isinstance(sid, tuple):
@@ -1294,6 +1302,8 @@ class DeviceRouteEngine:
                 cursors0.append(cursor)
         b.seg_len = seg_len
         b.n_slots = len(b.slot_key)
+        b.slot_fid = np.asarray(slot_fid, np.int64)
+        b.picks = GroupPicks(b.slot_key, self._host_shared_dispatch)
         b.seg_np = np.asarray(seg_len, np.int64)
         b.fid_shared = np.zeros(max(1, n), bool)
         for fid in filter_slots:
@@ -2949,8 +2959,9 @@ class DeviceRouteEngine:
         swap gate must cover in-flight lane work).
 
         The clean common case — local node, no delta/dirty filters, no
-        shared involvement for the message — is consumed by ONE
-        vectorized pre-pass over the whole sub-batch
+        shared involvement for the message beyond a member the device
+        picked in a group that is as the snapshot has it — is consumed
+        by ONE vectorized pre-pass over the whole sub-batch
         (_consume_batch_fast): the per-message Python walk over
         match/fan-out rows used to cost more than the entire host route
         (24ms vs 22ms per 1024-batch at 50k filters), which made the
@@ -2958,9 +2969,15 @@ class DeviceRouteEngine:
 
         With the delivery lanes active (ISSUE 5; `defer=True` and a
         DeliveryLanePool on the node), this stage only BUILDS the
-        delivery plan: clean messages' rows are bucketed into
-        session-affine lanes, slow messages become ordered closures
-        behind the plan's barrier, and the returned LaneCounts is
+        delivery plan: clean messages' rows, the device's `$share`
+        picks among them (ISSUE 35), are bucketed into session-affine
+        lanes; slow messages (too long, overflowed, overlay-matched, a
+        rich or dirty filter, a group whose membership changed or that
+        was created since the snapshot or that has a remote member,
+        anything under a cluster or with uncovered delta filters)
+        become ordered closures behind the plan's barrier
+        (`pipeline.deliver.slow_msgs` / `.barriers`), and the returned
+        LaneCounts is
         back-filled when the plan completes (the `deliver` stage
         histogram then measures plan construction; the delivery time
         itself lands in the per-lane deliver_lane{i} histograms).
@@ -3021,8 +3038,8 @@ class DeviceRouteEngine:
             else:
                 fast = self._consume_batch_fast(
                     msgs, matches[k], rows[k], opts[k], shared_sids[k],
-                    too_long, overflow_k, h.dev_shared, b, d_counts_k,
-                    pending, plan=plan)
+                    shared_rows[k], shared_opts[k], too_long, overflow_k,
+                    h.dev_shared, b, d_counts_k, pending, plan=plan)
             dev_shared, ov = h.dev_shared, h.delta
             counts: list[int] = []
             for i, msg in enumerate(msgs):
@@ -3118,8 +3135,8 @@ class DeviceRouteEngine:
                 dev_shared, b, drow=drow, ov=ov, pending=pending)
         return run
 
-    def _consume_batch_fast(self, msgs, m_k, r_k, o_k, ss_k, too_long,
-                            overflow_k, dev_shared: bool, b,
+    def _consume_batch_fast(self, msgs, m_k, r_k, o_k, ss_k, sr_k, so_k,
+                            too_long, overflow_k, dev_shared: bool, b,
                             d_counts_k=None, pending: bool = False,
                             plan=None):
         """Vectorized consume for provably-clean messages. Returns a list
@@ -3128,8 +3145,9 @@ class DeviceRouteEngine:
         forward / cluster group sweep), no delta filters beyond the
         fused overlay (`pending`), no post-snapshot shared groups; per
         message: no too-long/overflow, no dirty/rich matched filter, no
-        delta-overlay match, and no shared involvement (no device slot
-        matched; no matched filter with host shared groups)."""
+        delta-overlay match, and no shared involvement but a pick the
+        device made in a group the snapshot still describes
+        (`_fast_deliver`)."""
         if (self.broker.cluster is not None or pending
                 or self.new_slots_by_filter):
             return [None] * len(msgs)
@@ -3137,14 +3155,18 @@ class DeviceRouteEngine:
         mask = m_k[:B] >= 0
         mi = np.nonzero(mask)[0]
         fids = m_k[:B][mask]
-        shared_any = (ss_k[:B] >= 0).any(axis=1)
+        reported = ss_k[:B] >= 0
 
         def fetch(row_msg, col):
             return r_k[row_msg, col], o_k[row_msg, col]
 
+        def fetch_picks():
+            at = np.nonzero(reported)   # message-major, `ss_row` order
+            return at[0], ss_k[at], sr_k[at], so_k[at]
+
         return self._fast_deliver(msgs, mi, fids, too_long, overflow_k,
-                                  shared_any, fetch, dev_shared, b,
-                                  d_counts_k, plan=plan)
+                                  reported.any(axis=1), fetch, fetch_picks,
+                                  dev_shared, b, d_counts_k, plan=plan)
 
     def _consume_batch_fast_csr(self, msgs, off_k, c3_k, pay_k, too_long,
                                 overflow_k, dev_shared: bool, b,
@@ -3160,7 +3182,7 @@ class DeviceRouteEngine:
         B = len(msgs)
         cm = c3_k[:B, 0].astype(np.int64)
         cf = c3_k[:B, 1].astype(np.int64)
-        cs = c3_k[:B, 2]
+        cs = c3_k[:B, 2].astype(np.int64)
         base = off_k[:B].astype(np.int64)
         total_m = int(cm.sum())
         mi = np.repeat(np.arange(B), cm)
@@ -3170,7 +3192,6 @@ class DeviceRouteEngine:
                          + np.repeat(base, cm)]
         else:
             fids = np.zeros(0, np.int32)
-        shared_any = cs[:B] > 0
         fbase = base + cm           # fan rows start, per message
         obase = base + cm + cf      # fan opts start, per message
 
@@ -3178,8 +3199,18 @@ class DeviceRouteEngine:
             return (pay_k[fbase[row_msg] + col],
                     pay_k[obase[row_msg] + col])
 
+        def fetch_picks():
+            # the shared family: `cs` slots (the valid ones, a prefix
+            # of the plane), then as many picked sids, then as many
+            # packed opts (ops/compact.csr_slices)
+            n = np.repeat(cs, cs)
+            at = np.repeat(base + cm + 2 * cf, cs) + np.arange(n.size) \
+                - np.repeat(np.cumsum(cs) - cs, cs)
+            return (np.repeat(np.arange(B), cs), pay_k[at],
+                    pay_k[at + n], pay_k[at + 2 * n])
+
         return self._fast_deliver(msgs, mi, fids, too_long, overflow_k,
-                                  shared_any, fetch, dev_shared, b,
+                                  cs > 0, fetch, fetch_picks, dev_shared, b,
                                   d_counts_k, plan=plan)
 
     @staticmethod
@@ -3243,22 +3274,55 @@ class DeviceRouteEngine:
         return (row_msg[valid], sid[valid], opt[valid],
                 row_fid[valid]), any_wide
 
+    def _with_picked_rows(self, rows, picked, fast_ok, b):
+        """The plan's rows with the device's `$share` picks among them:
+        one row a reported slot of a clean message whose group has a
+        member, (message, picked sid, its packed opts, the fid of the
+        slot's filter), after the message's plain rows. Returns (rows,
+        slot): the slot of every row's group, -1 on a plain one."""
+        p_msg, p_slot, p_sid, p_opt = picked
+        keep = fast_ok[p_msg] & (p_sid >= 0)
+        if not keep.any():
+            return rows, None
+        p_msg, p_slot = p_msg[keep], p_slot[keep].astype(np.int64)
+        self.node.metrics.inc("routing.device.shared_lane_rows",
+                              len(p_msg))
+        p_rows = (p_msg, p_sid[keep], p_opt[keep], b.slot_fid[p_slot])
+        if rows is None:
+            return p_rows, p_slot
+        # both are in message order: a stable sort on the message keeps
+        # a message's plain rows ahead of its picks
+        order = np.argsort(np.concatenate((rows[0], p_msg)),
+                           kind="stable")
+        slot = np.concatenate((np.full(len(rows[0]), -1, np.int64),
+                               p_slot))[order]
+        return tuple(np.concatenate((a, p))[order]
+                     for a, p in zip(rows, p_rows)), slot
+
     def _fast_deliver(self, msgs, mi, fids, too_long, overflow_k,
-                      shared_any, fetch, dev_shared: bool, b,
+                      shared_any, fetch, fetch_picks, dev_shared: bool, b,
                       d_counts_k=None, plan=None):
         """Shared tail of the vectorized fast consume (dense and CSR):
         per-message clean proof, row attribution, and delivery. `mi`/
         `fids` list every valid match (message index, filter id) in
         match order; `fetch(row_msg, col)` gathers the (sid, packed
-        opts) of fan-out entry `col` within message `row_msg`.
+        opts) of fan-out entry `col` within message `row_msg`;
+        `shared_any` marks the messages for which the device reports a
+        `$share` slot and `fetch_picks()` gathers those reports, message by
+        message in the planes' order: (message index, slot, picked sid,
+        packed opts).
 
         With `plan` attached (ISSUE 5: deliver lanes active) this stops
         looping entirely: the gathered (row_msg, sid, opt, fid) arrays
         are handed to the plan, which buckets them into session-affine
         lane slices — delivery (and the no-subscriber bookkeeping for
-        these messages) then overlaps the next window's dispatch.
+        these messages) then overlaps the next window's dispatch. A
+        member the device picked is such a row too (ISSUE 35), after
+        its message's plain rows as `_consume_one` delivers it, where
+        the group is as the snapshot describes it.
         `plan=None` is the inline A/B baseline (deliver_lanes=0 or no
-        running loop): the per-row loop below, unchanged semantics."""
+        running loop): the per-row loop below, unchanged semantics,
+        every shared message through `_consume_one`."""
         broker = self.broker
         B = len(msgs)
         # per-fid host-side mask, memoized on (snapshot, dirty version)
@@ -3273,9 +3337,27 @@ class DeviceRouteEngine:
             # pay, everything else stays fast)
             slow |= d_counts_k[:B] > 0
         if fids.size:
-            np.logical_or.at(slow, mi, hostside[fids] | b.fid_shared[fids])
-        if dev_shared:
-            slow |= shared_any
+            # where the host picks, a filter with a group is the host's;
+            # where the device did, what it reports below says it all
+            np.logical_or.at(slow, mi, hostside[fids] if dev_shared
+                             else hostside[fids] | b.fid_shared[fids])
+        picked = None
+        if dev_shared and shared_any.any():
+            # a remote member in the snapshot with no cluster to forward
+            # to (torn down since the build) leaves the pick to the
+            # host: for the whole snapshot, so that a topic's messages
+            # stay on one side of the barrier
+            if plan is None or b.remote_members:
+                slow |= shared_any
+            else:
+                picked = fetch_picks()
+                if self.dirty_slots:
+                    # membership changed since the snapshot: the host's
+                    stale = np.zeros(b.n_slots, bool)
+                    stale[[b.slot_of[key] for key in self.dirty_slots
+                           if key in b.slot_of]] = True
+                    p_msg, p_slot = picked[:2]
+                    slow[p_msg[stale[p_slot]]] = True
 
         out: list = [None] * B
         fast_ok = ~slow
@@ -3288,8 +3370,12 @@ class DeviceRouteEngine:
             # here — the lanes deliver these messages off this stage
             fast_idx = np.flatnonzero(fast_ok)
             plan.register_fast(fast_idx)
+            slot = None
+            if picked is not None:
+                rows, slot = self._with_picked_rows(rows, picked,
+                                                    fast_ok, b)
             if rows is not None:
-                plan.add_rows(*rows, b.fid_filter)
+                plan.add_rows(*rows, b.fid_filter, slot, b.picks)
             for i in fast_idx.tolist():
                 out[i] = DEFERRED
             return out
@@ -3647,6 +3733,12 @@ class DeviceRouteEngine:
             "wide_rows": self.node.metrics.val("routing.device.wide_rows"),
             "fanout_overflow": self.node.metrics.val(
                 "routing.device.fanout_overflow"),
+            # `$share` members the device picked, handed to the lanes as
+            # rows, and those the host picked again at delivery
+            "shared_lane_rows": self.node.metrics.val(
+                "routing.device.shared_lane_rows"),
+            "shared_repick": self.node.metrics.val(
+                "routing.device.shared_repick"),
             "filters": len(b.fid_filter) if b else 0,
             "shared_slots": b.n_slots if b else 0,
             "churn": self.staleness(),

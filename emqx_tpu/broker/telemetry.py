@@ -546,7 +546,7 @@ class PipelineTelemetry:
         deliver = {}
         for key in ("rows", "plans", "deliveries", "drains",
                     "backpressure_waits", "deliver_errors",
-                    "slow_errors"):
+                    "slow_errors", "slow_msgs", "barriers"):
             v = self.metrics.val(f"pipeline.deliver.{key}")
             if v:
                 deliver[key] = v

@@ -56,11 +56,17 @@ def run(coro, timeout=120):
         loop.close()
 
 
-def _node(lanes: int, depth: int = 8) -> Node:
-    return Node({"broker": {"deliver_lanes": lanes,
+def _node(lanes: int, depth: int = 8, readback: str = "csr",
+          backend: str = "shapes") -> Node:
+    node = Node({"broker": {"deliver_lanes": lanes,
                             "deliver_lane_depth": depth,
                             "device_fanout_cap": 16,
-                            "device_slot_cap": 4}})
+                            "device_slot_cap": 4,
+                            "compact_readback": readback == "csr"}})
+    if backend == "trie":
+        # the worlds here have two filter shapes or more
+        node.device_engine.shape_cap = 1
+    return node
 
 
 def _build_world(node, rng, sink_cls=Rec):
@@ -162,25 +168,39 @@ def _churn_actions():
 
 
 class TestOrderProperty:
+    @pytest.mark.parametrize("backend", ["shapes", "trie"])
+    @pytest.mark.parametrize("readback", ["csr", "dense"])
     @pytest.mark.parametrize("lanes", [1, 4])
-    def test_per_session_order_identical_to_inline(self, lanes):
+    def test_per_session_order_identical_to_inline(self, lanes, readback,
+                                                   backend):
         """The acceptance oracle: per-session delivery sequences are
         bit-identical between deliver_lanes=0 and deliver_lanes=N,
         across clean/shared/rich/dirty interleaving, churn mid-schedule
-        and a mid-window unsubscribe."""
+        and a mid-window unsubscribe; the groups' device-picked members
+        ride the lanes as rows (ISSUE 35), read from the CSR payload or
+        the dense planes of a shape-hash or a trie snapshot."""
         rng = np.random.RandomState(7)
         windows = _schedule(rng)
 
-        n0 = _node(0)
+        n0 = _node(0, readback=readback, backend=backend)
         s0 = _build_world(n0, rng)
         c0 = run(_drive(n0, windows, _churn_actions()))
 
-        nL = _node(lanes)
+        nL = _node(lanes, readback=readback, backend=backend)
         sL = _build_world(nL, rng)
         cL = run(_drive(nL, windows, _churn_actions()))
 
         assert n0.deliver_lanes is None
         assert nL.deliver_lanes is not None
+        assert nL.device_engine.stats()["backend"] == backend
+        m = nL.metrics
+        assert (m.val("pipeline.readback.windows.compact") > 0) \
+            == (readback == "csr")
+        # every s/<i>/y message is a row of its plan (the fresh filter
+        # of window 4 is the delta overlay's, and not on their topics)
+        n_shared = sum(t.startswith("s/") for w in windows for t, _p in w)
+        assert m.val("routing.device.shared_lane_rows") == n_shared > 20
+        assert m.val("routing.device.shared_repick") == 0
 
         got0 = {sid: s.got for sid, s in s0.items()}
         gotL = {sid: s.got for sid, s in sL.items()}
@@ -350,6 +370,298 @@ class TestWideFanoutThroughTheLanes:
             logs.append(([s.got for s in sinks.values()], counts))
         assert logs[0] == logs[1]
         assert max(len(g) for g in logs[0][0]) > 60
+
+
+# ---------- ISSUE 35: a device-picked $share delivery is a row ----------
+
+class OptRec:
+    """Recording sink that keeps the delivered subopts; `accept` False
+    nacks (and records nothing), as a subscriber whose session is gone."""
+
+    def __init__(self):
+        self.got = []
+        self.accept = True
+
+    def deliver(self, topic_filter, msg):
+        if not self.accept:
+            return False
+        self.got.append((topic_filter, msg.topic, bytes(msg.payload),
+                         dict(msg.headers.get("subopts"))))
+        return True
+
+
+class OptRecBatch(OptRec):
+    def deliver_batch(self, items):
+        if not self.accept:
+            return 0
+        for f, m in items:
+            self.deliver(f, m)
+        return len(items)
+
+
+def _members(node, filt, n, sink_cls=OptRec, opts=None):
+    out = []
+    for i in range(n):
+        s = sink_cls()
+        sid = node.broker.register(s, f"{filt}#{i}")
+        node.broker.subscribe(sid, filt, dict(opts or {"qos": 0}))
+        out.append((sid, s))
+    return out
+
+
+async def _window(node, msgs, held=None):
+    """One window through the serving stages with the lanes on; `held`
+    runs between plan and delivery (the lanes paused meanwhile)."""
+    eng = node.device_engine
+    loop = asyncio.get_running_loop()
+    pool = node.deliver_lanes
+    pool.ensure_loop()
+    h = eng.prepare(msgs, gate_cold=False)
+    await loop.run_in_executor(None, eng.dispatch, h)
+    await loop.run_in_executor(None, eng.materialize, h)
+    if held is not None:
+        pool.pause()
+    counts = eng.finish_sub(h, 0)
+    if held is not None:
+        held()
+        pool.resume()
+    await pool.drain()
+    return list(counts)
+
+
+_LANE_COUNTERS = ("routing.device.shared_lane_rows",
+                  "routing.device.shared_repick",
+                  "pipeline.deliver.slow_msgs", "pipeline.deliver.barriers",
+                  "pipeline.deliver.rows", "messages.delivered",
+                  "messages.routed.device")
+
+
+def _lane_counters(node):
+    return {n.rsplit(".", 1)[1]: node.metrics.val(n)
+            for n in _LANE_COUNTERS}
+
+
+class TestSharedRowsThroughTheLanes:
+    @pytest.mark.parametrize("readback", ["csr", "dense"])
+    def test_clean_shared_window_raises_no_barrier(self, readback):
+        """A window of nothing but device-picked group deliveries: every
+        one is a row of the plan, no closure, no barrier; members in
+        turn, `share=<group>` beside the packed options."""
+        node = _node(4, readback=readback)
+        a = _members(node, "$share/ga/job/+", 2, opts={"qos": 1})
+        b = _members(node, "$share/gb/job/+", 3)
+        node.device_engine.rebuild()
+        msgs = [mkmsg(f"job/{i % 5}", b"m%03d" % i) for i in range(60)]
+        counts = run(_window(node, msgs))
+        assert counts == [2] * 60
+        c = _lane_counters(node)
+        assert c["barriers"] == 0 and c["slow_msgs"] == 0
+        assert c["shared_lane_rows"] == c["rows"] == 120
+        assert c["delivered"] == c["device"] == 120
+        assert c["shared_repick"] == 0
+        assert [len(s.got) for _sid, s in a] == [30, 30]
+        assert [len(s.got) for _sid, s in b] == [20, 20, 20]
+        for group, qos, members in (("ga", 1, a), ("gb", 0, b)):
+            got = sorted(p for _sid, s in members for _f, _t, p, _o in s.got)
+            assert got == [m.payload for m in msgs]     # once each
+            for _sid, s in members:
+                assert [p for _f, _t, p, _o in s.got] == sorted(
+                    p for _f, _t, p, _o in s.got)
+                assert all(f == "job/+" and o == {
+                    "qos": qos, "nl": 0, "rap": 0, "rh": 0,
+                    "share": group} for f, _t, _p, o in s.got)
+        # one frozen dict a (packed opts, group), not one a delivery
+        assert len(node.device_engine._built.picks._subopts) == 2
+
+    @pytest.mark.parametrize("reason", [
+        "dirty_slot", "new_group", "cluster", "remote_member",
+        "hostside_filter", "none"])
+    def test_what_keeps_the_closure_delivers_once(self, reason):
+        """Each state `_consume_one`'s shared branch has a case for
+        keeps the message behind the barrier, and the group still gets
+        the message exactly once."""
+        node = _node(4)
+        members = _members(node, "$share/g/s/+", 2)
+        plain = _members(node, "p/+", 1)
+        extra = []
+        if reason == "hostside_filter":
+            extra = _members(node, "s/#", 1, opts={"qos": 1, "subid": 3})
+        eng = node.device_engine
+        eng.rebuild()
+        want = 1
+        if reason == "dirty_slot":
+            members += _members(node, "$share/g/s/+", 1)
+        elif reason == "new_group":
+            extra = _members(node, "$share/g2/s/+", 1)
+        elif reason == "cluster":
+            class Cluster:      # joined since the build; nothing remote
+                _groups_by_real = {}
+
+                def forward(self, msg, matched):
+                    return 0
+
+                def _dispatch_one_group(self, broker, f, g, msg):
+                    raise AssertionError("a clean slot is the device's")
+            node.broker.cluster = Cluster()
+        elif reason == "remote_member":
+            # a member on a node that has left with the cluster
+            eng._built.remote_members.append(("gone@host", 7))
+        want += len(extra)
+        msgs = [mkmsg("s/1", b"m%03d" % i) for i in range(12)] \
+            + [mkmsg("p/1", b"plain")]
+        counts = run(_window(node, msgs))
+        assert counts == [want] * 12 + [1]
+        got = sorted(p for _sid, s in members for _f, _t, p, _o in s.got)
+        assert got == [m.payload for m in msgs[:12]]
+        assert all(o.get("share") == "g" for _sid, s in members
+                   for _f, _t, _p, o in s.got)
+        for _sid, s in extra:
+            assert [p for _f, _t, p, _o in s.got] == got
+        assert len(plain[0][1].got) == 1
+        c = _lane_counters(node)
+        if reason == "none":
+            assert (c["shared_lane_rows"], c["slow_msgs"],
+                    c["barriers"]) == (12, 0, 0)
+        else:
+            # new_group and cluster take the whole window
+            n_slow = 13 if reason in ("new_group", "cluster") else 12
+            assert (c["shared_lane_rows"], c["slow_msgs"],
+                    c["barriers"]) == (0, n_slow, 1)
+
+    @pytest.mark.parametrize("sink_cls", [OptRec, OptRecBatch])
+    @pytest.mark.parametrize("how", ["closed", "unsubscribed"])
+    def test_a_pick_that_left_is_redispatched_once(self, how, sink_cls):
+        """The picked member goes between plan and delivery (its
+        connection closes, or it unsubscribes and its session nacks):
+        each of its rows goes once through the host's dispatch of the
+        group, to a member that is there, in message order."""
+        node = _node(4)
+        (sid0, s0), (sid1, s1) = _members(node, "$share/g/s/+", 2,
+                                          sink_cls=sink_cls)
+        node.device_engine.rebuild()
+        msgs = [mkmsg("s/1", b"m%03d" % i) for i in range(8)]
+
+        def leave():
+            if how == "closed":
+                node.broker.subscriber_down(sid0)
+            else:
+                node.broker.unsubscribe(sid0, "$share/g/s/+")
+                s0.accept = False
+
+        counts = run(_window(node, msgs, held=leave))
+        assert counts == [1] * 8
+        assert s0.got == []
+        # its own four, then (the lane of sid 0 or after it) the other's
+        assert sorted(p for _f, _t, p, _o in s1.got) == \
+            [m.payload for m in msgs]
+        c = _lane_counters(node)
+        assert c["shared_lane_rows"] == 8 and c["shared_repick"] == 4
+        assert c["slow_msgs"] == 0 and c["delivered"] == 8
+        picked = [p for _f, _t, p, o in s1.got]
+        mine = [m.payload for m in msgs[1::2]]
+        assert [p for p in picked if p in mine] == mine
+        assert [p for p in picked if p not in mine] == \
+            [m.payload for m in msgs[0::2]]
+
+    @pytest.mark.parametrize("ack", [False, True])
+    def test_a_live_members_nack_is_final_without_the_ack_protocol(
+            self, ack):
+        node = _node(4)
+        node.broker.shared_dispatch_ack = ack
+        (sid0, s0), (sid1, s1) = _members(node, "$share/g/s/+", 2)
+        node.device_engine.rebuild()
+        s0.accept = False
+        msgs = [mkmsg("s/1", b"m%03d" % i) for i in range(8)]
+        counts = run(_window(node, msgs))
+        c = _lane_counters(node)
+        assert c["shared_lane_rows"] == 8 and c["slow_msgs"] == 0
+        if ack:
+            # the host's pick walks the members until one takes it
+            assert counts == [1] * 8 and len(s1.got) == 8
+            assert c["shared_repick"] == 4
+        else:
+            assert counts == [0, 1] * 4 and len(s1.got) == 4
+            assert c["shared_repick"] == 0
+            assert node.metrics.val(
+                "messages.dropped.no_subscribers") == 4
+
+    @pytest.mark.parametrize("sink_cls", [OptRec, OptRecBatch])
+    def test_one_session_by_a_plain_filter_and_by_a_group(self, sink_cls):
+        """A session a message reaches through a plain filter and as
+        the picked member of a group gets both, the plain one first as
+        `_consume_one` delivers them, message after message, the shared
+        one alone with `share=<group>` in its subopts."""
+        node = _node(4)
+        s = sink_cls()
+        sid = node.broker.register(s, "both")
+        node.broker.subscribe(sid, "s/+", {"qos": 1})
+        node.broker.subscribe(sid, "$share/g/s/+", {"qos": 0})
+        other = _members(node, "$share/g/s/+", 1)[0][1]
+        node.device_engine.rebuild()
+        msgs = [mkmsg(f"s/{i % 3}", b"m%03d" % i) for i in range(10)]
+        counts = run(_window(node, msgs))
+        assert counts == [2] * 10
+        plain = {"qos": 1, "nl": 0, "rap": 0, "rh": 0}
+        want = []
+        for i, m in enumerate(msgs):
+            want.append(("s/+", m.topic, m.payload, plain))
+            if i % 2 == 0:      # round robin: this session, then the other
+                want.append(("s/+", m.topic, m.payload,
+                             {"qos": 0, "nl": 0, "rap": 0, "rh": 0,
+                              "share": "g"}))
+        assert s.got == want
+        assert [p for _f, _t, p, _o in other.got] == \
+            [m.payload for m in msgs[1::2]]
+        c = _lane_counters(node)
+        assert (c["shared_lane_rows"], c["rows"], c["barriers"]) \
+            == (10, 20, 0)
+
+    def test_a_qos1_pick_enters_the_inflight_window(self):
+        """Through a real connection: a group's QoS 0 deliveries leave
+        as the one shared frame, its QoS 1 ones take the copy path into
+        the session's inflight window and are acknowledged."""
+        from emqx_tpu.broker.connection import Listener
+        from emqx_tpu.client import Client
+        node = _node(2)
+
+        async def go():
+            lst = Listener(node, bind="127.0.0.1", port=0)
+            await lst.start()
+            c = Client(port=lst.port, clientid="member")
+            await c.connect()
+            await c.subscribe("$share/g/job/+", qos=1)
+            node.device_engine.rebuild()
+            ch = next(iter(node.broker._subscribers.values()))
+            msgs = [make("pub", i % 2, f"job/{i}", b"m%03d" % i)
+                    for i in range(10)]
+            before = node.metrics.val("messages.qos0.sent")
+            pool = node.deliver_lanes
+            pool.ensure_loop()
+            h = node.device_engine.prepare(msgs, gate_cold=False)
+            node.device_engine.dispatch(h)
+            node.device_engine.materialize(h)
+            counts = node.device_engine.finish_sub(h, 0)
+            await pool.drain()
+            counts = list(counts)
+            inflight = len(ch.session.inflight)
+            got = [await c.recv(10) for _ in range(10)]
+            for _ in range(200):
+                if len(ch.session.inflight) == 0:
+                    break
+                await asyncio.sleep(0.01)
+            left = len(ch.session.inflight)
+            sent0 = node.metrics.val("messages.qos0.sent") - before
+            await c.disconnect()
+            await lst.stop()
+            return counts, inflight, left, got, sent0
+
+        counts, inflight, left, got, sent0 = run(go())
+        assert counts == [1] * 10
+        assert inflight == 5 and left == 0 and sent0 == 5
+        assert sorted((m.topic, m.qos) for m in got) == sorted(
+            (f"job/{i}", i % 2) for i in range(10))
+        c = _lane_counters(node)
+        assert c["shared_lane_rows"] == 10 and c["slow_msgs"] == 0
 
 
 class TestBackpressure:
@@ -615,6 +927,11 @@ class TestSharedFrame:
         "expiry": (0, {}, {"message_expiry_interval": 60},
                    {"qos": 0}, False),
         "no_local_own": (0, {}, None, {"qos": 0, "nl": 1}, False),
+        # ISSUE 35: a device-picked `$share` member's subopts
+        "share": (0, {}, None, {"qos": 0, "share": "g"}, True),
+        "share_sub_qos1_msg_qos0": (0, {}, None,
+                                    {"qos": 1, "share": "g"}, True),
+        "share_qos1": (1, {}, None, {"qos": 1, "share": "g"}, False),
     }
 
     @pytest.mark.parametrize("ver", [4, 5])
@@ -665,24 +982,26 @@ class TestSharedFrame:
                     == shared
                 got = ch.deliver_batch([("t/#", v) for v in views[:2]])
                 ok = ch.deliver("t/#", views[2])
-                if qos and so["qos"]:
-                    # packet ids differ run to run: strip them
-                    ch.session.inflight.clear() if hasattr(
-                        ch.session.inflight, "clear") else None
+                held = len(ch.session.inflight)
+                # packet ids differ run to run: strip them
+                ch.session.inflight.clear()
                 out.append((tap.data, got, ok,
                             {n: node.metrics.val(n) - before[n]
                              for n in names},
-                            ch.session.deliver_count - n0))
+                            ch.session.deliver_count - n0, held))
             await lst.stop()
             return out
 
         with_frames, with_copies = run(go())
-        if case == "qos1":
-            # the packet ids move on: same length, same counters
+        if qos and so["qos"]:
+            # the packet ids move on: same length, same counters, and
+            # all three in the session's inflight window
             assert len(with_frames[0]) == len(with_copies[0])
             assert with_frames[1:] == with_copies[1:]
+            assert with_frames[5] == 3
         else:
             assert with_frames == with_copies
+            assert with_frames[5] == 0
         assert with_frames[1] == 2 and with_frames[2] is True
         if case != "no_local_own":
             assert with_frames[0]

@@ -315,6 +315,79 @@ class TestEndToEnd:
             loop.close()
 
 
+    def test_shared_picks_ride_the_lanes_over_tcp(self):
+        """ISSUE 35 over real sockets and the batcher: concurrent QoS 1
+        publishes to a topic two `$share` members and a plain
+        subscriber hold are delivered by the lanes as rows (no closure,
+        no barrier): each once inside the group, members in turn, the
+        plain subscriber all of them, every publisher acknowledged."""
+        from emqx_tpu.broker.connection import Listener
+        from emqx_tpu.client import Client
+
+        loop = asyncio.new_event_loop()
+        try:
+            node = Node()
+            listener = Listener(node, bind="127.0.0.1", port=0)
+            loop.run_until_complete(listener.start())
+
+            async def go():
+                members = []
+                for i in range(2):
+                    c = Client(port=listener.port, clientid=f"member{i}")
+                    await c.connect()
+                    await c.subscribe("$share/g/bench/+/t", qos=i)
+                    members.append(c)
+                plain = Client(port=listener.port, clientid="plain")
+                await plain.connect()
+                await plain.subscribe("bench/#", qos=0)
+                pubs = []
+                for i in range(8):
+                    c = Client(port=listener.port, clientid=f"pub{i}")
+                    await c.connect()
+                    pubs.append(c)
+                from tests.test_pipeline import _await_device_engaged
+                await _await_device_engaged(node, "warm/{}")
+                node.publish_batcher._device_worth_it = \
+                    lambda n, n_subs=1: True
+                for rnd in range(3):
+                    await asyncio.gather(*[
+                        c.publish(f"bench/{i}/t", b"r%dp%d" % (rnd, i),
+                                  qos=1)
+                        for i, c in enumerate(pubs)])
+                got = [[(m.topic, m.payload, m.qos) for m in
+                        [await asyncio.wait_for(c.messages.get(), 10)
+                         for _ in range(n)]]
+                       for c, n in ((members[0], 12), (members[1], 12),
+                                    (plain, 24))]
+                await asyncio.sleep(0.05)
+                stray = sum(c.messages.qsize()
+                            for c in members + [plain])
+                for c in pubs + members + [plain]:
+                    await c.disconnect()
+                return got, stray
+
+            (m0, m1, pl), stray = loop.run_until_complete(
+                asyncio.wait_for(go(), 60))
+            sent = sorted((f"bench/{i}/t", b"r%dp%d" % (rnd, i))
+                          for rnd in range(3) for i in range(8))
+            assert stray == 0
+            assert sorted((t, p) for t, p, _q in m0 + m1) == sent
+            assert sorted((t, p) for t, p, _q in pl) == sent
+            assert {q for _t, _p, q in m0} == {0}
+            assert {q for _t, _p, q in m1} == {1}
+            m = node.metrics
+            assert m.val("routing.device.shared_lane_rows") >= 16
+            assert m.val("pipeline.deliver.slow_msgs") == 0
+            assert m.val("pipeline.deliver.barriers") == 0
+            assert m.val("routing.device.shared_repick") == 0
+            st = node.device_engine.stats()
+            assert st["shared_lane_rows"] == m.val(
+                "routing.device.shared_lane_rows")
+            loop.run_until_complete(listener.stop())
+        finally:
+            loop.close()
+
+
 class TestAdaptiveDeviceChoice:
     """SURVEY §7 hard-part 2: the batcher measures device-batch vs
     host-per-message cost and routes each batch to the cheaper path,
