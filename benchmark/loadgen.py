@@ -289,6 +289,10 @@ class Publishers:
                  out: str):
         self.out = out
         self.seed = seed
+        # a configuration may set the generator's parameters of a mix
+        # for its own cell: `"traffic": {"<mix>": {...}}` in its file
+        traffic = {**traffic, **config.get("traffic", {}).get(
+            traffic.get("name"), {})}
         self.traffic = traffic
         self.pop = populations.load(config)
         pub = config["publish"]
@@ -298,6 +302,14 @@ class Publishers:
         self.plen = int(pub["payload_bytes"])
         self.n = int(traffic["connections"])
         self.max_unacked = int(traffic.get("max_unacked_qos1", 256))
+        # a flood's start: connection p writes its first PUBLISH
+        # `start_after_s[p]` seconds after the run's t0 (all at t0
+        # without the key)
+        self.start_after = [int(float(x) * 1e9) for x in
+                            traffic.get("start_after_s", [0.0] * self.n)]
+        if len(self.start_after) != self.n:
+            raise ValueError(f"start_after_s names {len(self.start_after)} "
+                             f"connections, the mix has {self.n}")
         # a closed loop needs an acknowledgement to close it: QoS 1
         # PUBACKs where the configuration has them; where it is all
         # QoS 0, a PINGREQ after every `fence_every` PUBLISHes, with at
@@ -416,10 +428,14 @@ class Publishers:
         return not buf
 
     # -- closed loop ----------------------------------------------------
-    def flood(self, conns: int, until_ns: int = 0, messages: int = 0) -> None:
+    def flood(self, conns: int, until_ns: int = 0, messages: int = 0,
+              t0_ns: int = 0) -> None:
         """`conns` publishers write as fast as TCP backpressure and the
-        QoS 1 in-flight bound let them, until a deadline or a count."""
+        QoS 1 in-flight bound let them, until a deadline or a count;
+        from `t0_ns`, each from its own `start_after_s`."""
         left = messages
+        starts = [t0_ns + a for a in self.start_after] if t0_ns else []
+        late = any(self.start_after) and bool(starts)
         per_chunk = self.CHUNK
         q1 = -(-per_chunk // self.qos1_every) if self.qos1_every else 0
         send_col = self.log.col["send_ns"]
@@ -429,7 +445,12 @@ class Publishers:
             if messages and left <= 0:
                 break
             wrote = False
+            if late:
+                now = now_ns()
+                late = now < max(starts)
             for p in range(conns):
+                if late and now < starts[p]:
+                    continue
                 if not self.push(p):
                     continue
                 if self.unacked[p] + q1 > self.max_unacked \
@@ -477,7 +498,8 @@ class Publishers:
                 self.poll(min(0.001, max(0.0, (t0 - now_ns()) / 1e9)))
             if self.traffic["loop"] != "closed":
                 raise ValueError(f"unknown loop {self.traffic['loop']!r}")
-            self.flood(self.n, until_ns=t0 + int(cmd["seconds"] * 1e9))
+            self.flood(self.n, until_ns=t0 + int(cmd["seconds"] * 1e9),
+                       t0_ns=t0)
             reply(**self.status())
         elif kind == "status":
             reply(**self.status())

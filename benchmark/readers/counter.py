@@ -7,6 +7,15 @@ generators' logs. The value is scale * sum(delta num) / sum(delta den);
 without `den` it is the summed delta. A denominator that did not move
 (no burst, no window of that kind) reads as 0: the metric is still
 reported, since a cell's line has to carry each of its metrics.
+
+A share is not capped. Where its numerator and denominator move at
+different moments of one window's life (`routing.device.windows` at
+prepare and `routing.device.cached_windows` at dispatch;
+`routing.device.nfa_steps` before a device read and
+`routing.device.nfa_narrow_steps` after it), a snapshot can fall
+between the two, so the share is off by the windows in flight at either
+edge and can read a little over 100 (100.38 seen, PR 37). Anything
+beyond that is a miscount, and a cap would hide it.
 """
 
 from __future__ import annotations

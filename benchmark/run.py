@@ -409,6 +409,11 @@ class Run:
         live = ~sub["dup"]
         w["deliveries"] = int(((sub["recv_ns"][live] >= w["t0_ns"])
                                & (sub["recv_ns"][live] < w["t1_ns"])).sum())
+        # a change of regime inside the window shows here (PERF.md,
+        # section 5); the last bin is what is left of the window
+        per_5s, _ = np.histogram(sub["recv_ns"][live], bins=np.append(
+            np.arange(w["t0_ns"], w["t1_ns"], int(5e9)), w["t1_ns"]))
+        say(f"deliveries per 5 s of the window: {per_5s.tolist()}")
         t = time.monotonic()
         verdict = checker.check(self.pop, pub, sub, self.args.seed,
                                 limits=self.cell.config.get("limits"))
@@ -441,6 +446,13 @@ class Run:
             "node_deliveries": d("messages.delivered"),
             "device_routed_deliveries": d("messages.routed.device"),
             "device_windows": d("routing.device.batches"),
+            # the cell's per-layer metrics that need the counters alone,
+            # each through its own file: an untraced run then shows, for
+            # one, which regime `share50-250k.flood` drew
+            # (`fuse_depth.flood`; PERF.md, section 5)
+            "by_counter": {m["name"]: round(read_metric(
+                c, m["reader"], m["args"]), 4) for m in self.cell.per_layer
+                if m["reader"] == "counter"},
             "bypassed": d("routing.device.bypassed"),
             "cold_class": d("routing.device.cold_class"),
             "batches": {k.rsplit(".", 1)[1]: d(k) for k in c["m1"]

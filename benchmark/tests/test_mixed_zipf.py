@@ -217,14 +217,15 @@ def test_the_cell_with_a_guarantee_broken_is_not_correct(control, number):
     assert out["compared"][number]["value"] > out["compared"][number]["limit"]
 
 
-def test_the_cell_reports_its_33_metrics_and_the_trie():
+def test_the_cell_reports_its_34_metrics_and_the_trie():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [m["name"] for m in bench["per_layer"]
             if CELL in m.get("workloads", ())]
-    assert len(mine) == 33
+    assert len(mine) == 34      # PR 37: nfa_narrow_step_share.flood
     assert {"nfa_window_share.flood", "match_overflow_share.flood",
             "route_nfa_roofline.flood", "snapshot_build_s",
+            "nfa_narrow_step_share.flood",
             "match_cache_hit_share.flood"} <= set(mine)
     assert "route_roofline.flood" not in mine \
         and "puback_per_s.flood" not in mine

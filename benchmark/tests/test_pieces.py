@@ -666,6 +666,28 @@ def test_counter_and_telemetry_readers():
 
 # ------------------------------------------------------------- the loader
 
+def test_a_share_counted_at_two_moments_reads_as_counted():
+    """`routing.device.windows` moves at prepare and
+    `routing.device.cached_windows` at dispatch: a window prepared
+    before the first snapshot and dispatched after it is in the
+    numerator alone, and a short span then reads over 100. The reader
+    does not cap it (a cap would hide a miscount), and no metric's file
+    asks for one."""
+    ctx = {"m0": {"routing.device.windows": 12,
+                  "routing.device.cached_windows": 9},
+           "m1": {"routing.device.windows": 14,
+                  "routing.device.cached_windows": 12},
+           "window": {"seconds": 3.0}}
+    num, den = ["routing.device.cached_windows"], ["routing.device.windows"]
+    assert counter.read(ctx, num, den, 100.0) == 150.0
+    with pytest.raises(TypeError):
+        counter.read(ctx, num, den, 100.0, cap=100.0)
+    folder = os.path.join(os.path.dirname(HERE), "layer_metrics")
+    for name in sorted(os.listdir(folder)):
+        with open(os.path.join(folder, name)) as f:
+            assert "cap" not in json.load(f).get("args", {}), name
+
+
 def test_every_cell_of_the_manifest_loads():
     with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -769,3 +791,118 @@ def test_the_set_ups_direct_warm_deliveries_are_left_out():
     extra["pub"][-7:] = check.WARM_PUB
     v = check.check(pop, pub, extra, seed=1)
     assert v["correct"] and v["info"]["deliveries"] == len(sub["sub"])
+
+
+# ------------------------------------------------- the generator's start
+
+class _Sink:
+    """A broker that answers CONNECT and reads everything else away."""
+
+    def __init__(self):
+        import socket
+        import threading
+        self.srv = socket.socket()
+        self.srv.bind(("127.0.0.1", 0))
+        self.srv.listen(16)
+        self.port = self.srv.getsockname()[1]
+        self.stop = False
+        self.threads = [threading.Thread(target=self._accept, daemon=True)]
+        self.threads[0].start()
+
+    def _accept(self):
+        import threading
+        self.srv.settimeout(0.1)
+        while not self.stop:
+            try:
+                c, _a = self.srv.accept()
+            except OSError:
+                continue
+            t = threading.Thread(target=self._serve, args=(c,), daemon=True)
+            t.start()
+            self.threads.append(t)
+
+    def _serve(self, c):
+        c.settimeout(0.1)
+        greeted = False
+        while not self.stop:
+            try:
+                data = c.recv(1 << 16)
+            except OSError:
+                continue
+            if not data:
+                break
+            if not greeted:
+                c.sendall(bytes([codec.CONNACK << 4, 2, 0, 0]))
+                greeted = True
+        c.close()
+
+    def close(self):
+        self.stop = True
+        for t in self.threads:
+            t.join(2)
+        self.srv.close()
+
+
+def test_a_flood_brings_each_connection_up_at_its_own_start(tmp_path,
+                                                            capsys):
+    """`start_after_s` of a traffic mix: connection p writes nothing
+    before t0 + start_after_s[p] (PR 37: `share50-250k` brings the
+    flood's eight connections up in two halves)."""
+    from benchmark import loadgen
+    cell = manifest.Cell("plus-100k.flood")           # all QoS 0
+    config = dict(cell.config)
+    config["population"] = dict(config["population"], params=dict(
+        config["population"]["params"], **config["rehearse"]["population"]))
+    traffic = {"loop": "closed", "connections": 3, "fence_every": 0,
+               "start_after_s": [0.0, 0.4, 0.4]}
+    sink = _Sink()
+    r, w = os.pipe()
+    saved = os.dup(0)
+    os.dup2(r, 0)                # the generator's command pipe is fd 0
+    try:
+        pubs = loadgen.Publishers(sink.port, config, traffic, 7,
+                                  str(tmp_path))
+        t0 = loadgen.now_ns() + int(0.05e9)
+        pubs.run_cmd({"cmd": "run", "t0_ns": t0, "seconds": 0.8})
+        col, n = pubs.log.col, pubs.log.n
+        first = [int(col["send_ns"][:n][col["pub"][:n] == p].min()) - t0
+                 for p in range(3)]
+        # without the key all start at t0; a configuration sets it for
+        # its own cell under the mix's name, and that wins
+        mix = {"name": "flood", "loop": "closed", "connections": 3}
+        plain = loadgen.Publishers(sink.port, config, mix, 7, str(tmp_path))
+        assert plain.start_after == [0, 0, 0]
+        own = dict(config, traffic={"flood": {"start_after_s": [0, .1, .2]},
+                                    "other": {"start_after_s": [9, 9, 9]}})
+        mine = loadgen.Publishers(sink.port, own, dict(
+            mix, start_after_s=[0, 0, 0]), 7, str(tmp_path))
+        assert mine.start_after == [0, int(.1e9), int(.2e9)]
+        with pytest.raises(ValueError, match="names 2 connections"):
+            loadgen.Publishers(sink.port, config,
+                               dict(mix, start_after_s=[0, 0]), 7,
+                               str(tmp_path))
+        for s in pubs.socks + plain.socks + mine.socks:
+            s.close()
+    finally:
+        os.dup2(saved, 0)
+        for fd in (saved, r, w):
+            os.close(fd)
+        sink.close()
+    assert 0 <= first[0] < int(0.2e9)
+    assert int(0.4e9) <= first[1] < int(0.6e9)
+    assert int(0.4e9) <= first[2] < int(0.6e9)
+    assert json.loads(capsys.readouterr().out.strip())["sent"] == n
+
+
+def test_only_share50_250k_sets_a_parameter_of_its_mix():
+    """The two halves are the one cell's: every other configuration
+    runs the flood mix as its file has it."""
+    with open(os.path.join(manifest.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for c in bench["configs"]:
+        with open(os.path.join(manifest.ROOT, c["file"])) as f:
+            own = json.load(f).get("traffic")
+        if c["name"] == "share50-250k":
+            assert own == {"flood": {"start_after_s": [0.0] * 4 + [0.3] * 4}}
+        else:
+            assert own is None, c["name"]

@@ -58,6 +58,17 @@ def test_rehearsal_of_each_cell_end_to_end(cell):
     assert out["device"]["busy_s"] >= 0 and out["device"]["window_s"] > 2.5
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     assert out["split"]["window"]["publishes"] > 0
+    # the metrics that need the counters alone are in the split too,
+    # each read through its own file, so that an untraced run shows them
+    # (`fuse_depth.flood`: which regime `share50-250k.flood` drew)
+    by_counter = out["split"]["window"]["by_counter"]
+    for name, value in by_counter.items():
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               name + ".json")) as f:
+            assert json.load(f)["reader"] == "counter"
+        assert value == pytest.approx(values[name]["value"], abs=1e-3)
+    assert ("fuse_depth.flood" in by_counter) == \
+        (cell == "share50-250k.flood")
 
 
 @pytest.mark.parametrize("control,number,cell", [

@@ -51,6 +51,13 @@ def recorded():
         return json.load(f)
 
 
+def metric_spec(name):
+    """A per-layer metric's own file."""
+    with open(os.path.join(os.path.dirname(HERE), "layer_metrics",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
 def names_only(trace):
     """The form `xplane.from_file` gives (what `ctx["trace"]` holds)."""
     return {"planes": [{"name": p["name"], "lines": [
@@ -306,9 +313,10 @@ def test_of_two_possible_alignments_the_tighter_readbacks_win(recorded):
 
 # ------------------------------------------------- the manifest's entries
 
-def test_every_new_metric_file_reads_the_recorded_trace(recorded):
-    """Each per-layer metric this PR adds, through its own file, on the
-    recorded trace plus hand-made counters."""
+def held_to_the_recorded_trace(recorded, letter_for_letter: bool) -> None:
+    """Each per-layer metric of PRs 24-26, through its own file, on the
+    recorded trace plus hand-made counters. Its `workloads` list starts
+    with the cells it was added for; later PRs append theirs."""
     ctx = ctx_of(recorded)
     ctx.update(
         window={"seconds": 51.0},
@@ -356,11 +364,73 @@ def test_every_new_metric_file_reads_the_recorded_trace(recorded):
                            "BENCHMARK.json")) as f:
         manifest = {m["name"]: m for m in json.load(f)["per_layer"]}
     for name, value in want.items():
-        with open(os.path.join(os.path.dirname(HERE), "layer_metrics",
-                               name + ".json")) as f:
-            spec = json.load(f)
+        spec = metric_spec(name)
         assert spec["unit"] == manifest[name]["unit"]
-        assert manifest[name]["workloads"] == ["plus-100k.flood"] * (
-            name not in of_share) + ["share50-250k.flood"]
+        first = ["plus-100k.flood"] * (name not in of_share) \
+            + ["share50-250k.flood"]
+        listed = manifest[name]["workloads"]
+        assert listed == first if letter_for_letter \
+            else listed[:len(first)] == first, name
+        assert len(set(listed)) == len(listed), name
         assert read_metric(ctx, spec["reader"], spec["args"]) == \
             pytest.approx(value), name
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "PR 26's pin, letter for letter; kept red for the strict xfail "
+    "wrapper of the same name in tests/test_benchmark.py, which calls "
+    "it: the next PR that may touch tests/ deletes wrapper and case"))
+def test_every_new_metric_file_reads_the_recorded_trace(recorded):
+    """PR 26's pin, the lists letter for letter: red since PR 28
+    appended `mixed-zipf.flood`. Tier-1 carries it under this name as a
+    strict xfail in `tests/test_benchmark.py`, a file no `benchmark` PR
+    may touch, so it stays as it was until that wrapper goes (PERF.md,
+    section 7), marked here as what it is so that a direct run of
+    `benchmark/tests` is green; the case below is the one that holds."""
+    held_to_the_recorded_trace(recorded, letter_for_letter=True)
+
+
+def test_every_metric_file_reads_the_recorded_trace(recorded):
+    """The same metrics, values and files; a `workloads` list has to
+    start with the cells the metric was added for, and later cells may
+    follow."""
+    held_to_the_recorded_trace(recorded, letter_for_letter=False)
+
+
+def test_the_counters_of_prs_29_and_35_and_the_fuse_depth_have_readers():
+    """PR 37's per-layer metrics, through their own files, on hand-made
+    counters: each is listed for the one cell where its counters move."""
+    ctx = {"window": {"seconds": 51.0},
+           "m0": {"routing.device.windows": 40,
+                  "routing.device.window_subs": 50,
+                  "routing.device.shared_lane_rows": 1_000,
+                  "routing.device.nfa_steps": 1_700,
+                  "routing.device.nfa_narrow_steps": 700},
+           "m1": {"routing.device.windows": 540,
+                  "routing.device.window_subs": 810,
+                  "routing.device.shared_lane_rows": 241_000,
+                  "pipeline.deliver.slow_msgs": 60_000,
+                  "routing.device.nfa_steps": 18_700,
+                  "routing.device.nfa_narrow_steps": 13_450}}
+    want = {"fuse_depth.flood": (1.52, "share50-250k.flood",
+                                 "batcher + chooser"),
+            "shared_lane_share.flood": (80.0, "share50-250k.flood",
+                                        "consume + lanes"),
+            "nfa_narrow_step_share.flood": (75.0, "mixed-zipf.flood",
+                                            "route programs + kernels")}
+    root = os.path.dirname(os.path.dirname(HERE))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name, (value, cell, layer) in want.items():
+        spec = metric_spec(name)
+        entry = manifest[name]
+        assert (spec["unit"], spec["moves"]) == (entry["unit"],
+                                                 entry["moves"])
+        assert entry["workloads"] == [cell] and entry["layer"] == layer
+        assert entry["source"] == "program_counter"
+        assert read_metric(ctx, spec["reader"], spec["args"]) == \
+            pytest.approx(value), name
+    # a window in which nothing was consumed by a closure reads 100
+    del ctx["m1"]["pipeline.deliver.slow_msgs"]
+    spec = metric_spec("shared_lane_share.flood")
+    assert read_metric(ctx, spec["reader"], spec["args"]) == 100.0
