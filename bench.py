@@ -12,7 +12,7 @@ into $share groups (BASELINE.md config 4).
 
 One process holds the chip: this script starts JAX itself, names the
 device in its JSON and exits non-zero when JAX finds no TPU (`--phase0`
-and the full run; the `--latency-probe` / `--skew` / `--churn` / `--cover`
+and the full run; the `--latency-probe` / `--skew` / `--churn`
 modes are CPU correctness drives and run anywhere). The
 full run's `cpu_*` rows are `JAX_PLATFORMS=cpu` children — correctness
 rows, never speeds; they keep the chip free for this process. One JSON
@@ -1270,7 +1270,6 @@ _CPU_ROWS_LATE = (
     ("cpu_sharded", os.path.join(_TOOLS, "sharded_bench.py"), (), 1200),
     ("cpu_skew", os.path.join(_TOOLS, "skew_bench.py"), (), 600),
     ("cpu_churn", os.path.join(_TOOLS, "churn_bench.py"), (), 600),
-    ("cpu_cover", os.path.join(_TOOLS, "cover_bench.py"), (), 600),
     ("cpu_ingress", os.path.join(_TOOLS, "ingress_bench.py"), (), 1500),
     ("cpu_overload", os.path.join(_TOOLS, "overload_bench.py"), (), 1200),
 )
@@ -1325,8 +1324,7 @@ def main() -> int:
         print(json.dumps(_latency_probe()), flush=True)
         return 0
     # CPU correctness microbenches; the harnesses live in tools/
-    for flag, mod in (("--skew", "skew_bench"), ("--churn", "churn_bench"),
-                      ("--cover", "cover_bench")):
+    for flag, mod in (("--skew", "skew_bench"), ("--churn", "churn_bench")):
         if flag in sys.argv:
             __import__(mod).main()
             return 0

@@ -31,8 +31,11 @@ Round-2 rework:
   `host_probe_every` device batches (round 2's estimator starved: under
   steady device load the host was never sampled and `device_bypassed`
   could not fire); the device cost is re-probed every `_PROBE_EVERY`
-  bypassed batches so a transiently slow device is not written off forever
-  (that re-try's sample replaces the estimate outright).
+  bypassed batches so a transiently slow device is not written off
+  forever (that re-try's sample replaces the estimate outright). The
+  chip is left only where the host is ahead by `_LEAVE_MARGIN` and taken
+  back at parity: two paths that cost about the same do not trade places
+  by the sample.
   Pipelined device cost is sampled as completion-to-completion time (the
   amortized rate the pipeline actually delivers), not the full round-trip
   — except across an idle gap, where the round-trip is the sample.
@@ -75,6 +78,16 @@ from emqx_tpu.broker.message import Message
 # batches, so a transiently slow device (cold compile, a stall) is not
 # written off forever
 _PROBE_EVERY = 64
+# the chooser leaves the chip only where the host is ahead by this
+# much: leaving drops the fusion width to 1 and makes the host window
+# wait out the lanes, so two costs within a quarter of each other do
+# not pay for the change of path, and flapping between them is a
+# run's throughput by the draw. Measured against `umbrella-cover.
+# flood` on a v5e, the one cell where the two costs meet
+# (`chooser_margin` 0.4-1.7): 1-8 windows bypassed a run of ~600 and
+# no episode on the host in seven runs, where the parent's rule read
+# 3-11 and, in two runs of six, an episode of 64 (PERF.md, PR 38)
+_LEAVE_MARGIN = 1.25
 # deliveries that may stand between the batch queue and the sockets (the
 # windows formed and not yet settled, by their recent fan-out, plus the
 # lanes' plans), and in the batch queue itself: one sub-batch of 1,024
@@ -208,6 +221,7 @@ class PublishBatcher:
         # _dev_batch_s / (n * _host_msg_s) of the last cost comparison
         self.chooser_margin: Optional[float] = None
         self._since_probe = 0         # host batches since last device try
+        self._on_host = False         # the last cost comparison chose it
         self._dev_reprobe = False     # next device sample is that re-try's
         self._since_host_probe = 0    # device batches since last host probe
         self._last_dev_done: Optional[float] = None
@@ -1300,9 +1314,13 @@ class PublishBatcher:
         if host_s > 0:
             # < 1: the chip wins this window by the two measured costs
             self.chooser_margin = self._dev_batch_s / host_s
-        if self._dev_batch_s <= host_s:
+        # a dead band: back on the chip at parity, off it past the margin
+        if self._dev_batch_s <= host_s * (
+                1.0 if self._on_host else _LEAVE_MARGIN):
+            self._on_host = False
             count("routing.chooser.cost_device")
             return True
+        self._on_host = True
         count("routing.chooser.cost_host")
         count("routing.device.bypassed")
         self._fuse_cwnd = 1      # re-enter fusion carefully next time

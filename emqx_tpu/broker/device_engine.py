@@ -584,7 +584,8 @@ class _Handle:
     sub has been finished or abandoned."""
 
     __slots__ = ("subs", "built", "dev_shared", "enc", "res", "np_res",
-                 "np_counts", "np_mov", "np_fov", "error", "refs", "t0",
+                 "np_counts", "np_mov", "np_fov", "np_cov", "error", "refs",
+                 "t0",
                  "plan", "cache_info",
                  "pcap", "cres", "delta", "dres", "dcres", "np_delta",
                  "trace", "sub_traces")
@@ -600,6 +601,8 @@ class _Handle:
                               # only from a trie window with a flagged lane
         self.np_fov = None    # fan-out stage overflow [W, B], read back
                               # only from a window with a flagged lane
+        self.np_cov = None    # a covering snapshot's expansion overflow
+                              # [W, B], read back on the same condition
         self.error = None
         self.refs = len(subs)
         self.t0 = None        # consumer-side window processing start
@@ -643,6 +646,12 @@ class DeviceRouteEngine:
         self.fanout_cap = fanout_cap
         self.slot_cap = slot_cap
         self.shape_cap = shape_cap
+        # the most candidates a covering snapshot's expansion holds for
+        # one topic (`CoverTables.cand_pad`); a build takes less where
+        # its largest segment lets it. A topic whose matched roots own
+        # more flags `cover_overflow` and host-routes
+        self.cover_cand_cap = min(4096,
+                                  _next_pow2(max(256, 4 * match_cap)))
 
         self.intern = I.InternTable()
         self._built: Optional[_Built] = None
@@ -712,6 +721,7 @@ class DeviceRouteEngine:
             compact_readback = _ENV_COMPACT
         self.compact_readback = bool(compact_readback)
         self._pay_ewma: dict[int, float] = {}   # Bp -> peak entry total
+        self._pay_mult: dict[int, int] = {}     # Bp -> the class held
 
         # delta overlay (ISSUE 4 tentpole): post-snapshot filters match
         # ON DEVICE via a small linear overlay table fused into the
@@ -1358,16 +1368,27 @@ class DeviceRouteEngine:
                 # whose full diversity overflows the shape cap into
                 # the trie
                 sub_ids = np.flatnonzero(owner < 0)
+                root_shapes = cover_mod.full_shape_count(
+                    rows[sub_ids], lens[sub_ids])
                 cover_shapes = L <= cover_mod.SHAPE_MAX_LEVELS \
-                    and cover_mod.full_shape_count(
-                        rows[sub_ids], lens[sub_ids]) <= self.shape_cap
-                cand_cap = min(4096,
-                               _next_pow2(max(256, 4 * self.match_cap)))
+                    and root_shapes <= self.shape_cap
+                # room for the largest segment (a root and what it
+                # owns) beside a root of its own in every other slot
+                # of the roots' match row. Every candidate lane costs
+                # a row gather and a sort key a topic: where a segment
+                # holds 50, a sub-batch of 1024 took a v5e 55.6 ms at
+                # the fixed 256 lanes, 34.5 at 128, 25.4 at 64 (PR 36's
+                # builder), and at 256 the chooser left the chip
+                # (PERF.md, PR 38). A topic under two roots that own
+                # much overflows and host-routes, counted
+                seg_max = 1 + int(np.bincount(owner[covered]).max())
+                slots = root_shapes if cover_shapes else self.match_cap
                 cover_np = cover_mod.build_cover_tables(
                     rows, lens, owner,
                     cover_mod.trie_order_keys(rows, lens),
                     fid_cap=filter_cap, out_width=self.match_cap,
-                    cand_cap=cand_cap)
+                    cand_cap=min(self.cover_cand_cap,
+                                 _next_pow2(seg_max + slots - 1)))
                 cover_state = _CoverState(
                     sub_ids, cover_np, L, len(covered), int(inc.sum()))
                 # pad the consume companions to filter_cap: cover-set
@@ -2165,7 +2186,12 @@ class DeviceRouteEngine:
         _PAYLOAD_MULTS * Bp ladder so the compile-class count stays
         bounded. A window that still outgrows its class falls back to
         the dense readback of the SAME dispatch (row_overflow), so an
-        undershoot costs bytes, never correctness."""
+        undershoot costs bytes, never correctness. A class is held
+        against a smaller one until that one would hold the EWMA with
+        a quarter to spare: an EWMA that sits on a step of the ladder
+        (`umbrella-cover.flood`: 3,900-4,700 entries against 2 x 4,096)
+        otherwise changes class every few windows, each class of every
+        (W, plan) pair met cold once and warmed behind the traffic."""
         if not self.compact_readback or self._built is None:
             return None
         dense = self._dense_msg_entries()
@@ -2177,9 +2203,14 @@ class DeviceRouteEngine:
             # no traffic measured at this class yet: start mid-ladder
             # (the first window's offsets seed the EWMA either way)
             return mults[min(1, len(mults) - 1)] * Bp
+        held = self._pay_mult.get(Bp)
         for m in mults:
-            if m * Bp >= 2.0 * ew:
+            if m * Bp >= 2.0 * ew and (
+                    held is None or m >= held
+                    or 0.75 * m * Bp >= 2.0 * ew):
+                self._pay_mult[Bp] = m
                 return m * Bp
+        self._pay_mult.pop(Bp, None)
         return None             # sustained heavy fan-out: dense wins
 
     def _note_payload(self, Bp: int, totals: np.ndarray) -> None:
@@ -2476,14 +2507,15 @@ class DeviceRouteEngine:
             self.ledger.note_window()
             self.ledger.pin(id(h), h)
         self.node.metrics.inc("routing.device.windows")
+        # topics the match stage matches, whatever matcher the snapshot
+        # has: every real lane or, under a dedup plan, its misses only
+        lanes = h.plan.n_miss if h.plan is not None \
+            else sum(len(msgs) for msgs in lives)
+        self.node.metrics.inc("routing.device.match_lanes", lanes)
         if b.backend != "shapes":
-            # matched by the trie NFA (ops/match.match_batch), over
-            # every real lane or, under a dedup plan, its misses only
+            # matched by the trie NFA (ops/match.match_batch)
             self.node.metrics.inc("routing.device.nfa_windows")
-            self.node.metrics.inc(
-                "routing.device.nfa_lanes",
-                h.plan.n_miss if h.plan is not None
-                else sum(len(msgs) for msgs in lives))
+            self.node.metrics.inc("routing.device.nfa_lanes", lanes)
         self.node.metrics.inc("routing.device.window_subs", W)
         b = self._built
         if b is not None and b.cover is not None:
@@ -2549,8 +2581,9 @@ class DeviceRouteEngine:
                     res.occur]
             if h.cache_info is not None and self._match_cache is not None:
                 out.append(res.match_counts)
-        if res.nfa_wide_steps is not None:
-            out.append(res.nfa_wide_steps)
+        for counted in (res.nfa_wide_steps, res.cover_candidates):
+            if counted is not None:
+                out.append(counted)
         return out
 
     def _start_readback(self, h) -> None:
@@ -2784,6 +2817,11 @@ class DeviceRouteEngine:
         res = h.res
         cp = h.cres
         self._count_nfa_steps(h)
+        if res.cover_candidates is not None:
+            # a covering snapshot's window: the candidates the
+            # expansion verified, a [W] plane
+            metrics.inc("routing.device.cover_candidates",
+                        int(np.asarray(res.cover_candidates).sum()))
         delta_bytes = self._materialize_delta(h)
         csr_probe_bytes = 0
         if cp is not None:
@@ -2874,10 +2912,12 @@ class DeviceRouteEngine:
     def _read_overflow_stages(h, overflow: np.ndarray) -> None:
         """Which stage flagged a window's flagged lanes: the NFA itself
         (frontier or match_cap; a trie window's), for
-        routing.device.match_overflow, and the fan-out stage, for
-        routing.device.fanout_overflow. One more small plane each, and
-        only when a lane was flagged at all: a window without overflow
-        reads nothing."""
+        routing.device.match_overflow, the fan-out stage, for
+        routing.device.fanout_overflow, and a covering snapshot's
+        expansion (candidates past `cand_cap`, or more verified matches
+        than the row holds), for routing.device.cover_overflow. One
+        more small plane each, and only when a lane was flagged at all:
+        a window without overflow reads nothing."""
         if not overflow.any():
             return
         if h.built.backend != "shapes" \
@@ -2885,6 +2925,8 @@ class DeviceRouteEngine:
             h.np_mov = np.asarray(h.res.match_overflow)
         if h.res.fanout_overflow is not None:
             h.np_fov = np.asarray(h.res.fanout_overflow)
+        if h.res.cover_overflow is not None:
+            h.np_cov = np.asarray(h.res.cover_overflow)
 
     def _count_nfa_steps(self, h) -> None:
         """How many level steps the NFA took for a trie window, and how
@@ -2907,14 +2949,17 @@ class DeviceRouteEngine:
     def _note_host_fallback(self, h, k: int, i: int) -> None:
         """Lane i of sub-batch k goes to the host trie (too deep, or a
         device capacity overflowed): count it, and separately the lanes
-        the NFA's own caps sent there and those whose narrow fan-out
+        the NFA's own caps sent there, those whose narrow fan-out
         rows alone passed `fanout_cap` (a filter wider than the cap is
-        not among them: it travels by reference)."""
+        not among them: it travels by reference) and those a covering
+        snapshot's expansion flagged."""
         self.node.metrics.inc("routing.device.host_fallback")
         if h.np_mov is not None and h.np_mov[k][i]:
             self.node.metrics.inc("routing.device.match_overflow")
         if h.np_fov is not None and h.np_fov[k][i]:
             self.node.metrics.inc("routing.device.fanout_overflow")
+        if h.np_cov is not None and h.np_cov[k][i]:
+            self.node.metrics.inc("routing.device.cover_overflow")
 
     def _corrupt_readback(self, h) -> None:
         """Apply the injected corrupt-shape fault: truncate the window
@@ -3725,6 +3770,15 @@ class DeviceRouteEngine:
             "nfa_steps": self.node.metrics.val("routing.device.nfa_steps"),
             "nfa_narrow_steps": self.node.metrics.val(
                 "routing.device.nfa_narrow_steps"),
+            # topics the match stage matched (any matcher), and of a
+            # covering snapshot's expansion the candidates it verified
+            # and the lanes its own caps sent to the host route
+            "match_lanes": self.node.metrics.val(
+                "routing.device.match_lanes"),
+            "cover_candidates": self.node.metrics.val(
+                "routing.device.cover_candidates"),
+            "cover_overflow": self.node.metrics.val(
+                "routing.device.cover_overflow"),
             # filters wider than `fanout_cap`, served from the device
             # window by reference, and the lanes fan-out still sent to
             # the host route

@@ -74,6 +74,14 @@ class RouteResult(NamedTuple):
     # passed `fanout_cap`): what routing.device.fanout_overflow counts.
     # None where a program does not report it (the mesh's sharded step)
     fanout_overflow: jax.Array = None
+    # a covering snapshot's programs only (`MatchResult`'s fields of the
+    # same names): the candidates `cover_expand` verified for the
+    # sub-batch ([W] from a window program; a window with a plan expands
+    # once, over its miss lanes, and reports it in row 0), and [B] the
+    # expansion's own part of `overflow`: what
+    # routing.device.cover_candidates / .cover_overflow count
+    cover_candidates: jax.Array = None
+    cover_overflow: jax.Array = None
     # `route_window`'s optional stages, None where the stage did not
     # run. The fid spaces of `matches` (built-snapshot fids) and
     # `delta.fids` (the engine's delta fids) are disjoint by
@@ -154,7 +162,8 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
         shared_sids=sids, shared_rows=sp.rows, shared_opts=sp.opts,
         overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur,
         match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps,
-        fanout_overflow=fr.overflow)
+        fanout_overflow=fr.overflow, cover_candidates=mr.cover_candidates,
+        cover_overflow=mr.cover_overflow)
 
 
 @functools.partial(
@@ -209,15 +218,19 @@ def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
     walk."""
     B = topics.shape[0]
     M = match_cap if trie.cover is None else trie.cover.out_pad.shape[0]
+    empty = MatchResult(
+        matches=jnp.full((B, M), -1, jnp.int32),
+        counts=jnp.zeros(B, jnp.int32),
+        overflow=jnp.zeros(B, bool), wide_steps=jnp.int32(0))
+    if trie.cover is not None:
+        empty = empty._replace(cover_candidates=jnp.int32(0),
+                               cover_overflow=jnp.zeros(B, bool))
     return jax.lax.cond(
         (lens > 0).any(),
         lambda: match_batch(trie, topics, lens, is_dollar,
                             frontier_cap=frontier_cap,
                             match_cap=match_cap),
-        lambda: MatchResult(
-            matches=jnp.full((B, M), -1, jnp.int32),
-            counts=jnp.zeros(B, jnp.int32),
-            overflow=jnp.zeros(B, bool), wide_steps=jnp.int32(0)))
+        lambda: empty)
 
 
 def _match_stage(tables, topics: jax.Array, lens: jax.Array,
@@ -315,7 +328,6 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
     program they called: traced afresh for every payload class, a trie
     snapshot's direct warm took 12.1–13.4 s for 9.5–10.1 on a v5e's
     host (my chip runs, PR 30)."""
-    trie = _is_trie(tables)
     nfa = dict(frontier_cap=frontier_cap, match_cap=match_cap)
     if plan is None:
         lanes = (topics, lens, is_dollar)
@@ -328,17 +340,25 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
                               plan.miss_dollar, **nfa)
             um = merge_match_results(plan.base_matches, plan.base_counts,
                                      plan.base_overflow, mr, plan.miss_pos)
-        # the one walk over the miss lanes, reported in row 0
-        wide = jnp.zeros(plan.inv.shape[0], jnp.int32).at[0].set(
-            mr.wide_steps) if trie else None
-        lanes = (plan.inv, wide)
+
+        def in_row_0(count):
+            """The one match over the miss lanes: what it counted (the
+            trie's wide steps, a cover's candidates), reported by the
+            window's first sub-batch."""
+            return None if count is None else jnp.zeros(
+                plan.inv.shape[0], jnp.int32).at[0].set(count)
+
+        lanes = (plan.inv, in_row_0(mr.wide_steps),
+                 in_row_0(mr.cover_candidates))
 
         def matched(lane):
-            inv_k, wide_k = lane
-            return MatchResult(matches=um.matches[inv_k],
-                               counts=um.counts[inv_k],
-                               overflow=um.overflow[inv_k],
-                               wide_steps=wide_k)
+            inv_k, wide_k, cand_k = lane
+            return MatchResult(
+                matches=um.matches[inv_k], counts=um.counts[inv_k],
+                overflow=um.overflow[inv_k], wide_steps=wide_k,
+                cover_candidates=cand_k,
+                cover_overflow=None if um.cover_overflow is None
+                else um.cover_overflow[inv_k])
 
     def step(cur, xs):
         lane, mh_k = xs

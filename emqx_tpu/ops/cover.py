@@ -424,16 +424,24 @@ def _verify_rows(vwords, vlens, sel, topics, lens, is_dollar):
     root-'$' exclusion, empty rows match nothing."""
     import jax.numpy as jnp
 
-    L = topics.shape[1]
-    Lv = vwords.shape[1]
-    Lc = min(L, Lv)
     safe = jnp.clip(sel, 0, vwords.shape[0] - 1)
     fl = jnp.where(sel >= 0, vlens[safe], 0)            # [B, C]
     # ONE row gather [B, C, Lv] + broadcast compares: per-level
     # vwords[safe, l] gathers serialize terribly on the CPU proxy (L
     # gather kernels over the same index plane), and this stage sits on
     # the serving critical path
-    vrow = vwords[safe]                                 # [B, C, Lv]
+    res = _verify(vwords[safe], fl, topics, lens, is_dollar)
+    return jnp.where(sel >= 0, res, True)
+
+
+def _verify(vrow, fl, topics, lens, is_dollar):
+    """`_verify_rows` on rows already in hand: vrow [B | 1, C, Lv] the
+    filters' levels, fl [B | 1, C] their lengths (0 = no filter)."""
+    import jax.numpy as jnp
+
+    L = topics.shape[1]
+    Lv = vrow.shape[2]
+    Lc = min(L, Lv)
     last = jnp.take_along_axis(
         vrow, jnp.clip(fl - 1, 0, Lv - 1)[:, :, None], axis=2)[:, :, 0]
     last_hash = (fl > 0) & (last == HASH)
@@ -451,8 +459,7 @@ def _verify_rows(vwords, vlens, sel, topics, lens, is_dollar):
                        lens[:, None] == fl)
     first = vrow[:, :, 0]
     dskip = is_dollar[:, None] & ((first == PLUS) | (first == HASH))
-    res = ok & len_ok & ~dskip & (fl > 0) & (lens > 0)[:, None]
-    return jnp.where(sel >= 0, res, True)
+    return ok & len_ok & ~dskip & (fl > 0) & (lens > 0)[:, None]
 
 
 def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
@@ -465,7 +472,16 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     per-filter order key so the output row is bit-identical to the
     covering-off twin's (values AND order). Overflow = base overflow
     | candidate-capacity overflow | true count past the output width
-    (the same condition the off twin flags)."""
+    (the same condition the off twin flags); the last two are reported
+    apart as `cover_overflow`, beside the candidates verified. Traced
+    under scope `cover` (inside the caller's `match`)."""
+    import jax
+
+    with jax.named_scope("cover"):
+        return _cover_expand(ct, mr, topics, lens, is_dollar)
+
+
+def _cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     import jax.numpy as jnp
 
     from emqx_tpu.ops.fanout import _segment_expand
@@ -491,10 +507,9 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     live = ct.app_root >= 0
     hit = ((mr.matches[:, :, None] == ct.app_root[None, None, :])
            & (mr.matches >= 0)[:, :, None]).any(axis=1)     # [B, A]
-    app_sel = jnp.broadcast_to(
-        jnp.arange(A, dtype=jnp.int32)[None, :], hit.shape)
-    app_ok = _verify_rows(ct.app_words, ct.app_lens, app_sel, topics,
-                          lens, is_dollar)
+    # every lane verifies the same A rows: broadcast, no gather
+    app_ok = _verify(ct.app_words[None], ct.app_lens[None], topics, lens,
+                     is_dollar)
     app_valid = hit & app_ok & live[None, :]
 
     cand_fid = jnp.concatenate(
@@ -528,9 +543,13 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
         s_ok = jnp.take_along_axis(cand_valid, order, axis=1)[:, :M]
     out = jnp.where(s_ok, s_fid, -1)
     count = cand_valid.sum(axis=1, dtype=jnp.int32)
-    overflow = mr.overflow | cand_oflow | (count > M)
-    return MatchResult(matches=out, counts=jnp.minimum(count, M),
-                       overflow=overflow, wide_steps=mr.wide_steps)
+    own_oflow = cand_oflow | (count > M)
+    return MatchResult(
+        matches=out, counts=jnp.minimum(count, M),
+        overflow=mr.overflow | own_oflow, wide_steps=mr.wide_steps,
+        cover_candidates=(fids >= 0).sum(dtype=jnp.int32)
+        + (hit & live[None, :]).sum(dtype=jnp.int32),
+        cover_overflow=own_oflow)
 
 
 # ---- host-side cover lookup (append path) --------------------------------

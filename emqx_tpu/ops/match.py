@@ -55,6 +55,14 @@ class MatchResult(NamedTuple):
     # `frontier_cap` (of topics.shape[1] + 1). None where no NFA walked
     # (the shape-hash and overlay matchers, cached rows)
     wide_steps: jax.Array = None
+    # a covering snapshot only (`ops/cover.cover_expand`; None from any
+    # other matcher): scalar int32, the candidates the expansion
+    # verified for this batch (each matched root's own entry, its owned
+    # filters and the append rows riding a lane; padding excluded), and
+    # [B] bool, the expansion's own part of `overflow` (a lane's
+    # candidates passed `cand_cap`, or its verified matches the row)
+    cover_candidates: jax.Array = None
+    cover_overflow: jax.Array = None
 
 
 def edge_lookup(tables: TrieTables, parent: jax.Array, word: jax.Array) -> jax.Array:
@@ -193,11 +201,17 @@ def merge_match_results(base_matches: jax.Array, base_counts: jax.Array,
     match stage is a pure function of the
     immutable table snapshot, so a cached row and a fresh row for the same
     (snapshot, topic) are bit-identical by construction — merging is a
-    plain last-writer scatter, no reconciliation needed."""
+    plain last-writer scatter, no reconciliation needed. A cached row
+    stores one flag, so `cover_overflow` (a covering snapshot's) is the
+    miss lanes' alone: a hit whose stored flag was the expansion's
+    still goes to the host, uncounted by stage."""
     return MatchResult(
         matches=base_matches.at[miss_pos].set(mr.matches, mode="drop"),
         counts=base_counts.at[miss_pos].set(mr.counts, mode="drop"),
-        overflow=base_overflow.at[miss_pos].set(mr.overflow, mode="drop"))
+        overflow=base_overflow.at[miss_pos].set(mr.overflow, mode="drop"),
+        cover_overflow=None if mr.cover_overflow is None else
+        jnp.zeros_like(base_overflow).at[miss_pos].set(
+            mr.cover_overflow, mode="drop"))
 
 
 def encode_topics_str(intern, topics: list, max_levels: int):

@@ -12,11 +12,13 @@ file of their own, so that another worker takes them.
 
 import pytest
 
+from benchmark.tests import test_fleet_bcast as _fleet
 from benchmark.tests import test_trace_readers as _readers
 from benchmark.tests.test_fleet_bcast import *      # noqa: F401,F403
 from benchmark.tests.test_mixed_zipf import *       # noqa: F401,F403
 from benchmark.tests.test_pieces import *           # noqa: F401,F403
 from benchmark.tests.test_trace_readers import *    # noqa: F401,F403
+from benchmark.tests.test_umbrella_cover import *   # noqa: F401,F403
 
 
 @pytest.mark.xfail(strict=True, reason=(
@@ -27,3 +29,14 @@ from benchmark.tests.test_trace_readers import *    # noqa: F401,F403
 def test_every_new_metric_file_reads_the_recorded_trace(  # noqa: F811
         recorded):                                  # noqa: F405
     _readers.test_every_new_metric_file_reads_the_recorded_trace(recorded)
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "benchmark/tests/test_fleet_bcast.py pins the manifest at PR 32's "
+    "four cells and four configurations (its last line); PR 38 appends "
+    "`umbrella-cover.flood` and may not edit that file: the pin is a "
+    "`benchmark` PR's to drop. Everything else the case holds is held "
+    "by test_umbrella_cover.py::"
+    "test_fleet_bcast_still_reports_its_33_metrics (CHANGES.md, PR 38)"))
+def test_fleet_bcast_reports_its_33_metrics():      # noqa: F811
+    _fleet.test_fleet_bcast_reports_its_33_metrics()

@@ -287,6 +287,30 @@ class TestByteAccounting:
         text = collect(comp)
         assert "emqx_pipeline_readback_bytes_compact" in text
 
+    def test_a_payload_class_is_held_on_a_step_of_the_ladder(self):
+        """An EWMA that swings across a step (2 x 4,096 = the 8 x 1024
+        class) does not change class every few windows: up at once,
+        down only where the smaller class has a quarter to spare."""
+        node = Node()
+        b = node.broker
+        b.subscribe(b.register(Sink(), "c"), "t/+", {"qos": 0})
+        eng = node.device_engine
+        eng.rebuild()
+        assert 128 < eng._dense_msg_entries()
+        picks = []
+        for ew in (3900.0, 4700.0, 3900.0, 3200.0, 3000.0, 3900.0,
+                   20000.0, 4700.0, 2000.0):
+            eng._pay_ewma[1024] = ew
+            picks.append(eng._choose_payload_cap(1024) // 1024)
+        assert picks == [8, 32, 32, 32, 8, 8, 128, 32, 8]
+        # each batch class holds its own; past the ladder it is dense
+        eng._pay_ewma[64] = 200.0
+        assert eng._choose_payload_cap(64) == 8 * 64
+        eng._pay_ewma[1024] = 1e6
+        assert eng._choose_payload_cap(1024) is None
+        eng._pay_ewma[1024] = 3900.0
+        assert eng._choose_payload_cap(1024) == 8 * 1024
+
     def test_disabled_knob(self):
         node = Node(DENSE_CONF)
         b = node.broker

@@ -456,6 +456,30 @@ class TestAdaptiveDeviceChoice:
         b._observe_device_cost(10.06, 10.16, 1, False)
         assert b._dev_batch_s == pytest.approx(0.8 * 0.06 + 0.2 * 0.10)
 
+    def test_a_narrow_loss_does_not_leave_the_chip(self):
+        """Two costs within `_LEAVE_MARGIN` of each other do not pay
+        for a change of path: the chooser leaves the chip past the
+        margin and comes back at parity (`umbrella-cover.flood`: a
+        fused sub-batch every 82 ms against 110-150 ms of host route,
+        and a run's rate by how often the two estimates crossed)."""
+        from emqx_tpu.broker import batcher as BM
+        b, node = self._batcher()
+        b._host_msg_s = 0.0001
+        b._dev_batch_s = 0.1024 * (BM._LEAVE_MARGIN - 0.05)
+        assert b._device_worth_it(1024)             # inside the band
+        assert b.chooser_margin == pytest.approx(BM._LEAVE_MARGIN - 0.05)
+        assert node.metrics.val("routing.device.bypassed") == 0
+        b._dev_batch_s = 0.1024 * (BM._LEAVE_MARGIN + 0.05)
+        assert not b._device_worth_it(1024)         # past it
+        assert b._fuse_cwnd == 1
+        b._dev_batch_s = 0.1024 * 1.1
+        assert not b._device_worth_it(1024)         # off: back at parity
+        b._dev_batch_s = 0.1024 * 0.95
+        assert b._device_worth_it(1024)
+        b._dev_batch_s = 0.1024 * 1.1
+        assert b._device_worth_it(1024)             # on: the band again
+        assert node.metrics.val("routing.device.bypassed") == 2
+
     def test_ewma_pessimizes_fast_optimizes_slow(self):
         """Cost estimates pessimize fast but not on ONE bad sample: a
         first >3x outlier folds in smoothly and arms the streak; a

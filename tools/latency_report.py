@@ -21,10 +21,10 @@ artifact from {cpu_latency0, e2e_host, e2e_device} — a row that
 ran but lost its latency section fails; a phase that never ran (e.g.
 BENCH_E2E=0) is not invented. ``--require a,b`` pins an explicit list
 instead (a named row that is absent then also fails: the gate is "this
-run MUST carry these measured tails"). The microbench rows
-``cpu_sharded`` and ``cpu_cover`` are also requirable: they carry
-matches/s + speedup headlines instead of a latency section, so for them
-the gate is row presence and the report prints their scalar summary.
+run MUST carry these measured tails"). The microbench row
+``cpu_sharded`` is also requirable: it carries matches/s + speedup
+headlines instead of a latency section, so for it the gate is row
+presence and the report prints its scalar summary.
 """
 
 from __future__ import annotations
@@ -37,7 +37,7 @@ DEFAULT_ROWS = ("cpu_latency0", "e2e_host", "e2e_device")
 # microbench phase rows --require can pin: they carry their own metric
 # (matches/s, speedup, reduction) instead of a latency section, so the
 # gate checks PRESENCE and renders the headline numbers
-MICRO_ROWS = ("cpu_sharded", "cpu_cover")
+MICRO_ROWS = ("cpu_sharded",)
 
 
 def _rows_of(doc: dict) -> dict:
@@ -172,7 +172,7 @@ def main(argv=None) -> int:
     for name in wanted:
         row = rows.get(name)
         if name in MICRO_ROWS:
-            # microbench rows (sharded/cover) carry their own metric,
+            # a microbench row (sharded) carries its own metric,
             # not a latency section: the gate is row PRESENCE
             if row is None:
                 missing.append(name)
