@@ -2516,7 +2516,10 @@ class DeviceRouteEngine:
             # matched by the trie NFA (ops/match.match_batch)
             self.node.metrics.inc("routing.device.nfa_windows")
             self.node.metrics.inc("routing.device.nfa_lanes", lanes)
+        # sub-batches held against the class's W: 1 - subs / slots is
+        # the share of scan steps the window program skipped as padding
         self.node.metrics.inc("routing.device.window_subs", W)
+        self.node.metrics.inc("routing.device.window_slots", Wp)
         b = self._built
         if b is not None and b.cover is not None:
             # windows matched against the covering set (expansion fused
@@ -2933,8 +2936,8 @@ class DeviceRouteEngine:
         many of them at a narrow width (ops/match.NARROW_WIDTHS): the
         program's [W] plane holds the steps that ran at `frontier_cap`.
         A plain window walks once for every sub-batch that holds a
-        topic (`_nfa_unless_padding`), a match-cache plan once over its
-        miss lanes."""
+        topic (`route_window` skips the step of any other), a
+        match-cache plan once over its miss lanes."""
         wide = h.res.nfa_wide_steps
         if wide is None:        # a shape-hash program has no such plane
             return
@@ -3779,6 +3782,12 @@ class DeviceRouteEngine:
                 "routing.device.cover_candidates"),
             "cover_overflow": self.node.metrics.val(
                 "routing.device.cover_overflow"),
+            # sub-batches the device windows held, and the scan steps
+            # of their classes (the rest were padding, skipped)
+            "window_subs": self.node.metrics.val(
+                "routing.device.window_subs"),
+            "window_slots": self.node.metrics.val(
+                "routing.device.window_slots"),
             # filters wider than `fanout_cap`, served from the device
             # window by reference, and the lanes fan-out still sent to
             # the host route

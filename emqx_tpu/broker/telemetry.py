@@ -405,6 +405,8 @@ class PipelineTelemetry:
                       "routing.device.cold_cached_class",
                       "routing.device.cold_compact_class",
                       "routing.device.cached_windows",
+                      "routing.device.window_subs",
+                      "routing.device.window_slots",
                       "routing.device.compact_overflow",
                       "routing.device.host_fallback",
                       "routing.device.dispatch_failed",

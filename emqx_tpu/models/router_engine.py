@@ -67,7 +67,7 @@ class RouteResult(NamedTuple):
     match_overflow: jax.Array = None
     # trie programs only: level steps of the sub-batch's NFA walk that
     # ran at `frontier_cap` (`MatchResult.wide_steps`; 0 for a padding
-    # sub-batch, whose walk is skipped). [W] from a window program; a
+    # sub-batch, whose step is skipped). [W] from a window program; a
     # window with a plan walks once and reports it in row 0
     nfa_wide_steps: jax.Array = None
     # [B] the fan-out stage's own part of `overflow` (the expanded rows
@@ -202,50 +202,49 @@ def _is_trie(tables) -> bool:
     return isinstance(tables, RouterTables)
 
 
-def _nfa_unless_padding(trie: TrieTables, topics: jax.Array,
-                        lens: jax.Array, is_dollar: jax.Array, *,
-                        frontier_cap: int, match_cap: int) -> MatchResult:
-    """`match_batch` for one sub-batch of a fused window, skipped where
-    the sub-batch is the window class's padding. A window is padded to
-    its class's W, and the NFA steps through every level whether a
-    topic is there or not (13.5 ms at 1024 lanes on a v5e, at the
-    narrowest of `ops/match.NARROW_WIDTHS`, which an empty frontier
-    takes; 82.7 ms at `frontier_cap`; my chip runs, PR 29): a sub-batch
-    without a topic matches nothing, so it is handed the empty result
-    `match_batch` returns for it (no frontier, nothing emitted, no wide
-    step, and for a covering snapshot nothing for `cover_expand` to
-    re-expand: its row is as wide as the cover's output) without the
-    walk."""
-    B = topics.shape[0]
-    M = match_cap if trie.cover is None else trie.cover.out_pad.shape[0]
-    empty = MatchResult(
-        matches=jnp.full((B, M), -1, jnp.int32),
-        counts=jnp.zeros(B, jnp.int32),
-        overflow=jnp.zeros(B, bool), wide_steps=jnp.int32(0))
-    if trie.cover is not None:
-        empty = empty._replace(cover_candidates=jnp.int32(0),
-                               cover_overflow=jnp.zeros(B, bool))
-    return jax.lax.cond(
-        (lens > 0).any(),
-        lambda: match_batch(trie, topics, lens, is_dollar,
-                            frontier_cap=frontier_cap,
-                            match_cap=match_cap),
-        lambda: empty)
+def _match_holes(tables) -> bool:
+    """Whether the match rows a window program hands its compact stage
+    can hold interior `-1` holes, which `ops.compact.compact_result`
+    then closes: only the shape-hash matcher's own rows do (one slot a
+    shape, empty where the shape's bucket held no match). The trie NFA
+    emits a packed prefix, and a covering snapshot's rows come out of
+    `ops.cover.cover_expand` packed whatever matched the roots (sorted,
+    valid keys first); under a plan a cached base row is that same row
+    or the CSR's prefix. Static like `_is_trie`: the tables' pytree
+    structure."""
+    return not _is_trie(tables) and tables.shapes.cover is None
 
 
 def _match_stage(tables, topics: jax.Array, lens: jax.Array,
                  is_dollar: jax.Array, *, frontier_cap: int,
-                 match_cap: int, sub_batch: bool = False) -> MatchResult:
+                 match_cap: int) -> MatchResult:
     """The backend's matcher over [B] lanes: the trie NFA for
-    `RouterTables` (the caps are its own; `sub_batch` says the lanes are
-    one sub-batch of a padded window, which may hold no topic), one
-    bucket gather per shape for `ShapeRouterTables` (which takes no
-    cap)."""
+    `RouterTables` (the caps are its own), one bucket gather per shape
+    for `ShapeRouterTables` (which takes no cap)."""
     if _is_trie(tables):
-        nfa = _nfa_unless_padding if sub_batch else match_batch
-        return nfa(tables.trie, topics, lens, is_dollar,
-                   frontier_cap=frontier_cap, match_cap=match_cap)
+        return match_batch(tables.trie, topics, lens, is_dollar,
+                           frontier_cap=frontier_cap, match_cap=match_cap)
     return shape_match(tables.shapes, topics, lens, is_dollar)
+
+
+# the planes of a `RouteResult` row that read -1 where nothing matched
+# (ids and session rows); every other plane reads 0 / False there
+_EMPTY_IS_MINUS_1 = frozenset(
+    ("matches", "rows", "shared_sids", "shared_rows"))
+
+
+def _empty_step(like: RouteResult, cursors: jax.Array) -> RouteResult:
+    """The row a scan step returns for a sub-batch in which no lane
+    matches anything: what `post_match` computes from an all-empty
+    `MatchResult`, plane for plane (ids and rows -1, counts and flags
+    0 / False, the cursors as they came, no occurrence, no wide step,
+    no candidate), without computing it. `like`: the full step's
+    result as shapes (`jax.eval_shape`)."""
+    r = RouteResult(*[
+        s if s is None else jnp.full(
+            s.shape, -1 if name in _EMPTY_IS_MINUS_1 else 0, s.dtype)
+        for name, s in zip(RouteResult._fields, like)])
+    return r._replace(new_cursors=cursors)
 
 
 def _compact_stage(r: RouteResult, dp, payload_cap: int,
@@ -257,9 +256,10 @@ def _compact_stage(r: RouteResult, dp, payload_cap: int,
     `row_overflow` fires (payload class too small for this window), so
     the dense fallback needs no re-dispatch.
 
-    match_holes=True for the shape-hash backend (matches carry
-    interior holes at unmatched shape slots), False for the trie NFA
-    (emissions are densely packed already, the hole-closing stage
+    match_holes (`_match_holes`): True for a shape-hash snapshot
+    without cover state (matches carry interior holes at unmatched
+    shape slots), False for the trie NFA and for a covering snapshot
+    (their rows are packed prefixes already, the hole-closing stage
     compiles away). The delta family reuses `compact_result` with a
     width-1 all-empty shared family (cs == 0 in every row), so
     `csr_slices` decodes both with one code path; delta matches are
@@ -332,14 +332,27 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
     if plan is None:
         lanes = (topics, lens, is_dollar)
 
+        def occupied():
+            """[W] a sub-batch that holds a topic (a padding lane's
+            length is 0)."""
+            return (lens > 0).any(axis=1)
+
         def matched(lane):
-            return _match_stage(tables, *lane, sub_batch=True, **nfa)
+            return _match_stage(tables, *lane, **nfa)
     else:
         with jax.named_scope("match"):
             mr = _match_stage(tables, plan.miss_topics, plan.miss_lens,
                               plan.miss_dollar, **nfa)
             um = merge_match_results(plan.base_matches, plan.base_counts,
                                      plan.base_overflow, mr, plan.miss_pos)
+
+        def occupied():
+            """[W] a sub-batch with a lane on a unique row that holds
+            a match or a flag (`cover_overflow` is part of `overflow`).
+            Every lane of a padding sub-batch sits on the sentinel row,
+            which holds neither: nothing matched it, no hit filled
+            it."""
+            return ((um.counts > 0) | um.overflow)[plan.inv].any(axis=1)
 
         def in_row_0(count):
             """The one match over the miss lanes: what it counted (the
@@ -360,8 +373,7 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
                 cover_overflow=None if um.cover_overflow is None
                 else um.cover_overflow[inv_k])
 
-    def step(cur, xs):
-        lane, mh_k = xs
+    def routed(cur, lane, mh_k):
         with jax.named_scope("match"):
             mr_k = matched(lane)
         r = post_match(tables.subs, mr_k, cur, mh_k, strategy,
@@ -369,8 +381,26 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
                        wide_by_ref=True)
         return r.new_cursors, r
 
+    def skipped(cur, lane, mh_k):
+        r = _empty_step(jax.eval_shape(routed, cur, lane, mh_k)[1], cur)
+        if plan is not None:
+            # the one match's counts ride in row 0 whatever it holds
+            r = r._replace(nfa_wide_steps=lane[1], cover_candidates=lane[2])
+        return cur, r
+
+    # a window is padded to its class's W and every stage of the step is
+    # fixed-shape, so a sub-batch of padding would cost what a full one
+    # does: the step is skipped where the sub-batch is not occupied. A
+    # class of W = 1 has no padding sub-batch and carries no predicate
+    def step(cur, xs):
+        lane, mh_k, occupied_k = xs
+        if occupied_k is None:
+            return routed(cur, lane, mh_k)
+        return jax.lax.cond(occupied_k, routed, skipped, cur, lane, mh_k)
+
     with jax.named_scope("scan"):
-        _, r = jax.lax.scan(step, cursors, (lanes, msg_hash))
+        _, r = jax.lax.scan(step, cursors, (
+            lanes, msg_hash, None if msg_hash.shape[0] == 1 else occupied()))
     return r
 
 
@@ -406,8 +436,12 @@ def route_window(tables, cursors: jax.Array, topics: jax.Array,
     order.
 
     Either backend: the tables' type picks the matcher (`_is_trie`; the
-    trie NFA takes `frontier_cap` / `match_cap` and is skipped for a
-    sub-batch of padding). Up to three optional stages, each chosen by
+    trie NFA takes `frontier_cap` / `match_cap`). A window costs what it
+    holds: where W > 1 the scan step of a sub-batch in which nothing
+    can match (the class's padding: no topic, or under a plan no lane
+    on a row with a match or a flag) is skipped under one `lax.cond`
+    and returns the row the full step computes there (`_empty_step`).
+    Up to three optional stages, each chosen by
     what the caller passes; `None`-ness of a pytree argument and the
     value of a static are part of the jit key like the tables' type, so
     a class compiles only its own stages:
@@ -434,7 +468,7 @@ def route_window(tables, cursors: jax.Array, topics: jax.Array,
     cp = dcp = None
     if payload_cap is not None:
         cp, dcp = _compact_stage(r, dp, payload_cap, d_payload_cap,
-                                 match_holes=not _is_trie(tables))
+                                 match_holes=_match_holes(tables))
     return r._replace(delta=dp, compact=cp, d_compact=dcp)
 
 

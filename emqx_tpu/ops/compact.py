@@ -107,13 +107,18 @@ def compact_result(matches: jax.Array, rows: jax.Array, opts: jax.Array,
     B=1024 — ~20x the route step it compacts; the gather form is
     ~0.7ms and vectorizes on every backend.
 
-    Every plane's valid entries are a PREFIX except `matches` on the
-    shape-hash backend (one filter per shape SLOT → interior holes),
-    closed with a rank→position searchsorted over the validity cumsum —
-    valid ids keep their match order, which is the order fan-out
-    segments concatenate in. The trie backend emits prefix-compacted
-    matches already: pass `match_holes=False` (static) and the whole
-    hole-closing stage compiles away.
+    Every plane's valid entries are a PREFIX except `matches` from the
+    shape-hash matcher's own rows (one filter per shape SLOT → interior
+    holes), closed with a rank→position searchsorted over the validity
+    cumsum — valid ids keep their match order, which is the order
+    fan-out segments concatenate in. That stage is W·B·M queries into
+    an array as long (19 gather rounds at 8 x 1,024 x 64), so it is run
+    only where holes can be: the trie NFA emits prefix-compacted
+    matches, and so does a covering snapshot whatever matched its roots
+    (`ops.cover.cover_expand` sorts valid keys first; a cached row is
+    that row or the CSR's prefix): for both pass `match_holes=False`
+    (static) and the whole hole-closing stage compiles away
+    (`models/router_engine._match_holes`).
     """
     W, B, M = matches.shape
     D = rows.shape[-1]
@@ -142,7 +147,7 @@ def compact_result(matches: jax.Array, rows: jax.Array, opts: jax.Array,
         mcomp = jnp.where(
             jnp.arange(M, dtype=jnp.int32) < cm[..., None], mcomp, -1)
     else:
-        mcomp = matches      # trie NFA output: already prefix-compacted
+        mcomp = matches      # trie NFA / cover_expand: a packed prefix
 
     opts32 = opts.astype(jnp.int32)
     sopts32 = shared_opts.astype(jnp.int32)

@@ -470,7 +470,12 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     state): CSR-gather each matched cover's candidates, verify each
     against the topic, merge the append region, and sort by the
     per-filter order key so the output row is bit-identical to the
-    covering-off twin's (values AND order). Overflow = base overflow
+    covering-off twin's (values AND order). The row is PREFIX-PACKED
+    by construction, valid ids first and `-1` after, with no interior
+    hole whatever matcher found the roots (the sort puts every invalid
+    key last): the window's compact stage relies on it and closes no
+    holes over a covering snapshot
+    (`models/router_engine._match_holes`). Overflow = base overflow
     | candidate-capacity overflow | true count past the output width
     (the same condition the off twin flags); the last two are reported
     apart as `cover_overflow`, beside the candidates verified. Traced

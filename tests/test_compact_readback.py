@@ -215,6 +215,69 @@ class TestCsrDecode:
         _finish_all(dense, hd)
 
 
+class TestHoleClosingOnlyWhereHolesAre:
+    """`compact_result(match_holes=False)` is the same CSR wherever the
+    match rows are packed prefixes (the trie NFA's, a covering
+    snapshot's: `models/router_engine._match_holes`), and not where a
+    matcher leaves holes."""
+
+    @staticmethod
+    def _planes(seed, holes):
+        rng = np.random.RandomState(seed)
+        W, B, M, D, K = 3, 16, 64, 24, 6
+        cm = rng.randint(0, 5, size=(W, B))
+        matches = np.full((W, B, M), -1, np.int32)
+        for w in range(W):
+            for b in range(B):
+                at = rng.choice(M, cm[w, b], replace=False) if holes \
+                    else np.arange(cm[w, b])
+                matches[w, b, np.sort(at)] = rng.randint(
+                    0, 1000, size=cm[w, b])
+        cf = rng.randint(0, D + 1, size=(W, B)).astype(np.int32)
+        lane = np.arange(D)[None, None, :] < cf[..., None]
+        rows = np.where(lane, rng.randint(0, 99, (W, B, D)), -1).astype(
+            np.int32)
+        opts = np.where(lane, rng.randint(0, 4, (W, B, D)), 0).astype(
+            np.int8)
+        cs = rng.randint(0, 3, size=(W, B))
+        slot = np.arange(K)[None, None, :] < cs[..., None]
+        sids = np.where(slot, rng.randint(0, 9, (W, B, K)), -1).astype(
+            np.int32)
+        srows = np.where(slot, rng.randint(0, 99, (W, B, K)), -1).astype(
+            np.int32)
+        sopts = np.where(slot, 1, 0).astype(np.int8)
+        return matches, rows, opts, cf, sids, srows, sopts
+
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_packed_rows_compact_alike_either_way(self, seed):
+        from emqx_tpu.ops.compact import compact_result
+        planes = self._planes(seed, holes=False)
+        a = compact_result(*planes, payload_cap=2048, match_holes=True)
+        b = compact_result(*planes, payload_cap=2048, match_holes=False)
+        for name, x, y in zip(a._fields, a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=name)
+        assert np.asarray(a.counts3)[..., 0].max() >= 3
+        assert not np.asarray(a.row_overflow).any()
+
+    def test_rows_with_holes_need_the_closing(self):
+        """The control: on the shape-hash matcher's own rows the two
+        differ, and only the closing one keeps every valid id."""
+        from emqx_tpu.ops.compact import compact_result, csr_slices
+        planes = self._planes(4, holes=True)
+        a = compact_result(*planes, payload_cap=2048, match_holes=True)
+        b = compact_result(*planes, payload_cap=2048, match_holes=False)
+        assert (np.asarray(a.payload) != np.asarray(b.payload)).any()
+        off, c3, pay = (np.asarray(x) for x in (a.offsets, a.counts3,
+                                                a.payload))
+        for w in range(planes[0].shape[0]):
+            for i in range(planes[0].shape[1]):
+                row = planes[0][w, i]
+                np.testing.assert_array_equal(
+                    csr_slices(off[w], c3[w], pay[w], i)[0],
+                    row[row >= 0])
+
+
 class TestCachePopulationFromCsr:
     def test_rows_equivalent_to_dense_population(self):
         """A cache row built from the CSR view carries the same valid

@@ -470,6 +470,92 @@ class TestAppendAndCache:
 
 # ---------------- knob & surfaces ----------------
 
+def _is_packed(rows) -> tuple:
+    """([R] whether each row's valid ids are a prefix, no `-1` before a
+    valid id; [R] how many it holds)."""
+    rows = np.asarray(rows).reshape(-1, np.asarray(rows).shape[-1])
+    n = (rows >= 0).sum(axis=1)
+    return ((rows >= 0) == (np.arange(rows.shape[1])[None, :]
+                            < n[:, None])).all(axis=1), n
+
+
+def _packed(rows, counts) -> None:
+    ok, n = _is_packed(rows)
+    assert ok.all() and (n == np.asarray(counts).reshape(-1)).all()
+
+
+class TestPrefixPackedRows:
+    """A covering snapshot's match rows carry no interior hole, whatever
+    matched the roots and wherever a row comes from, which is why the
+    window's compact stage closes none over such a snapshot
+    (`models/router_engine._match_holes`, ISSUE 39). The three origins a
+    row has on the served path: fresh from `cover_expand`, a cached row
+    filled from the CSR readback, a cached row filled from a dense
+    one."""
+
+    # the shape-hash probe leaves its holes among the ROOTS' slots:
+    # `top/a/b` matches root shapes 0 (`top/#`) and, two slots on,
+    # nothing in between; after the expansion the row must be packed
+    TOPICS = TRAFFIC + ["top/a/b", "top/q/c", "top/x1", "d3/m3/t3"]
+
+    def _window(self, compact: bool, shape_cap, rounds: int):
+        on, _off = _mk_twin_nodes(
+            POPULATIONS["mixed"],
+            conf={"compact_readback": compact, "topic_dedup": rounds > 1},
+            shape_cap=shape_cap)
+        node = on[0]
+        eng = node.device_engine
+        # 70 lanes: past the smallest batch class, so the plan's
+        # analysis runs and the readback seeds the cache
+        msgs = [mkmsg(self.TOPICS[i % len(self.TOPICS)])
+                for i in range(70)]
+        for _ in range(rounds):
+            h = eng.prepare(msgs, gate_cold=False)
+            eng.dispatch(h)
+            res = h.res
+            eng.materialize(h)
+            eng.finish(h)
+        return node, eng, h, res
+
+    @pytest.mark.parametrize("shape_cap", [SHAPE_CAPS["mixed"], 0],
+                             ids=["roots_by_shapes", "roots_by_trie"])
+    def test_fresh_from_the_expansion(self, shape_cap):
+        node, eng, h, res = self._window(True, shape_cap, 1)
+        assert eng.stats()["cover"]["covered"] > 0
+        assert eng.stats()["backend"] == ("shapes" if shape_cap else "trie")
+        assert h.plan is None
+        counts = np.asarray(res.match_counts)
+        assert counts.max() >= 2
+        _packed(res.matches, counts)
+        # and the roots' own probe does leave holes on this traffic,
+        # where the shape-hash table matched them
+        if shape_cap:
+            from emqx_tpu.ops.shapes import shape_match
+            enc, lens, dol = h.enc
+            st = eng._tables.shapes._replace(cover=None)
+            roots = shape_match(st, enc[0], lens[0], dol[0]).matches
+            assert not _is_packed(roots)[0].all()
+
+    @pytest.mark.parametrize("compact", [True, False],
+                             ids=["filled_from_the_csr",
+                                  "filled_from_a_dense_readback"])
+    def test_a_cached_row_and_the_window_it_serves(self, compact):
+        node, eng, h, res = self._window(compact, SHAPE_CAPS["mixed"], 2)
+        assert node.metrics.val(
+            "pipeline.readback.windows.compact" if compact
+            else "pipeline.readback.windows.dense") == 2
+        cache = eng._match_cache
+        with cache._lock:
+            rows = list(cache._rows.values())
+        assert len(rows) >= 8   # topics of unknown words share a key
+        assert max(r[1] for r in rows) >= 2
+        _packed(np.stack([r[0] for r in rows]), [r[1] for r in rows])
+        # the second window was served under a plan from those rows:
+        # what it hands the compact stage is packed too
+        assert h.plan is not None and h.plan.n_hit > 0
+        _packed(res.matches, res.match_counts)
+
+
 class TestKnobAndSurfaces:
     def test_config_beats_env_beats_default(self, monkeypatch):
         assert DE.resolve_subscription_covering() is True
