@@ -12,6 +12,7 @@ window).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 import os
 import socket
@@ -29,6 +30,7 @@ from emqx_tpu.mqtt.frame import (FrameError, FrameParser, PublishBurst,
 log = logging.getLogger("emqx_tpu.connection")
 
 READ_CHUNK = 65536
+_NOT_OPEN = contextlib.nullcontext()    # this read has no control span yet
 
 
 def resolve_columnar_ingress(configured=None) -> bool:
@@ -124,11 +126,18 @@ class Connection:
                 serialize(p, self.channel.proto_ver) for p in pkts))
 
     def _send_frames(self, data: bytes) -> None:
-        """Frames serialized already (`Channel._send_shared`)."""
+        """Frames serialized already (`Channel._send_shared`). The one
+        place this connection writes, so the one clocked: asyncio tries
+        the `send()` inline (`pipeline.egress.write_us` / `.writes`)."""
         if self.writer.is_closing():
             return
-        self.node.metrics.inc("bytes.sent", len(data))
+        m = self.node.metrics
+        m.inc("bytes.sent", len(data))
+        t0 = time.perf_counter_ns()
         self.writer.write(data)
+        m.inc("pipeline.egress.write_us",
+              (time.perf_counter_ns() - t0 + 500) // 1000)
+        m.inc("pipeline.egress.writes")
 
     def _request_close(self, reason: str) -> None:
         if self._closing is None:
@@ -216,6 +225,7 @@ class Connection:
                     break
                 n_rows = 0
                 n_pub = 0
+                n_ctl = 0
                 for it in items:
                     if type(it) is PublishBurst:
                         n_rows += len(it)
@@ -224,40 +234,60 @@ class Connection:
                         n_rows += 1
                         if type(it) is P.Publish:
                             n_pub += 1
+                        else:
+                            n_ctl += 1
                 if columnar and items:
                     m.inc("pipeline.ingress.bytes", len(data))
+                    m.inc("pipeline.ingress.control_packets", n_ctl)
                 n_done = 0
-                for item in items:
-                    if type(item) is PublishBurst:
-                        m.inc("pipeline.ingress.bursts")
-                        m.inc("pipeline.ingress.rows", len(item))
-                        tele = self.node.pipeline_telemetry
-                        if tele is not None:
-                            tele.record_ingress_burst(len(item))
+                # one emqx:control span a read, opened at its first
+                # packet that is no burst and released wherever this
+                # task waits: PUBACKs, PINGREQ fences, SUBSCRIBEs and
+                # the PUBLISHes of a read too small to decode by column
+                ctl = None
+                try:
+                    for item in items:
+                        if type(item) is PublishBurst:
+                            m.inc("pipeline.ingress.bursts")
+                            m.inc("pipeline.ingress.rows", len(item))
+                            tele = self.node.pipeline_telemetry
+                            if tele is not None:
+                                tele.record_ingress_burst(len(item))
+                            away = _NOT_OPEN if ctl is None \
+                                else ctl.released()
+                            try:
+                                with away:
+                                    await self.channel.handle_publish_burst(
+                                        item)
+                            except ProtocolError as e:
+                                reason = f"protocol_error:0x{e.rc:02x}"
+                                self._protocol_error_out(e)
+                                break
+                            n_done += len(item)
+                            continue
+                        if columnar:
+                            m.inc("pipeline.ingress.fallback_frames")
+                        if ctl is None:
+                            ctl = self._spans.span("control").__enter__()
                         try:
-                            await self.channel.handle_publish_burst(item)
+                            await ctl.run(self.channel.handle_in(item))
                         except ProtocolError as e:
                             reason = f"protocol_error:0x{e.rc:02x}"
                             self._protocol_error_out(e)
                             break
-                        n_done += len(item)
-                        continue
-                    if columnar:
-                        m.inc("pipeline.ingress.fallback_frames")
-                    try:
-                        await self.channel.handle_in(item)
-                    except ProtocolError as e:
-                        reason = f"protocol_error:0x{e.rc:02x}"
-                        self._protocol_error_out(e)
-                        break
-                    n_done += 1
-                    if n_done % 64 == 0:
-                        # one read can carry hundreds of frames; without
-                        # a scheduling point the whole burst handles
-                        # back-to-back and stalls every other task for
-                        # tens of ms (handle_in's awaits don't yield
-                        # unless they actually block)
-                        await asyncio.sleep(0)
+                        n_done += 1
+                        if n_done % 64 == 0:
+                            # one read can carry hundreds of frames;
+                            # without a scheduling point the whole burst
+                            # handles back-to-back and stalls every
+                            # other task for tens of ms (handle_in's
+                            # awaits don't yield unless they actually
+                            # block)
+                            with ctl.released():
+                                await asyncio.sleep(0)
+                finally:
+                    if ctl is not None:
+                        ctl.__exit__(None, None, None)
                 if items:
                     # offender score counts PUBLISH rows ONLY: a
                     # subscriber's PUBACK stream (or SUBSCRIBE/PING
@@ -417,8 +447,10 @@ class Listener:
         watch = getattr(self.node, "gc_watch", None)
         if watch is not None and not self._gc_watched:
             # the node serves from its first listener on: collections
-            # are counted (runtime.gc.*) until the last one stops
+            # are counted (runtime.gc.*) and the loop is timed
+            # (runtime.loop.*) until the last one stops
             watch.start()
+            self.node.loop_watch.start()
             self._gc_watched = True
 
     async def _listen(self) -> None:
@@ -488,6 +520,7 @@ class Listener:
         if self._gc_watched:
             self._gc_watched = False
             self.node.gc_watch.stop()
+            self.node.loop_watch.stop()
         servers = self._lane_servers or \
             ([self._server] if self._server else [])
         for srv in servers:

@@ -70,6 +70,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -160,10 +161,6 @@ class _ViewHeaders:
 
     def __init__(self, view: "DeliveryView"):
         self._v = view
-
-    def _own(self):
-        h = self._v._headers
-        return h if h is not None else None
 
     def get(self, key, default=None):
         h = self._v._headers
@@ -588,7 +585,6 @@ class DeliveryLanePool:
         self.broker = broker
         self.metrics = metrics
         self.hooks = hooks
-        self.telemetry = telemetry
         # the one span call: deliver_lane{i} histogram, lane{i} ring
         # span, emqx:lane on the profiler timeline
         if spans is None:
@@ -1020,6 +1016,11 @@ class DeliveryLanePool:
                 if sp is not None:
                     if worked:
                         sp.__exit__(None, None, None)
+                        # what emqx:lane covered: the item less the
+                        # stretches it was released for
+                        self.metrics.inc(
+                            "pipeline.deliver.lane_us",
+                            round((sp.dur - sp.away) * 1e6))
                     else:
                         sp.drop()
 
@@ -1103,6 +1104,8 @@ class DeliveryLanePool:
         fids, midx = plan.s_fid, plan.s_midx
         delivered = 0
         drains = 0
+        accept_ns = 0   # inside the subscribers' deliver calls
+        clock = time.perf_counter_ns
         # one DeliveryView per (message, subopts word), shared across
         # the fan-out: at fan-out F this builds 1 view instead of F. The
         # share is safe by the copy-on-write contract — every mutation
@@ -1149,12 +1152,14 @@ class DeliveryLanePool:
             if batch_fn is not None:
                 # coalesced drain: one session accept + one socket
                 # write for the whole run (all-or-none by contract)
+                t0 = clock()
                 try:
                     got = batch_fn(items)
                 except Exception:  # noqa: BLE001 — one bad subscriber
                     log.exception("deliver_batch failed sid=%s", sid)
                     self.metrics.inc("pipeline.deliver.deliver_errors")
                     got = 0
+                accept_ns += clock() - t0
                 drains += 1
                 if got:
                     delivered_midx.extend(midx[i:j])
@@ -1167,6 +1172,7 @@ class DeliveryLanePool:
                     nacked = range(i, j)
             else:
                 drains += j - i
+                t0 = clock()
                 for k, (f, view) in zip(range(i, j), items):
                     try:
                         ok = sub.deliver(f, view)
@@ -1183,6 +1189,7 @@ class DeliveryLanePool:
                                       (meta.get(sid), view))
                     else:
                         nacked += (k,)
+                accept_ns += clock() - t0
             if nacked and picks is not None:
                 delivered_midx.extend(self._repick(plan, sid, nacked))
             i = j
@@ -1198,6 +1205,7 @@ class DeliveryLanePool:
         n_rows = hi - lo
         metrics.inc("pipeline.deliver.deliveries", n_rows)
         metrics.inc("pipeline.deliver.drains", drains)
+        metrics.inc("pipeline.deliver.accept_us", round(accept_ns / 1000))
         if n_rows:
             metrics.hist("pipeline.deliver.coalesce.ratio",
                          lo=1.0 / 256, n_buckets=9,

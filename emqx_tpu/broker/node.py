@@ -119,8 +119,8 @@ class Node:
         # (self.flight_recorder stays None everywhere).
         self.flight_recorder = None
         mc = perf.get("multichip") or {}
-        from emqx_tpu.broker.trace import (FlightRecorder, GcWatch, Spans,
-                                           resolve_trace)
+        from emqx_tpu.broker.trace import (FlightRecorder, GcWatch,
+                                           LoopWatch, Spans, resolve_trace)
         if resolve_trace(perf.get("trace")) \
                 and (use_device or mc.get("enable")):
             self.flight_recorder = FlightRecorder(
@@ -137,6 +137,13 @@ class Node:
         # (trace.HeapFreeze, one a process)
         self.gc_watch = GcWatch(self.metrics, self.spans)
         self.stats.register_stats_fun(self.gc_watch.stats_fun)
+        # the loop's own clock (runtime.loop.*): waits, work and CPU of
+        # the one asyncio loop, timed at its selector; started and
+        # stopped with the collections' watch
+        self.loop_watch = LoopWatch(self.metrics, self.spans)
+        self.stats.register_stats_fun(self.loop_watch.stats_fun)
+        self.pipeline_telemetry.runtime_state_fn = \
+            lambda: {"loop": self.loop_watch.state()}
         # fault-domain supervision (ISSUE 6): the per-node supervision
         # tree every pipeline stage plugs into — fault injection points,
         # per-stage circuit breakers driving the degradation ladder
@@ -480,12 +487,14 @@ class Node:
                 asyncio.ensure_future(self._housekeeping(interval)),
                 "node-housekeeping", self.metrics)
             self.gc_watch.start()
+            self.loop_watch.start()
 
     def stop_timers(self) -> None:
         if self._timer_task is not None:
             self._timer_task.cancel()
             self._timer_task = None
             self.gc_watch.stop()
+            self.loop_watch.stop()
 
     # ---- facade (emqx.erl) ----
     def publish(self, msg: Message) -> int:

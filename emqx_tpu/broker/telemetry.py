@@ -158,6 +158,10 @@ class PipelineTelemetry:
         # live delivery-lane gauges provider (set by the node when the
         # ISSUE-5 DeliveryLanePool exists): lane depth, live plans
         self.deliver_state_fn = None
+        # the interpreter under the pipeline (set by the node):
+        # snapshot() carries what it returns as the `runtime` section,
+        # today the loop's own clock (`trace.LoopWatch.state()`)
+        self.runtime_state_fn = None
         # live supervision gauges provider (set by the node when the
         # ISSUE-6 PipelineSupervisor exists): breaker states, ladder
         # rung, window-journal depth, armed fault clauses
@@ -603,7 +607,8 @@ class PipelineTelemetry:
         # purely from traffic: with broker.columnar_ingress=0 nothing
         # increments, so the section is absent exactly as pre-ISSUE-11.
         ingress = {}
-        for k in ("bursts", "rows", "fallback_frames", "bytes"):
+        for k in ("bursts", "rows", "fallback_frames", "control_packets",
+                  "bytes"):
             v = self.metrics.val(f"pipeline.ingress.{k}")
             if v:
                 ingress[k] = v
@@ -662,6 +667,11 @@ class PipelineTelemetry:
         }
         if self.device_info is not None:
             out["device"] = dict(self.device_info)
+        if self.runtime_state_fn is not None:
+            try:
+                out["runtime"] = self.runtime_state_fn()
+            except Exception:  # noqa: BLE001 — telemetry never raises
+                pass
         if chooser or (full and self.chooser_state_fn is not None):
             out["chooser"] = chooser
         if supervise or full:

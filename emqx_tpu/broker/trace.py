@@ -13,17 +13,33 @@ it is on the host plane of ANY active profiler session, whoever started
 it (a benchmark, or an operator's `emqx_ctl trace device start`), on
 the same clock as the device's operations. Every dispatch also runs
 under ``StepTraceAnnotation("route_step", step_num=<trace id>)``. Spans
-are per window, per read burst and per lane item, never per message;
-on the event-loop thread no `emqx:` span encloses an `await`
-(`span.released()` around each one), or it would bill other
-coroutines' work to itself. Waits (enqueue, lane_admit, lane_drain, the
-window and message roll-ups) are recorded in retrospect
-(`Spans.record`): histogram and ring only. `GcWatch` counts the
-interpreter's collections (`runtime.gc.*`) and makes a generation-2
-collection a span (`emqx:gc`), and reports each of those to the
-process's `HeapFreeze`, which moves a heap that a long full collection
-found alive into the collector's permanent generation. With
-``broker.trace`` off the ring is absent and sinks (a) and (c) remain.
+are per window, per read (`emqx:ingress` its decode and each burst's
+hand-off, `emqx:control` every packet of it that is no PUBLISH burst)
+and per lane item, never per message; on the event-loop thread no
+`emqx:` span encloses a suspension (`span.released()` around an
+`await`, or the coroutine awaited through `span.run()`, which releases
+only while it really waits), or it would bill other coroutines' work to
+itself. Waits (enqueue, lane_admit, lane_drain, the window and message
+roll-ups) are recorded in retrospect (`Spans.record`): histogram and
+ring only. The two stages off the loop (`dispatch`, `materialize`) also
+read their thread's CPU clock (`runtime.dispatch.cpu_us`,
+`runtime.readback.cpu_us`). With ``broker.trace`` off the ring is absent
+and sinks (a) and (c) remain.
+
+**The interpreter under the pipeline**, two watches that a node starts
+with its first listener or timer and stops with its last. `GcWatch`
+counts the interpreter's collections (`runtime.gc.*`) and makes a
+generation-2 collection a span (`emqx:gc`), and reports each of those
+to the process's `HeapFreeze`, which moves a heap that a long full
+collection found alive into the collector's permanent generation.
+`LoopWatch` is the one asyncio loop's own clock: a timed stand-in for
+the running loop's selector splits every turn into the wait inside
+`select()` and the work between two of them, with the loop thread's CPU
+beside the work (`runtime.loop.turns`, `.wait_us`, `.busy_us`,
+`.cpu_us`, `.long_turns`, gauge `runtime.loop.longest_turn_us`;
+`LoopWatch.state()`, the `runtime` section of the telemetry snapshot),
+and puts `emqx:loop_wait` around a `select()` that may block, which
+marks the loop's line on the profiler's host timeline.
 
 **The flight recorder.** PR 1's stage histograms aggregate away exactly
 what the device-e2e gap diagnosis needs: CAUSALITY (which admit fed
@@ -84,6 +100,7 @@ import itertools
 import json
 import os
 import time
+import types
 from collections import defaultdict
 from typing import Optional
 
@@ -338,27 +355,33 @@ class _Released:
     """`with span.released():` around an `await` inside a span on the
     event-loop thread: the profiler annotation is left for the wait and
     a fresh one entered after it, so it never bills other coroutines'
-    work to this span. Histogram and ring still see the whole stage."""
+    work to this span. Histogram and ring still see the whole stage;
+    the stretch is added to the span's `away`."""
 
-    __slots__ = ("_sp",)
+    __slots__ = ("_sp", "_t")
 
     def __init__(self, sp):
         self._sp = sp
 
     def __enter__(self):
         self._sp._ann.__exit__(None, None, None)
+        self._t = time.perf_counter()
 
     def __exit__(self, *exc):
-        self._sp._ann = self._sp._annotate()
+        sp = self._sp
+        sp.away += time.perf_counter() - self._t
+        sp._ann = sp._annotate()
 
 
 class _Span:
     """One open span (see `Spans.span`). After exit `dur` is its
-    seconds and `sid` its ring span id (0 when the ring took nothing),
-    for child linking."""
+    seconds, `away` those of them it spent released (`dur - away` is
+    what its `emqx:` annotation covered) and `sid` its ring span id (0
+    when the ring took nothing), for child linking."""
 
-    __slots__ = ("_o", "_label", "_kw", "_ann", "trace", "stage", "ring",
-                 "track", "parent", "meta", "t0", "dur", "sid")
+    __slots__ = ("_o", "_label", "_kw", "_ann", "_cpu", "_c0", "trace",
+                 "stage", "ring", "track", "parent", "meta", "t0", "dur",
+                 "away", "sid")
 
     def __init__(self, owner, name, trace, stage, ring, track, parent,
                  meta):
@@ -368,12 +391,15 @@ class _Span:
         if trace:
             kw["trace_id"] = trace
         self._kw = kw
+        # an off-loop stage also reads its thread's CPU clock (`Spans`)
+        self._cpu = owner.cpu_counters.get(name)
         self.trace = trace
         self.stage = stage
         self.ring = ring
         self.track = track
         self.parent = parent
         self.meta = meta
+        self.away = 0.0
         self.sid = 0
 
     def _annotate(self):
@@ -382,8 +408,37 @@ class _Span:
     def released(self) -> _Released:
         return _Released(self)
 
+    @types.coroutine
+    def run(self, coro):
+        """`await span.run(coro)` is `await coro` with the span released
+        for as long as `coro` is suspended, and only then: a coroutine
+        that runs through without waiting stays under the annotation
+        whole. For awaiting code that cannot wrap its own awaits."""
+        it = coro.__await__()
+        try:
+            waits_on = it.send(None)
+            while True:
+                rel = self.released()
+                rel.__enter__()
+                try:
+                    got = yield waits_on
+                except GeneratorExit:
+                    rel.__exit__()
+                    it.close()
+                    raise
+                except BaseException as e:  # noqa: BLE001 — handed on
+                    rel.__exit__()
+                    waits_on = it.throw(e)
+                else:
+                    rel.__exit__()
+                    waits_on = it.send(got)
+        except StopIteration as e:
+            return e.value
+
     def __enter__(self):
         self.t0 = time.perf_counter()
+        if self._cpu is not None:
+            self._c0 = time.thread_time_ns()
         self._ann = self._annotate()
         return self
 
@@ -396,7 +451,12 @@ class _Span:
         self._ann.__exit__(None, None, None)
         t1 = time.perf_counter()
         self.dur = t1 - self.t0
-        self.sid = self._o.record(
+        o = self._o
+        if self._cpu is not None and o.metrics is not None:
+            # entered and left on one thread by construction (a `with`)
+            o.metrics.inc(self._cpu,
+                          (time.thread_time_ns() - self._c0) // 1000)
+        self.sid = o.record(
             self.ring, self.trace, self.t0, t1, stage=self.stage,
             track=self.track, parent=self.parent, meta=self.meta)
         return False
@@ -418,14 +478,26 @@ class Spans:
 
     `span()` is for work: per window, per burst, per lane item, never
     per message; on the event-loop thread every `await` inside one is
-    wrapped in `span.released()`. `record()` is the retrospective form
-    for waits (enqueue, lane_drain, ...): sinks (a) and (b) only."""
+    wrapped in `span.released()` (or awaited through `span.run()`).
+    `record()` is the retrospective form for waits (enqueue,
+    lane_drain, ...): sinks (a) and (b) only.
+
+    The two stages that run off the loop (`cpu_counters`: `dispatch`
+    on the dispatch thread, `materialize` on the read threads) also
+    read their thread's CPU clock, into `runtime.dispatch.cpu_us` /
+    `runtime.readback.cpu_us`: how much of a second the threads beside
+    the loop compute, and so can hold the GIL (`LoopWatch` has the
+    loop's side)."""
+
+    cpu_counters = {"dispatch": "runtime.dispatch.cpu_us",
+                    "materialize": "runtime.readback.cpu_us"}
 
     def __init__(self, tele=None, rec=None):
         from jax.profiler import TraceAnnotation
         from emqx_tpu.broker.telemetry import STAGES
         self.tele = tele
         self.rec = rec
+        self.metrics = getattr(tele, "metrics", None)
         self._annotation = TraceAnnotation
         self._stages = frozenset(STAGES)
 
@@ -746,6 +818,210 @@ class GcWatch:
 
     def stats_fun(self, stats) -> None:
         stats.setstat("runtime.gc.frozen_objects", self.heap.frozen)
+
+
+# ---- the loop's own clock (ISSUE 40) ------------------------------------
+
+# A busy stretch (every callback between two `select` calls) longer than
+# this counts in `runtime.loop.long_turns`: every connection waits that
+# long for its next read. A constant, not a setting.
+LONG_TURN_NS = 10_000_000
+# The watch adds up on itself and brings its sums into the node's
+# counters this often (and whenever it is read): a turn then costs one
+# clock read (four, two of them of the CPU clock, where the loop went
+# to sleep) and a dozen integer operations, and no dictionary.
+_LOOP_FLUSH_NS = 50_000_000
+
+
+class _TimedSelector:
+    """The running loop's selector with `select` timed; everything else
+    is the selector's own (its hot methods bound here once, the rest
+    through `__getattr__`)."""
+
+    def __init__(self, selector, watch):
+        self._sel = selector
+        self._watch = watch
+        for name in ("register", "unregister", "modify", "get_key",
+                     "get_map", "close"):
+            setattr(self, name, getattr(selector, name))
+
+    def __getattr__(self, name):
+        return getattr(self._sel, name)
+
+    def select(self, timeout=None):
+        w = self._watch
+        # the CPU clock costs a system call (6 us on the benchmark's
+        # host, PR 40), so it is read only where the loop is about to
+        # sleep and has the time, and at the one marked select a flush
+        sleeps = timeout is None or timeout > 0 or w._mark
+        if sleeps:
+            # read inside the wall clock's stretch at both ends, so a
+            # busy stretch's CPU never passes its wall time
+            cpu = time.thread_time_ns()
+        t0 = time.perf_counter_ns()
+        busy = t0 - w._t_ret
+        w.turns += 1
+        w.busy_ns += busy
+        if busy > w._recent_ns:
+            w._recent_ns = busy
+        if busy > LONG_TURN_NS:
+            w.long_turns += 1
+        if t0 >= w._flush_at:
+            w.flush(t0)
+        if not sleeps:
+            # a poll: callbacks are ready and the loop only looks. That
+            # is work of the loop's, not a wait: it goes to the next
+            # turn's busy stretch, which starts here
+            w.polls += 1
+            w._t_ret = t0
+            return self._sel.select(timeout)
+        w._mark = False
+        w.cpu_ns += cpu - w._c_ret
+        ann = w.spans.annotate(PROFILER_PREFIX + "loop_wait")
+        try:
+            events = self._sel.select(timeout)
+        finally:
+            ann.__exit__(None, None, None)
+        w._t_ret = t1 = time.perf_counter_ns()
+        w._c_ret = time.thread_time_ns()
+        w.wait_ns += t1 - t0
+        return events
+
+
+class LoopWatch:
+    """The one asyncio loop's waits, work and CPU, timed where they
+    happen. A selector loop calls its selector's `select(timeout)` once
+    a turn (`BaseEventLoop._run_once`); while the node serves
+    (`start`/`stop` are counted, one per listener or timer, beside
+    `GcWatch`'s) a `_TimedSelector` stands in for the running loop's
+    selector. No loop subclass, no policy: whoever made the loop keeps
+    it. Where the running loop has no selector to wrap (uvloop, a
+    proactor loop) the watch stays off, its counters stay 0 and
+    `state()` says why.
+
+    Counters: `runtime.loop.turns`; `runtime.loop.wait_us`, inside a
+    `select` that may block (timeout not 0); `runtime.loop.busy_us`,
+    everything else: from a `select`'s return to the next one's call
+    (every callback of the turn), and the zero-timeout polls a loop
+    with callbacks ready makes between them, which are its own work
+    (a loop that only polls has no room, and reads 100 % busy);
+    `runtime.loop.cpu_us`, the loop thread's own CPU over the busy
+    stretches (read where the loop goes to sleep, so it follows
+    `busy_us` by one stretch between two sleeps), so that
+    `busy_us - cpu_us` is time the thread was runnable and not running
+    (the GIL in another thread's hands, or the core in another
+    process's); `runtime.loop.long_turns`, busy
+    stretches over 10 ms. Gauge `runtime.loop.longest_turn_us`: the
+    longest busy stretch since the gauges were last sampled. A `select`
+    that may block is also `emqx:loop_wait` on the profiler's host
+    timeline: the same stretch `wait_us` counts, on the device trace's
+    clock. So is the first `select` after each flush, whatever its
+    timeout, so that the line of the loop's thread carries the span at
+    least twenty times a second however busy the loop is."""
+
+    def __init__(self, metrics, spans: Spans):
+        self.metrics = metrics
+        self.spans = spans
+        self._users = 0
+        self._loop = None
+        self._proxy: Optional[_TimedSelector] = None
+        self._why = "not started"
+        self.turns = self.polls = self.long_turns = 0
+        self._mark = False      # the next select is a span, poll or not
+        self.wait_ns = self.busy_ns = self.cpu_ns = 0
+        self._recent_ns = self._longest_ns = 0
+        self._t_ret = self._c_ret = self._flush_at = 0
+        self._flushed = dict.fromkeys(self._totals(), 0)
+
+    def _totals(self) -> dict:
+        """The counters' values as the watch has them, by their names
+        under `runtime.loop.`."""
+        return {"turns": self.turns, "wait_us": self.wait_ns // 1000,
+                "busy_us": self.busy_ns // 1000,
+                "cpu_us": self.cpu_ns // 1000,
+                "long_turns": self.long_turns}
+
+    # ---- lifecycle ------------------------------------------------------
+    def start(self) -> None:
+        self._users += 1
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            if self._proxy is None:
+                self._why = "no running loop"
+            return
+        if loop is self._loop and self._proxy is not None:
+            return
+        # the first start, or a start on another loop than the one
+        # watched (tests run several loops against one node)
+        self._unwrap()
+        sel = getattr(loop, "_selector", None)
+        if sel is None or not callable(getattr(sel, "select", None)):
+            self._why = f"{type(loop).__name__} has no selector to wrap"
+            return
+        for name in self._flushed:
+            self.metrics.inc(f"runtime.loop.{name}", 0)
+        self._loop = loop
+        self._why = ""
+        self._t_ret = time.perf_counter_ns()
+        self._c_ret = time.thread_time_ns()
+        self._flush_at = self._t_ret + _LOOP_FLUSH_NS
+        self._proxy = loop._selector = _TimedSelector(sel, self)
+
+    def stop(self) -> None:
+        if self._users == 0:
+            return
+        self._users -= 1
+        if self._users == 0:
+            self._unwrap()
+            self._why = "stopped"
+
+    def _unwrap(self) -> None:
+        """Put the loop's own selector back: ours out of the chain,
+        wherever in it another node's watch has left it."""
+        proxy, loop = self._proxy, self._loop
+        self._proxy = self._loop = None
+        if proxy is None:
+            return
+        self.flush()
+        at = getattr(loop, "_selector", None)
+        if at is proxy:
+            loop._selector = proxy._sel
+            return
+        while isinstance(at, _TimedSelector):
+            if at._sel is proxy:
+                at._sel = proxy._sel
+                return
+            at = at._sel
+
+    # ---- reading --------------------------------------------------------
+    def flush(self, now_ns: int = 0) -> None:
+        """Bring what was added up since the last flush into the node's
+        counters (whole microseconds; the remainder stays)."""
+        self._flush_at = (now_ns or time.perf_counter_ns()) + _LOOP_FLUSH_NS
+        self._mark = True
+        if self._recent_ns > self._longest_ns:
+            self._longest_ns = self._recent_ns
+        done = self._flushed
+        for name, total in self._totals().items():
+            if total != done[name]:
+                self.metrics.inc(f"runtime.loop.{name}", total - done[name])
+                done[name] = total
+
+    def state(self) -> dict:
+        self.flush()
+        out = {"on": self._proxy is not None, "users": self._users}
+        if self._why:
+            out["why"] = self._why
+        out.update(self._totals(), polls=self.polls,
+                   longest_turn_us=self._longest_ns // 1000)
+        return out
+
+    def stats_fun(self, stats) -> None:
+        self.flush()
+        stats.setstat("runtime.loop.longest_turn_us",
+                      self._recent_ns // 1000)
+        self._recent_ns = 0
 
 
 # ---- the overlap/bubble analyzer (pure functions, reusable offline) ----

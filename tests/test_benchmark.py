@@ -13,10 +13,13 @@ file of their own, so that another worker takes them.
 import pytest
 
 from benchmark.tests import test_fleet_bcast as _fleet
+from benchmark.tests import test_mixed_zipf as _zipf
 from benchmark.tests import test_trace_readers as _readers
+from benchmark.tests import test_umbrella_cover as _umbrella
 from benchmark.tests.test_fleet_bcast import *      # noqa: F401,F403
 from benchmark.tests.test_mixed_zipf import *       # noqa: F401,F403
 from benchmark.tests.test_pieces import *           # noqa: F401,F403
+from benchmark.tests.test_trace_loop import *       # noqa: F401,F403
 from benchmark.tests.test_trace_readers import *    # noqa: F401,F403
 from benchmark.tests.test_umbrella_cover import *   # noqa: F401,F403
 
@@ -40,3 +43,33 @@ def test_every_new_metric_file_reads_the_recorded_trace(  # noqa: F811
     "test_fleet_bcast_still_reports_its_33_metrics (CHANGES.md, PR 38)"))
 def test_fleet_bcast_reports_its_33_metrics():      # noqa: F811
     _fleet.test_fleet_bcast_reports_its_33_metrics()
+
+
+_PR40 = (
+    "benchmark/tests/{file} pins the number of per-layer metrics listed "
+    "for {cell} at {n}{also}; PR 40 appends thirteen metrics of the loop "
+    "for every cell and may not edit that file: the pin is a `benchmark` "
+    "PR's to move. Everything else the case holds is held, at the new "
+    "count, by test_trace_loop.py::{held} (CHANGES.md, PR 40)")
+
+
+@pytest.mark.xfail(strict=True, reason=_PR40.format(
+    file="test_mixed_zipf.py", cell="mixed-zipf.flood", n=34, also="",
+    held="test_mixed_zipf_reports_its_47_metrics_and_the_trie"))
+def test_the_cell_reports_its_34_metrics_and_the_trie():    # noqa: F811
+    _zipf.test_the_cell_reports_its_34_metrics_and_the_trie()
+
+
+@pytest.mark.xfail(strict=True, reason=_PR40.format(
+    file="test_umbrella_cover.py", cell="umbrella-cover.flood", n=34,
+    also=" and its own three at the manifest's end",
+    held="test_umbrella_cover_reports_its_47_metrics_and_its_own_three"))
+def test_the_cell_reports_its_34_metrics_and_the_three_new_ones():  # noqa: F811,E501
+    _umbrella.test_the_cell_reports_its_34_metrics_and_the_three_new_ones()
+
+
+@pytest.mark.xfail(strict=True, reason=_PR40.format(
+    file="test_umbrella_cover.py", cell="fleet-bcast.flood", n=33, also="",
+    held="test_fleet_bcast_reports_its_46_metrics"))
+def test_fleet_bcast_still_reports_its_33_metrics():        # noqa: F811
+    _umbrella.test_fleet_bcast_still_reports_its_33_metrics()
