@@ -1040,6 +1040,25 @@ class Channel:
                 self._send_deliveries(self.session.deliver(pairs))
         return len(items)
 
+    def deliver_frames(self, joined, i: int, j: int) -> bool:
+        """The lanes' entry for a session's run that may be all shared
+        frames (ISSUE 41): `joined(i, j, version, clientid)` is the
+        plan's (`DeliveryPlan.joined`) and gives rows i..j as one
+        `bytes`, or None where a row needs a copy of this subscriber's
+        own. What `_send_shared` does a run, with no Python a row; this
+        connection's state is read now, when the run is sent. False,
+        with nothing sent or counted, where the run has to go by
+        `deliver_batch` / `deliver`."""
+        raw = self._shared_write()
+        if raw is None:
+            return False
+        data = joined(i, j, self.proto_ver, self.clientid)
+        if data is None:
+            return False
+        self._count_shared(j - i)
+        raw(data)
+        return True
+
     def _send_shared(self, items) -> bool:
         """ROADMAP Speed 1: a run of lane deliveries (`DeliveryView`s)
         that are each one frame for every subscriber goes out as those
@@ -1048,11 +1067,8 @@ class Channel:
         sent, where any of them or this connection needs a copy of its
         own (QoS above 0, no-local on the publisher, an expiry, a
         topic alias, a mountpoint, a client that is not connected)."""
-        raw = self.send_frames
-        if raw is None or self.conn_state != CONN_CONNECTED \
-                or self.alias_out_max or self.mountpoint or self._aborted \
-                or self.session.conf.upgrade_qos \
-                or self.mqtt.get("ignore_loop_deliver"):
+        raw = self._shared_write()
+        if raw is None:
             return False
         ver, me = self.proto_ver, self.clientid
         frames = []
@@ -1062,15 +1078,30 @@ class Channel:
             if data is None:
                 return False
             frames.append(data)
-        n = len(frames)
+        self._count_shared(len(frames))
+        raw(frames[0] if len(frames) == 1 else b"".join(frames))
+        return True
+
+    def _shared_write(self) -> Optional[Callable[[bytes], None]]:
+        """The transport's raw write where this connection, as it is
+        now, can take frames that are the same for every subscriber;
+        else None."""
+        raw = self.send_frames
+        if raw is None or self.session is None \
+                or self.conn_state != CONN_CONNECTED \
+                or self.alias_out_max or self.mountpoint or self._aborted \
+                or self.session.conf.upgrade_qos \
+                or self.mqtt.get("ignore_loop_deliver"):
+            return None
+        return raw
+
+    def _count_shared(self, n: int) -> None:
         self.session.deliver_count += n
         metrics = self.node.metrics
         metrics.inc("messages.sent", n)
         metrics.inc("messages.qos0.sent", n)
         metrics.inc("packets.sent", n)
         metrics.inc("packets.publish.sent", n)
-        raw(frames[0] if n == 1 else b"".join(frames))
-        return True
 
     def _send_deliveries(self, out: list) -> None:
         pkts = []

@@ -52,6 +52,16 @@ pipeline stage:
   into one `deliver_batch()` call (one session accept + one socket
   drain) when the subscriber supports it.
 
+- **The plan's table** (ISSUE 41): `submit` cuts the session runs and
+  finds the distinct (message, subopts word) keys by numpy; a key has
+  ONE view and one QoS 0 frame a protocol version for the whole plan,
+  built on first touch by whichever lane, chunk or retry asks
+  (`DeliveryPlan.view` / `.joined`). A run whose subscriber has
+  `deliver_frames` (`Channel`) and whose rows all share their frame
+  goes out as one gather, one `join` and one write, no Python a row;
+  any other run is walked row by row as before, its views from the
+  same table.
+
 Ordering contract (what the property tests pin): for every session,
 the delivered sequence under `deliver_lanes=N` is identical to the
 inline `deliver_lanes=0` sequence. Within a window the inline order is
@@ -71,9 +81,13 @@ import asyncio
 import logging
 import os
 import time
+from bisect import bisect_left
 from typing import Callable, Optional
 
 import numpy as np
+
+from emqx_tpu.mqtt import packet as P
+from emqx_tpu.mqtt.frame import serialize
 
 log = logging.getLogger("emqx.deliver")
 
@@ -260,7 +274,7 @@ class DeliveryView:
 
     __slots__ = ("topic", "payload", "qos", "from_", "id", "ts", "extra",
                  "_base_flags", "_base_headers", "_subopts", "_flags",
-                 "_headers", "_wire")
+                 "_headers", "_wire", "_tally")
 
     def __init__(self, msg, subopts: dict):
         self.topic = msg.topic
@@ -276,33 +290,47 @@ class DeliveryView:
         self._flags = None
         self._headers = None
         self._wire = None       # protocol version -> shared frame | None
+        # [frames serialized] of the plan whose view this is, if any
+        # (`pipeline.deliver.frames_built`)
+        self._tally: Optional[list] = None
 
     # -- the frame every subscriber gets (ROADMAP Speed 1) --
     def wire_qos0(self, ver: int, clientid: str) -> Optional[bytes]:
         """This delivery's PUBLISH frame at protocol `ver` where it is
-        the same bytes for every subscriber the view is shared by: QoS
-        0 after the subscription's cap (no packet id), no expiry to
-        recompute at send time, nothing written through the view.
-        Serialized once a (view, version). None where a subscriber's
-        own copy has to be built: the caller then takes the path every
-        delivery took before (`Session.deliver` + `_to_publish`)."""
-        so = self._subopts
-        if so.get("nl") and self.from_ == clientid:
+        the same bytes for every subscriber the view is shared by
+        (`frame_qos0`) and `clientid` is not the publisher under a
+        no-local subscription. None where a subscriber's own copy has
+        to be built: the caller then takes the path every delivery took
+        before (`Session.deliver` + `_to_publish`)."""
+        if self._subopts.get("nl") and self.from_ == clientid:
             return None
+        return self.frame_qos0(ver)
+
+    def shares_frame(self) -> bool:
+        """What `frame_qos0` asks of the message and the subscription,
+        without serializing: QoS 0 after the subscription's cap (no
+        packet id), no `subid`, no expiry to recompute at send time,
+        nothing written through the view."""
+        so = self._subopts
+        props = self._base_headers.get("properties")
+        return min(self.qos, so.get("qos", 0)) == 0 \
+            and self._headers is None and self._flags is None \
+            and "subid" not in so \
+            and not (props and "message_expiry_interval" in props)
+
+    def frame_qos0(self, ver: int) -> Optional[bytes]:
+        """The frame whoever the subscriber is, serialized once a
+        (view, version); None where `shares_frame` says no."""
         w = self._wire
         if w is None:
             w = self._wire = {}
         elif ver in w:
             return w[ver]
         data = None
-        props = self._base_headers.get("properties")
-        if min(self.qos, so.get("qos", 0)) == 0 \
-                and self._headers is None and self._flags is None \
-                and "subid" not in so \
-                and not (props and "message_expiry_interval" in props):
-            from emqx_tpu.mqtt import packet as P
-            from emqx_tpu.mqtt.frame import serialize
+        if self.shares_frame():
+            so = self._subopts
             flags = self._base_flags
+            props = self._base_headers.get("properties")
             # Session._enrich: the retain bit survives only with
             # retain-as-published or on a retained-store replay
             retain = bool(flags.get("retain")) and bool(
@@ -311,6 +339,8 @@ class DeliveryView:
                 topic=self.topic, payload=self.payload, qos=0,
                 retain=retain, dup=bool(flags.get("dup")), packet_id=0,
                 properties=dict(props or {}) if ver == 5 else None), ver)
+            if self._tally is not None:
+                self._tally[0] += 1
         w[ver] = data
         return data
 
@@ -428,9 +458,10 @@ class DeliveryPlan:
 
     __slots__ = ("pool", "msgs", "counts", "fast_idx", "slow_items",
                  "filters", "_chunks", "routed_device", "pending",
-                 "done", "target", "_cbs", "s_midx", "s_sid", "s_opt",
-                 "s_fid", "_barrier_left", "_barrier_evt", "trace",
-                 "n_rows", "picks")
+                 "done", "target", "_cbs", "s_midx", "_s_opt", "_s_fid",
+                 "_lists", "run_lo", "run_sid", "keys", "inv", "views",
+                 "frames", "tally", "_nl", "_barrier_left",
+                 "_barrier_evt", "trace", "n_rows", "picks")
 
     def __init__(self, pool: "DeliveryLanePool", msgs: list):
         self.pool = pool
@@ -447,7 +478,19 @@ class DeliveryPlan:
         self.done = False
         self.target = None          # LaneCounts to back-fill
         self._cbs: list[Callable[[], None]] = []
-        self.s_midx = self.s_sid = self.s_opt = self.s_fid = None
+        # the fast rows as `_sort_rows` leaves them: columns sorted so
+        # that a session's rows are one run, the runs' bounds, and the
+        # plan-wide table of views and frames a (message, subopts word)
+        self.s_midx = self._s_opt = self._s_fid = self._lists = None
+        self.run_lo: list[int] = [0]
+        self.run_sid: list[int] = []
+        self.keys: list[int] = []
+        self.inv: list[int] = []
+        self.views: list[Optional[DeliveryView]] = []
+        self.frames: dict[int, list] = {}
+        # [frames its views serialized, of them counted already]
+        self.tally = [0, 0]
+        self._nl: Optional[tuple[dict[int, str], set]] = None
         self._barrier_left = 0
         self._barrier_evt: Optional[asyncio.Event] = None
         # flight-recorder trace id (ISSUE 7): set by the engine from
@@ -460,7 +503,7 @@ class DeliveryPlan:
     def register_fast(self, indices) -> None:
         """Mark message indices whose deliveries the lanes own (their
         no-subscriber drop bookkeeping moves to finalize)."""
-        self.fast_idx.extend(int(i) for i in indices)
+        self.fast_idx.extend(np.asarray(indices, np.int64).tolist())
 
     def add_rows(self, midx, sid, opt, fid, filters, slot=None,
                  picks: Optional[GroupPicks] = None) -> None:
@@ -517,6 +560,114 @@ class DeliveryPlan:
         else:
             self._cbs.append(cb)
 
+    # -- the sorted rows and their table (submit / deliver_now) --
+    def _sort_rows(self, order, midx, sid, opt, fid) -> np.ndarray:
+        """Take the fast rows in `order` (stable in the session id, so
+        a session's rows are contiguous and in the order given) and cut
+        them by numpy: a run is one session's rows, `run_lo[r]` to
+        `run_lo[r + 1]`; `keys` are the distinct (message << 32 | word)
+        of the plan and `inv` a row's index into them, so that a view
+        and a frame are made once a key for the whole plan, whichever
+        lane, chunk or retry asks first (`view`, `joined`). Returns the
+        runs' session ids."""
+        sid = sid[order]
+        midx = self.s_midx = midx[order]
+        opt = self._s_opt = opt[order]
+        self._s_fid = fid[order]
+        n = self.n_rows = len(sid)
+        if not n:
+            return sid
+        starts = np.concatenate(([0], np.flatnonzero(np.diff(sid)) + 1))
+        run_sid = sid[starts]
+        self.run_sid = run_sid.tolist()
+        self.run_lo = starts.tolist()
+        self.run_lo.append(n)
+        keys, inv = np.unique((midx << 32) | opt, return_inverse=True)
+        self.keys = keys.tolist()
+        self.inv = inv.tolist()
+        self.views = [None] * len(keys)
+        # no-local rows (bit 2 of the word): key -> the publisher whose
+        # own session must not get the frame, and those publishers
+        nl = np.flatnonzero(keys & 4).tolist()
+        if nl:
+            by_key = {k: self.msgs[self.keys[k] >> 32].from_ for k in nl}
+            self._nl = (by_key, set(by_key.values()))
+        return run_sid
+
+    def row_lists(self) -> tuple:
+        """(message index, word, filter id) a sorted row as plain lists,
+        for a run that is walked row by row: per-row numpy scalar
+        indexing costs ~3x a list index. Made on first use: a plan
+        whose runs all go out as joined frames never asks."""
+        if self._lists is None:
+            self._lists = (self.s_midx.tolist(), self._s_opt.tolist(),
+                           self._s_fid.tolist())
+        return self._lists
+
+    def view(self, k: int) -> DeliveryView:
+        """The plan's one DeliveryView of key `k`, shared across its
+        fan-out and across lanes. The share is safe by the
+        copy-on-write contract: every mutation path on the view
+        (set_header / set_flag / update_expiry / copy) materializes
+        private state, and delivered messages are read-only by
+        protocol (Subscriber docstring in pubsub.py). A `$share` row's
+        word names its group (GroupPicks): a session a message reaches
+        by a plain filter and by a group gets two views, the second
+        with `share=<group>` in its subopts."""
+        v = self.views[k]
+        if v is None:
+            key = self.keys[k]
+            word = key & 0xFFFFFFFF
+            v = self.views[k] = DeliveryView(
+                self.msgs[key >> 32], OPT_TABLE[word] if word < 64
+                else self.picks.subopts(word))
+            v._tally = self.tally
+        return v
+
+    def joined(self, i: int, j: int, ver: int,
+               clientid: str) -> Optional[bytes]:
+        """Rows i..j (one session's run) as the frames every subscriber
+        gets at protocol `ver`, joined for one write: a gather through
+        `inv` and a `join`, no Python a row. None where a row of the
+        run needs a copy of its subscriber's own
+        (`DeliveryView.shares_frame`, or no-local with `clientid` the
+        publisher): the run then goes by `deliver_batch` / `deliver`
+        whole."""
+        fr = self.frames.get(ver)
+        if fr is None:
+            fr = self.frames[ver] = [None] * len(self.keys)
+        own = self._nl
+        if own is not None and clientid not in own[1]:
+            own = None      # no publisher of this plan: no row to spare
+        if j - i == 1:
+            k = self.inv[i]
+            if own is not None and own[0].get(k) == clientid:
+                return None
+            data = fr[k]
+            if data is None:
+                data = fr[k] = self.view(k).frame_qos0(ver) or False
+            return data or None
+        idx = self.inv[i:j]
+        if own is not None and any(own[0].get(k) == clientid for k in idx):
+            return None
+        try:
+            return b"".join(map(fr.__getitem__, idx))
+        except TypeError:
+            # a key no run has asked for at this version yet (None), or
+            # one that shares no frame (False): `join` found it at C
+            # speed, and the first touch pays for the look
+            views, view = self.views, self.view
+            for k in idx:
+                data = fr[k]
+                if data is None:
+                    data = fr[k] = \
+                        (views[k] or view(k)).frame_qos0(ver) or False
+                if data is False:
+                    # in row order, so what was serialized before this
+                    # row is what `_send_shared` would have
+                    return None
+            return b"".join(map(fr.__getitem__, idx))
+
     # -- completion (lane workers, event loop) --
     def _finish_part(self) -> None:
         self.pending -= 1
@@ -526,19 +677,21 @@ class DeliveryPlan:
     def _finalize(self) -> None:
         self.done = True
         pool = self.pool
+        counts = self.counts
         if self.target is not None:
-            counts = self.counts
-            for i in range(len(self.msgs)):
-                self.target[i] = int(counts[i])
+            self.target[:] = counts.tolist()
             # the LaneCounts points back at this plan: let go, or every
             # delivered window's messages wait for a full collection
             self.target = None
         # no-subscriber bookkeeping for lane-owned messages (the slow
         # closures did their own inside the inline consume)
-        metrics = pool.metrics
-        hooks = pool.hooks
-        for i in self.fast_idx:
-            if self.counts[i] == 0 and not self.msgs[i].is_sys:
+        if self.fast_idx:
+            metrics = pool.metrics
+            hooks = pool.hooks
+            fast = np.asarray(self.fast_idx, np.int64)
+            for i in fast[counts[fast] == 0].tolist():
+                if self.msgs[i].is_sys:
+                    continue
                 metrics.inc("messages.dropped")
                 metrics.inc("messages.dropped.no_subscribers")
                 if hooks is not None:
@@ -616,11 +769,18 @@ class DeliveryLanePool:
         self._lane_items: list[int] = [0] * n_lanes  # real work per lane
         # same-sid coalescing yields one drain per run; chunk big slices
         # so one huge fan-out cannot monopolize the loop between yields.
-        # 2048 rows ≈ 1-2ms of delivery per burst — well under the
-        # pipeline's loop-stall budget — while finer chunks measurably
-        # thrash (sweep on a 2-cpu box: 512→310k, 2048→556k, 8192→378k
-        # deliveries/s at lanes=4: too-fine interleaving rotates lanes'
-        # working sets through cache per yield)
+        # What 2048 rows hold the loop for, on the host of a TPU v5e
+        # (PERF.md section 5, PR 41's chip runs): 3.6 ms where every run
+        # is joined frames at a fan-out of 110 (1.74 us a row), 16-26 ms
+        # where four rows in five or one in two serialize their frame
+        # (8.0-12.6 us a row at fan-outs of 1.25-2), 52-57 ms where
+        # every run holds a QoS 1 row and is walked (25-28 us); at a
+        # fan-out under 8 a lane's slice of a 1,024-message window is
+        # 250-500 rows, so only a wide fan-out meets a full chunk.
+        # Finer chunks measurably thrash (sweep on a 2-cpu box, before
+        # the frame path: 512→310k, 2048→556k, 8192→378k deliveries/s
+        # at lanes=4: too-fine interleaving rotates lanes' working sets
+        # through cache per yield)
         self._chunk = 2048
 
     # ---- lifecycle ------------------------------------------------------
@@ -719,23 +879,18 @@ class DeliveryLanePool:
             # order within a sid (sids are < 2^31 — broker sid counter)
             order = np.argsort((lane << np.int64(31)) | sid,
                                kind="stable")
-            # plain lists for the delivery walk: per-row numpy scalar
-            # indexing costs ~3x a list index in the hot loop
-            plan.s_midx = midx[order].tolist()
-            plan.s_sid = sid[order].tolist()
-            plan.s_opt = opt[order].tolist()
-            plan.s_fid = fid[order].tolist()
-            lanes_sorted = lane[order]
-            bounds = np.searchsorted(lanes_sorted,
-                                     np.arange(self.n_lanes + 1))
+            run_sid = plan._sort_rows(order, midx, sid, opt, fid)
+            # a lane's slice in RUNS: lanes change only where sessions do
+            bounds = np.searchsorted(
+                run_sid % self.n_lanes,
+                np.arange(self.n_lanes + 1)).tolist()
             for ln in range(self.n_lanes):
-                lo, hi = int(bounds[ln]), int(bounds[ln + 1])
+                lo, hi = bounds[ln], bounds[ln + 1]
                 if lo == hi:
                     continue
                 parts += 1
                 slices.append((ln, lo, hi))
-            plan.n_rows = len(order)
-            self.metrics.inc("pipeline.deliver.rows", len(order))
+            self.metrics.inc("pipeline.deliver.rows", plan.n_rows)
         if plan.slow_items:
             parts += 1
             plan._barrier_left = self.n_lanes
@@ -769,19 +924,20 @@ class DeliveryLanePool:
                     filters) -> np.ndarray:
         """The synchronous form, for a caller that needs its counts on
         return (`finish_sub(defer=False)`): the rows of one sub-batch,
-        walked session by session on the caller's stack exactly as a
-        lane walks its slice (shared views, one `deliver_batch` a
-        session). Stable in the session id, so every session sees its
-        rows in the order given. Returns the deliveries a message."""
+        delivered session by session on the caller's stack exactly as
+        a lane delivers its slice (one table of views and frames, one
+        write a session). Stable in the session id, so every session
+        sees its rows in the order given. Returns the deliveries a
+        message."""
         plan = DeliveryPlan(self, msgs)
         plan.routed_device = True
         plan.filters = filters
-        order = np.argsort(sid, kind="stable")
-        plan.s_midx = np.asarray(midx)[order].tolist()
-        plan.s_sid = np.asarray(sid)[order].tolist()
-        plan.s_opt = (np.asarray(opt) & 0x3F)[order].tolist()
-        plan.s_fid = np.asarray(fid)[order].tolist()
-        self._deliver_rows(plan, 0, len(order))
+        sid = np.asarray(sid, np.int64)
+        plan._sort_rows(np.argsort(sid, kind="stable"),
+                        np.asarray(midx, np.int64), sid,
+                        np.asarray(opt, np.int64) & 0x3F,
+                        np.asarray(fid, np.int64))
+        self._deliver_rows(plan, 0, len(plan.run_sid))
         return plan.counts
 
     def _plan_done(self, plan: DeliveryPlan) -> None:
@@ -946,7 +1102,7 @@ class DeliveryLanePool:
                 # (not lane work). item is ("slice", plan, lo, hi) or
                 # ("barrier", plan): the plan rides at [1] and carries
                 # its window's trace. The profiler annotation covers
-                # the synchronous row walk only: every await below
+                # the synchronous walk of runs only: every await below
                 # releases it
                 sp = spans.span(
                     "lane", getattr(item[1], "trace", 0),
@@ -1062,15 +1218,16 @@ class DeliveryLanePool:
         succeeded. Counts apply only on a chunk's successful return, so
         a retried chunk is at-least-once for its subscribers but never
         double-counted toward the publisher."""
-        sids = plan.s_sid
+        run_lo = plan.run_lo
         sup = self.sup
         pos = lo
         while pos < hi:
-            nxt = min(hi, pos + self._chunk)
-            # never split a same-session run across chunks: the
-            # coalesced drain and its all-or-none accept are per run
-            while nxt < hi and sids[nxt] == sids[nxt - 1]:
-                nxt += 1
+            # `lo`, `hi`, `pos` count RUNS (a session's contiguous rows:
+            # the coalesced drain and its all-or-none accept are per
+            # run), so a chunk never splits one: it ends at the first
+            # run boundary `_chunk` rows on
+            nxt = bisect_left(run_lo, run_lo[pos] + self._chunk,
+                              pos + 1, hi)
             if sup is None:
                 self._deliver_rows(plan, pos, nxt)
             else:
@@ -1091,110 +1248,135 @@ class DeliveryLanePool:
                     await asyncio.sleep(0)
 
     def _deliver_rows(self, plan: DeliveryPlan, lo: int, hi: int) -> None:
+        """Deliver runs lo..hi of the plan, a run a session. A run whose
+        subscriber takes joined frames (`Channel.deliver_frames`) and
+        whose rows all share theirs costs no Python a row: the
+        subscriber gathers and joins them from the plan's table
+        (`DeliveryPlan.joined`) and writes once. Every other run is
+        walked row by row into `deliver_batch` / `deliver`, its views
+        from the same table."""
         broker = self.broker
         registry = broker._subscribers
         meta = broker._sub_meta
         hooks = self.hooks
         delivered_cbs = hooks.lookup("message.delivered") \
             if hooks is not None else ()
-        msgs = plan.msgs
-        filters = plan.filters
         picks = plan.picks
-        sids, opts = plan.s_sid, plan.s_opt
-        fids, midx = plan.s_fid, plan.s_midx
+        run_lo, run_sid = plan.run_lo, plan.run_sid
+        joined = plan.joined
         delivered = 0
+        frame_rows = 0
         drains = 0
         accept_ns = 0   # inside the subscribers' deliver calls
         clock = time.perf_counter_ns
-        # one DeliveryView per (message, subopts word), shared across
-        # the fan-out: at fan-out F this builds 1 view instead of F. The
-        # share is safe by the copy-on-write contract — every mutation
-        # path on the view (set_header/set_flag/update_expiry/copy)
-        # materializes private state, and delivered messages are
-        # read-only by protocol (Subscriber docstring in pubsub.py).
-        # A `$share` row's word names its group (GroupPicks): a session
-        # a message reaches by a plain filter and by a group gets two
-        # views, the second with `share=<group>` in its subopts.
-        vcache: dict[int, DeliveryView] = {}
-        delivered_midx: list[int] = []
-        i = lo
-        while i < hi:
-            sid = sids[i]
-            j = i + 1
-            while j < hi and sids[j] == sid:
-                j += 1
+        # what was accepted: whole runs as [first row, end) with
+        # neighbours merged (a chunk in which every run is accepted is
+        # ONE pair: its counts are one bincount of a slice), and the
+        # message index of a row accepted by itself, or delivered by
+        # the host's dispatch of its group (which counts its own
+        # `messages.delivered`)
+        taken: list[list[int]] = []
+        singles: list[int] = []
+        repicked: list[int] = []
+        for r in range(lo, hi):
+            sid = run_sid[r]
+            i, j = run_lo[r], run_lo[r + 1]
             sub = registry.get(sid)
             if sub is None:
                 if picks is not None:
-                    delivered_midx.extend(
-                        self._repick(plan, sid, range(i, j)))
-                i = j
+                    repicked.extend(self._repick(plan, sid, range(i, j)))
                 continue
-            items = []
-            for k in range(i, j):
-                word = opts[k]
-                vk = (midx[k] << 32) | word
-                view = vcache.get(vk)
-                if view is None:
-                    view = vcache[vk] = DeliveryView(
-                        msgs[midx[k]], OPT_TABLE[word] if word < 64
-                        else picks.subopts(word))
-                items.append((filters[fids[k]], view))
-            batch_fn = getattr(sub, "deliver_batch", None) \
-                if j - i > 1 else None
-            nacked = ()
             # Deliberate divergence from the inline loop: a raising
             # subscriber/hook here is contained to ITS deliveries
             # (logged + counted) instead of failing the whole batch's
             # publish futures — one bad session must not poison every
             # publisher sharing the window. deliver_errors/slow_errors
             # make the containment observable.
-            if batch_fn is not None:
-                # coalesced drain: one session accept + one socket
-                # write for the whole run (all-or-none by contract)
+            send = getattr(sub, "deliver_frames", None)
+            if delivered_cbs:
+                send = None     # a callback wants (meta, view) a row
+            sent = False
+            if send is not None:
                 t0 = clock()
                 try:
-                    got = batch_fn(items)
+                    sent = send(joined, i, j)
                 except Exception:  # noqa: BLE001 — one bad subscriber
-                    log.exception("deliver_batch failed sid=%s", sid)
+                    log.exception("deliver_frames failed sid=%s", sid)
                     self.metrics.inc("pipeline.deliver.deliver_errors")
-                    got = 0
+                    sent = None
                 accept_ns += clock() - t0
+            whole = False       # the run was accepted, all of it
+            nacked = ()
+            if sent is not False:
+                # the frame entry took the run, or raised under it (as
+                # a raising deliver_batch: not taken, not tried again)
                 drains += 1
-                if got:
-                    delivered_midx.extend(midx[i:j])
-                    delivered += len(items)
-                    if delivered_cbs:
+                if sent:
+                    whole = True
+                    frame_rows += j - i
+                else:
+                    nacked = range(i, j)
+            else:
+                midx, _opts, fids = plan.row_lists()
+                filters, views, view = plan.filters, plan.views, plan.view
+                items = [(filters[f], views[k] or view(k))
+                         for f, k in zip(fids[i:j], plan.inv[i:j])]
+                batch_fn = getattr(sub, "deliver_batch", None) \
+                    if j - i > 1 else None
+                if batch_fn is not None:
+                    # coalesced drain: one session accept + one socket
+                    # write for the whole run (all-or-none by contract)
+                    t0 = clock()
+                    try:
+                        whole = bool(batch_fn(items))
+                    except Exception:  # noqa: BLE001 — one bad subscriber
+                        log.exception("deliver_batch failed sid=%s", sid)
+                        self.metrics.inc(
+                            "pipeline.deliver.deliver_errors")
+                    accept_ns += clock() - t0
+                    drains += 1
+                    if not whole:
+                        nacked = range(i, j)
+                    elif delivered_cbs:
                         for _f, v in items:
                             hooks.run("message.delivered",
                                       (meta.get(sid), v))
                 else:
-                    nacked = range(i, j)
-            else:
-                drains += j - i
-                t0 = clock()
-                for k, (f, view) in zip(range(i, j), items):
-                    try:
-                        ok = sub.deliver(f, view)
-                    except Exception:  # noqa: BLE001
-                        log.exception("deliver failed sid=%s", sid)
-                        self.metrics.inc(
-                            "pipeline.deliver.deliver_errors")
-                        ok = False
-                    if ok:
-                        delivered_midx.append(midx[k])
-                        delivered += 1
-                        if delivered_cbs:
-                            hooks.run("message.delivered",
-                                      (meta.get(sid), view))
-                    else:
-                        nacked += (k,)
-                accept_ns += clock() - t0
+                    drains += j - i
+                    t0 = clock()
+                    for k, (f, v) in zip(range(i, j), items):
+                        try:
+                            ok = sub.deliver(f, v)
+                        except Exception:  # noqa: BLE001
+                            log.exception("deliver failed sid=%s", sid)
+                            self.metrics.inc(
+                                "pipeline.deliver.deliver_errors")
+                            ok = False
+                        if ok:
+                            singles.append(midx[k])
+                            if delivered_cbs:
+                                hooks.run("message.delivered",
+                                          (meta.get(sid), v))
+                        else:
+                            nacked += (k,)
+                    accept_ns += clock() - t0
+            if whole:
+                if taken and taken[-1][1] == i:
+                    taken[-1][1] = j
+                else:
+                    taken.append([i, j])
             if nacked and picks is not None:
-                delivered_midx.extend(self._repick(plan, sid, nacked))
-            i = j
-        if delivered_midx:
-            np.add.at(plan.counts, delivered_midx, 1)
+                repicked.extend(self._repick(plan, sid, nacked))
+        counts = plan.counts
+        if taken:
+            s_midx = plan.s_midx
+            rows = s_midx[taken[0][0]:taken[0][1]] if len(taken) == 1 \
+                else np.concatenate([s_midx[a:b] for a, b in taken])
+            counts += np.bincount(rows, minlength=len(counts))
+            delivered = len(rows)
+        if singles or repicked:
+            np.add.at(counts, singles + repicked, 1)
+            delivered += len(singles)
         # per-slice (not per-row) bookkeeping: the batching win the
         # coalesce.ratio histogram quantifies
         metrics = self.metrics
@@ -1202,10 +1384,22 @@ class DeliveryLanePool:
             metrics.inc("messages.delivered", delivered)
             if plan.routed_device:
                 metrics.inc("messages.routed.device", delivered)
-        n_rows = hi - lo
+        n_rows = run_lo[hi] - run_lo[lo]
         metrics.inc("pipeline.deliver.deliveries", n_rows)
         metrics.inc("pipeline.deliver.drains", drains)
         metrics.inc("pipeline.deliver.accept_us", round(accept_ns / 1000))
+        # rows that went out as a run of joined frames, and the frames
+        # the plan's views serialized since it last counted them (by
+        # either path; a chunk that raised left its own for its retry,
+        # which finds them in the table): against `deliveries`, how
+        # often the frame path engages and how far a frame is shared
+        if frame_rows:
+            metrics.inc("pipeline.deliver.frame_rows", frame_rows)
+        tally = plan.tally
+        if tally[0] != tally[1]:
+            metrics.inc("pipeline.deliver.frames_built",
+                        tally[0] - tally[1])
+            tally[1] = tally[0]
         if n_rows:
             metrics.hist("pipeline.deliver.coalesce.ratio",
                          lo=1.0 / 256, n_buckets=9,
@@ -1221,7 +1415,7 @@ class DeliveryLanePool:
         delivered so. The host's dispatch counts its own
         `messages.delivered` and runs its own hooks."""
         picks, broker = plan.picks, self.broker
-        opts, midx = plan.s_opt, plan.s_midx
+        midx, opts, _fids = plan.row_lists()
         out = []
         for k in rows:
             if opts[k] < 64:

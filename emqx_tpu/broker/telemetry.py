@@ -551,6 +551,7 @@ class PipelineTelemetry:
         # StatsD/$SYS stats all carry the point-in-time value.
         deliver = {}
         for key in ("rows", "plans", "deliveries", "drains",
+                    "frame_rows", "frames_built",
                     "backpressure_waits", "deliver_errors",
                     "slow_errors", "slow_msgs", "barriers"):
             v = self.metrics.val(f"pipeline.deliver.{key}")
@@ -560,6 +561,12 @@ class PipelineTelemetry:
             deliver["coalesce_ratio"] = round(
                 1.0 - deliver.get("drains", 0) / deliver["deliveries"],
                 4)
+            # ISSUE 41: the share of rows that went out as a run of
+            # joined shared frames, and the frames serialized a row
+            deliver["frame_share"] = round(
+                deliver.get("frame_rows", 0) / deliver["deliveries"], 4)
+            deliver["frames_per_delivery"] = round(
+                deliver.get("frames_built", 0) / deliver["deliveries"], 4)
         if self.deliver_state_fn is not None:
             try:
                 deliver["state"] = self.deliver_state_fn()

@@ -1126,3 +1126,484 @@ class TestTelemetry:
         text = collect(node)
         assert "emqx_pipeline_deliver_plans" in text
         assert "emqx_pipeline_deliver_lane_depth" in text
+
+
+# ------ ISSUE 41: a run of shared frames is one gather-and-join ------
+
+class _Wire:
+    """What one socket-less `Channel` wrote, in order, whichever write
+    it took: packets (`send`) or frames serialized already
+    (`send_frames`)."""
+
+    def __init__(self, ver):
+        self.ver, self.out = ver, []
+
+    def send(self, pkts):
+        from emqx_tpu.mqtt.frame import serialize
+        self.out.append(b"".join(serialize(p, self.ver) for p in pkts))
+
+
+def _chan(node, cid, ver=4):
+    """A connected MQTT channel with no socket under it: the subscriber
+    the lanes' frame entry is for."""
+    from emqx_tpu.broker.channel import CONN_CONNECTED, Channel
+    from emqx_tpu.broker.session import Session
+    wire = _Wire(ver)
+    ch = Channel(node, {}, wire.send, lambda reason: None)
+    ch.clientid, ch.proto_ver = cid, ver
+    ch.session = Session(cid)
+    ch.conn_state = CONN_CONNECTED
+    ch.send_frames = wire.out.append
+    ch.wire = wire
+    return ch
+
+
+class _World:
+    """One node, its subscribers and one plan's rows, built the same
+    way on the side that may join frames and on the side a
+    `message.delivered` hook holds to the row walk."""
+
+    def __init__(self, hooked, lanes=4):
+        self.node = Node({"broker": {"deliver_lanes": lanes}})
+        self.pool = self.node.deliver_lanes
+        self.subs, self.sids = {}, {}
+        self.rows = []                  # (message, name, word, filter)
+        self.msgs = []
+        self.picks = None
+        self.slots = {}                 # row index -> slot of its group
+        self.held = None                # runs between submit and the lanes
+        self.how = "submit"
+        self.hooked_rows = []
+        self.redispatched = []
+        if hooked:
+            self.node.hooks.add(
+                "message.delivered",
+                lambda meta, m: self.hooked_rows.append((meta, m.topic)))
+
+    def chan(self, name, ver=4):
+        self.subs[name] = ch = _chan(self.node, name, ver)
+        self.sids[name] = self.node.broker.register(ch, name)
+        return ch
+
+    def sink(self, name, cls=Rec):
+        self.subs[name] = s = cls()
+        self.sids[name] = self.node.broker.register(s, name)
+        return s
+
+    def msg(self, topic, payload=b"x", qos=0, from_="pub", flags=None,
+            props=None):
+        self.msgs.append(Message(
+            topic=topic, payload=payload, qos=qos, from_=from_,
+            flags=dict(flags or {}),
+            headers={"properties": dict(props)} if props else {}))
+        return len(self.msgs) - 1
+
+    def row(self, m, name, word=0, filt="t/#", slot=None):
+        if slot is not None:
+            self.slots[len(self.rows)] = slot
+        self.rows.append((m, name, word, filt))
+
+    def groups(self, keys):
+        from emqx_tpu.broker.deliver import GroupPicks
+
+        def redispatch(f, g, m):
+            self.redispatched.append((f, g, m.topic))
+            return 1
+        self.picks = GroupPicks(keys, redispatch)
+
+    def columns(self):
+        filters = sorted({r[3] for r in self.rows})
+        return (np.array([r[0] for r in self.rows], np.int64),
+                np.array([self.sids[r[1]] for r in self.rows], np.int64),
+                np.array([r[2] for r in self.rows], np.int64),
+                np.array([filters.index(r[3]) for r in self.rows],
+                         np.int64), filters)
+
+    async def deliver(self):
+        pool, n = self.pool, len(self.msgs)
+        before = dict(self.node.metrics.all())
+        midx, sid, opt, fid, filters = self.columns()
+        if self.how == "deliver_now":
+            counts = pool.deliver_now(self.msgs, midx, sid, opt, fid,
+                                      filters)
+        else:
+            plan = pool.new_plan(self.msgs)
+            plan.routed_device = True
+            plan.register_fast(range(n))
+            if self.how == "add_rows_py":
+                for m in range(n):
+                    plan.add_rows_py(m, [
+                        (self.sids[name], word, filt)
+                        for mi, name, word, filt in self.rows if mi == m])
+            elif self.picks is not None:
+                slot = np.full(len(self.rows), -1, np.int64)
+                for k, s in self.slots.items():
+                    slot[k] = s
+                plan.add_rows(midx, sid, opt, fid, filters, slot,
+                              self.picks)
+            else:
+                plan.add_rows(midx, sid, opt, fid, filters)
+            pool.pause()
+            pool.submit(plan)
+            if self.held is not None:
+                self.held(self)
+            pool.resume()
+            await pool.drain()
+            counts = plan.counts
+        after = self.node.metrics.all()
+        moved = {k: after[k] - before.get(k, 0) for k in after
+                 if k.startswith(("messages.", "packets.", "delivery.",
+                                  "pipeline.deliver.", "routing.device."))
+                 and after[k] != before.get(k, 0)}
+        return counts.tolist(), moved
+
+    def seen(self):
+        """What each subscriber has, by name."""
+        out = {}
+        for name, s in self.subs.items():
+            if hasattr(s, "wire"):
+                sess = s.session
+                out[name] = (b"".join(s.wire.out), sess.deliver_count,
+                             len(sess.inflight), len(sess.mqueue))
+            else:
+                out[name] = list(s.got)
+        return out
+
+
+def _fanout(n_sess, fan, n_msgs):
+    def build(w):
+        names = [f"c{i}" for i in range(n_sess)]
+        for name in names:
+            w.chan(name)
+        for m in range(n_msgs):
+            w.msg(f"t/{m}", b"p%03d" % m)
+            for k in range(fan):
+                w.row(m, names[(m + k) % n_sess])
+        return n_msgs * fan
+    return build
+
+
+def _two_words(w):
+    """One retained message by a plain subscription and by one with
+    retain-as-published: two frames, one a word."""
+    w.chan("a"), w.chan("b")
+    m = w.msg("t/1", flags={"retain": True})
+    w.row(m, "a", 0), w.row(m, "b", 8), w.row(m, "a", 8, "t/+")
+    return 3
+
+
+def _v4_and_v5(w):
+    w.chan("old", 4), w.chan("new", 5), w.chan("new2", 5)
+    for i in range(4):
+        m = w.msg(f"t/{i}", props={"user_property": [("k", "v")],
+                                   "content_type": "c"})
+        for name in ("old", "new", "new2"):
+            w.row(m, name)
+    return 12
+
+
+def _no_local_own(w):
+    """The publisher's own session under no-local: its run is walked
+    (the session drops the row), every other run is joined frames."""
+    w.chan("pub"), w.chan("other"), w.chan("third")
+    for i in range(3):
+        m = w.msg(f"t/{i}", from_="pub" if i != 1 else "else")
+        for name in ("pub", "other", "third"):
+            w.row(m, name, 4)
+    return 6
+
+
+def _subid(w):
+    """A row whose subopts carry a subscription identifier (never a
+    packed word's: here through a group's subopts) takes a copy."""
+    w.chan("a", 5), w.chan("b", 5)
+    w.groups([("t/#", "g")])
+    w.picks._subopts[(0, "g")] = dict(OPT_TABLE[0], share="g", subid=7)
+    for i in range(3):
+        m = w.msg(f"t/{i}")
+        w.row(m, "a", 0, slot=0), w.row(m, "b", 0)
+    return 3
+
+
+def _retain_rap(w):
+    w.chan("plain"), w.chan("rap")
+    for i in range(3):
+        m = w.msg(f"t/{i}", flags={"retain": True})
+        w.row(m, "plain", 0), w.row(m, "rap", 8)
+    return 6
+
+
+def _expiring(w):
+    w.chan("a", 5), w.chan("b", 5)
+    m0 = w.msg("t/0", props={"message_expiry_interval": 60})
+    m1 = w.msg("t/1")
+    w.row(m0, "a"), w.row(m1, "a"), w.row(m1, "b")
+    return 1
+
+
+def _qos1_mid_run(w):
+    """A QoS 1 row in the middle of a run: the whole run is walked,
+    in the order given; the other session's run is frames."""
+    w.chan("a"), w.chan("b")
+    for i in range(5):
+        m = w.msg(f"t/{i}", qos=1 if i == 2 else 0)
+        w.row(m, "a", 1), w.row(m, "b", 0)
+    return 5
+
+
+def _share_pick(w):
+    w.chan("member"), w.chan("plain")
+    w.groups([("t/#", "g"), ("t/+", "h")])
+    for i in range(4):
+        m = w.msg(f"t/{i}")
+        w.row(m, "plain", 0)
+        w.row(m, "member", 0, slot=i % 2)
+    return 8
+
+
+def _session_gone(w):
+    """A session that leaves the registry while its rows wait: the
+    plain ones are dropped, a group's go through the host's dispatch."""
+    w.chan("stays"), w.chan("goes")
+    w.groups([("t/#", "g")])
+    for i in range(3):
+        m = w.msg(f"t/{i}")
+        w.row(m, "stays", 0), w.row(m, "goes", 0)
+        w.row(m, "goes", 0, slot=0)
+    w.held = lambda w: w.node.broker.unregister(w.sids["goes"])
+    return 3
+
+
+def _closing(w):
+    """The connection's state is read when the run is sent: one that
+    stopped being connected since `submit` queues in its session."""
+    from emqx_tpu.broker.channel import CONN_DISCONNECTED
+    w.chan("up"), w.chan("down")
+    for i in range(3):
+        m = w.msg(f"t/{i}")
+        w.row(m, "up"), w.row(m, "down")
+
+    def close(w):
+        w.subs["down"].conn_state = CONN_DISCONNECTED
+    w.held = close
+    return 3
+
+
+def _deliver_only(w):
+    w.chan("mqtt"), w.sink("gateway", Rec), w.sink("batching", RecBatch)
+    for i in range(4):
+        m = w.msg(f"t/{i}")
+        for name in ("mqtt", "gateway", "batching"):
+            w.row(m, name)
+    return 4
+
+
+def _deliver_now(w):
+    n = _fanout(5, 3, 7)(w)
+    w.how = "deliver_now"
+    return n
+
+
+def _add_rows_py(w):
+    n = _fanout(5, 2, 6)(w)
+    w.how = "add_rows_py"
+    return n
+
+
+# case -> (builder, rows the frame path is expected to send)
+_FRAME_CASES = {
+    "fanout_1": _fanout(4, 1, 9), "fanout_2": _fanout(6, 2, 9),
+    "fanout_300": _fanout(300, 300, 3), "two_words": _two_words,
+    "v4_and_v5": _v4_and_v5, "no_local_own": _no_local_own,
+    "subid": _subid, "retain_rap": _retain_rap, "expiring": _expiring,
+    "qos1_mid_run": _qos1_mid_run, "share_pick": _share_pick,
+    "session_gone": _session_gone, "closing": _closing,
+    "deliver_only": _deliver_only, "deliver_now": _deliver_now,
+    "add_rows_py": _add_rows_py,
+}
+_CLOCKS = ("pipeline.deliver.lane_us", "pipeline.deliver.accept_us",
+           "pipeline.deliver.frame_rows")
+
+
+class TestFramePathEqualsTheRowWalk:
+    @pytest.mark.parametrize("case", sorted(_FRAME_CASES))
+    def test_same_plan_both_ways(self, case):
+        """The same plan by the joined-frame path and by the row walk
+        (forced by a `message.delivered` hook): byte-identical output a
+        connection in identical order, identical counts a message and
+        identical counters; and the frame path engaged where the case
+        says it does."""
+        sides = []
+        for hooked in (False, True):
+            w = _World(hooked)
+            expect = _FRAME_CASES[case](w)
+            counts, moved = run(w.deliver())
+            sides.append((w, counts, moved, expect))
+        (fw, f_counts, f_moved, expect), (hw, h_counts, h_moved, _e) = sides
+        assert f_moved.get("pipeline.deliver.frame_rows", 0) == expect
+        assert "pipeline.deliver.frame_rows" not in h_moved
+        assert fw.seen() == hw.seen()
+        assert f_counts == h_counts
+        assert {k: v for k, v in f_moved.items() if k not in _CLOCKS} \
+            == {k: v for k, v in h_moved.items() if k not in _CLOCKS}
+        assert fw.redispatched == hw.redispatched
+        assert fw.hooked_rows == []
+        # the hook saw every row a subscriber took, once
+        assert len(hw.hooked_rows) == h_moved.get("messages.delivered", 0)
+        assert f_moved["pipeline.deliver.deliveries"] == len(fw.rows)
+        if case == "fanout_300":
+            # 900 rows, three frames
+            assert f_moved["pipeline.deliver.frames_built"] == 3
+            assert f_moved["pipeline.deliver.drains"] == 300
+        if case == "no_local_own":
+            # its own two are dropped by its session, silently
+            assert fw.seen()["pub"][1] == 1
+            assert fw.seen()["other"][1] == 3
+        if case == "session_gone":
+            assert len(fw.redispatched) == 3
+            assert f_moved["routing.device.shared_repick"] == 3
+        if case == "closing":
+            assert fw.seen()["down"] == (b"", 0, 0, 3)
+
+
+class TestPlanTable:
+    """ISSUE 41: one table of views and frames a plan, runs cut by
+    numpy, whichever lane, chunk or retry asks first."""
+
+    def test_a_frame_is_serialized_once_a_key_a_version_a_plan(
+            self, monkeypatch):
+        """Four lanes, chunks of 16 rows, 40 sessions of two protocol
+        versions, 30 messages to every one of them and a second word on
+        every third row: 1,200 rows, and `serialize` runs once a
+        (message, word, version)."""
+        from emqx_tpu.broker import deliver as D
+        calls = []
+        real = D.serialize
+        monkeypatch.setattr(
+            D, "serialize",
+            lambda pkt, ver: calls.append((pkt.topic, pkt.retain, ver))
+            or real(pkt, ver))
+        w = _World(False)
+        w.pool._chunk = 16
+        names = [f"c{i}" for i in range(40)]
+        for i, name in enumerate(names):
+            w.chan(name, 5 if i % 2 else 4)
+        for m in range(30):
+            w.msg(f"t/{m}", b"p%03d" % m, flags={"retain": True})
+            for i, name in enumerate(names):
+                w.row(m, name, 8 if i % 3 == 0 else 0)
+        counts, moved = run(w.deliver())
+        assert counts == [40] * 30
+        # sessions 0, 6, 12 ... are v4 under the second word, and so on:
+        # both words meet both versions
+        assert len(calls) == len(set(calls)) == 30 * 2 * 2
+        assert moved["pipeline.deliver.frames_built"] == 120
+        assert moved["pipeline.deliver.frame_rows"] == 1200
+        assert moved["pipeline.deliver.drains"] == 40
+        # a run is one write, whatever the chunking
+        assert all(len(s.wire.out) == 1 for s in w.subs.values())
+        snap = w.node.pipeline_telemetry.snapshot()["deliver"]
+        assert snap["frame_rows"] == 1200 and snap["frames_built"] == 120
+        assert snap["frame_share"] == 1.0
+        assert snap["frames_per_delivery"] == 0.1
+        from emqx_tpu.apps.prometheus import collect
+        text = collect(w.node)
+        assert "emqx_pipeline_deliver_frame_rows" in text
+        assert "emqx_pipeline_deliver_frames_built" in text
+
+    def test_a_long_slice_yields_between_chunks_and_splits_no_run(self):
+        """One lane, 5,000 rows in runs of uneven length: a chunk ends
+        at the first run boundary 2,048 rows on, the loop gets a turn
+        between two chunks, and every session's run is one write."""
+        w = _World(False, lanes=1)
+        lengths = [1500, 700, 1, 2047, 300, 449, 3]
+        for i, n in enumerate(lengths):
+            w.chan(f"c{i}")
+        w.msg("t/0")
+        for i, n in enumerate(lengths):
+            for _ in range(n):
+                w.row(0, f"c{i}")
+        pool = w.pool
+        chunks, turns = [], []
+        real = pool._deliver_rows
+
+        def rows(plan, lo, hi):
+            chunks.append((plan.run_lo[lo], plan.run_lo[hi]))
+            real(plan, lo, hi)
+        pool._deliver_rows = rows
+
+        async def go():
+            async def ticker():
+                while len(chunks) < 3:
+                    turns.append(len(chunks))
+                    await asyncio.sleep(0)
+            t = asyncio.ensure_future(ticker())
+            out = await w.deliver()
+            await t
+            return out
+        counts, moved = run(go())
+        assert counts == [5000]
+        # 1500 + 700 pass 2,048 at 2,200; 1 + 2047 end at 4,248
+        assert chunks == [(0, 2200), (2200, 4248), (4248, 5000)]
+        assert {1, 2} <= set(turns)
+        assert [len(s.wire.out) for s in w.subs.values()] == [1] * 7
+        assert [s.session.deliver_count for s in w.subs.values()] \
+            == lengths
+        assert moved["pipeline.deliver.accept_us"] \
+            <= moved["pipeline.deliver.lane_us"]
+        assert moved["pipeline.deliver.frames_built"] == 1
+
+    def test_a_chunks_retry_reads_the_same_table_and_counts_once(self):
+        """The supervisor's per-chunk retry: the chunk fails at its
+        third run, is delivered again whole, and nothing is serialized
+        or counted a second time."""
+        w = _World(False, lanes=1)
+        assert w.pool.sup is not None
+        names = [f"c{i}" for i in range(5)]
+        for name in names:
+            w.chan(name)
+        for m in range(6):
+            w.msg(f"t/{m}", b"p%d" % m)
+            for name in names:
+                w.row(m, name)
+        broker = w.node.broker
+        third = sorted(w.sids.values())[2]
+
+        class Faulty(dict):
+            armed = True
+
+            def get(self, sid, default=None):
+                if sid == third and self.armed:
+                    self.armed = False
+                    raise RuntimeError("registry fault")
+                return dict.get(self, sid, default)
+        broker._subscribers = Faulty(broker._subscribers)
+        counts, moved = run(w.deliver())
+        assert counts == [5] * 6
+        assert moved["messages.delivered"] == 30
+        assert moved["pipeline.deliver.deliveries"] == 30
+        assert moved["pipeline.deliver.frames_built"] == 6
+        assert w.node.metrics.val("supervise.faults.lane_deliver") == 1
+        # at least once: the two runs before the fault went out twice
+        by_sid = sorted(w.subs.values(), key=lambda s: w.sids[s.clientid])
+        assert [len(s.wire.out) for s in by_sid] == [2, 2, 1, 1, 1]
+        assert by_sid[0].wire.out[0] == by_sid[0].wire.out[1] \
+            == by_sid[4].wire.out[0]
+
+    def test_a_raising_frame_entry_is_contained_to_its_run(self):
+        w = _World(False, lanes=1)
+        for name in ("a", "bad", "c"):
+            w.chan(name)
+        for m in range(3):
+            w.msg(f"t/{m}")
+            for name in ("a", "bad", "c"):
+                w.row(m, name)
+
+        def boom(data):
+            raise OSError("socket gone")
+        w.subs["bad"].send_frames = boom
+        counts, moved = run(w.deliver())
+        assert counts == [2] * 3
+        assert moved["pipeline.deliver.deliver_errors"] == 1
+        assert moved["pipeline.deliver.frame_rows"] == 6
+        assert moved["messages.delivered"] == 6
