@@ -503,12 +503,19 @@ class _CoverState:
     this new filter covered?" on the subscribe path, and the numpy
     CoverTables mirror backs the expansion-CSR APPEND region (a
     covered new filter becomes an append + small device upload, not a
-    rebuild). n_roots/n_covered feed stats()'s reduction factor."""
+    rebuild). n_roots/n_covered feed stats()'s reduction factor;
+    `wide` holds the roots the build found too wide to own anything
+    (`ops/cover.assign_owners`), `largest_segment` the most entries one
+    root's segment has."""
 
     __slots__ = ("trie", "root_words", "roots", "ct", "app_used",
-                 "level_cap", "n_roots", "n_covered", "incomplete")
+                 "level_cap", "n_roots", "n_covered", "incomplete",
+                 "wide", "largest_segment")
 
-    def __init__(self, roots, ct, level_cap, n_covered, incomplete):
+    def __init__(self, roots, ct, level_cap, n_covered, incomplete,
+                 wide, largest_segment):
+        self.wide = frozenset(int(f) for f in wide)
+        self.largest_segment = largest_segment
         self.roots = roots            # root fid array (covering set)
         self.trie = None              # HostTrie over roots, built
         self.root_words = None        # lazily on the first append try
@@ -648,8 +655,10 @@ class DeviceRouteEngine:
         self.shape_cap = shape_cap
         # the most candidates a covering snapshot's expansion holds for
         # one topic (`CoverTables.cand_pad`); a build takes less where
-        # its largest segment lets it. A topic whose matched roots own
-        # more flags `cover_overflow` and host-routes
+        # its largest segment lets it, and lets a root own only what
+        # the plane holds beside the other slots of the roots' match
+        # row (`_build_from_capture`). A topic whose matched roots own
+        # more between them flags `cover_overflow` and host-routes
         self.cover_cand_cap = min(4096,
                                   _next_pow2(max(256, 4 * match_cap)))
 
@@ -1009,7 +1018,11 @@ class DeviceRouteEngine:
         it). Returns False → the caller takes the overlay path, which
         is always correct; a False on an *eligible* snapshot counts
         toward the "covering" compaction reason (uncovered new filters
-        erode the covering reduction until a recompaction)."""
+        erode the covering reduction until a recompaction). Of the
+        roots that cover the filter it rides the smallest-fid one that
+        owns (a wide root's row would ride every topic under it), and
+        a wide one where nothing else covers it: the append region is
+        apart from the candidate plane, so that costs no lane."""
         b = self._built
         if b is None or b.cover is None or self._tables is None \
                 or not self.subscription_covering:
@@ -1034,7 +1047,12 @@ class DeviceRouteEngine:
 
         fid = len(b.fid_filter)
         k = cs.app_used
-        ct.app_root[k] = min(roots)
+        # under the narrowest company it can keep: a root that owns
+        # (an area's historian) before a wide one over it (the
+        # tenant's `#`, which every topic of the tenant would carry
+        # the row for); under the wide root where nothing else covers
+        ct.app_root[k] = min([r for r in roots if r not in cs.wide]
+                             or roots)
         ct.app_fid[k] = fid
         # dense order rank past every built filter's: appends deliver
         # in arrival order after the snapshot set, mirroring the
@@ -1350,8 +1368,19 @@ class DeviceRouteEngine:
                 dollar = np.fromiter((f.startswith("$") for f in filters),
                                      bool, n)
                 covs, inc = cover_mod.detect_covers(rows, lens, dollar)
-                owner = cover_mod.assign_owners(covs, inc)
+                # a root owns what the candidate plane can hold beside
+                # a root of its own in every other slot of the roots'
+                # match row (a slot a shape, or the NFA's match row:
+                # the roots' backend is not known yet), or nothing: no
+                # owning root passes the plane by its own segment
+                budget = max(0, self.cover_cand_cap
+                             - max(self.shape_cap, self.match_cap))
+                owner = cover_mod.assign_owners(covs, inc,
+                                                own_budget=budget)
                 covered = np.flatnonzero(owner >= 0)
+                wide = np.flatnonzero(cover_mod.fan_in(covs) > budget)
+                self.node.metrics.inc("routing.cover.wide_roots",
+                                      len(wide))
             if not len(covered):
                 b.cover_decision = "none_covered"
             else:
@@ -1380,7 +1409,8 @@ class DeviceRouteEngine:
                 # the fixed 256 lanes, 34.5 at 128, 25.4 at 64 (PR 36's
                 # builder), and at 256 the chooser left the chip
                 # (PERF.md, PR 38). A topic under two roots that own
-                # much overflows and host-routes, counted
+                # much overflows and host-routes, counted; under one it
+                # cannot (the budget above)
                 seg_max = 1 + int(np.bincount(owner[covered]).max())
                 slots = root_shapes if cover_shapes else self.match_cap
                 cover_np = cover_mod.build_cover_tables(
@@ -1390,7 +1420,8 @@ class DeviceRouteEngine:
                     cand_cap=min(self.cover_cand_cap,
                                  _next_pow2(seg_max + slots - 1)))
                 cover_state = _CoverState(
-                    sub_ids, cover_np, L, len(covered), int(inc.sum()))
+                    sub_ids, cover_np, L, len(covered), int(inc.sum()),
+                    wide, seg_max)
                 # pad the consume companions to filter_cap: cover-set
                 # churn APPENDS fids past n (spare padded SubTable rows
                 # deliver host-side via fid_rich), and the consume walk
@@ -2584,7 +2615,8 @@ class DeviceRouteEngine:
                     res.occur]
             if h.cache_info is not None and self._match_cache is not None:
                 out.append(res.match_counts)
-        for counted in (res.nfa_wide_steps, res.cover_candidates):
+        for counted in (res.nfa_wide_steps, res.cover_candidates,
+                        res.cover_roots):
             if counted is not None:
                 out.append(counted)
         return out
@@ -2822,9 +2854,12 @@ class DeviceRouteEngine:
         self._count_nfa_steps(h)
         if res.cover_candidates is not None:
             # a covering snapshot's window: the candidates the
-            # expansion verified, a [W] plane
+            # expansion verified and the matched roots they came from,
+            # a [W] plane each
             metrics.inc("routing.device.cover_candidates",
                         int(np.asarray(res.cover_candidates).sum()))
+            metrics.inc("routing.device.cover_roots",
+                        int(np.asarray(res.cover_roots).sum()))
         delta_bytes = self._materialize_delta(h)
         csr_probe_bytes = 0
         if cp is not None:
@@ -3774,10 +3809,13 @@ class DeviceRouteEngine:
             "nfa_narrow_steps": self.node.metrics.val(
                 "routing.device.nfa_narrow_steps"),
             # topics the match stage matched (any matcher), and of a
-            # covering snapshot's expansion the candidates it verified
-            # and the lanes its own caps sent to the host route
+            # covering snapshot's expansion the roots that entered it,
+            # the candidates it verified and the lanes its own caps
+            # sent to the host route
             "match_lanes": self.node.metrics.val(
                 "routing.device.match_lanes"),
+            "cover_roots": self.node.metrics.val(
+                "routing.device.cover_roots"),
             "cover_candidates": self.node.metrics.val(
                 "routing.device.cover_candidates"),
             "cover_overflow": self.node.metrics.val(
@@ -3828,6 +3866,12 @@ class DeviceRouteEngine:
                       "covered": b.cover.n_covered,
                       "appends": b.cover.app_used,
                       "incomplete": b.cover.incomplete,
+                      # roots too wide to own anything, the most
+                      # entries one root's segment holds, and the
+                      # candidate plane the build sized for it
+                      "wide_roots": len(b.cover.wide),
+                      "largest_segment": b.cover.largest_segment,
+                      "cand_cap": int(b.cover.ct.cand_pad.shape[0]),
                       "reduction": round(
                           (b.cover.n_roots + b.cover.n_covered)
                           / max(1, b.cover.n_roots), 2)}

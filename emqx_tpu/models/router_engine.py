@@ -79,9 +79,12 @@ class RouteResult(NamedTuple):
     # sub-batch ([W] from a window program; a window with a plan expands
     # once, over its miss lanes, and reports it in row 0), and [B] the
     # expansion's own part of `overflow`: what
-    # routing.device.cover_candidates / .cover_overflow count
+    # routing.device.cover_candidates / .cover_overflow count; and, as
+    # `cover_candidates`, the matched roots that entered the expansion
+    # (routing.device.cover_roots)
     cover_candidates: jax.Array = None
     cover_overflow: jax.Array = None
+    cover_roots: jax.Array = None
     # `route_window`'s optional stages, None where the stage did not
     # run. The fid spaces of `matches` (built-snapshot fids) and
     # `delta.fids` (the engine's delta fids) are disjoint by
@@ -163,7 +166,7 @@ def post_match(subs: SubTable, mr: MatchResult, cursors: jax.Array,
         overflow=overflow, new_cursors=sp.new_cursors, occur=sp.occur,
         match_overflow=mr.overflow, nfa_wide_steps=mr.wide_steps,
         fanout_overflow=fr.overflow, cover_candidates=mr.cover_candidates,
-        cover_overflow=mr.cover_overflow)
+        cover_overflow=mr.cover_overflow, cover_roots=mr.cover_roots)
 
 
 @functools.partial(
@@ -356,22 +359,22 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
 
         def in_row_0(count):
             """The one match over the miss lanes: what it counted (the
-            trie's wide steps, a cover's candidates), reported by the
-            window's first sub-batch."""
+            trie's wide steps, a cover's candidates and roots), reported
+            by the window's first sub-batch."""
             return None if count is None else jnp.zeros(
                 plan.inv.shape[0], jnp.int32).at[0].set(count)
 
         lanes = (plan.inv, in_row_0(mr.wide_steps),
-                 in_row_0(mr.cover_candidates))
+                 in_row_0(mr.cover_candidates), in_row_0(mr.cover_roots))
 
         def matched(lane):
-            inv_k, wide_k, cand_k = lane
+            inv_k, wide_k, cand_k, roots_k = lane
             return MatchResult(
                 matches=um.matches[inv_k], counts=um.counts[inv_k],
                 overflow=um.overflow[inv_k], wide_steps=wide_k,
                 cover_candidates=cand_k,
                 cover_overflow=None if um.cover_overflow is None
-                else um.cover_overflow[inv_k])
+                else um.cover_overflow[inv_k], cover_roots=roots_k)
 
     def routed(cur, lane, mh_k):
         with jax.named_scope("match"):
@@ -385,7 +388,8 @@ def _window_scan(tables, cursors, topics, lens, is_dollar, msg_hash,
         r = _empty_step(jax.eval_shape(routed, cur, lane, mh_k)[1], cur)
         if plan is not None:
             # the one match's counts ride in row 0 whatever it holds
-            r = r._replace(nfa_wide_steps=lane[1], cover_candidates=lane[2])
+            r = r._replace(nfa_wide_steps=lane[1], cover_candidates=lane[2],
+                           cover_roots=lane[3])
         return cur, r
 
     # a window is padded to its class's W and every stage of the step is

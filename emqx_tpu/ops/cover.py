@@ -46,6 +46,15 @@ lane order of ops/match's valid-first compaction is the plus-choice
 bits read LSB-first (exact children sort before plus children every
 step), so the key is `((step*2 + is_exact) << level_bits) | plus_bits`.
 
+What a root may own (`assign_owners`): a filter that covers more
+filters than the candidate plane leaves a root (`own_budget`: the
+engine's candidate ceiling less the other slots of the roots' match
+row) is WIDE; it owns nothing, stays in the match set with a segment
+of itself alone, and does not stop the filters it covers from being
+roots and owners themselves. Under a tenant-wide `org/#` over
+thousands of filters the area historians `org/area/#` still own their
+few dozen, and no topic passes the plane because of one root's segment.
+
 Where covering engages (`covering_decision`): only on a snapshot
 whose FULL set does not fit the shape-hash backend, so the off twin
 would run the trie NFA — the roots then match under shapes (where they
@@ -298,28 +307,56 @@ def detect_covers(words: np.ndarray, lens: np.ndarray,
     return covers, incomplete
 
 
+def fan_in(covers: list) -> np.ndarray:
+    """[F] how many filters each filter covers, from `detect_covers`'
+    lists (an `incomplete` filter's list is empty: it counts toward
+    none of its covers)."""
+    F = len(covers)
+    if not F:
+        return np.zeros(0, np.int64)
+    return np.bincount(np.concatenate(covers).astype(np.int64),
+                       minlength=F)
+
+
 def assign_owners(covers: list, incomplete: np.ndarray, *,
                   own_budget: int = 256) -> np.ndarray:
     """Pick one covering ROOT per covered filter → owner[fid] (-1 =
-    stays in the covering set). Roots are filters nothing covers; a
-    covered filter's owner is its smallest-fid covering root (covering
-    is transitive, so a maximal cover of B is itself uncovered and
-    appears in B's cover set). `own_budget` caps one cover's owned
-    count — past it, further covered filters stay roots, bounding the
-    per-topic expansion fan (candidate capacity stays honest)."""
+    stays in the covering set): a root owns what it can hold, or
+    nothing.
+
+    A filter is WIDE when it covers more than `own_budget` filters
+    (`fan_in`). A wide filter owns nothing and is left out of every
+    other filter's cover set here, so a filter that only wide filters
+    cover is a root and may own (under a tenant-wide `org/#` the area
+    historians `org/area/#` are roots again and own their filters).
+    A wide filter is itself a root with a segment of itself alone: no
+    narrower filter can cover it, since covering is transitive and its
+    fan-in would be the larger. An `incomplete` filter stays a root.
+    Every other covered filter's owner is its smallest-fid covering
+    root among the non-wide: a maximal non-wide cover of B has no
+    non-wide cover itself and appears in B's cover set, so one exists.
+    A root's segment is then at most 1 + `own_budget`, whatever the
+    order of the fids; on a set without a wide filter the assignment
+    is what a plain "smallest-fid covering root" gives.
+
+    Exactness: an owned filter's owner is in the match set and covers
+    it, so every topic the filter matches reaches its segment, where
+    it is verified; every other filter is in the match set itself."""
     F = len(covers)
     owner = np.full(F, -1, np.int64)
-    is_root = np.array([len(c) == 0 for c in covers]) | incomplete
-    owned = np.zeros(F, np.int64)
-    for fid in range(F):
-        if is_root[fid]:
-            continue
-        for a in sorted(int(x) for x in covers[fid]):
-            if is_root[a] and owned[a] < own_budget:
-                owner[fid] = a
-                owned[a] += 1
-                break
-    return owner
+    sizes = np.fromiter((len(c) for c in covers), np.int64, F)
+    if not sizes.any():
+        return owner
+    by = np.concatenate(covers).astype(np.int64)      # the covering fid
+    of = np.repeat(np.arange(F), sizes)               # the covered one
+    may_own = np.bincount(by, minlength=F) <= own_budget    # `fan_in`
+    held = np.zeros(F, bool)        # covered by a filter that may own
+    held[of[may_own[by]]] = True
+    is_root = ~held | np.asarray(incomplete, bool)
+    pick = may_own[by] & is_root[by] & ~is_root[of]
+    first = np.full(F, F, np.int64)
+    np.minimum.at(first, of[pick], by[pick])
+    return np.where(first < F, first, owner)
 
 
 # ---- table builder -------------------------------------------------------
@@ -478,8 +515,10 @@ def cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
     (`models/router_engine._match_holes`). Overflow = base overflow
     | candidate-capacity overflow | true count past the output width
     (the same condition the off twin flags); the last two are reported
-    apart as `cover_overflow`, beside the candidates verified. Traced
-    under scope `cover` (inside the caller's `match`)."""
+    apart as `cover_overflow`, beside the candidates verified and the
+    roots that entered (`cover_roots`: the matched roots of the real
+    lanes; a padding lane matches none). Traced under scope `cover`
+    (inside the caller's `match`)."""
     import jax
 
     with jax.named_scope("cover"):
@@ -554,7 +593,8 @@ def _cover_expand(ct: CoverTables, mr, topics, lens, is_dollar):
         overflow=mr.overflow | own_oflow, wide_steps=mr.wide_steps,
         cover_candidates=(fids >= 0).sum(dtype=jnp.int32)
         + (hit & live[None, :]).sum(dtype=jnp.int32),
-        cover_overflow=own_oflow)
+        cover_overflow=own_oflow,
+        cover_roots=(mr.matches >= 0).sum(dtype=jnp.int32))
 
 
 # ---- host-side cover lookup (append path) --------------------------------

@@ -60,9 +60,11 @@ class MatchResult(NamedTuple):
     # verified for this batch (each matched root's own entry, its owned
     # filters and the append rows riding a lane; padding excluded), and
     # [B] bool, the expansion's own part of `overflow` (a lane's
-    # candidates passed `cand_cap`, or its verified matches the row)
+    # candidates passed `cand_cap`, or its verified matches the row),
+    # and scalar int32, the matched roots that entered the expansion
     cover_candidates: jax.Array = None
     cover_overflow: jax.Array = None
+    cover_roots: jax.Array = None
 
 
 def edge_lookup(tables: TrieTables, parent: jax.Array, word: jax.Array) -> jax.Array:

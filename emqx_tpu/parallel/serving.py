@@ -67,6 +67,10 @@ from emqx_tpu.utils import topic as T
 # stands down (counted) rather than corrupting rows
 _EXCHANGE_MAX_GFID = 1 << 24
 
+# a covering shard's candidate lane (`CoverTables.cand_pad`): uniform
+# across shards and rebuilds, so that the stacked pytree keeps its shape
+_COVER_CAND_CAP = 256
+
 
 def resolve_device_exchange(configured=None) -> bool:
     """The one device-exchange resolution (ISSUE 15): config
@@ -452,8 +456,8 @@ class ShardedRouteServer:
         # alone decides attachment: when on, every shard carries cover
         # tables — an identity CSR (every filter its own root) where the
         # shard has no cover relations. Uniform constants (match_cap out
-        # width, 256-candidate verify lane, caps["filters"] verify rows,
-        # 1-row append region — mesh churn rides the per-shard rebuild,
+        # width, `_COVER_CAND_CAP`-candidate verify lane,
+        # caps["filters"] verify rows, 1-row append region — mesh churn rides the per-shard rebuild,
         # not the append path) keep shard slices stack/update-compatible.
         cover_np = None
         roots = None
@@ -466,14 +470,20 @@ class ShardedRouteServer:
                 if n >= 2:
                     covers, inc = cover_mod.detect_covers(
                         rows[:n], lens, dollar)
-                    owner = cover_mod.assign_owners(covers, inc)
+                    # a root owns what the fixed candidate lane holds
+                    # beside a root in every other slot of the NFA's
+                    # match row, or nothing (`assign_owners`): no
+                    # owning root overflows the lane by its own segment
+                    owner = cover_mod.assign_owners(
+                        covers, inc, own_budget=max(
+                            0, _COVER_CAND_CAP - self.match_cap))
                 else:
                     owner = np.full(n, -1, np.int64)
                 keys = cover_mod.trie_order_keys(rows[:n], lens)
                 cover_np = cover_mod.build_cover_tables(
                     rows[:n], lens, owner, keys,
                     fid_cap=caps["filters"], out_width=self.match_cap,
-                    cand_cap=256, verify_cap=caps["filters"],
+                    cand_cap=_COVER_CAND_CAP, verify_cap=caps["filters"],
                     append_cap=1)
                 roots = np.flatnonzero(owner < 0).astype(np.int64)
                 b.cover_roots = int(roots.size)

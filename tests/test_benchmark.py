@@ -14,11 +14,13 @@ import pytest
 
 from benchmark.tests import test_fleet_bcast as _fleet
 from benchmark.tests import test_mixed_zipf as _zipf
+from benchmark.tests import test_trace_loop as _loop
 from benchmark.tests import test_trace_readers as _readers
 from benchmark.tests import test_umbrella_cover as _umbrella
 from benchmark.tests.test_fleet_bcast import *      # noqa: F401,F403
 from benchmark.tests.test_mixed_zipf import *       # noqa: F401,F403
 from benchmark.tests.test_pieces import *           # noqa: F401,F403
+from benchmark.tests.test_tenant_umbrella import *  # noqa: F401,F403
 from benchmark.tests.test_trace_loop import *       # noqa: F401,F403
 from benchmark.tests.test_trace_readers import *    # noqa: F401,F403
 from benchmark.tests.test_umbrella_cover import *   # noqa: F401,F403
@@ -73,3 +75,28 @@ def test_the_cell_reports_its_34_metrics_and_the_three_new_ones():  # noqa: F811
     held="test_fleet_bcast_reports_its_46_metrics"))
 def test_fleet_bcast_still_reports_its_33_metrics():        # noqa: F811
     _umbrella.test_fleet_bcast_still_reports_its_33_metrics()
+
+
+_PR42 = (
+    "benchmark/tests/test_trace_loop.py pins {what}; PR 42 appends "
+    "`tenant-umbrella.flood` to every list `umbrella-cover.flood` is on "
+    "and one metric of its own, and may not edit that file: the pin is a "
+    "`benchmark` PR's to move. Everything else the case holds is held, "
+    "at the new lists, by test_tenant_umbrella.py::{held} (CHANGES.md, "
+    "PR 42)")
+
+
+@pytest.mark.xfail(strict=True, reason=_PR42.format(
+    what="the thirteen `loop_*` / `lane_*` / `egress_*` entries as the "
+         "manifest's last, each listed for PR 40's five cells",
+    held="test_the_thirteen_loop_entries_fit_their_files_at_the_new_lists"))
+def test_the_thirteen_entries_are_the_manifests_last_and_fit_their_files():  # noqa: F811,E501
+    _loop.test_the_thirteen_entries_are_the_manifests_last_and_fit_their_files()  # noqa: E501
+
+
+@pytest.mark.xfail(strict=True, reason=_PR42.format(
+    what="`umbrella-cover.flood` as the manifest's last cell and its "
+         "three cover metrics as listed for it alone",
+    held="test_umbrella_cover_still_reports_its_47_and_shares_its_three"))
+def test_umbrella_cover_reports_its_47_metrics_and_its_own_three():  # noqa: F811,E501
+    _loop.test_umbrella_cover_reports_its_47_metrics_and_its_own_three()

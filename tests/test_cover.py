@@ -161,13 +161,20 @@ class TestDetection:
         filters = ["a/#", "a/1", "a/2", "a/3", "b/c"]
         rows, lens, dollar = _encode(intern, filters)
         covers, inc = C.detect_covers(rows, lens, dollar)
+        assert C.fan_in(covers).tolist() == [3, 0, 0, 0, 0]
         owner = C.assign_owners(covers, inc)
         assert owner[0] == -1 and owner[4] == -1       # roots
         assert list(owner[1:4]) == [0, 0, 0]
-        # budget: each cover owns at most own_budget covered filters
+        # budget: a cover owns the filters it covers where the budget
+        # holds them all ...
+        assert C.assign_owners(covers, inc, own_budget=3).tolist() \
+            == owner.tolist()
+        # ... and nothing where it does not (the parent let `a/#` own
+        # the first two by fid and left the third a root): a wide root
+        # stays in the match set alone, and so does all it covers
         owner2 = C.assign_owners(covers, inc, own_budget=2)
-        assert (owner2[1:4] == 0).sum() == 2
-        assert (owner2 == -1).sum() == 3               # overflow -> root
+        assert (owner2[1:4] == 0).sum() == 0
+        assert (owner2 == -1).sum() == 5               # wide -> all roots
 
     def test_order_keys_reproduce_trie_emission(self):
         import jax.numpy as jnp
@@ -466,6 +473,284 @@ class TestAppendAndCache:
         assert eng._compaction_reason() in (None, "covering",
                                             "overflow", "churn",
                                             "tombstones")
+
+
+    def test_a_new_filter_under_a_wide_root(self):
+        """Subscribed after the build: a filter only the tenant's wide
+        `t/#` covers is appended under it (nothing narrower can carry
+        it), one under `t/#` AND an owning historian under the
+        historian (the min-fid root is the wide one). Both are held to
+        the off twin (counts, per-session order) and to the host's
+        `router.match` message by message."""
+        filters = _tenant(areas=2, per_area=100, alone=3)
+        on, off = _mk_twin_nodes(filters, shape_cap=3)
+        topics = ["t/a0/c0", "t/a1/q/c1", "t/solo/x0", "t/solo/new",
+                  "t/a0/new", "t/a0/deeper/new", "t/zz", "u/v"]
+        _route_and_compare(on, off, topics, b"built")
+        eng = on[0].device_engine
+        cs = eng._built.cover
+        fid_of = eng._built.fid_of
+        assert cs.wide == {fid_of["t/#"]} \
+            and eng.stats()["cover"]["wide_roots"] == 1
+        late = ["t/solo/new", "t/a0/new", "t/a0/+/new"]
+        for node, sinks, _sids in (on, off):
+            for i, f in enumerate(late):
+                s = Sink()
+                node.broker.subscribe(
+                    node.broker.register(s, f"late{i}"), f, {"qos": 0})
+                sinks[f] = s
+        m = on[0].metrics
+        assert m.val("routing.cover.appends") == 3 \
+            and m.val("routing.cover.append_rejects") == 0
+        assert eng.stats()["delta_filters"] == 0
+        under = {eng._built.fid_filter[int(f)]:
+                 eng._built.fid_filter[int(r)]
+                 for f, r in zip(cs.ct.app_fid[:3], cs.ct.app_root[:3])}
+        assert under == {"t/solo/new": "t/#", "t/a0/new": "t/a0/#",
+                         "t/a0/+/new": "t/a0/#"}
+        for rnd in range(2):
+            _route_and_compare(on, off, topics, b"late%d" % rnd)
+        # the host's own match, message by message: who got what
+        node, sinks, _sids = on
+        for t in topics:
+            want = sorted(node.router.match(t))
+            got = sorted(f for f, s in sinks.items()
+                         if (f, t, b"late1") in s.got)
+            assert got == want, t
+        assert sorted(node.router.match("t/a0/deeper/new")) \
+            == ["t/#", "t/a0/#", "t/a0/+/new"]
+        assert m.val("routing.device.host_fallback") == 0
+
+
+# ---------------- a root owns what it can hold, or nothing ----------
+
+def _parent_assign_owners(covers, incomplete, own_budget=256):
+    """`assign_owners` as it stood before PR 42: a covered filter goes
+    to its smallest-fid covering root (a filter nothing covers) while
+    that root has owned fewer than `own_budget`."""
+    F = len(covers)
+    owner = np.full(F, -1, np.int64)
+    is_root = np.array([len(c) == 0 for c in covers]) | incomplete
+    owned = np.zeros(F, np.int64)
+    for fid in range(F):
+        if is_root[fid]:
+            continue
+        for a in sorted(int(x) for x in covers[fid]):
+            if is_root[a] and owned[a] < own_budget:
+                owner[fid] = a
+                owned[a] += 1
+                break
+    return owner
+
+
+def _detected(filters):
+    rows, lens, dollar = _encode(InternTable(), filters)
+    covers, inc = C.detect_covers(rows, lens, dollar)
+    return rows, lens, covers, inc
+
+
+def _tenant(areas=3, per_area=4, alone=2):
+    """`t/#` over `areas` historians `t/a{i}/#`, each over `per_area`
+    filters, and `alone` exact filters only `t/#` covers."""
+    filters = ["t/#"]
+    for i in range(areas):
+        filters.append(f"t/a{i}/#")
+        filters += [f"t/a{i}/+/c{j}" if j % 2 else f"t/a{i}/c{j}"
+                    for j in range(per_area)]
+    filters += [f"t/solo/x{k}" for k in range(alone)]
+    return filters + ["u/v"]
+
+
+class TestOwnWhatYouCanHold:
+    def test_a_wide_root_owns_nothing_and_frees_the_roots_below(self):
+        filters = _tenant()
+        _rows, _lens, covers, inc = _detected(filters)
+        fan = C.fan_in(covers)
+        assert fan[0] == 3 * 5 + 2 and fan[1] == 4 and fan[2] == 0
+        owner = C.assign_owners(covers, inc, own_budget=4)
+        name = {i: f for i, f in enumerate(filters)}
+        owned_by = {name[i]: (name[o] if o >= 0 else None)
+                    for i, o in enumerate(owner.tolist())}
+        # the tenant's umbrella is wide: it owns nothing, stays a root
+        assert owned_by["t/#"] is None
+        assert "t/#" not in owned_by.values()
+        for i in range(3):
+            # a historian only the wide root covers is a root again ...
+            assert owned_by[f"t/a{i}/#"] is None
+            # ... and owns its own
+            assert all(owned_by[f] == f"t/a{i}/#" for f in filters
+                       if f.startswith(f"t/a{i}/") and f != f"t/a{i}/#")
+        # what only the wide root covers stays in the match set
+        assert owned_by["t/solo/x0"] is None \
+            and owned_by["t/solo/x1"] is None and owned_by["u/v"] is None
+        # the parent: `t/#` owned the first four by fid, no historian
+        # owned anything, the other thirteen stayed roots
+        old = _parent_assign_owners(covers, inc, own_budget=4)
+        assert (old == 0).sum() == 4 and (old > 0).sum() == 0 \
+            and (old == -1).sum() == len(filters) - 4
+
+    def test_nested_wide_roots(self):
+        """`#` over `t/#` over the historians: both wide, both own
+        nothing, the historians own; a filter under both and nothing
+        else is a root."""
+        filters = ["#"] + _tenant()
+        _rows, _lens, covers, inc = _detected(filters)
+        owner = C.assign_owners(covers, inc, own_budget=4)
+        wide = np.flatnonzero(C.fan_in(covers) > 4)
+        assert [filters[i] for i in wide] == ["#", "t/#"]
+        assert (owner[wide] == -1).all()
+        assert not np.isin(owner, wide).any()
+        hist = [i for i, f in enumerate(filters)
+                if f.startswith("t/a") and f.endswith("/#")]
+        assert (owner[hist] == -1).all()
+        assert sorted(np.bincount(owner[owner >= 0]).nonzero()[0]) == hist
+        assert owner[filters.index("u/v")] == -1     # only `#` covers it
+
+    @pytest.mark.parametrize("covered,owns", [(6, True), (7, False)])
+    def test_fan_in_at_the_budget_and_one_past_it(self, covered, owns):
+        filters = ["k/#"] + [f"k/f{i}" for i in range(covered)]
+        _rows, _lens, covers, inc = _detected(filters)
+        owner = C.assign_owners(covers, inc, own_budget=6)
+        assert owner[0] == -1
+        assert (owner[1:] == (0 if owns else -1)).all()
+        # whatever the fids' order, a root's segment holds 1 + budget
+        # at most
+        seg = 1 + np.bincount(owner[owner >= 0], minlength=1).max()
+        assert seg <= 7
+
+    def test_an_incomplete_filter_stays_a_root_and_may_own(self):
+        filters = ["s/#", "s/+/t", "s/u/t", "s/u/v"]
+        _rows, _lens, covers, inc = _detected(filters)
+        # `s/+/t`'s own cover set overflowed at detection: it is kept
+        # as a root (its list is empty) and still owns what it covers
+        covers[1] = np.zeros(0, np.int64)
+        inc = inc.copy()
+        inc[1] = True
+        owner = C.assign_owners(covers, inc)
+        assert owner.tolist() == [-1, -1, 0, 0]
+        # with `s/#` wide, `s/u/t` goes to the incomplete root, and
+        # `s/u/v`, which only the wide root covers, stays
+        assert C.assign_owners(covers, inc, own_budget=1).tolist() \
+            == [-1, -1, 1, -1]
+
+    @pytest.mark.parametrize("budget", [1, 4, 16, 256])
+    def test_every_filter_is_in_exactly_one_segment(self, budget):
+        filters = ["#"] + _tenant(areas=4, per_area=5, alone=3)
+        rows, lens, covers, inc = _detected(filters)
+        owner = C.assign_owners(covers, inc, own_budget=budget)
+        ct = C.build_cover_tables(rows, lens, owner,
+                                  C.trie_order_keys(rows, lens),
+                                  fid_cap=64, out_width=16, cand_cap=64)
+        F = len(filters)
+        held = ct.exp_fid[:ct.exp_start[F]]
+        assert sorted(held.tolist()) == list(range(F))
+        seg = np.diff(ct.exp_start[:F + 1])
+        # a covered filter's segment is empty, a root's is itself and
+        # what it owns, and no segment passes 1 + budget
+        assert (seg[owner >= 0] == 0).all() and (seg[owner < 0] >= 1).all()
+        assert seg.max() <= 1 + budget
+        for fid in np.flatnonzero(owner >= 0):
+            o = int(owner[fid])
+            assert owner[o] == -1 and o in covers[fid]
+
+    def test_without_a_wide_filter_the_assignment_is_the_parents(self):
+        from benchmark.populations import umbrella_cover
+        pop = umbrella_cover.Population({"areas": 7}, 16)
+        _rows, _lens, covers, inc = _detected(pop.filters())
+        assert C.fan_in(covers).max() == 49 and not inc.any()
+        for budget in (49, 192, 256):
+            new = C.assign_owners(covers, inc, own_budget=budget)
+            old = _parent_assign_owners(covers, inc, own_budget=budget)
+            assert new.dtype == old.dtype and (new == old).all()
+        assert (C.assign_owners(covers, inc) >= 0).sum() == 7 * 49
+        # and on the generator's own cover-heavy set, nested umbrellas
+        # and all, at a budget nothing passes
+        from tools.workloads import cover_heavy_filters
+        _r, _l, covers, inc = _detected(
+            sorted(set(cover_heavy_filters(300, cover_ratio=0.5))))
+        wide_at = int(C.fan_in(covers).max())
+        assert (C.assign_owners(covers, inc, own_budget=wide_at)
+                == _parent_assign_owners(covers, inc, wide_at)).all()
+
+    def test_an_empty_set_and_a_cover_free_one(self):
+        assert C.assign_owners([], np.zeros(0, bool)).shape == (0,)
+        assert C.fan_in([]).shape == (0,)
+        none = [np.zeros(0, np.int64)] * 3
+        assert C.assign_owners(none, np.zeros(3, bool)).tolist() == [-1] * 3
+        assert C.fan_in(none).tolist() == [0, 0, 0]
+
+
+def _engine_with_budget(filters, cand_cap, shape_cap=2):
+    """Covering twins whose engines give a root a budget of
+    `cand_cap` - 64 (the NFA's match row), set before the first
+    build."""
+    on, off = _mk_twin_nodes(filters, shape_cap=shape_cap)
+    on[0].device_engine.cover_cand_cap = cand_cap
+    return on, off
+
+
+class TestTheEnginesBudget:
+    @pytest.mark.parametrize("covered", [64, 65])
+    def test_no_owning_root_passes_the_candidate_plane(self, covered):
+        """The engine's budget is its candidate ceiling less the other
+        slots of the roots' match row: at 128 a root owns 64 filters
+        (a segment of 65 in a plane of 128), and one that covers 65
+        owns nothing. Either way no lane overflows the expansion."""
+        filters = ["k/#"] + [f"k/f{i}" if i % 2 else f"k/+/g{i}"
+                             for i in range(covered)] + ["z/y"]
+        on, off = _engine_with_budget(filters, 128)
+        topics = [f"k/f{i}" for i in range(1, covered, 2)] \
+            + [f"k/q/g{i}" for i in range(0, covered, 2)] \
+            + ["k/none", "z/y"]
+        for rnd in range(2):
+            _route_and_compare(on, off, topics, b"b%d" % rnd)
+        st = on[0].device_engine.stats()
+        m = on[0].metrics
+        assert m.val("routing.device.cover_overflow") == 0 \
+            and m.val("routing.device.host_fallback") == 0
+        if covered == 64:
+            assert st["cover_decision"] == "engaged"
+            assert st["cover"]["wide_roots"] == 0 \
+                and st["cover"]["largest_segment"] == 65 \
+                and st["cover"]["cand_cap"] == 128 \
+                and st["cover"]["covered"] == 64
+            assert m.val("routing.cover.wide_roots") == 0
+            # one root a topic: `k/#`, or `z/y` its own
+            assert m.val("routing.device.cover_roots") \
+                == m.val("routing.device.match_lanes") == len(topics)
+        else:
+            # the one cover relation's root is wide: nothing is covered
+            assert st["cover_decision"] == "none_covered" \
+                and st["cover"] is None
+            assert m.val("routing.cover.wide_roots") == 1
+
+    def test_a_wide_root_over_owning_historians_on_the_served_path(self):
+        filters = _tenant(areas=3, per_area=70, alone=4)
+        on, off = _engine_with_budget(filters, 256, shape_cap=3)
+        topics = [f"t/a{i}/c{j}" for i in range(3) for j in (0, 2)] \
+            + [f"t/a{i}/w/c{j}" for i in range(3) for j in (1, 3)] \
+            + ["t/solo/x0", "t/other", "u/v", "none/x"]
+        for rnd in range(2):
+            _route_and_compare(on, off, topics, b"w%d" % rnd)
+        st = on[0].device_engine.stats()
+        assert st["cover_decision"] == "engaged"
+        # `t/#` covers 3 * 71 + 4 = 217 > 192: wide; the historians own
+        assert st["cover"]["wide_roots"] == 1 \
+            and st["cover"]["largest_segment"] == 71 \
+            and st["cover"]["covered"] == 3 * 70 \
+            and st["cover"]["roots"] == 1 + 3 + 4 + 1
+        m = on[0].metrics
+        assert m.val("routing.cover.wide_roots") == 1
+        assert m.val("routing.device.cover_overflow") == 0 \
+            and m.val("routing.device.host_fallback") == 0
+        # per topic: 12 under `t/#` and a historian, 2 under `t/#` and
+        # nothing or one exact filter, `u/v` its own, one nothing
+        # (two rounds: a batch this small takes no match-cache plan)
+        assert m.val("routing.device.match_lanes") == 2 * len(topics)
+        assert m.val("routing.device.cover_roots") \
+            == 2 * (12 * 2 + 2 + 1 + 1 + 0) == st["cover_roots"]
+        assert m.val("routing.device.cover_candidates") \
+            == 2 * (12 * (1 + 71) + 2 + 1 + 1)
 
 
 # ---------------- knob & surfaces ----------------
@@ -779,3 +1064,67 @@ def test_mesh_twin_bit_identical(route):
     (c_on, got_on), (c_off, got_off) = results
     assert c_on == c_off
     assert got_on == got_off
+
+
+def test_mesh_serves_a_tenant_wide_umbrella():
+    """The mesh build inherits `assign_owners`' rule: the benchmark's
+    `tenant_umbrella` population at its rehearsal size over two shards
+    (each `org{k}/#` covers ~300 of its shard's filters, past the
+    budget the shard's fixed candidate lane leaves: 256 less the match
+    row's 64), delivery sets held to the population's closed form and
+    to the host's `router.match`, with no lane sent to the host; and
+    no shard's owning root can pass that lane by its own segment."""
+    from benchmark.populations import tenant_umbrella
+    from emqx_tpu.parallel import serving
+    pop = tenant_umbrella.Population({"areas": 12, "orgs": 2}, 16)
+    node = Node({"broker": {
+        "multichip": {"enable": True, "devices": 2, "dp": 1,
+                      "max_batch": 64},
+        "device_min_batch": 1, "subscription_covering": True}})
+    sinks = [Sink() for _ in range(pop.conns)]
+    for c, s in enumerate(sinks):
+        sid = node.broker.register(s, f"c{c}")
+        for f, q in pop.subscriptions(c):
+            node.broker.subscribe(sid, f, {"qos": q})
+    eng = node.device_engine
+    eng.rebuild()
+    budget = serving._COVER_CAND_CAP - eng.match_cap
+    assert budget == 192
+    segs = []
+    for b in eng._builts:
+        # a shard's cover state as its build left it: detect again and
+        # hold the owners to the rule at the budget the build passes
+        rows, lens, dollar = _encode(InternTable(), b.fid_filter)
+        covers, inc = C.detect_covers(rows, lens, dollar)
+        owner = C.assign_owners(covers, inc, own_budget=budget)
+        assert b.cover_covered == (owner >= 0).sum() > 0
+        assert b.cover_roots == (owner < 0).sum()
+        wide = np.flatnonzero(C.fan_in(covers) > budget)
+        assert len(wide) >= 1 and not np.isin(owner, wide).any()
+        assert all(b.fid_filter[w].count("/") == 1 for w in wide)
+        segs.append(1 + int(np.bincount(owner[owner >= 0]).max()))
+    # an owning root's segment and a root in every other slot of the
+    # match row fit the fixed lane
+    assert max(segs) + eng.match_cap - 1 <= serving._COVER_CAND_CAP
+    # (a filter's historian may live on the other shard: fewer than
+    # the 12 * 49 a single table covers)
+    assert 0 < eng.stats()["cover"]["covered"] \
+        == sum(b.cover_covered for b in eng._builts) <= 12 * 49
+    rng = np.random.default_rng(42)
+    keys = rng.integers(0, int(np.prod(pop.dims)), 120)
+    msgs = [mkmsg(pop.topic(int(k)), b"%d" % i) for i, k in enumerate(keys)]
+    counts = []
+    for lo in range(0, len(msgs), 60):
+        counts += eng.route_batch(msgs[lo:lo + 60], wait=True)
+    want = pop.expect(keys)
+    assert counts == (want >= 0).sum(axis=1).tolist()
+    owner_of = {f: c for c in range(pop.conns)
+                for f, _q in pop.subscriptions(c)}
+    for i, m in enumerate(msgs):
+        got = sorted((c, f) for c, s in enumerate(sinks)
+                     for f, t, p in s.got if p == b"%d" % i)
+        assert got == sorted((owner_of[f], f)
+                             for f in node.router.match(m.topic)), m.topic
+        assert sorted(c for c, _f in got) == sorted(
+            int(x) for x in want[i] if x >= 0)
+    assert node.metrics.val("routing.device.host_fallback") == 0

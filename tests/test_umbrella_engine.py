@@ -14,9 +14,9 @@ is held bit-equal, in delivery sets and per-session order, to
 
 and the expansion's counters (`routing.device.match_lanes`,
 `.cover_candidates`, `.cover_overflow`) are held to what the window
-did. One case gives the engine a candidate capacity an umbrella's
-segment passes: the new overflow counter moves and the host serves the
-lane. A cover-free snapshot's program reports what the parent's did.
+did. One case puts a second owning root across an umbrella, so that
+the topics under both pass the candidate plane: the overflow counter
+moves and the host serves the lane. A cover-free snapshot's program reports what the parent's did.
 """
 
 import numpy as np
@@ -184,29 +184,48 @@ def test_the_window_kinds_equal_the_off_twin_and_the_host(mode):
 
 
 def test_candidates_past_cand_cap_go_to_the_host_and_are_counted():
-    """An umbrella's segment holds 50 filters; with room for 32
-    candidates every topic under an umbrella flags the expansion's own
-    overflow, the host route serves it (same deliveries as the twin),
-    and `routing.device.cover_overflow` counts exactly those lanes."""
+    """An umbrella's segment holds 50 filters and the build gives the
+    plane 64 lanes. A second owning root across it (`+/area0/v5/#`
+    over 40 filters of other tenants: neither covers the other) puts
+    91 candidates on the topics under both: those lanes flag the
+    expansion's own overflow, the host route serves them (same
+    deliveries as the twin), and `routing.device.cover_overflow`
+    counts exactly them. One root's own segment cannot do that any
+    more: a root whose covered set the plane cannot hold owns nothing
+    (`ops/cover.assign_owners`, tests/test_cover.py)."""
     pop = umbrella_cover.Population({"areas": AREAS}, CONNS)
     conf = {"topic_dedup": False}
-    on, on_sinks = _node(True, pop, **conf)
-    off, off_sinks = _node(False, pop, **conf)
-    on.device_engine.cover_cand_cap = 32
+    across = ["+/area0/v5/#"] + [f"w{i}/area0/v5/k{i}" for i in range(40)]
+    twins = []
+    for covering in (True, False):
+        node, sinks = _node(covering, pop, **conf)
+        sinks.append(Sink())
+        sid = node.broker.register(sinks[-1], "across")
+        for f in across:
+            node.broker.subscribe(sid, f, {"qos": 0})
+        twins.append((node, sinks))
+    (on, on_sinks), (off, off_sinks) = twins
     lives, _keys = _window(pop, 41, 2, 96)
+    both = pop.topic(5)
+    assert both == "org0/area0/v5/c5"
+    lives[0] += [make("pub", 0, both, b"x%d" % i) for i in range(5)]
     h_on, n_on = _serve(on, lives)
     _h, n_off = _serve(off, lives)
     assert int(h_on.res.matches.shape[-1]) == 64
-    assert on.device_engine._tables.shapes.cover.cand_pad.shape[0] == 32
-    under = sum(m.topic.split("/")[1].startswith("area")
+    st = on.device_engine.stats()
+    assert st["cover"]["cand_cap"] == 64 < on.device_engine.cover_cand_cap
+    assert st["cover"]["largest_segment"] == 50 \
+        and st["cover"]["wide_roots"] == 0 \
+        and st["cover"]["covered"] == AREAS * 49 + 40
+    under = sum(m.topic.startswith("org0/area0/v5/")
                 for msgs in lives for m in msgs)
-    assert 0 < under < sum(map(len, lives))
+    assert 5 <= under < 20
     m = on.metrics
     assert m.val("routing.device.cover_overflow") == under \
         == m.val("routing.device.host_fallback")
     assert m.val("routing.device.match_overflow") == 0 \
         and m.val("routing.device.fanout_overflow") == 0
-    assert on.device_engine.stats()["cover_overflow"] == under
+    assert st["cover_overflow"] == under
     assert off.metrics.val("routing.device.host_fallback") == 0
     assert n_on == n_off
     for a, b in zip(on_sinks, off_sinks):
@@ -215,6 +234,7 @@ def test_candidates_past_cand_cap_go_to_the_host_and_are_counted():
         for topic in {t for _f, t, _p in a.got}:
             assert [p for _f, t, p in a.got if t == topic] \
                 == [p for _f, t, p in b.got if t == topic]
+    assert len(on_sinks[-1].got) == under
 
 
 @pytest.mark.parametrize("backend", ["shapes", "trie"])

@@ -300,7 +300,7 @@ def test_a_padded_window_equals_the_step_programs(backend, plan, stages,
         args = (None, None, None)
         in_row_0 = {f: None if getattr(probe, f) is None else np.array(
             [getattr(probe, f)] + [0] * (W8 - 1), np.int32)
-            for f in ("nfa_wide_steps", "cover_candidates")}
+            for f in ("nfa_wide_steps", "cover_candidates", "cover_roots")}
         want = want._replace(**in_row_0)
     if "delta" in stages:
         delta = RE.WindowDelta(fx["delta"], dbase if wplan else None)
@@ -335,7 +335,8 @@ def test_a_padded_window_equals_the_step_programs(backend, plan, stages,
             assert a.dtype == b.dtype and a.shape == b.shape, name
             assert (a == b).all(), name
     assert (got.nfa_wide_steps is not None) == fx["trie"]
-    assert (got.cover_candidates is not None) == fx["cover"]
+    assert (got.cover_candidates is not None) == fx["cover"] \
+        == (got.cover_roots is not None)
     if "compact" in stages:
         assert not np.asarray(got.compact.row_overflow).any()
 

@@ -60,9 +60,16 @@ def cover_heavy_filters(n: int, *, cover_ratio: float = 0.5,
     that cover nothing. Covered filters extend an umbrella's prefix by
     1-2 levels, every third one through a '+' (covered-with-wildcard is
     the case naive prefix tricks get wrong; the device detection must
-    still fold it). Umbrella fan-in stays far below the engine's
-    per-cover own_budget so the requested ratio is what the snapshot
-    actually detects."""
+    still fold it). At the sizes the tests draw, an umbrella's fan-in
+    stays under what the engine lets a root own (its candidate ceiling
+    less the other slots of the roots' match row, 192 by default), so
+    the requested ratio is what the snapshot detects. Past that (a
+    depth-1 umbrella `d{k}/#` covers every filter under its word: n /
+    97 of them) the build finds the umbrella wide: it owns nothing and
+    stays a root alone, and the narrower umbrellas under it are roots
+    again and own their filters (`ops/cover.assign_owners`; the
+    benchmark's `tenant-umbrella` is that deployment), so the detected
+    ratio is that of the filters some narrower umbrella covers."""
     if not 0 <= cover_ratio < 1:
         raise ValueError(f"cover_ratio {cover_ratio} outside [0, 1)")
     rng = np.random.RandomState(seed)
