@@ -21,24 +21,33 @@ Round-2 rework:
   (host batches ride the same in-order queue and are routed at consume
   time, never early). What is in flight is also bounded in DELIVERIES
   (`_ROWS_IN_FLIGHT`, by the last plans' rows a message): no window
-  forms while the formed windows and the lanes' plans stand for more,
-  `max_pending` shrinks by the same measure and a host probe is cut to
-  `_PROBE_ROWS`, so at a fan-out of 1,000 the publishers feel the
-  lanes instead of 100k messages queueing; at a fan-out of 2 the
-  bounds are idle.
+  forms while the formed windows and the lanes' plans stand for more
+  and `max_pending` shrinks by the same measure, so at a fan-out of
+  1,000 the publishers feel the lanes instead of 100k messages
+  queueing; at a fan-out of 2 the bounds are idle.
 - **Adaptive with live probes both ways**: the device/host choice compares
-  measured EWMA costs. The host cost is refreshed by an ACTIVE probe every
-  `host_probe_every` device batches (round 2's estimator starved: under
-  steady device load the host was never sampled and `device_bypassed`
-  could not fire); the device cost is re-probed every `_PROBE_EVERY`
-  bypassed batches so a transiently slow device is not written off
-  forever (that re-try's sample replaces the estimate outright). The
-  chip is left only where the host is ahead by `_LEAVE_MARGIN` and taken
-  back at parity: two paths that cost about the same do not trade places
-  by the sample.
+  measured EWMA costs. The host cost is refreshed by an ACTIVE probe
+  (round 2's estimator starved: under steady device load the host was
+  never sampled and `device_bypassed` could not fire). A probe costs
+  what a measurement needs: it holds at most `_PROBE_MSGS` messages and
+  `_PROBE_ROWS` deliveries (the rest of the queue forms the next device
+  window at once, behind it in the same FIFO), its sample is the host
+  route's own time (the turns other coroutines took inside its yields
+  are not the host's cost), and it comes every `host_probe_every`
+  device sub-batches only while the two costs are within a factor of
+  two: while the chip wins by half the gap doubles probe by probe up
+  to `_PROBE_GAP_MAX`, and falls back on the first comparison that
+  reads otherwise (`_probe_due`, `_probe_gap`). The device cost is
+  re-probed every `_PROBE_EVERY` bypassed batches so a transiently
+  slow device is not written off forever (that re-try's sample
+  replaces the estimate outright). The chip is left only where the
+  host is ahead by `_LEAVE_MARGIN` and taken back at parity: two paths
+  that cost about the same do not trade places by the sample.
   Pipelined device cost is sampled as completion-to-completion time (the
   amortized rate the pipeline actually delivers), not the full round-trip
-  — except across an idle gap, where the round-trip is the sample.
+  — except across an idle gap, where the round-trip is the sample, and
+  never from a window the process compiled under (a class's first call
+  says nothing of its next).
 
 Round-10 rework (ISSUE 9 tentpole) — the **double-buffered window
 pipeline**: at ``dispatch_depth >= 2`` the consumer becomes a bounded
@@ -94,8 +103,37 @@ _LEAVE_MARGIN = 1.25
 # at a fan-out of 128, fifty at 2.5, which is more than a flood's closed
 # loop holds, so at a narrow fan-out the bound is idle
 _ROWS_IN_FLIGHT = 1 << 17
-# deliveries the chooser's host probe may stand for (`_probe_cap`)
+# what the chooser's host probe may hold (`_probe_cap`): deliveries,
+# and messages. The host's cost is a message's, so 64 measure what a
+# full batch does, and 64 is one un-yielded stretch of
+# `_complete_host`'s walk: a probe is then one short turn of the loop
+# and not a batch of ~920 messages. Measured in `plus-100k.flood` on a
+# v5e's host (fan-out 2.05; PERF.md, PR 43, one seed a side on this
+# tree): `host_route` 395 -> 15.8 ms a probe at 234-243 us of loop a
+# message, `device_routed_share` 96.9 -> 99.99 %, the batcher's share
+# of the loop 198 -> 61-91 ms/s, 41,964 -> 50,037 deliveries/s (+19 %;
+# six pairs before the last edit of `_produce`: +18.2 %)
 _PROBE_ROWS = 1 << 13
+_PROBE_MSGS = 64
+# the most device sub-batches between two host probes: the gap starts
+# at `host_probe_every` and doubles, probe by probe, while the chip
+# wins by half (`_probe_gap`): 32 -> 1,024 by a run's fifth probe,
+# after 992 sub-batches. What the gap alone is worth, measured against
+# this tree with the gap held at 32 (PERF.md, PR 43): nothing in
+# `plus-100k.flood` (50,794 without, 50,037 with: 51 probes of 16 ms
+# or 5), and `fleet-bcast.flood`, where a message is 110 deliveries
+# and 10-15 ms of host route and a 64-message probe 0.6-1.0 s of
+# loop: 409-415k deliveries/s with 9 probes a run, 439-449k with 3-4.
+# A snapshot swap that makes the host cheaper is met after this many
+# sub-batches at the latest (~35 s at `plus-100k.flood`'s 29 a
+# second), and matters only where the host would have to get 2 x
+# cheaper than its last sample to change a verdict
+_PROBE_GAP_MAX = 1 << 10
+# `chooser_margin` under which a landed probe doubles the gap. PR 38's
+# dead band was measured at 0.4-1.7 (`_LEAVE_MARGIN`): there, and
+# wherever the two costs are within a factor of two, the cadence
+# stays `host_probe_every`
+_PROBE_RARER_MARGIN = 0.5
 
 
 def resolve_dispatch_depth(configured=None) -> int:
@@ -224,7 +262,13 @@ class PublishBatcher:
         self._on_host = False         # the last cost comparison chose it
         self._dev_reprobe = False     # next device sample is that re-try's
         self._since_host_probe = 0    # device batches since last host probe
+        # times the gap between host probes has doubled (`_probe_gap`),
+        # and whether a probe's sample landed since the last comparison
+        self._probe_doublings = 0
+        self._probe_out = False
+        self._probe_landed = False
         self._last_dev_done: Optional[float] = None
+        self._compiles_seen = 0       # `tele.compiles` at the last sample
         self._consuming = False       # consumer mid-entry (fast-path gate)
 
     # ---- producer side --------------------------------------------------
@@ -359,16 +403,23 @@ class PublishBatcher:
     # ---- producer: form batches, choose path, dispatch ------------------
     async def _produce(self) -> None:
         loop = asyncio.get_running_loop()
+        # messages a cut host probe left of the batch it was cut from:
+        # they form the next batch at once and by themselves, as the
+        # uncut batch would have. A beat's wait here is a turn of the
+        # loop in which other connections' bursts land behind them, and
+        # a batch that mixes two groups of connections puts them in
+        # step for good (`share50-250k.flood`: every run at a fuse
+        # depth of 2.0 with the loop a fifth idle; PERF.md, PR 43)
+        rest = 0
         while True:
             while self._queue:
                 # adaptive window: the first message opened it; give
                 # concurrent connections one short beat to pile on unless
                 # already full
-                if len(self._queue) < self.max_batch and self.window_s > 0:
+                if not rest and len(self._queue) < self.max_batch \
+                        and self.window_s > 0:
                     await asyncio.sleep(self.window_s)
-                def form_entry(cap=None):
-                    limit = min(self.max_batch, cap) if cap else \
-                        self.max_batch
+                def form_entry(limit):
                     batch = []
                     rec = self.rec
                     sampled = None
@@ -429,8 +480,13 @@ class PublishBatcher:
                         and self._rows_in_flight() > _ROWS_IN_FLIGHT:
                     await pool.progress()   # a plan done, or `_take`
                 # a host probe is one batch at host speed, a delivery
-                # at a time: bounded in deliveries too
-                group = [form_entry(self._probe_cap())]
+                # at a time: bounded in messages and in deliveries
+                cap = self._probe_cap()
+                # the batch as it stands: what a cut probe left of the
+                # last one, or the queue up to `max_batch`
+                whole = rest or min(self.max_batch, len(self._queue))
+                group = [form_entry(min(whole, cap or whole))]
+                rest = whole - len(group[0]["batch"])
                 try:
                     await self._fold_hooks(group[0])
                     if self.engine is not None:
@@ -499,7 +555,7 @@ class PublishBatcher:
                                <= _ROWS_IN_FLIGHT):
                             # later sub-batches must stay inside the
                             # window class too
-                            e2 = form_entry(cap=b_std)
+                            e2 = form_entry(min(self.max_batch, b_std))
                             await self._fold_hooks(e2)
                             group.append(e2)
                     lives = [e["live"] for e in group if e["live"]]
@@ -694,11 +750,11 @@ class PublishBatcher:
     async def _complete_host(self, entry: dict, routed=None) -> None:
         """Route an entry host-side (or publish a device result) and
         resolve its futures. Raises nothing. Yields every 64 routed
-        messages — a 1024-message host fallback otherwise stalls the
-        whole event loop for tens of ms. Safe against reordering: the
-        trickle caller runs in the producer task (nothing can enqueue
-        behind it while it awaits) and the consumer is strictly
-        sequential."""
+        messages (not after the last) — a 1024-message host fallback
+        otherwise stalls the whole event loop for tens of ms. Safe
+        against reordering: the trickle caller runs in the producer
+        task (nothing can enqueue behind it while it awaits) and the
+        consumer is strictly sequential."""
         batch = entry["batch"]
         counts = [0] * len(batch)
         tele = self.tele
@@ -747,6 +803,7 @@ class PublishBatcher:
                 with spans.span("host_route", tid, track="host",
                                 parent=entry.get("replay_span")
                                 or entry.get("root_span", 0)) as sp:
+                    last = len(live) - 1
                     for j, m in enumerate(live):
                         if tele is not None and j % 32 == 0:
                             # sampled host match split: the host-side
@@ -760,12 +817,17 @@ class PublishBatcher:
                         else:
                             mt = broker.router.match(m.topic)
                         routed.append(broker._route(m, mt))
-                        if j % 64 == 63:
+                        if j % 64 == 63 and j < last:
                             with sp.released():
                                 await asyncio.sleep(0)
+                # the host's own time: what `emqx:host_route` covered,
+                # not the turns other coroutines took inside its yields
+                # (each ~30 ms of ready callbacks under load)
                 self._host_msg_s, self._host_spike = _ewma(
-                    self._host_msg_s, sp.dur / len(live),
+                    self._host_msg_s, (sp.dur - sp.away) / len(live),
                     self._host_spike)
+                if self._probe_out:
+                    self._probe_out, self._probe_landed = False, True
                 # a host completion breaks the device completion chain:
                 # the next device sample must be a full round-trip, not
                 # completion-to-completion across this host batch
@@ -1049,19 +1111,36 @@ class PublishBatcher:
         return (self._formed - self._taken) * self._rows_per_msg \
             + (pool.live_rows if pool is not None else 0)
 
+    def _probe_gap(self) -> int:
+        """Device sub-batches between two scheduled host probes:
+        `host_probe_every`, doubled once for every probe in a row whose
+        sample left the chip ahead by half (`_device_worth_it`), up to
+        `_PROBE_GAP_MAX`."""
+        every = self.host_probe_every
+        return max(every, min(every << self._probe_doublings,
+                              _PROBE_GAP_MAX))
+
+    def _probe_due(self) -> bool:
+        """The next decision sends its batch to the host to seed or
+        refresh the host's cost: a device cost is known and the host's
+        is not, or `_probe_gap` sub-batches went to the chip since the
+        last probe."""
+        return self._dev_batch_s is not None and (
+            self._host_msg_s is None
+            or self._since_host_probe >= self._probe_gap())
+
     def _probe_cap(self) -> Optional[int]:
-        """How many messages the next batch may hold where it is due
-        to be the chooser's host probe (`_device_worth_it`) and a full
-        batch would stand for more than `_PROBE_ROWS` deliveries on
-        the host route: the host's cost is a message's, so a probe of
-        74 messages at a fan-out of 110 measures what one of 1,024
-        does, in a fourteenth of the seconds. None: a full batch."""
-        if self._dev_batch_s is None or not (
-                self._host_msg_s is None
-                or self._since_host_probe >= self.host_probe_every):
+        """How many messages the next batch may hold where it is due to
+        be the chooser's host probe (`_probe_due`): `_PROBE_MSGS`, or
+        what stands for `_PROBE_ROWS` deliveries where that is fewer
+        (40 at a fan-out of 200), never under `device_min_batch` (a
+        smaller batch would not reach the chooser). What the probe
+        leaves in the queue forms the next device window at once.
+        None: no probe is due, a full batch."""
+        if not self._probe_due():
             return None
         cap = max(self.device_min_batch,
-                  int(_PROBE_ROWS / self._rows_per_msg))
+                  min(_PROBE_MSGS, int(_PROBE_ROWS / self._rows_per_msg)))
         return cap if cap < self.max_batch else None
 
     def _pending_limit(self) -> int:
@@ -1263,12 +1342,23 @@ class PublishBatcher:
         latency (from `t0`, the window's stage start) otherwise — and
         never across an idle gap: a window that started after the last
         completion did not wait behind it (the first window of a burst
-        would otherwise sample the whole pause before it)."""
+        would otherwise sample the whole pause before it). No sample
+        where the telemetry counted a compile since the last one."""
         start = t0 or done
         if busy and self._last_dev_done is not None:
             start = max(start, self._last_dev_done)
         sample = (done - start) / n_subs
         self._last_dev_done = done
+        tele = self.tele
+        if tele is not None and tele.compiles != self._compiles_seen:
+            # a window the process compiled under is no cost sample
+            # (a first call of a class: hundreds of ms that say
+            # nothing of the next window). Two in a row used to read
+            # as a sustained slowdown and write the device off for
+            # `_PROBE_EVERY` host batches; the host's sample hid that
+            # while a cold first yield was billed to it (PERF.md, PR 43)
+            self._compiles_seen = tele.compiles
+            return
         if self._dev_reprobe:
             # the scheduled re-try of a written-off device measures an
             # estimate no sample has touched for _PROBE_EVERY host
@@ -1284,7 +1374,7 @@ class PublishBatcher:
     def _device_worth_it(self, n: int) -> bool:
         """Measured-cost routing choice with active probes BOTH ways: the
         device is re-tried every _PROBE_EVERY host batches, and the host is
-        re-sampled every host_probe_every device batches (otherwise the host
+        re-sampled every `_probe_gap` device sub-batches (otherwise the host
         estimate starves under steady device load and the bypass can never
         engage — round-2 weak #2). The decision runs on the FIRST batch of
         a prospective window (n = its live count) before any fusion;
@@ -1294,16 +1384,21 @@ class PublishBatcher:
         if self._dev_batch_s is None:
             count("routing.chooser.first")
             return True      # optimistic: measure the device first
-        if self._host_msg_s is None \
-                or self._since_host_probe >= self.host_probe_every:
+        if self._probe_due():
             # active host probe: route this one host-side to seed/refresh
-            # the estimate (costs one batch at host speed). Without it the
-            # host cost is never measured under steady device load and the
-            # bypass can never engage (round-2 weak #2). Counters reset at
-            # DECISION time — resetting at consume time would turn one
-            # scheduled probe into a pipeline_depth-long probe burst.
+            # the estimate (costs one batch of `_probe_cap` messages at
+            # host speed). Without it the host cost is never measured
+            # under steady device load and the bypass can never engage
+            # (round-2 weak #2). Counters reset at DECISION time —
+            # resetting at consume time would turn one scheduled probe
+            # into a pipeline_depth-long probe burst.
             self._since_host_probe = 0
+            # the seeding probe's lone sample makes no probe rarer
+            self._probe_out = self._host_msg_s is not None
             count("routing.chooser.host_probe")
+            # messages the probes routed; outside `routing.chooser.*`,
+            # which holds verdicts alone (they are summed as such)
+            count("routing.host_probe.msgs", n)
             return False
         if self._since_probe >= _PROBE_EVERY:
             self._since_probe = 0
@@ -1314,6 +1409,15 @@ class PublishBatcher:
         if host_s > 0:
             # < 1: the chip wins this window by the two measured costs
             self.chooser_margin = self._dev_batch_s / host_s
+        # host probes come rarer while the chip wins by half: one
+        # doubling a probe whose sample has landed, and back to
+        # `host_probe_every` at the first comparison that reads closer
+        if self._dev_batch_s >= host_s * _PROBE_RARER_MARGIN:
+            self._probe_doublings = 0
+        elif self._probe_landed \
+                and self._probe_gap() < _PROBE_GAP_MAX:
+            self._probe_doublings += 1
+        self._probe_landed = False
         # a dead band: back on the chip at parity, off it past the margin
         if self._dev_batch_s <= host_s * (
                 1.0 if self._on_host else _LEAVE_MARGIN):
@@ -1328,7 +1432,8 @@ class PublishBatcher:
 
     def chooser_state(self) -> dict:
         """The `chooser` section of `PipelineTelemetry.snapshot()`: the
-        two cost EWMAs and the margin of the last cost comparison."""
+        two cost EWMAs, the margin of the last cost comparison and the
+        device sub-batches between two host probes as it stands."""
         out = {}
         if self._dev_batch_s is not None:
             out["dev_batch_ms"] = round(self._dev_batch_s * 1e3, 4)
@@ -1336,6 +1441,7 @@ class PublishBatcher:
             out["host_msg_us"] = round(self._host_msg_s * 1e6, 4)
         if self.chooser_margin is not None:
             out["margin"] = round(self.chooser_margin, 4)
+        out["probe_gap"] = self._probe_gap()
         return out
 
 

@@ -63,6 +63,8 @@ CONN_TAKING_OVER = "taking_over"
 CONN_DISCONNECTED = "disconnected"
 
 _ASSIGNED_SEQ = iter(range(1, 1 << 62))
+# what a shared QoS 0 frame stands in for (`Channel._shared_write`)
+_SESSION_DELIVER = Session.deliver
 
 
 class ProtocolError(Exception):
@@ -1085,13 +1087,16 @@ class Channel:
     def _shared_write(self) -> Optional[Callable[[bytes], None]]:
         """The transport's raw write where this connection, as it is
         now, can take frames that are the same for every subscriber;
-        else None."""
+        else None. The shared frame stands in for `Session.deliver`, so
+        an overridden `deliver` (a subclass's, or one patched in) is
+        honoured: such a session sees every delivery instead."""
         raw = self.send_frames
         if raw is None or self.session is None \
                 or self.conn_state != CONN_CONNECTED \
                 or self.alias_out_max or self.mountpoint or self._aborted \
                 or self.session.conf.upgrade_qos \
-                or self.mqtt.get("ignore_loop_deliver"):
+                or self.mqtt.get("ignore_loop_deliver") \
+                or type(self.session).deliver is not _SESSION_DELIVER:
             return None
         return raw
 

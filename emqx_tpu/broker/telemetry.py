@@ -420,8 +420,10 @@ class PipelineTelemetry:
                 decisions[extra] = v
         # the chooser: why each window went where it went. `margin` is
         # dev_batch / (n * host_msg) of the last cost comparison (< 1:
-        # the chip wins); `verdicts` counts every decision by reason
-        # (first, host_probe, device_probe, cost_device, cost_host)
+        # the chip wins); `probe_gap` the device sub-batches between
+        # two host probes as it stands; `verdicts` counts every decision
+        # by reason (first, host_probe, device_probe, cost_device,
+        # cost_host)
         chooser = {}
         if self.chooser_state_fn is not None:
             try:

@@ -818,27 +818,140 @@ class TestRowsInFlight:
         b._rows_per_msg = 1e6
         assert b._pending_limit() == b.max_batch
 
-    def test_a_host_probe_is_bounded_in_deliveries(self):
-        """The chooser's host probe routes one batch a delivery at a
-        time: where a full batch stands for more than `_PROBE_ROWS`
-        deliveries the probe's batch is cut to what stands for that
-        many; at a narrow fan-out, or where no probe is due, it is a
-        full batch."""
+    @pytest.mark.parametrize("rows_per_msg, due, first, want", [
+        (2.5, True, False, 64),         # a narrow fan-out: `_PROBE_MSGS`
+        (110.75, True, False, 64),      # 8,192 / 110.75 = 73 is more
+        (200.0, True, False, 40),       # `_PROBE_ROWS` deliveries is fewer
+        (1e6, True, False, "min"),      # never under `device_min_batch`
+        (2.5, False, False, None),      # no probe due: a full batch
+        (2.5, False, True, 64),         # the first probe is cut the same
+    ], ids=["fanout2.5", "fanout110", "fanout200", "fanout1e6",
+            "not_due", "first_probe"])
+    def test_a_host_probe_is_bounded_in_messages_and_deliveries(
+            self, rows_per_msg, due, first, want):
+        """The chooser's host probe routes its batch a delivery at a
+        time and measures a cost that is a message's: where one is due
+        the batch is cut to `_PROBE_MSGS` messages, or to what stands
+        for `_PROBE_ROWS` deliveries where that is fewer; where none is
+        due it is a full batch."""
         from emqx_tpu.broker import batcher as bm
+        assert (bm._PROBE_MSGS, bm._PROBE_ROWS) == (64, 8192)
         b = _node(2).publish_batcher
         assert b._probe_cap() is None           # nothing measured yet
-        b._dev_batch_s, b._host_msg_s = 0.01, 1e-5
-        b._since_host_probe = b.host_probe_every
-        b._rows_per_msg = 2.5
-        assert b._probe_cap() is None           # 1,024 x 2.5 is small
-        b._rows_per_msg = 110.75
-        assert b._probe_cap() == int(bm._PROBE_ROWS / 110.75) == 73
-        b._rows_per_msg = 1e6
-        assert b._probe_cap() == b.device_min_batch
-        b._since_host_probe = 0
-        assert b._probe_cap() is None           # no probe due
-        b._host_msg_s = None                    # the first probe
-        assert b._probe_cap() == b.device_min_batch
+        b._dev_batch_s = 0.01
+        b._host_msg_s = None if first else 1e-5
+        b._since_host_probe = b.host_probe_every if due else 0
+        b._rows_per_msg = rows_per_msg
+        assert b._probe_cap() == (b.device_min_batch if want == "min"
+                                  else want)
+
+    def test_one_publishers_stream_through_probes_and_windows_in_order(
+            self, monkeypatch):
+        """`host_probe_every` 1: every other decision is a host probe
+        of at most 64 messages, and the window behind it takes what
+        the probe left in the queue to the chip; one publisher's 2,000
+        messages on one topic still arrive in publish order, once."""
+        from emqx_tpu.broker import batcher as bm
+        monkeypatch.setattr(bm, "_PROBE_GAP_MAX", 1)
+        # the two costs as a chip that wins would leave them, and held
+        # there: what is under test is the interleaving, not the choice
+        monkeypatch.setattr(
+            bm, "_ewma", lambda cur, sample, streak=0: (cur, 0))
+        node = _node(2)
+        b = node.broker
+        sink = RecBatch()
+        b.subscribe(b.register(sink, "c1"), "seq/#", {"qos": 0})
+        bat = node.publish_batcher
+        bat.host_probe_every = 1
+        # many decisions whatever the load: unfused windows of 128
+        bat.max_batch, bat.window_fuse = 128, 1
+        bat._dev_batch_s, bat._host_msg_s = 1e-6, 1.0
+        m = node.metrics
+
+        async def go():
+            eng = node.device_engine
+            eng.rebuild()
+            eng._kick_class_warm()
+            if eng._fuse_warm_task is not None:
+                await eng._fuse_warm_task
+            for k in range(2000):
+                while not bat.enqueue(mkmsg("seq/t", b"%05d" % k)):
+                    await asyncio.sleep(0.001)
+                if k % 500 == 499:
+                    await asyncio.sleep(0.002)
+            for _ in range(3000):
+                if len(sink.got) >= 2000:
+                    break
+                await asyncio.sleep(0.01)
+            await node.deliver_lanes.drain()
+
+        run(go())
+        if m.val("routing.device.batches") == 0:
+            pytest.skip("the device path never engaged")
+        assert [p for _f, _t, p in sink.got] == \
+            [b"%05d" % k for k in range(2000)]
+        probes = m.val("routing.chooser.host_probe")
+        assert probes >= 5 and m.val("routing.device.batches") >= 5
+        assert 0 < m.val("routing.host_probe.msgs") <= 64 * probes
+        assert m.val("routing.chooser.cost_host") == 0
+
+    @pytest.mark.parametrize("every, want", [
+        (None, [64, 872, 500]),
+        # a probe due at every decision: what the first left is cut
+        # again and again, and still by itself (14 x 64 + 40 = 936)
+        (0, [64] * 14 + [40] + [64] * 7 + [52]),
+    ], ids=["one_probe", "a_probe_every_batch"])
+    def test_what_a_cut_probe_leaves_forms_the_next_batch_alone(
+            self, monkeypatch, every, want):
+        """A probe is cut from a batch of 936; 500 messages of other
+        connections land in the queue while its hooks fold. The 872 it
+        left form the next batch at once and by themselves, as the
+        uncut batch would have, and the 500 the one after; where the
+        next probe is due while they wait, it is cut from them alone."""
+        from emqx_tpu.broker import batcher as bm
+        monkeypatch.setattr(
+            bm, "_ewma", lambda cur, sample, streak=0: (cur, 0))
+        node = _node(2)
+        b = node.broker
+        sink = RecBatch()
+        b.subscribe(b.register(sink, "c1"), "seq/#", {"qos": 0})
+        bat = node.publish_batcher
+        bat.window_fuse = 1
+        bat._dev_batch_s, bat._host_msg_s = 1e-6, 1.0
+        if every is not None:
+            bat.host_probe_every = every
+        bat._since_host_probe = bat.host_probe_every     # a probe is due
+        formed = []
+        fold = bat._fold_hooks
+
+        async def noting(entry):
+            formed.append(len(entry["batch"]))
+            if len(formed) == 1:
+                for k in range(500):
+                    assert bat.enqueue(mkmsg("seq/b", b"%05d" % k))
+            await fold(entry)
+        bat._fold_hooks = noting
+
+        async def go():
+            eng = node.device_engine
+            eng.rebuild()
+            eng._kick_class_warm()
+            if eng._fuse_warm_task is not None:
+                await eng._fuse_warm_task
+            for k in range(936):
+                assert bat.enqueue(mkmsg("seq/a", b"%05d" % k))
+            for _ in range(3000):
+                if len(sink.got) >= 1436:
+                    break
+                await asyncio.sleep(0.01)
+            await node.deliver_lanes.drain()
+
+        run(go())
+        assert formed == want
+        assert [p for _f, t, p in sink.got if t == "seq/a"] == \
+            [b"%05d" % k for k in range(936)]
+        assert node.metrics.val("routing.host_probe.msgs") == \
+            (64 if every is None else 1436)
 
     def test_windows_wait_for_the_lanes_rows_not_their_count(
             self, monkeypatch):
@@ -1005,6 +1118,47 @@ class TestSharedFrame:
         assert with_frames[1] == 2 and with_frames[2] is True
         if case != "no_local_own":
             assert with_frames[0]
+
+
+    @pytest.mark.parametrize("entry", ["deliver_frames", "deliver_batch"])
+    def test_a_session_with_a_deliver_of_its_own_sees_every_delivery(
+            self, entry, monkeypatch):
+        """The shared frame stands in for `Session.deliver`: where that
+        is not the session's `deliver` (the benchmark's controls patch
+        one in to lose, duplicate or reorder) the run goes through it,
+        by either entry of the lanes."""
+        from emqx_tpu.broker.connection import Listener
+        from emqx_tpu.broker.session import Session
+        from emqx_tpu.client import Client
+        node = Node({"broker": {"deliver_lanes": 2}})
+        seen = []
+        real = Session.deliver
+
+        def tapped(self, msgs):
+            seen.extend(m.topic for m, _so in msgs)
+            return real(self, msgs)
+
+        async def go():
+            lst = Listener(node, bind="127.0.0.1", port=0)
+            await lst.start()
+            c = Client(port=lst.port, clientid="me")
+            await c.connect()
+            await c.subscribe("t/#", qos=0)
+            ch = next(iter(node.broker._subscribers.values()))
+            views = [DeliveryView(Message(topic=f"t/{i}", payload=b"p",
+                                          qos=0, from_="pub"),
+                                  OPT_TABLE[0]) for i in range(3)]
+            items = [("t/#", v) for v in views]
+            assert ch._shared_write() is not None
+            monkeypatch.setattr(Session, "deliver", tapped)
+            assert ch._shared_write() is None
+            if entry == "deliver_frames":
+                assert ch.deliver_frames(lambda *a: b"", 0, 3) is False
+            assert ch.deliver_batch(items) == 3
+            await lst.stop()
+
+        run(go())
+        assert seen == ["t/0", "t/1", "t/2"]
 
 
 class TestDeliveryView:

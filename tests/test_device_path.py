@@ -439,6 +439,35 @@ class TestAdaptiveDeviceChoice:
         assert b._dev_batch_s == pytest.approx(0.02)
         assert b._dev_spike == 0
 
+    def test_a_window_the_process_compiled_under_is_no_cost_sample(self):
+        """Two first calls of two classes in a row (hundreds of ms
+        each) read as a sustained slowdown and wrote the device off
+        for `_PROBE_EVERY` host batches: a window is no sample where
+        the telemetry counted a jit-cache miss since the last one.
+        It still ends the completion chain it is part of, and a
+        pending re-try waits for a clean window."""
+        b, node = self._batcher()
+        tele = b.tele = node.pipeline_telemetry
+        assert tele is not None and tele.compiles == 0
+        tele.compiles = 7                   # the warm-up's
+        b._observe_device_cost(1.0, 1.4, 1, False)
+        assert b._dev_batch_s is None and b._last_dev_done == 1.4
+        assert b._device_worth_it(64)       # still optimistic
+        b._observe_device_cost(2.0, 2.02, 1, False)
+        assert b._dev_batch_s == pytest.approx(0.02)
+        for k in (8, 9):                    # two cold classes in a row
+            tele.compiles = k
+            b._observe_device_cost(3.0 + k, 3.3 + k, 1, False)
+        assert b._dev_batch_s == pytest.approx(0.02)
+        assert b._dev_spike == 0
+        b._dev_reprobe = True
+        tele.compiles = 10
+        b._observe_device_cost(20.0, 20.5, 1, False)
+        assert b._dev_reprobe and b._dev_batch_s == pytest.approx(0.02)
+        b._observe_device_cost(21.0, 21.05, 1, False)
+        assert not b._dev_reprobe
+        assert b._dev_batch_s == pytest.approx(0.05)
+
     def test_device_reprobe_sample_is_adopted(self):
         """A pessimized device estimate recovers on the scheduled
         re-try's own sample, not at alpha a probe period."""

@@ -270,6 +270,12 @@ class TestBitIdentityAB:
                 eng.warm_exchange(2)
                 eng.warm_exchange(4)
 
+            # every wave takes the path under test: the chooser's
+            # verdicts follow what each run happened to compile (a
+            # window compiled under is no cost sample), and a
+            # host-routed wave is a different order source
+            node.publish_batcher._device_worth_it = lambda n: True
+
             async def go():
                 for w in range(4):
                     await asyncio.gather(*[

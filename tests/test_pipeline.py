@@ -111,10 +111,12 @@ class TestNonBlocking:
         # one residual flake under parallel tier-1 load — CHANGES.md)
         assert max(samples) < 0.040, f"loop stalled {max(samples)*1e3:.1f}ms"
 
-    def test_fifo_order_across_device_and_host_batches(self):
+    def test_fifo_order_across_device_and_host_batches(self, monkeypatch):
         """One publisher's messages must arrive in order even when the
         batcher alternates device- and host-routed batches (host batches
         ride the same in-order pipeline, routed at consume time)."""
+        from emqx_tpu.broker import batcher as bm
+        monkeypatch.setattr(bm, "_PROBE_GAP_MAX", 1)    # keep alternating
         node = Node()
         node.publish_batcher.host_probe_every = 1   # alternate every batch
         node.publish_batcher.window_s = 0.001
@@ -140,11 +142,13 @@ class TestNonBlocking:
         assert len(sink.got) == 200
         assert sink.got == sorted(sink.got), "per-publisher order violated"
 
-    def test_slow_device_gets_bypassed(self):
+    def test_slow_device_gets_bypassed(self, monkeypatch):
         """Round-2 weak #2: when the device path is much slower than the
         host path, the active host probe must measure it and the bypass
         must engage (device_bypassed > 0), keeping throughput at host
         speed."""
+        from emqx_tpu.broker import batcher as bm
+        monkeypatch.setattr(bm, "_PROBE_GAP_MAX", 4)    # a probe every 4
         node = Node()
         batcher = node.publish_batcher
         batcher.host_probe_every = 4
@@ -364,6 +368,136 @@ class TestAdaptiveProbes:
         bt._since_host_probe = bt.host_probe_every
         # due a host probe even though the device looks cheap
         assert not bt._device_worth_it(4)
+        assert bt._since_host_probe == 0
+        assert node.metrics.val("routing.chooser.host_probe") == 1
+        assert node.metrics.val("routing.host_probe.msgs") == 4
+
+    @staticmethod
+    def _probed(bt, n=64):
+        """One scheduled host probe from decision to landed sample, as
+        `_device_worth_it` and `_complete_host` leave the state."""
+        bt._since_host_probe = bt._probe_gap()
+        assert bt._probe_due() and not bt._device_worth_it(n)
+        assert bt._probe_out and not bt._probe_due()
+        bt._probe_out, bt._probe_landed = False, True
+
+    def test_the_gap_doubles_while_the_chip_wins_by_half(self):
+        """Margin under 0.5: every probe whose sample has landed
+        doubles the gap at the next cost comparison, 32 -> 1,024 and no
+        further; comparisons between two probes leave it alone."""
+        from emqx_tpu.broker import batcher as bm
+        from emqx_tpu.broker.batcher import PublishBatcher
+        assert bm._PROBE_GAP_MAX == 1024
+        bt = PublishBatcher(Node(use_device=False), None)
+        bt._dev_batch_s, bt._host_msg_s = 0.010, 0.001  # 64 msgs: 0.156
+        gaps = [bt._probe_gap()]
+        for _ in range(7):
+            self._probed(bt)
+            assert bt._probe_gap() == gaps[-1]      # not before it lands
+            assert bt._device_worth_it(64)
+            assert bt.chooser_margin == pytest.approx(0.15625)
+            gaps.append(bt._probe_gap())
+            assert bt._device_worth_it(64)          # no probe between
+            assert bt._probe_gap() == gaps[-1]
+        assert gaps == [32, 64, 128, 256, 512, 1024, 1024, 1024]
+        assert bt.chooser_state()["probe_gap"] == 1024
+        # the probes come after 32, 96, 224, 480, 992 sub-batches
+
+    @pytest.mark.parametrize("n, verdict", [
+        (20, "cost_device"),    # margin 0.5: within a factor of two
+        (12, "cost_device"),    # 0.83
+        (9, "cost_device"),     # 1.11, inside the dead band
+        (4, "cost_host"),       # 2.5: the host wins
+    ])
+    def test_the_gap_returns_where_the_costs_are_within_two(
+            self, n, verdict):
+        from emqx_tpu.broker.batcher import PublishBatcher
+        node = Node(use_device=False)
+        bt = PublishBatcher(node, None)
+        bt._dev_batch_s, bt._host_msg_s = 0.010, 0.001
+        for _ in range(3):
+            self._probed(bt)
+            assert bt._device_worth_it(64)
+        assert bt._probe_gap() == 256
+        # no probe in between: any comparison that reads closer resets
+        assert bt._device_worth_it(n) is (verdict == "cost_device")
+        assert node.metrics.val(f"routing.chooser.{verdict}") >= 1
+        assert bt._probe_gap() == bt.host_probe_every == 32
+        assert bt.chooser_state()["probe_gap"] == 32
+
+    def test_the_seeding_probe_makes_no_probe_rarer(self):
+        from emqx_tpu.broker.batcher import PublishBatcher
+        bt = PublishBatcher(Node(use_device=False), None)
+        bt._dev_batch_s = 0.010
+        assert bt._probe_due() and not bt._device_worth_it(64)
+        assert not bt._probe_out            # one sample is no estimate
+        bt._host_msg_s = 0.001
+        assert bt._device_worth_it(64) and bt._probe_gap() == 32
+
+    def test_the_gap_follows_host_probe_every(self, monkeypatch):
+        """A caller that sets `host_probe_every` (the tests that force
+        alternation do) gets that gap at once; past `_PROBE_GAP_MAX` it
+        is the gap and never doubles."""
+        from emqx_tpu.broker import batcher as bm
+        from emqx_tpu.broker.batcher import PublishBatcher
+        bt = PublishBatcher(Node(use_device=False), None)
+        bt._dev_batch_s, bt._host_msg_s = 0.010, 0.001
+        bt._probe_doublings = 2
+        assert bt._probe_gap() == 128
+        bt.host_probe_every = 5
+        assert bt._probe_gap() == 20
+        bt.host_probe_every = 2000
+        assert bt._probe_gap() == 2000
+        self._probed(bt)
+        assert bt._device_worth_it(64) and bt._probe_gap() == 2000
+        monkeypatch.setattr(bm, "_PROBE_GAP_MAX", 1)
+        bt.host_probe_every = 1
+        bt._probe_doublings = 0
+        self._probed(bt)
+        assert bt._device_worth_it(64) and bt._probe_gap() == 1
+
+    def test_the_hosts_sample_is_its_own_time(self):
+        """`_host_msg_s` is what `emqx:host_route` covered: a coroutine
+        that holds the loop 20 ms in each of the walk's yields (as the
+        lanes and the read loops do under load) does not move it, the
+        stage histogram keeps the whole stage, and no yield follows the
+        last row."""
+        from emqx_tpu.broker.batcher import PublishBatcher
+        node = Node(use_device=False)
+        bt = PublishBatcher(node, None)
+        b = node.broker
+        sink = Sink()
+        b.subscribe(b.register(sink, "c1"), "t/#", {"qos": 0})
+        turns = []
+
+        async def hog():
+            while True:
+                await asyncio.sleep(0)
+                turns.append(time.perf_counter())
+                time.sleep(0.02)
+
+        async def go():
+            live = [mkmsg(f"t/{k}") for k in range(192)]
+            entry = {"batch": [(m, None) for m in live], "live": live,
+                     "live_idx": list(range(192)), "handle": None}
+            task = asyncio.get_running_loop().create_task(hog())
+            await asyncio.sleep(0)          # the hog is ready to run
+            n0 = len(turns)
+            t0 = time.perf_counter()
+            await bt._complete_host(entry)
+            wall = time.perf_counter() - t0
+            n1 = len(turns)
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+            return wall, n1 - n0
+
+        wall, hogged = run(go())
+        assert len(sink.got) == 192
+        assert hogged == 2                  # after rows 63 and 127 only
+        assert wall >= 0.04
+        assert bt._host_msg_s * 192 < wall - 0.035
+        st = node.pipeline_telemetry.snapshot()["stages"]["host_route"]
+        assert st["sum_ms"] >= 40
 
 
 class TestWindowFusion:

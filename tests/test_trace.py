@@ -982,9 +982,13 @@ class TestGcAndChooserCounters:
                               "device_probe": 1, "cost_device": 1,
                               "cost_host": 1}
         assert m.val("routing.device.bypassed") == 1
+        # what the probes routed is counted beside the verdicts, not
+        # among them (a reader sums `routing.chooser.*` as verdicts)
+        assert m.val("routing.host_probe.msgs") == 16
         ch = node.pipeline_telemetry.snapshot()["chooser"]
         assert ch == {"dev_batch_ms": 4.0, "host_msg_us": 1000.0,
-                      "margin": 2.0, "verdicts": verdicts()}
+                      "margin": 2.0, "probe_gap": 32,
+                      "verdicts": verdicts()}
 
 
 # ---------- ISSUE 24: named scopes change metadata only ----------
