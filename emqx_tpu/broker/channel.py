@@ -340,6 +340,7 @@ class Channel:
             pkt.clean_start, clientid, conf, self)
         session.inflight.max_size = conf.max_inflight
         session.on_dropped = self._delivery_dropped
+        session.metrics = self.node.metrics
         self.session = session
         if present:
             self.node.metrics.inc("session.resumed")

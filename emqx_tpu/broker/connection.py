@@ -139,6 +139,13 @@ class Connection:
               (time.perf_counter_ns() - t0 + 500) // 1000)
         m.inc("pipeline.egress.writes")
 
+    def _end_ack(self, sp) -> None:
+        """Leave a run of PUBACKs' emqx:ack span and count what it
+        covered, as the lanes count `pipeline.deliver.lane_us`."""
+        sp.__exit__(None, None, None)
+        self.node.metrics.inc("session.ack_us",
+                              round((sp.dur - sp.away) * 1e6))
+
     def _request_close(self, reason: str) -> None:
         if self._closing is None:
             self._closing = reason
@@ -244,9 +251,16 @@ class Connection:
                 # packet that is no burst and released wherever this
                 # task waits: PUBACKs, PINGREQ fences, SUBSCRIBEs and
                 # the PUBLISHes of a read too small to decode by column
-                ctl = None
+                # and inside it one emqx:ack span a run of PUBACKs (a
+                # subscriber's read is little else): from the first
+                # one's `handle_in` to the write of what the run let
+                # out of the session's mqueue, `session.ack_us`
+                ctl = ack = None
                 try:
                     for item in items:
+                        if ack is not None and type(item) is not P.Puback:
+                            self._end_ack(ack)
+                            ack = None
                         if type(item) is PublishBurst:
                             m.inc("pipeline.ingress.bursts")
                             m.inc("pipeline.ingress.rows", len(item))
@@ -269,6 +283,8 @@ class Connection:
                             m.inc("pipeline.ingress.fallback_frames")
                         if ctl is None:
                             ctl = self._spans.span("control").__enter__()
+                        if ack is None and type(item) is P.Puback:
+                            ack = self._spans.span("ack").__enter__()
                         try:
                             await ctl.run(self.channel.handle_in(item))
                         except ProtocolError as e:
@@ -283,9 +299,14 @@ class Connection:
                             # other task for tens of ms (handle_in's
                             # awaits don't yield unless they actually
                             # block)
+                            if ack is not None:
+                                self._end_ack(ack)
+                                ack = None
                             with ctl.released():
                                 await asyncio.sleep(0)
                 finally:
+                    if ack is not None:
+                        self._end_ack(ack)
                     if ctl is not None:
                         ctl.__exit__(None, None, None)
                 if items:

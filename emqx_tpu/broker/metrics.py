@@ -56,6 +56,10 @@ DELIVERY_METRICS = [
     "delivery.dropped", "delivery.dropped.no_local",
     "delivery.dropped.too_large", "delivery.dropped.qos0_msg",
     "delivery.dropped.queue_full", "delivery.dropped.expired",
+    # a session's mqueue in the delivery path (ours, not upstream's):
+    # rows parked because the inflight window was full or the client
+    # away, and rows an acknowledgement (or a resume) sent from it
+    "delivery.queued", "delivery.dequeued",
 ]
 CLIENT_METRICS = [
     "client.connect", "client.connack", "client.connected",
@@ -65,6 +69,9 @@ CLIENT_METRICS = [
 SESSION_METRICS = [
     "session.created", "session.resumed", "session.takenover",
     "session.discarded", "session.terminated",
+    # loop microseconds under emqx:ack: subscribers' PUBACKs, from
+    # `handle_in` to the write of what they released (ours)
+    "session.ack_us",
 ]
 AUTHZ_METRICS = ["authorization.allow", "authorization.deny",
                  "authorization.cache_hit"]

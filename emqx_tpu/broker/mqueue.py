@@ -99,7 +99,10 @@ class MQueue:
         return out
 
     def filter(self, pred) -> int:
-        """Drop messages failing pred; returns count dropped (expiry sweep)."""
+        """Remove messages failing pred; returns how many (expiry sweep).
+        They are not `dropped`: that counts what `insert` turned away,
+        the number `delivery.dropped.queue_full` / `.qos0_msg` carry
+        (emqx_mqueue:filter/2 moves `len` alone)."""
         removed = 0
         for q in self._qs.values():
             keep = [m for m in q if pred(m)]
@@ -107,7 +110,6 @@ class MQueue:
             q.clear()
             q.extend(keep)
         self._len -= removed
-        self.dropped += removed
         return removed
 
     def stats(self) -> dict:

@@ -54,16 +54,33 @@ class Session:
         self.created_at = now_ms()
         # counters (emqx_session:info/1)
         self.deliver_count = 0
-        self.enqueue_count = 0
+        self.enqueue_count = 0      # rows parked in the mqueue
+        self.dequeue_count = 0      # rows that left it for the wire
         # wired by the owning channel: callable(msg, reason) invoked when
         # the mqueue evicts a message (the reference's delivery.dropped
         # hook + delivery.dropped.queue_full metric)
         self.on_dropped: Optional[Callable[[Message, str], None]] = None
+        # the node's counters, wired by the owning channel and kept
+        # while the session is parked: `delivery.queued` /
+        # `delivery.dequeued` are fed where the two counts above move,
+        # once a call and never a row
+        self.metrics = None
 
     def _mq_insert(self, m: Message) -> None:
+        """The one place a session's mqueue turns a message away, so
+        `mqueue.dropped` and `delivery.dropped.<reason>` are one number
+        wherever `on_dropped` is wired: a QoS 0 message the queue does
+        not store is `qos0_msg`, the oldest of a full queue
+        `queue_full` (emqx_session:handle_dropped)."""
         dropped = self.mqueue.insert(m)
         if dropped is not None and self.on_dropped is not None:
-            self.on_dropped(dropped, "queue_full")
+            self.on_dropped(dropped, "qos0_msg" if dropped is m
+                            else "queue_full")
+
+    def _parked(self, n: int) -> None:
+        self.enqueue_count += n
+        if self.metrics is not None:
+            self.metrics.inc("delivery.queued", n)
 
     # ---- packet id allocation (emqx_session:next_pkt_id) ----
     def alloc_packet_id(self) -> int:
@@ -119,6 +136,7 @@ class Session:
         now. QoS0 → (None, msg); QoS1/2 → allocated id + inflight; window
         full → mqueue."""
         out = []
+        parked = 0
         for msg, subopts in msgs:
             m = self._enrich(msg, subopts)
             if m is None:
@@ -127,13 +145,15 @@ class Session:
                 self.deliver_count += 1
                 out.append((None, m))
             elif self.inflight.is_full():
-                self.enqueue_count += 1
+                parked += 1
                 self._mq_insert(m)
             else:
                 pid = self.alloc_packet_id()
                 self.inflight.insert(pid, ("publish", m))
                 self.deliver_count += 1
                 out.append((pid, m))
+        if parked:
+            self._parked(parked)
         return out
 
     def _enrich(self, msg: Message, subopts: dict) -> Optional[Message]:
@@ -159,11 +179,14 @@ class Session:
 
     def enqueue(self, msgs: list[tuple[Message, dict]]) -> None:
         """Buffer while disconnected (persistent session)."""
+        parked = 0
         for msg, subopts in msgs:
             m = self._enrich(msg, subopts)
             if m is not None:
-                self.enqueue_count += 1
+                parked += 1
                 self._mq_insert(m)
+        if parked:
+            self._parked(parked)
 
     # ---- acks (emqx_session:puback/pubrec/pubcomp) ----
     def puback(self, packet_id: int) -> Message:
@@ -206,6 +229,10 @@ class Session:
             self.inflight.insert(pid, ("publish", m))
             self.deliver_count += 1
             out.append((pid, m))
+        if out:
+            self.dequeue_count += len(out)
+            if self.metrics is not None:
+                self.metrics.inc("delivery.dequeued", len(out))
         return out
 
     # ---- retry (emqx_session:retry/1) ----

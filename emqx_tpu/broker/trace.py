@@ -14,8 +14,10 @@ it (a benchmark, or an operator's `emqx_ctl trace device start`), on
 the same clock as the device's operations. Every dispatch also runs
 under ``StepTraceAnnotation("route_step", step_num=<trace id>)``. Spans
 are per window, per read (`emqx:ingress` its decode and each burst's
-hand-off, `emqx:control` every packet of it that is no PUBLISH burst)
-and per lane item, never per message; on the event-loop thread no
+hand-off, `emqx:control` every packet of it that is no PUBLISH burst,
+`emqx:ack` inside that a run of subscribers' PUBACKs up to the write of
+what they released: `session.ack_us`) and per lane item, never per
+message; on the event-loop thread no
 `emqx:` span encloses a suspension (`span.released()` around an
 `await`, or the coroutine awaited through `span.run()`, which releases
 only while it really waits), or it would bill other coroutines' work to
