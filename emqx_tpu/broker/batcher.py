@@ -838,13 +838,9 @@ class PublishBatcher:
                 # with `routed` precomputed (finish_sub just returned),
                 # host/fallback/replay rungs just finished the trie
                 # walk. Only socket-ingress messages carry a stamp.
-                t_ns = time.perf_counter_ns()
-                tr = entry.get("trace", 0)
-                for m in live:
-                    ing = m.ingress_ns
-                    if ing:
-                        obs.record_routed(m, lpath, (t_ns - ing) / 1e9,
-                                          trace=tr)
+                obs.record_window("routed", live, lpath,
+                                  time.perf_counter_ns(),
+                                  trace=entry.get("trace", 0))
             def _settle() -> None:
                 with spans.span("settle", tid, track="batcher",
                                 parent=entry.get("root_span", 0)):
@@ -864,12 +860,8 @@ class PublishBatcher:
                     # the deliveries are written — inline for host
                     # batches, via the DeliveryPlan done-callback when
                     # the PR 5 lanes own the walk
-                    t_ns = time.perf_counter_ns()
-                    for m in live:
-                        ing = m.ingress_ns
-                        if ing:
-                            obs.record_delivered(m, lpath,
-                                                 (t_ns - ing) / 1e9)
+                    obs.record_window("delivered", live, lpath,
+                                      time.perf_counter_ns())
                 # PUBLISH→route latency sample: oldest enqueue →
                 # completion (covers both host- and device-routed
                 # entries — the device path funnels through here with

@@ -20,8 +20,9 @@ Mechanics:
 - **Two legs**: ``ingress→routed`` (frame decode → route result in
   hand; the SLO objective's leg) and ``ingress→delivered`` (frame
   decode → every delivery written, i.e. the PR 5 delivery plan
-  settled). Both recorded per message at batch settle, keyed by
-  ``(qos, path)`` where path ∈ {device, device_cached, host,
+  settled). Both recorded for every message at batch settle — a
+  window at once, by its (burst stamp, QoS) groups: ``record_window``
+  — keyed by ``(qos, path)`` where path ∈ {device, device_cached, host,
   host_fallback, replay} — a breaker-driven journal replay and a
   prepare-time device fallback each land in their OWN series, so a
   latency regression names its rung.
@@ -35,8 +36,9 @@ Mechanics:
   burn 1.0 = spending the 1% p99 budget exactly at the sustainable
   rate), and **breach exemplars**: a message exceeding the objective
   records a bounded exemplar carrying its window's PR 7 flight-
-  recorder trace id, lands a ``slo_breach`` instant event on that
-  trace, and fires a throttled ``latency.breach`` hook so the tracer
+  recorder trace id, its window lands ONE ``slo_breach`` instant event
+  on that trace (``count`` = the breaching messages it stands for),
+  and fires a throttled ``latency.breach`` hook so the tracer
   logs the causal chain (queue wait vs dispatch vs materialize vs lane
   backpressure) for the exact slow message, not an aggregate.
 
@@ -58,6 +60,9 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
+from itertools import compress, groupby, islice
+from functools import partial
+from operator import attrgetter, eq
 from typing import Optional
 
 SCHEMA = "emqx_tpu.latency/v1"
@@ -91,6 +96,11 @@ _P99_BUDGET = 0.01
 
 _EXEMPLAR_CAP = 16
 _HOOK_MIN_INTERVAL_S = 1.0
+
+# what a window's record reads of a message, and nothing else
+_STAMP = attrgetter("ingress_ns")
+_QOS = attrgetter("qos")
+_ONE = (0,)
 
 
 def resolve_latency_observatory(configured=None) -> bool:
@@ -135,12 +145,14 @@ def resolve_slo_route_p99_ms(configured=None) -> float:
 class LatencyObservatory:
     """Per-node end-to-end latency recorder + SLO engine.
 
-    Hot-path contract: ``record_routed`` / ``record_delivered`` run on
-    the event loop only (batcher settle, host publish path) — one
-    histogram observe plus, on the routed leg, one slot-counter bump;
-    no locks, no allocation beyond the first observation of a new
-    ``(leg, qos, path)`` series. Everything else (burn rates, the
-    section document) is read-side."""
+    Hot-path contract: ``record_window`` (batcher settle: a window at
+    once, by its (burst stamp, QoS) groups) and its one-message forms
+    ``record_routed`` / ``record_delivered`` (host publish path) run on
+    the event loop only — one histogram observe a group plus, on the
+    routed leg, one slot-counter bump a call; no locks. A breaching
+    window costs at most 16 exemplars, one ring event and one hook
+    fire, whatever it holds. Everything else (burn rates, the section
+    document) is read-side."""
 
     def __init__(self, metrics, *, hooks=None, recorder=None,
                  objective_ms: Optional[float] = None):
@@ -157,6 +169,12 @@ class LatencyObservatory:
         self._slots: deque = deque(maxlen=_BURN_WINDOWS[-1][1])
         self.samples = 0           # routed-leg observations
         self.breaches = 0
+        # how the routed leg was taken: windows recorded by the window
+        # form and (stamp, QoS) groups observed by either form, so
+        # groups / samples is the sharing (~0.01 in a flood, 1.0 on a
+        # trickle or a batcher-less node)
+        self.windows = 0
+        self.groups = 0
         self.exemplars: deque = deque(maxlen=_EXEMPLAR_CAP)
         self.hook_fires = 0
         self.hook_throttled = 0
@@ -188,65 +206,156 @@ class LatencyObservatory:
             self._hist[key] = h
         return h
 
+    def record_window(self, leg: str, msgs, path: str, t_ns: int,
+                      trace: int = 0) -> None:
+        """A window's messages (a sequence) at once, on one leg
+        (``"routed"`` or ``"delivered"``), all at the one clock read
+        ``t_ns``. A window of 840 messages from 7 read bursts holds ~7
+        distinct latencies (``ingress_ns`` is one stamp a burst), so
+        the record is taken once a (stamp, QoS) group: C-level passes
+        read the two attributes and count the stamps, and everything
+        after costs a group, not a message. Messages without a stamp
+        (internal publishes) are left out. The numbers are those of
+        one ``record_routed`` / ``record_delivered`` call a stamped
+        message, in order."""
+        if self.clamp > 1:
+            # the per-message tick's own sample: it counts stamped
+            # messages only, and its phase carries across windows
+            msgs = self._sampled(
+                leg, list(compress(msgs, map(_STAMP, msgs))))
+        qoses = set(map(_QOS, msgs))
+        groups = []
+        for qos in qoses:
+            part = msgs if len(qoses) == 1 else compress(
+                msgs, map(partial(eq, qos), map(_QOS, msgs)))
+            # a burst's rows are one run of the window (or a few, where
+            # QoS or a yield cut it): counted a run, merged a stamp
+            tally: dict = {}
+            for ing, run in groupby(map(_STAMP, part)):
+                if ing:
+                    tally[ing] = tally.get(ing, 0) + len(list(run))
+            groups += [((t_ns - ing) / 1e9, qos, n, ing)
+                       for ing, n in tally.items()]
+        if not groups:
+            return
+        if leg == "routed":
+            self.windows += 1
+            self.metrics.inc("pipeline.latency.windows")
+        over = self._observe(leg, path, groups)
+        if over:
+            # the last few messages that carry a breaching stamp, in
+            # message order: a lazy scan from the window's end that
+            # stops at the cap (16 steps where everything breaches)
+            last = list(islice(compress(reversed(msgs), map(
+                set(over[3]).__contains__,
+                map(_STAMP, reversed(msgs)))), _EXEMPLAR_CAP))
+            self._breach(path, trace, over,
+                         [(m, (t_ns - m.ingress_ns) / 1e9)
+                          for m in reversed(last)])
+
     def record_routed(self, msg, path: str, seconds: float,
                       trace: int = 0) -> None:
-        """One message's ingress→routed latency (the SLO leg)."""
-        if self.clamp > 1:
-            self._clamp_tick += 1
-            if self._clamp_tick % self.clamp:
-                self.clamped += 1
-                return
-        self._h("routed", min(msg.qos, 2), path).observe(seconds)
-        self.samples += 1
-        sid = int(time.monotonic() / _SLOT_S)
-        slots = self._slots
-        if not slots or slots[-1][0] != sid:
-            slots.append([sid, 0, 0])
-        cur = slots[-1]
-        cur[1] += 1
-        if seconds > self._objective_s:
-            cur[2] += 1
-            self.breaches += 1
-            self.metrics.inc("pipeline.latency.breaches")
-            self._exemplar(msg, path, seconds, trace)
+        """One message's ingress→routed latency (the SLO leg): the
+        one-message form of ``record_window`` for a caller that holds
+        the latency itself (the batcher-less host path)."""
+        if self.clamp > 1 and not self._sampled("routed", _ONE):
+            return
+        over = self._observe("routed", path,
+                             ((seconds, msg.qos, 1, None),))
+        if over:
+            self._breach(path, trace, over, ((msg, seconds),))
 
     def record_delivered(self, msg, path: str, seconds: float) -> None:
         """One message's ingress→delivered latency (route + the PR 5
         delivery-lane walk / inline delivery, settled)."""
-        if self.clamp > 1:
-            # the delivered leg keeps its OWN 1-in-N phase: deliveries
-            # settle asynchronously (lane done-callbacks), so reusing
-            # the routed tick would sample in window-sized clumps
-            # decided by whichever routed call last moved it
-            self._clamp_tick_d += 1
-            if self._clamp_tick_d % self.clamp:
-                return
+        if self.clamp > 1 and not self._sampled("delivered", _ONE):
+            return
         self._h("delivered", min(msg.qos, 2), path).observe(seconds)
 
-    def _exemplar(self, msg, path: str, seconds: float,
-                  trace: int) -> None:
-        """Breach exemplar: the exact slow message, linked to its
-        window's flight-recorder trace, with the hook throttled so a
-        degraded pipeline (where EVERY message breaches) logs one
-        causal chain per second instead of one per message."""
-        ex = {"topic": msg.topic, "qos": msg.qos, "path": path,
-              "latency_ms": round(seconds * 1000, 3),
-              "trace_id": trace, "ts": round(time.time(), 3)}
-        self.exemplars.append(ex)
+    def _sampled(self, leg: str, seq):
+        """The members of ``seq`` (stamped messages, in order) that the
+        1-in-``clamp`` tick takes. The delivered leg keeps its OWN
+        phase: deliveries settle asynchronously (lane done-callbacks),
+        so reusing the routed tick would sample in window-sized clumps
+        decided by whichever routed call last moved it."""
+        n = len(seq)
+        if leg == "routed":
+            tick = self._clamp_tick
+            self._clamp_tick = tick + n
+        else:
+            tick = self._clamp_tick_d
+            self._clamp_tick_d = tick + n
+        taken = seq[(-tick - 1) % self.clamp::self.clamp]
+        if leg == "routed":
+            self.clamped += n - len(taken)
+        return taken
+
+    def _observe(self, leg: str, path: str, groups):
+        """What both forms share: the histograms and, on the routed
+        leg, the SLO slot. ``groups``: ``(seconds, qos, n, key)``,
+        ``n`` messages of one QoS that waited ``seconds``. Where a
+        routed group is over the objective, returns what ``_breach``
+        needs of them: the slot's clock, their messages' count, the
+        worst latency, their keys."""
+        objective = self._objective_s
+        total = n_over = 0
+        worst = 0.0
+        over = []
+        for seconds, qos, n, key in groups:
+            self._h(leg, min(qos, 2), path).observe_n(seconds, n)
+            total += n
+            if seconds > objective:
+                n_over += n
+                over.append(key)
+                if seconds > worst:
+                    worst = seconds
+        if leg != "routed":
+            return None
+        self.samples += total
+        self.groups += len(groups)
+        self.metrics.inc("pipeline.latency.groups", len(groups))
+        now = time.monotonic()
+        sid = int(now / _SLOT_S)
+        slots = self._slots
+        if not slots or slots[-1][0] != sid:
+            slots.append([sid, 0, 0])
+        cur = slots[-1]
+        cur[1] += total
+        if not n_over:
+            return None
+        cur[2] += n_over
+        self.breaches += n_over
+        self.metrics.inc("pipeline.latency.breaches", n_over)
+        return now, n_over, worst, over
+
+    def _breach(self, path: str, trace: int, over, tail) -> None:
+        """A record's breach (``over``: ``_observe``'s): exemplars for
+        the last of its messages (``tail``: ``(msg, seconds)`` in
+        message order; the deque holds 16, so no more are built), ONE
+        ``slo_breach`` instant event on their window's flight-recorder
+        trace, and the hook throttled so a degraded pipeline (where
+        EVERY message breaches) logs one causal chain per second
+        instead of one per message."""
+        now, n_over, worst, _keys = over
+        ts = round(time.time(), 3)
+        exs = [{"topic": msg.topic, "qos": msg.qos, "path": path,
+                "latency_ms": round(seconds * 1000, 3),
+                "trace_id": trace, "ts": ts} for msg, seconds in tail]
+        self.exemplars.extend(exs)
         rec = self.recorder
         if rec is not None and trace:
             rec.event(trace, "slo_breach", track="latency",
-                      meta={"latency_ms": ex["latency_ms"],
-                            "path": path})
+                      meta={"latency_ms": round(worst * 1000, 3),
+                            "path": path, "count": n_over})
         hooks = self.hooks
         if hooks is not None:
-            now = time.monotonic()
             if now - self._last_hook >= _HOOK_MIN_INTERVAL_S:
                 self._last_hook = now
                 self.hook_fires += 1
-                hooks.run("latency.breach", (ex,))
+                self.hook_throttled += n_over - 1
+                hooks.run("latency.breach", (exs[0],))
             else:
-                self.hook_throttled += 1
+                self.hook_throttled += n_over
 
     def reset(self) -> None:
         """Zero every recorded distribution, slot and exemplar (the
@@ -262,6 +371,8 @@ class LatencyObservatory:
         self._slots.clear()
         self.samples = 0
         self.breaches = 0
+        self.windows = 0
+        self.groups = 0
         self.exemplars.clear()
         # clamp/hook bookkeeping resets with the distributions: the
         # post-reset section's clamp.skipped must describe the
@@ -337,6 +448,7 @@ class LatencyObservatory:
         slo = {
             "objective_p99_ms": self.objective_ms,
             "samples": self.samples,
+            "record": {"windows": self.windows, "groups": self.groups},
             "breaches": self.breaches,
             "burn": self.burn_rates(),
         }

@@ -145,6 +145,14 @@ class Histogram:
         self.sum += v
         self.count += 1
 
+    def observe_n(self, v: float, n: int) -> None:
+        """``n`` observations of one value: a batch whose members share
+        a measurement (a window's messages of one read burst) costs one
+        bucket search, not ``n``."""
+        self.counts[self._index(v)] += n
+        self.sum += v * n
+        self.count += n
+
     def cumulative(self) -> list[tuple[float, int]]:
         """Prometheus-shaped (le, cumulative_count) pairs; the final
         entry is (+Inf, total count)."""

@@ -314,6 +314,282 @@ class TestSloEngine:
         json.dumps(obs.section())
 
 
+# ---------- the window form (ISSUE 45) ----------
+
+class _Clock:
+    """The two clocks the observatory reads, held still inside a
+    window so that both sides of a comparison meet the same slot and
+    the same hook throttle."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def monotonic(self):
+        return self.now
+
+    def time(self):
+        return 1.7e9 + self.now
+
+
+def _window(w, n, stamps, t_ns, over):
+    """`n` messages of window `w`: `stamps` read bursts, every 4th row
+    QoS 1 and every 10th QoS 2, every 7th unstamped; burst b waited
+    0.3 ms + b x 0.1 ms where b >= `over`, else 300 ms + b ms (the
+    objective is 2 ms). The last burst's rows come in two runs with
+    another burst's between them."""
+    msgs = []
+    for i in range(n):
+        b = i * stamps // n
+        if stamps > 2 and b == stamps - 2 and i % 2:
+            b = stamps - 1
+        m = Message(topic=f"w{w}/b{b}/{i}",
+                    qos=2 if i % 10 == 9 else 1 if i % 4 == 3 else 0)
+        if i % 7 != 6:
+            wait = 300_000_000 + b * 1_000_000 if b < over \
+                else 300_000 + b * 100_000
+            m.ingress_ns = t_ns - wait
+        msgs.append(m)
+    return msgs
+
+
+def _per_message(obs, leg, msgs, path, t_ns, trace=0):
+    """The loop the batcher ran before ISSUE 45, one call a message."""
+    for m in msgs:
+        ing = m.ingress_ns
+        if ing:
+            if leg == "routed":
+                obs.record_routed(m, path, (t_ns - ing) / 1e9,
+                                  trace=trace)
+            else:
+                obs.record_delivered(m, path, (t_ns - ing) / 1e9)
+
+
+class TestWindowForm:
+    def _obs(self, clamp):
+        hooks = Hooks()
+        seen = []
+        hooks.add("latency.breach", lambda ex: seen.append(ex))
+        rec = FlightRecorder(Metrics(), cap=8192)
+        obs = L.LatencyObservatory(Metrics(), hooks=hooks, recorder=rec,
+                                   objective_ms=2.0)
+        obs.clamp = clamp
+        return obs, rec, seen
+
+    @pytest.mark.parametrize("clamp", [1, 16])
+    @pytest.mark.parametrize("n,stamps,over", [
+        (840, 7, 7),        # a flood's window: every group breaches
+        (500, 9, 4),        # some groups under, some over
+        (60, 3, 1),         # fewer than 16 breaches a window at clamp 16
+        (20, 1, 1),         # one burst, near 16 breaches a window
+        (40, 5, 0),         # nothing breaches
+    ])
+    def test_a_window_equals_the_per_message_loop(
+            self, monkeypatch, clamp, n, stamps, over):
+        clock = _Clock()
+        monkeypatch.setattr(L, "time", clock)
+        a, rec_a, seen_a = self._obs(clamp)
+        b, rec_b, seen_b = self._obs(clamp)
+        traces = []
+        # three windows in a row: the second in the first's slot and
+        # inside the hook's second, the third in a slot of its own
+        for w, at in enumerate((1000.0, 1000.5, 1011.0)):
+            clock.now = at
+            t_ns = 5_000_000_000 + w * 40_000_000
+            msgs = _window(w, n + w, stamps, t_ns, over)
+            path = ("device", "device_cached", "host")[w]
+            ta, tb = rec_a.new_trace(), rec_b.new_trace()
+            late = [(t_ns - m.ingress_ns) / 1e9 for m in msgs
+                    if m.ingress_ns
+                    and (t_ns - m.ingress_ns) / 1e9 > 0.002]
+            traces.append((ta, len(late), path, late))
+            for leg in L.LEGS:
+                a.record_window(leg, msgs, path, t_ns, trace=ta)
+                _per_message(b, leg, msgs, path, t_ns, trace=tb)
+        assert a._hist.keys() == b._hist.keys()
+        for key, hb in b._hist.items():
+            ha = a._hist[key]
+            assert ha.counts == hb.counts and ha.count == hb.count, key
+            assert ha.sum == pytest.approx(hb.sum, abs=1e-9), key
+        assert b.samples > 0 and a.samples == b.samples
+        assert a.breaches == b.breaches
+        assert a.metrics.val("pipeline.latency.breaches") \
+            == b.metrics.val("pipeline.latency.breaches") == b.breaches
+        assert list(a._slots) == list(b._slots) and len(a._slots) == 2
+        assert a.burn_rates() == b.burn_rates()
+        assert a.clamped == b.clamped and (a.clamped > 0) == (clamp > 1)
+        assert (a._clamp_tick, a._clamp_tick_d) \
+            == (b._clamp_tick, b._clamp_tick_d)
+        assert (a.hook_fires, a.hook_throttled) \
+            == (b.hook_fires, b.hook_throttled)
+        assert len(seen_a) == len(seen_b) == b.hook_fires
+        # the deque holds what the loop left in it, dict for dict
+        # (the trace ids are each side's own)
+        def untraced(exs):
+            return [{k: v for k, v in ex.items() if k != "trace_id"}
+                    for ex in exs]
+        assert untraced(a.exemplars) == untraced(b.exemplars)
+        # one `slo_breach` a breaching window, for all it holds; the
+        # loop left one a message
+        if clamp == 1:
+            marks = [s for s in rec_a.spans() if s.name == "slo_breach"]
+            assert [(s.trace_id, s.meta) for s in marks] == [
+                (t, {"count": c, "path": p,
+                     "latency_ms": round(max(late) * 1000, 3)})
+                for t, c, p, late in traces if c]
+            assert sum(s.name == "slo_breach" for s in rec_b.spans()) \
+                == b.breaches
+        # the record block: one window a routed call that held a stamp
+        rec_doc = a.section()["slo"]["record"]
+        assert rec_doc["windows"] == 3 == a.metrics.val(
+            "pipeline.latency.windows")
+        assert rec_doc["groups"] == a.metrics.val(
+            "pipeline.latency.groups") <= 3 * 3 * stamps
+        # the one-message form counts a group a sample and no window
+        assert b.groups == b.samples and b.windows == 0
+
+    def test_breach_event_names_the_worst_group(self):
+        rec = FlightRecorder(Metrics(), cap=64)
+        obs = L.LatencyObservatory(Metrics(), recorder=rec,
+                                   objective_ms=2.0)
+        t_ns = 9_000_000_000
+        msgs = _window(0, 100, 4, t_ns, 4)
+        tid = rec.new_trace()
+        obs.record_window("routed", msgs, "replay", t_ns, trace=tid)
+        (mark,) = [s for s in rec.spans() if s.name == "slo_breach"]
+        stamped = [m for m in msgs if m.ingress_ns]
+        assert mark.trace_id == tid and mark.meta == {
+            "latency_ms": 303.0, "path": "replay", "count": len(stamped)}
+        # at most 16 exemplars, the window's last, oldest first
+        assert [ex["topic"] for ex in obs.exemplars] \
+            == [m.topic for m in stamped[-16:]]
+        assert len({ex["ts"] for ex in obs.exemplars}) == 1
+
+    def test_unstamped_window_records_nothing(self):
+        obs = L.LatencyObservatory(Metrics(), objective_ms=2.0)
+        msgs = [Message(topic="sys/x", qos=0) for _ in range(5)]
+        for leg in L.LEGS:
+            obs.record_window(leg, msgs, "host", 1_000_000)
+            obs.record_window(leg, [], "host", 1_000_000)
+        assert not obs._hist and not obs._slots and obs.windows == 0
+        assert obs.section()["slo"]["verdict"] == "no_data"
+
+    def test_window_guard_under_a_tenth_of_the_breach_path(self):
+        """The guard ISSUE 45 would have been caught by, deterministic
+        like `test_overhead_guard_under_3pct`: the path every flood
+        window takes (all over the objective, a recorder and a hook
+        chain in place) against the loop's budget, not the path under
+        the objective against a latency. A 1,024-message window of 8
+        stamps on both legs: under 0.3 us a message, and under a tenth
+        of the one-message breach path measured here."""
+        rec = FlightRecorder(Metrics(), cap=4096)
+        tid = rec.new_trace()
+        t_ns = time.perf_counter_ns()
+        msgs = []
+        for i in range(1024):
+            m = Message(topic=f"t/{i}", qos=0)
+            m.ingress_ns = t_ns - 300_000_000 - (i // 128) * 1_000_000
+            msgs.append(m)
+
+        def best(fn, reps, rounds=5):
+            out = float("inf")
+            for _ in range(rounds):
+                t0 = time.perf_counter()
+                for _i in range(reps):
+                    fn()
+                out = min(out, (time.perf_counter() - t0)
+                          / (reps * len(msgs)))
+            return out
+
+        win = L.LatencyObservatory(Metrics(), hooks=Hooks(),
+                                   recorder=rec)
+        one = L.LatencyObservatory(Metrics(), hooks=Hooks(),
+                                   recorder=rec)
+
+        def window():
+            for leg in L.LEGS:
+                win.record_window(leg, msgs, "device", t_ns, trace=tid)
+
+        def loop():
+            for leg in L.LEGS:
+                _per_message(one, leg, msgs, "device", t_ns, trace=tid)
+        per_win, per_one = best(window, 40), best(loop, 2)
+        assert win.breaches == win.samples > 0 and win.groups \
+            == 8 * win.windows
+        assert per_win < 0.3e-6, (
+            f"a window's record costs {per_win * 1e6:.3f} us a message")
+        assert per_win < 0.1 * per_one, (
+            f"window form {per_win * 1e6:.3f} us a message against "
+            f"{per_one * 1e6:.3f} us one by one")
+
+
+class TestServedWindow:
+    def test_a_burst_is_one_window_form_call_a_leg(self):
+        """One read burst through a listener into a node with lanes:
+        the batcher hands the observatory the window once a leg, the
+        two counters move, and the flight recorder holds ONE
+        `slo_breach` for the window, on its trace."""
+        from emqx_tpu.broker.connection import Listener
+        from emqx_tpu.client import Client
+        # an objective nothing meets, so the window breaches whole
+        node = Node({"broker": {"deliver_lanes": 2,
+                                "slo_route_p99_ms": 1e-4}})
+        obs = node.latency_observatory
+        calls = []
+        real = obs.record_window
+
+        def spy(leg, msgs, path, t_ns, trace=0):
+            calls.append((leg, len(msgs), trace))
+            real(leg, msgs, path, t_ns, trace=trace)
+        obs.record_window = spy
+        obs.record_routed = obs.record_delivered = None     # not the path
+        n = 96
+
+        async def go():
+            lst = Listener(node, bind="127.0.0.1", port=0)
+            await lst.start()
+            sub = Client(port=lst.port, clientid="sub")
+            await sub.connect()
+            await sub.subscribe("t/#", qos=0)
+            pub = Client(port=lst.port, clientid="pub")
+            await pub.connect()
+            blob = b"".join(
+                serialize(P.Publish(topic=f"t/{i}", payload=b"x" * 48,
+                                    qos=0), C.MQTT_V4)
+                for i in range(n))
+            assert len(blob) > FrameParser.BURST_SCAN_MIN
+            pub._writer.write(blob)
+            await pub._writer.drain()
+            got = [(await sub.recv()).topic for _ in range(n)]
+            pool = node.deliver_lanes
+            if pool.busy():
+                await pool.drain()
+            await pub.close()
+            await sub.close()
+            await lst.stop()
+            return got
+        got = run(go(), timeout=120)
+        assert got == [f"t/{i}" for i in range(n)]
+        routed = [c for c in calls if c[0] == "routed"]
+        delivered = [c for c in calls if c[0] == "delivered"]
+        # a leg a window, every message in exactly one window
+        assert len(routed) == len(delivered) >= 1
+        assert sum(c[1] for c in routed) == n \
+            == sum(c[1] for c in delivered)
+        m = node.metrics
+        assert m.val("pipeline.latency.windows") == len(routed)
+        assert len(routed) <= m.val("pipeline.latency.groups") < n
+        assert obs.samples == obs.breaches == n
+        sec = node.pipeline_telemetry.snapshot()["latency"]
+        assert sec["slo"]["record"] == {
+            "windows": len(routed),
+            "groups": m.val("pipeline.latency.groups")}
+        marks = [s for s in node.flight_recorder.spans()
+                 if s.name == "slo_breach"]
+        assert [(s.trace_id, s.meta["count"]) for s in marks] \
+            == [(tr, k) for _leg, k, tr in routed]
+        assert all(tr for _leg, _k, tr in routed)
+
+
 # ---------- ingress stamp: burst vs per-packet equivalence ----------
 
 class TestIngressStamp:
